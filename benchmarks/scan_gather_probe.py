@@ -1,6 +1,6 @@
 """Does GSPMD slice the per-layer gather inside nn.scan, or gather the
 whole stacked leaf?  (The question the 8B memory table's scan-stacked
-caveat hinges on — docs/STATUS.md round 3.)
+caveat hinges on, round 3.)
 
 Method: compile the FSDP+gossip step on a small scan+remat Llama over the
 8-device CPU mesh and read the post-partitioner HLO: if all-gather result

@@ -36,12 +36,6 @@ import jax
 
 if os.environ.get("JAX_PLATFORMS"):
     jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-# JAX_COMPILATION_CACHE_DIR="" opts out: memory_analysis() on a
-# cache-deserialized executable reports alias_size_in_bytes == 0, so the
-# memory-contract tests need --compile to run against a fresh build.
-_cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR", "/tmp/bluefog_jax_cache")
-jax.config.update("jax_compilation_cache_dir", _cache_dir or None)
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 
 import jax.numpy as jnp
 import numpy as np
@@ -49,6 +43,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
+from bench import use_compile_cache
 import bluefog_tpu as bf
 from bluefog_tpu import topology_util
 from bluefog_tpu.core import basics
@@ -175,6 +170,10 @@ def execute_truncated(layers_list, batch=1):
 
 
 def main():
+    # JAX_COMPILATION_CACHE_DIR="" opts out: memory_analysis() on a
+    # cache-deserialized executable reports alias_size_in_bytes == 0, so the
+    # memory-contract tests need --compile to run against a fresh build.
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--execute-truncated", nargs="*", type=int, default=None,
                     metavar="LAYERS",

@@ -14,43 +14,6 @@ idiomatic JAX: every collective is a pure function, usable both eagerly on
 per-rank ("rank-major") arrays and inside user ``jit``/``shard_map`` code.
 """
 
-import jax as _jax
-
-if not hasattr(_jax, "shard_map"):
-    # jax < 0.5 ships shard_map under jax.experimental with the same
-    # core keyword signature (mesh/in_specs/out_specs); alias it so the
-    # package (and its tests) run on either generation.  The newer
-    # partial-manual spelling ``axis_names={manual axes}`` maps to the
-    # older complement ``auto={the other mesh axes}``.
-    from jax.experimental import shard_map as _shard_map_mod
-
-    def _shard_map_compat(f, *, mesh, in_specs, out_specs, **kw):
-        axis_names = kw.pop("axis_names", None)
-        if axis_names is not None and "auto" not in kw:
-            kw["auto"] = frozenset(mesh.axis_names) - frozenset(axis_names)
-        if "check_vma" in kw and "check_rep" not in kw:
-            kw["check_rep"] = kw.pop("check_vma")
-        return _shard_map_mod.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-
-    _jax.shard_map = _shard_map_compat
-
-try:
-    # jax 0.4.x shard_map has no replication rule for the remat name
-    # primitive, so ``checkpoint_name`` inside a shard_map'd function dies
-    # with "No replication rule for name".  name_p is identity-shaped —
-    # the standard check/rewrite rules are exactly right for it; newer jax
-    # registers them itself (and this block no-ops on ImportError there).
-    from jax._src.ad_checkpoint import name_p as _name_p
-    from jax.experimental import shard_map as _sm_mod
-
-    if _name_p not in getattr(_sm_mod, "_check_rules", {}):
-        _sm_mod.register_standard_check(_name_p)
-        _sm_mod.register_standard_rewrite(_name_p)
-    del _name_p, _sm_mod
-except (ImportError, AttributeError):  # pragma: no cover - other jax gens
-    pass
-
 from bluefog_tpu.version import __version__
 
 from bluefog_tpu.core.basics import (

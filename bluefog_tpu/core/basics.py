@@ -261,27 +261,6 @@ def _cpu_platform_selected() -> bool:
     return "cpu" in str(plats).replace(" ", "").split(",")
 
 
-def _maybe_enable_cpu_collectives() -> None:
-    """Cross-process collectives on the plain CPU backend need the gloo
-    implementation (jax >= 0.4.34); without it every psum/all-gather across
-    processes raises "Multiprocess computations aren't implemented on the
-    CPU backend".  No-op on jax builds that predate the option."""
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:
-        pass
-
-
-def _distributed_is_initialized() -> bool:
-    """jax < 0.5 has no ``jax.distributed.is_initialized``; fall back to the
-    client handle the service keeps on the module (None until initialize)."""
-    probe = getattr(jax.distributed, "is_initialized", None)
-    if probe is not None:
-        return bool(probe())
-    state = getattr(jax.distributed, "global_state", None)
-    return state is not None and getattr(state, "client", None) is not None
-
-
 def init(
     topology: Optional[nx.DiGraph] = None,
     *,
@@ -310,7 +289,7 @@ def init(
     # NB: probing jax.process_count() here would itself initialize the XLA
     # backend and make jax.distributed.initialize raise — ask the
     # distributed service directly whether it is already up
-    if distributed and not _distributed_is_initialized():
+    if distributed and not jax.distributed.is_initialized():
         # jax.distributed.initialize only auto-detects num_processes /
         # process_id on TPU/Slurm/OMPI — forward bftpu-run's env explicitly
         # so plain multi-host (CPU sim included) bootstraps too
@@ -324,7 +303,10 @@ def init(
         if os.environ.get("JAX_PROCESS_ID"):
             kwargs["process_id"] = int(os.environ["JAX_PROCESS_ID"])
         if _cpu_platform_selected():
-            _maybe_enable_cpu_collectives()
+            # cross-process collectives on the plain CPU backend need gloo;
+            # without it every psum/all-gather across processes raises
+            # "Multiprocess computations aren't implemented on the CPU backend"
+            jax.config.update("jax_cpu_collectives_implementation", "gloo")
         jax.distributed.initialize(**kwargs)
     _context = BlueFogContext(devices=devices, local_size=local_size, topology=topology)
 

@@ -37,6 +37,7 @@ from __future__ import annotations
 import contextlib
 import itertools
 import os
+import threading
 import time
 from typing import Dict, List, Optional, Sequence, Tuple, Union
 
@@ -2839,6 +2840,14 @@ class DistributedWinPutOptimizer:
 
 
 def _spawn_worker(fn, r, nranks, job, args, q, tolerant=False):
+    # an island on this host is a CPU process: a child whose first jnp call
+    # (``broadcast_parameters``, the optimizers) initialised the default
+    # backend would try to take the chip its parent holds.  spawn() starts
+    # us with JAX_PLATFORMS=cpu; the config update covers a forked child,
+    # whose already-imported jax no longer reads the environment.
+    import jax
+
+    jax.config.update("jax_platforms", "cpu")
     try:
         init(r, nranks, job)
         out = fn(r, nranks, *args)
@@ -2873,6 +2882,26 @@ def _spawn_worker(fn, r, nranks, job, args, q, tolerant=False):
 # distinguishes concurrent spawn() calls from one parent: pid alone is not
 # enough (same fn name + nranks would collide on shm job/barrier segments)
 _spawn_counter = itertools.count()
+_spawn_env_lock = threading.Lock()
+
+
+@contextlib.contextmanager
+def _children_on_cpu():
+    """Processes started inside this block inherit ``JAX_PLATFORMS=cpu``.
+    Under the "spawn" start method a child re-imports the parent's main
+    module before ``_spawn_worker`` runs, so the pin has to be in the
+    environment it is born with; the parent's own jax read the variable
+    at import and is unaffected."""
+    with _spawn_env_lock:
+        prev = os.environ.get("JAX_PLATFORMS")
+        os.environ["JAX_PLATFORMS"] = "cpu"
+        try:
+            yield
+        finally:
+            if prev is None:
+                del os.environ["JAX_PLATFORMS"]
+            else:
+                os.environ["JAX_PLATFORMS"] = prev
 
 
 def spawn(fn, nranks: int, job: Optional[str] = None, timeout: float = 120.0,
@@ -2881,7 +2910,9 @@ def spawn(fn, nranks: int, job: Optional[str] = None, timeout: float = 120.0,
     """Run ``fn(rank, size, *args)`` in ``nranks`` processes, each
     auto-``init``-ed; returns the per-rank return values in rank order.  The
     miniature in-process ``bfrun``: tests and notebooks use this, production
-    uses ``bftpu-run --islands`` (one process per host).
+    uses ``bftpu-run --islands`` (one process per host).  The children
+    are pinned to JAX's CPU backend whatever the parent holds: one process
+    owns a chip, and on this host that is the parent.
 
     ``method`` is the multiprocessing start method: the default "spawn" is
     safe after the parent has touched JAX (fresh interpreter per island —
@@ -2907,8 +2938,9 @@ def spawn(fn, nranks: int, job: Optional[str] = None, timeout: float = 120.0,
                        args=(fn, r, nranks, job, args, q, allow_failures))
         for r in range(nranks)
     ]
-    for p in procs:
-        p.start()
+    with _children_on_cpu():
+        for p in procs:
+            p.start()
     results: Dict[int, object] = {}
     failures = []
     deadline = time.monotonic() + timeout

@@ -87,7 +87,7 @@ def make_spmd_comm_fn(
     params-sized pack+unpack per round, trading HBM bandwidth for
     collective count — the right side of that trade depends on leaf
     count and interconnect latency, so it is a measured knob, not a
-    default (see docs/STATUS.md round-4 fusion-buffer entry; the exact
+    default (the exact
     methods in :mod:`bluefog_tpu.algorithms`, whose trees are small and
     carry an odd-shaped push-sum scalar, use it unconditionally)."""
     if fuse and comm_type != CommunicationType.neighbor_allreduce:

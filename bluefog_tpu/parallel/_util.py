@@ -13,33 +13,22 @@ def vma_full(ref, shape, dtype, fill=0.0):
     The safe way to build sentinels/inits inside ``shard_map``: fresh
     ``jnp.full`` constants are unvarying-typed and fail vma checks against
     compute branches, while operand arithmetic (``ref * 0.0``) propagates
-    NaN whenever ``ref`` contains inf.  Outside a trace (or on pre-vma
-    JAX) this is just ``jnp.full``.
+    NaN whenever ``ref`` contains inf.  Outside a trace this is just
+    ``jnp.full``.
     """
     z = jnp.full(shape, fill, dtype)
-    try:
-        vma = tuple(jax.typeof(ref).vma)
-    except (AttributeError, TypeError):
-        return z
-    if not vma:
-        return z
-    if hasattr(lax, "pcast"):
-        return lax.pcast(z, vma, to="varying")
-    if hasattr(lax, "pvary"):
-        return lax.pvary(z, vma)
-    return z
+    vma = tuple(jax.typeof(ref).vma)
+    return lax.pcast(z, vma, to="varying") if vma else z
 
 
 def pvary(x, axis_name):
-    """Re-type a replicated value as varying over ``axis_name`` under
-    shard_map's varying-manual-axes checking, across JAX versions
-    (``pcast`` is current, ``pvary`` its deprecated predecessor, pre-vma
-    JAX needs nothing)."""
-    if hasattr(lax, "pcast"):
-        return lax.pcast(x, axis_name, to="varying")
-    if hasattr(lax, "pvary"):
-        return lax.pvary(x, axis_name)
-    return x
+    """Re-type a value as varying over ``axis_name`` under shard_map's
+    varying-manual-axes checking.  ``pcast`` refuses an axis the value
+    already varies over, so only the missing axes are cast."""
+    axes = (axis_name,) if isinstance(axis_name, str) else tuple(axis_name)
+    have = jax.typeof(x).vma
+    missing = tuple(a for a in axes if a not in have)
+    return lax.pcast(x, missing, to="varying") if missing else x
 
 
 def resolve_axis_size(axis_name: str, axis_size) -> int:
@@ -53,10 +42,7 @@ def resolve_axis_size(axis_name: str, axis_size) -> int:
     inside a trace, an error outside one.
     """
     try:
-        # lax.axis_size is current jax; psum of a literal constant-folds
-        # to the bound axis size as a Python int on versions without it
-        n = (lax.axis_size(axis_name) if hasattr(lax, "axis_size")
-             else lax.psum(1, axis_name))
+        n = lax.axis_size(axis_name)
     except NameError:
         if axis_size is None:
             raise

@@ -263,8 +263,8 @@ def ring_flash_attention(
             # lax.cond between two static-offset calls: the cond's
             # transpose hoists the branches' scalar offset constants to
             # the shard_map boundary, where their (zero) cotangents fail
-            # jax-0.4.x's rep checking — the same class of failure the
-            # tp/pipeline blocks hit (docs/STATUS.md rounds 11-12)
+            # shard_map's replication checking — the same class of failure
+            # the tp/pipeline blocks hit
             delta = 0 if step == 0 else jnp.where(j <= idx, 0, 1)
             o_s, lse_s = flash(q, kb, vb, q_start=0, k_start=delta,
                                causal_=True)

@@ -468,7 +468,7 @@ def make_fsdp_gossip_train_step(
     divisible dimension and lets XLA insert the per-use all-gathers in
     the forward and reduce-scatters on the gradients (the standard GSPMD
     FSDP recipe) — peak transient memory is per-OPERAND, not per-model,
-    which is what closes the memory math at 8B (docs/STATUS.md round 3).
+    which is what closes the memory math at 8B.
 
     Decentralized semantics: each MACHINE holds its own replica (leaves
     gain a leading ``[machines]`` axis, sharded over ``bf_machines``);

@@ -1,0 +1,564 @@
+"""chip_smoke.py — the quickest proof that the trainer still starts on the chip.
+
+One process, the entry points a user calls (``bf.init`` → topology/plan →
+eager gossip and window ops → ``make_decentralized_train_step``), full
+widths, random weights from ``--seed``.  Every phase prints one JSON line;
+the LAST stdout line is
+
+    {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}}
+
+and the exit code is 0 only if JAX found a TPU and every phase passed.  A
+phase that fails raises: the traceback goes to stderr, the last line says
+``"ok": false`` and the exit code is 1.
+
+    python chip_smoke.py             one chip: ops/windows parity, ResNet-50
+                                     b128 224² ATC + allreduce steps, a
+                                     decoder step with the compiled Pallas
+                                     flash kernel vs dense attention, and
+                                     the block_until_ready probe
+    python chip_smoke.py --chips 4   four chips, only what exists across
+                                     chips: parity on exp2(4), placement,
+                                     ResNet-50 ATC vs allreduce, contraction
+    python chip_smoke.py --rehearse  control-flow rehearsal at tiny sizes on
+                                     whatever backend there is; never "ok"
+
+Times printed here are observations of a smoke run, not performance.
+"""
+
+import argparse
+import json
+import os
+import statistics
+import sys
+import time
+
+import jax
+import jax.monitoring
+import jax.numpy as jnp
+import numpy as np
+import optax
+
+import bluefog_tpu as bf
+from bench import use_compile_cache
+from bluefog_tpu import models, native, topology_util
+from bluefog_tpu.core import basics
+from bluefog_tpu.kernels import make_flash_attention_fn
+from bluefog_tpu.kernels.flash_attention import _default_interpret
+from bluefog_tpu.models.transformer import LlamaLM
+from bluefog_tpu.optim import CommunicationType
+from bluefog_tpu.training import (
+    make_decentralized_train_step,
+    make_lm_loss_fns,
+    replicate_for_mesh,
+)
+
+
+# ---------------------------------------------------------------------------
+# sizes: what a user runs (FULL) and what the CPU can rehearse (TINY)
+# ---------------------------------------------------------------------------
+
+FULL = dict(
+    gossip_elems=1 << 20,
+    resnet=dict(model="ResNet50", classes=1000, img=224, batch=128),
+    # benchmarks/llama.py preset "small": hidden 768, 12 heads of 64
+    decoder=dict(vocab=32000, hidden=768, layers=12, heads=12, dff=2048,
+                 seq=2048, batch=8, head_chunks=8, logits_rows=2),
+    probe=dict(dim=4096, iters=512),
+)
+TINY = dict(
+    gossip_elems=256,
+    resnet=dict(model="ResNet18", classes=10, img=16, batch=2),
+    decoder=dict(vocab=256, hidden=64, layers=2, heads=4, dff=128,
+                 seq=128, batch=2, head_chunks=2, logits_rows=1),
+    probe=dict(dim=128, iters=8),
+)
+
+# flash-vs-dense agreement at bf16 compute on seeded weights.  The two paths
+# round the attention output in a different order (one bf16 ulp is 0.4-0.8%)
+# and every later block carries that on, so single logits may part by a few
+# percent of the largest logit while the whole tensor agrees to about a
+# percent (first v5e run: 1.4e-2 relative L2, 1.6 % of the largest logit,
+# over 12 blocks).  A wrong mask, scale or block offset moves both by O(1).
+LOGITS_L2_RTOL = 3e-2
+LOGITS_MAX_RTOL = 5e-2
+LOSS_ATOL = 5e-3
+
+
+class _CompileClock:
+    """Seconds XLA spent compiling (or fetching from the persistent cache)
+    since the last ``take()`` — JAX's own backend-compile duration events,
+    which do not nest the way its trace and lowering events do."""
+
+    def __init__(self):
+        self._secs = 0.0
+        jax.monitoring.register_event_duration_secs_listener(self._on)
+
+    def _on(self, event, secs, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            self._secs += secs
+
+    def take(self):
+        s, self._secs = self._secs, 0.0
+        return s
+
+
+def _emit(phase, t0, clock, **fields):
+    print(json.dumps({
+        "phase": phase,
+        "seconds": round(time.perf_counter() - t0, 3),
+        "compile_seconds": round(clock.take(), 3),
+        **fields,
+    }), flush=True)
+
+
+def _max_abs_diff(a, b):
+    return float(np.max(np.abs(np.asarray(a, np.float64)
+                               - np.asarray(b, np.float64))))
+
+
+def _exp2_mixing_matrix(n):
+    """W for ExponentialTwoGraph(n) written from its definition, not read
+    from the code under test: rank i hears from (i - 2^j) % n, every
+    in-edge and the self-loop weigh 1/(in_degree + 1)."""
+    W = np.zeros((n, n))
+    for i in range(n):
+        srcs = {(i - (1 << j)) % n for j in range(max(n - 1, 0).bit_length())}
+        srcs.discard(i)
+        for s in srcs | {i}:
+            W[i, s] = 1.0 / (len(srcs) + 1)
+    return W
+
+
+def _distinct_devices(tree):
+    return min(len({s.device for s in leaf.addressable_shards})
+               for leaf in jax.tree_util.tree_leaves(tree))
+
+
+def _lowered_text(step_fn, *args):
+    """StableHLO of a train step as the user-facing ``step_fn`` lowers it."""
+    return jax.jit(step_fn).lower(*args).as_text()
+
+
+# ---------------------------------------------------------------------------
+# phase: eager gossip + window ops against W @ x
+# ---------------------------------------------------------------------------
+
+
+def phase_ops_windows(n, elems, seed, clock):
+    t0 = time.perf_counter()
+    W = _exp2_mixing_matrix(n)
+    topo_W = topology_util.GetWeightMatrix(bf.load_topology())
+    assert np.allclose(W, topo_W), f"installed topology is not exp2({n})"
+    rng = np.random.default_rng(seed)
+    # per-rank seeded values: a wrong edge or weight shows as a wrong row
+    x, y, z, u = (rng.normal(size=(n, elems)).astype(np.float32)
+                  for _ in range(4))
+    diffs = {}
+
+    diffs["neighbor_allreduce"] = _max_abs_diff(bf.neighbor_allreduce(x), W @ x)
+
+    bf.win_create(x, "smoke")
+    bf.win_put(y, "smoke")
+    diffs["win_put+win_update"] = _max_abs_diff(bf.win_update("smoke"), W @ y)
+    diffs["win_put_update"] = _max_abs_diff(bf.win_put_update(z, "smoke"), W @ z)
+    bf.win_free("smoke")
+
+    # one push-sum round: column-stochastic sends, the associated weight p
+    # rides the same mailbox.  exp2 is regular, so the column-stochastic
+    # matrix is W again and p must stay 1.
+    out_nbrs = [[r for r in range(n) if r != s and W[r, s] > 0] for s in range(n)]
+    in_nbrs = [[s for s in range(n) if s != r and W[r, s] > 0] for r in range(n)]
+    keep = [1.0 / (len(o) + 1) for o in out_nbrs]
+    bf.turn_on_win_ops_with_associated_p()
+    try:
+        bf.win_create(u, "smoke_ps", zero_init=True)
+        bf.win_accumulate(
+            u, "smoke_ps",
+            dst_weights=[{d: keep[s] for d in out_nbrs[s]} for s in range(n)])
+        got = bf.win_update(
+            "smoke_ps", self_weight=keep,
+            neighbor_weights=[{s: 1.0 for s in in_nbrs[r]} for r in range(n)],
+            reset=True)
+        diffs["push_sum_round"] = _max_abs_diff(got, W @ u)
+        diffs["push_sum_p"] = _max_abs_diff(
+            bf.win_associated_p("smoke_ps"), W @ np.ones(n))
+        bf.win_free("smoke_ps")
+    finally:
+        bf.turn_off_win_ops_with_associated_p()
+
+    jax.block_until_ready(got)
+    worst = max(diffs.values())
+    _emit("ops_windows", t0, clock, ranks=n, elems_per_rank=elems,
+          compared="eager op vs NumPy W @ x, W = exp2 mixing matrix",
+          max_abs_diff=diffs, tolerance=1e-5)
+    assert worst <= 1e-5, f"gossip result differs from W @ x: {diffs}"
+
+
+# ---------------------------------------------------------------------------
+# phase: ResNet train steps through make_decentralized_train_step
+# ---------------------------------------------------------------------------
+
+
+class _ResNetJob:
+    """Seeded ResNet variables and one rank-major batch on ``ctx.mesh``."""
+
+    def __init__(self, cfg, seed):
+        self.cfg = cfg
+        self.ctx = ctx = basics.context()
+        n, b, img = ctx.size, cfg["batch"], cfg["img"]
+        if cfg["model"] == "ResNet50":
+            self.model = models.ResNet50(num_classes=cfg["classes"])
+        else:  # rehearsal only
+            self.model = models.ResNet18(
+                num_classes=cfg["classes"], num_filters=8, small_images=True)
+        self.variables = jax.jit(
+            lambda key, x: self.model.init(key, x, train=True)
+        )(jax.random.PRNGKey(seed), jnp.ones((b, img, img, 3), jnp.float32))
+        rng = np.random.default_rng(seed)
+        sharding = basics.rank_major_sharding(ctx)
+        self.batch = jax.device_put(
+            rng.normal(size=(n, b, img, img, 3)).astype(np.float32), sharding)
+        self.labels = jax.device_put(
+            rng.integers(0, cfg["classes"], size=(n, b)).astype(np.int32),
+            sharding)
+
+    def build(self, comm_type, plan):
+        """(step_fn, params, batch_stats, opt_state), each phase its own
+        copies because the step donates them."""
+        n = self.ctx.size
+        init_fn, step_fn = make_decentralized_train_step(
+            self.model.apply, optax.sgd(0.1, momentum=0.9), self.ctx.mesh,
+            communication_type=comm_type, plan=plan, has_batch_stats=True,
+            donate=True)
+        params = replicate_for_mesh(self.variables["params"], n)
+        batch_stats = replicate_for_mesh(self.variables["batch_stats"], n)
+        return step_fn, params, batch_stats, init_fn(params)
+
+
+def _all_finite(tree):
+    return bool(jax.jit(lambda t: jnp.all(jnp.stack(
+        [jnp.all(jnp.isfinite(a)) for a in jax.tree_util.tree_leaves(t)])))(tree))
+
+
+def _run_steps(step_fn, state, batch, labels, steps):
+    """``steps`` train steps, each timed on the host clock around
+    ``jax.block_until_ready``.  Returns (state, losses[steps][ranks], secs)."""
+    losses, secs = [], []
+    for _ in range(steps):
+        t = time.perf_counter()
+        *state, loss, _acc = step_fn(*state, batch, labels)
+        jax.block_until_ready((state, loss))
+        secs.append(time.perf_counter() - t)
+        losses.append(np.asarray(loss, np.float64))
+    assert np.isfinite(losses).all(), f"non-finite loss: {losses}"
+    return state, losses, secs
+
+
+def _peak_bytes(ctx):
+    stats = ctx.devices[0].memory_stats() or {}
+    return stats.get("peak_bytes_in_use")
+
+
+def phase_resnet(job, name, comm_type, plan, clock, steps=3):
+    t0 = time.perf_counter()
+    step_fn, params, batch_stats, opt_state = job.build(comm_type, plan)
+    placed = _distinct_devices((params, batch_stats, opt_state,
+                                job.batch, job.labels))
+    assert placed == job.ctx.size, (
+        f"a leaf of params/batch_stats/opt_state/batch sits on {placed} "
+        f"device(s) before step one, not {job.ctx.size}")
+    # gossip is collective-permutes, and only where there are neighbours:
+    # exp2(1) has no edge, and the allreduce step never needs one
+    permutes = "collective_permute" in _lowered_text(
+        step_fn, params, batch_stats, opt_state, job.batch, job.labels)
+    want = (job.ctx.size > 1
+            and comm_type == CommunicationType.neighbor_allreduce)
+    assert permutes == want, (
+        f"{name}: collective-permute "
+        f"{'missing from' if want else 'found in'} the lowered step")
+    probe_leaf = jax.tree_util.tree_leaves(params)[-1]
+    before = np.asarray(probe_leaf)
+    state, losses, secs = _run_steps(
+        step_fn, (params, batch_stats, opt_state), job.batch, job.labels, steps)
+    after = np.asarray(jax.tree_util.tree_leaves(state[0])[-1])
+    moved = _max_abs_diff(after, before)
+    assert moved > 0, "parameters did not change"
+    assert _all_finite(state[0]), "non-finite parameters"
+    cfg = job.cfg
+    _emit(name, t0, clock, model=cfg["model"], ranks=job.ctx.size,
+          per_rank_batch=cfg["batch"], image=cfg["img"],
+          classes=cfg["classes"],
+          distinct_devices_per_leaf_before_step_one=placed,
+          collective_permute_in_lowered_step=permutes,
+          loss_per_step=[float(l.mean()) for l in losses],
+          step_seconds=[round(s, 4) for s in secs],
+          param_max_abs_change=moved,
+          peak_bytes_in_use=_peak_bytes(job.ctx))
+
+
+def phase_contraction(job, clock):
+    """Four chips: per-rank parameters differ after a local step, one
+    gossip round is W @ them, the spread shrinks by what W predicts, and
+    the ATC step from the same start lands on the same point."""
+    t0 = time.perf_counter()
+    n = job.ctx.size
+    W = _exp2_mixing_matrix(n)
+    step_fn, *state = job.build(CommunicationType.empty, None)
+    (local, _, _), _, _ = _run_steps(step_fn, tuple(state), job.batch,
+                                     job.labels, 1)
+    local_np = [np.asarray(a, np.float64) for a in jax.tree_util.tree_leaves(local)]
+    mixed_np = [np.asarray(a, np.float64) for a in
+                jax.tree_util.tree_leaves(bf.neighbor_allreduce(local))]
+
+    def spread(leaves):
+        return float(np.sqrt(sum(
+            np.sum((a - a.mean(axis=0, keepdims=True)) ** 2) for a in leaves)))
+
+    s_local, s_mixed = spread(local_np), spread(mixed_np)
+    assert s_local > 0, "ranks hold identical parameters after a local step"
+    # W's action off the consensus direction: its largest singular value
+    # there bounds the contraction; for exp2(4) every such mode has |λ| = 1/3
+    sv = np.linalg.svd(W - np.full((n, n), 1.0 / n), compute_uv=False)
+    predicted = float(sv[0])
+    ratio = s_mixed / s_local
+    gossip_diff = max(
+        _max_abs_diff(m, np.tensordot(W, a, axes=1))
+        for m, a in zip(mixed_np, local_np))
+
+    atc_step, *atc_state = job.build(
+        CommunicationType.neighbor_allreduce, job.ctx.plan)
+    (atc, _, _), _, _ = _run_steps(atc_step, tuple(atc_state), job.batch,
+                                   job.labels, 1)
+    atc_diff = max(
+        _max_abs_diff(a, m) for a, m in
+        zip(jax.tree_util.tree_leaves(atc), mixed_np))
+    scale = max(float(np.max(np.abs(a))) for a in local_np)
+    _emit("contraction", t0, clock, ranks=n,
+          compared="local step then gossip vs NumPy W @ params; ATC step vs both",
+          spread_after_local_step=s_local, spread_after_gossip=s_mixed,
+          contraction=ratio, predicted_by_W=predicted,
+          gossip_vs_W_max_abs_diff=gossip_diff,
+          atc_step_vs_W_max_abs_diff=atc_diff, param_max_abs=scale,
+          tolerance={"contraction": 1e-3, "gossip": 1e-5 * scale,
+                     "atc": 1e-3 * scale})
+    assert gossip_diff <= 1e-5 * scale, "gossiped parameters are not W @ params"
+    assert ratio <= predicted + 1e-3, (
+        f"spread contracted by {ratio}, W allows at most {predicted}")
+    assert atc_diff <= 1e-3 * scale, "ATC step is not local step then W"
+
+
+# ---------------------------------------------------------------------------
+# phase: decoder step, compiled Pallas flash kernel vs dense attention
+# ---------------------------------------------------------------------------
+
+
+def phase_decoder(cfg, seed, on_tpu, clock, steps=3):
+    t0 = time.perf_counter()
+    ctx = basics.context()
+    n, B, T = ctx.size, cfg["batch"], cfg["seq"]
+    # on the chip the kernel is compiled, and says so; interpret mode is
+    # only how the CPU rehearsal walks the same control flow
+    interpret = not on_tpu
+    assert _default_interpret() is interpret, (
+        f"on platform {jax.devices()[0].platform!r} the flash kernel would "
+        f"default to interpret={_default_interpret()}")
+    flash = make_flash_attention_fn(impl="pallas", interpret=interpret)
+
+    def lm(attention_fn, head_chunks):
+        return LlamaLM(
+            vocab_size=cfg["vocab"], hidden_size=cfg["hidden"],
+            num_layers=cfg["layers"], num_heads=cfg["heads"], dff=cfg["dff"],
+            head_chunks=head_chunks, attention_fn=attention_fn)
+
+    model = lm(flash, cfg["head_chunks"])
+    rng = np.random.default_rng(seed)
+    ids_np = rng.integers(0, cfg["vocab"], size=(n, B, T)).astype(np.int32)
+    ids = jax.device_put(ids_np, basics.rank_major_sharding(ctx))
+    params0 = jax.jit(model.init)(
+        jax.random.PRNGKey(seed), jnp.asarray(ids_np[0]))["params"]
+    n_params = sum(a.size for a in jax.tree_util.tree_leaves(params0))
+
+    # the reference: same weights, same batch, the model's dense attention
+    row = jnp.asarray(ids_np[0])
+    dense_apply, dense_loss = make_lm_loss_fns(lm(None, cfg["head_chunks"]))
+    loss_dense = float(jax.jit(
+        lambda p, x: dense_loss(dense_apply({"params": p}, x, labels=x), x)
+    )(params0, row))
+    few = row[:cfg["logits_rows"]]
+    logits = {
+        name: jax.jit(lambda p, x, m=lm(fn, 0): m.apply({"params": p}, x))(
+            params0, few)
+        for name, fn in (("flash", flash), ("dense", None))}
+    delta = logits["flash"] - logits["dense"]
+    logits_diff = float(jnp.max(jnp.abs(delta)))
+    logits_max = float(jnp.max(jnp.abs(logits["dense"])))
+    logits_rel = float(jnp.linalg.norm(delta) / jnp.linalg.norm(logits["dense"]))
+    assert np.isfinite(logits_diff) and logits_max > 0
+    del logits, delta
+
+    apply_fn, loss_fn = make_lm_loss_fns(model)
+    init_fn, step_fn = make_decentralized_train_step(
+        apply_fn, optax.adamw(3e-4), ctx.mesh,
+        communication_type=CommunicationType.neighbor_allreduce,
+        plan=ctx.plan, loss_fn=loss_fn)
+    params = replicate_for_mesh(params0, n)
+    opt_state = init_fn(params)
+    hlo = _lowered_text(step_fn, params, {}, opt_state, ids, ids)
+    custom_calls = hlo.count("tpu_custom_call")
+    if on_tpu:
+        assert custom_calls > 0, "no tpu_custom_call in the lowered decoder step"
+    state, losses, secs = _run_steps(
+        step_fn, (params, {}, opt_state), ids, ids, steps)
+    loss_flash = float(losses[0][0])
+    loss_diff = abs(loss_flash - loss_dense)
+    _emit("decoder_flash_vs_dense", t0, clock, params=int(n_params),
+          hidden=cfg["hidden"], heads=cfg["heads"],
+          head_dim=cfg["hidden"] // cfg["heads"], layers=cfg["layers"],
+          seq=T, per_rank_batch=B, interpret=interpret,
+          tpu_custom_calls_in_lowered_step=custom_calls,
+          compared="first-step loss (train step, Pallas flash) and logits of "
+                   f"{cfg['logits_rows']} sequences vs the same weights and "
+                   "batch through dense attention",
+          loss_flash=loss_flash, loss_dense=loss_dense,
+          loss_abs_diff=loss_diff, loss_atol=LOSS_ATOL,
+          logits_max_abs_diff=logits_diff, logits_max_abs=logits_max,
+          logits_max_abs_tol=LOGITS_MAX_RTOL * logits_max,
+          logits_rel_l2_err=logits_rel, logits_rel_l2_tol=LOGITS_L2_RTOL,
+          loss_per_step=[float(l.mean()) for l in losses],
+          step_seconds=[round(s, 4) for s in secs],
+          peak_bytes_in_use=_peak_bytes(ctx))
+    assert _all_finite(state[0]), "non-finite parameters"
+    assert loss_diff <= LOSS_ATOL, f"flash loss differs from dense by {loss_diff}"
+    assert logits_rel <= LOGITS_L2_RTOL, (
+        f"flash logits differ from dense by {logits_rel} in relative L2")
+    assert logits_diff <= LOGITS_MAX_RTOL * logits_max, (
+        f"flash logits differ from dense by {logits_diff} (max |logit| {logits_max})")
+
+
+# ---------------------------------------------------------------------------
+# phase: does block_until_ready block here?
+# ---------------------------------------------------------------------------
+
+
+def phase_sync_probe(cfg, seed, clock, repeats=3):
+    """Evidence for ROADMAP S2, not a pass/fail: a long dependent matmul
+    chain timed to (a) dispatch return, (b) ``jax.block_until_ready``,
+    (c) ``bf.device_sync`` (block + a scalar fetch)."""
+    t0 = time.perf_counter()
+    d, iters = cfg["dim"], cfg["iters"]
+    w = jax.random.normal(jax.random.PRNGKey(seed), (d, d), jnp.bfloat16) / (d ** 0.5)
+
+    @jax.jit
+    def chain(x):
+        return jax.lax.fori_loop(0, iters, lambda _, a: a @ w, x)
+
+    x = jnp.eye(d, dtype=jnp.bfloat16)
+    bf.device_sync(chain(x))  # compile + warm
+    dispatch, block, fetch_after_block, sync = [], [], [], []
+    for _ in range(repeats):
+        t = time.perf_counter()
+        y = chain(x)
+        t1 = time.perf_counter()
+        jax.block_until_ready(y)
+        t2 = time.perf_counter()
+        np.asarray(y[0, 0])
+        t3 = time.perf_counter()
+        dispatch.append(t1 - t)
+        block.append(t2 - t)
+        fetch_after_block.append(t3 - t2)
+        t = time.perf_counter()
+        bf.device_sync(chain(x))
+        sync.append(time.perf_counter() - t)
+    med = statistics.median
+    flops = 2.0 * d ** 3 * iters
+    _emit("block_until_ready_probe", t0, clock, matmul_dim=d, chain_length=iters,
+          flops=flops,
+          dispatch_return_seconds=med(dispatch),
+          block_until_ready_seconds=med(block),
+          scalar_fetch_after_block_seconds=med(fetch_after_block),
+          device_sync_seconds=med(sync),
+          implied_tflops_at_block_until_ready=flops / med(block) / 1e12,
+          block_until_ready_blocks=bool(med(block) >= 0.9 * med(sync)))
+
+
+# ---------------------------------------------------------------------------
+
+
+def _rebuild_native():
+    """The plan compiler on this path loads the native library when it is
+    there; the chip tool copies the disk, so build it from the committed
+    sources instead of trusting whatever binary travelled."""
+    if os.path.exists(native._LIB_PATH):
+        os.remove(native._LIB_PATH)
+    if not native.build() or native.get_lib() is None:
+        raise RuntimeError(
+            "could not build bluefog_tpu/native/libbluefog_native.so from "
+            "the committed sources (make/g++ failed)")
+
+
+def run(args, device):
+    on_tpu = device["platform"] == "tpu"
+    sizes = TINY if args.rehearse else FULL
+    clock = _CompileClock()
+    t0 = time.perf_counter()
+    _rebuild_native()
+    # the rehearsal's CPU programs are no use to the chip and are not kept
+    cache_dir = use_compile_cache() if on_tpu else None
+    n = args.chips
+    bf.init(devices=jax.devices()[:n])
+    bf.set_topology(topology_util.ExponentialTwoGraph(n))
+    ctx = basics.context()
+    _emit("init", t0, clock, ranks=n, compile_cache_dir=cache_dir,
+          jax=jax.__version__, mesh_devices=[str(d) for d in ctx.devices])
+
+    phase_ops_windows(n, sizes["gossip_elems"], args.seed, clock)
+    t0 = time.perf_counter()
+    job = _ResNetJob(sizes["resnet"], args.seed)
+    jax.block_until_ready((job.variables, job.batch, job.labels))
+    _emit("resnet_setup", t0, clock, made="seeded variables and batch")
+    phase_resnet(job, "resnet_atc", CommunicationType.neighbor_allreduce,
+                 ctx.plan, clock)
+    phase_resnet(job, "resnet_allreduce", CommunicationType.allreduce, None,
+                 clock)
+    if n > 1:
+        phase_contraction(job, clock)
+    else:
+        del job
+        phase_decoder(sizes["decoder"], args.seed, on_tpu, clock)
+        phase_sync_probe(sizes["probe"], args.seed, clock)
+    bf.shutdown()
+
+
+def main():
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--chips", type=int, default=1, choices=(1, 4))
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--rehearse", action="store_true",
+                    help="tiny sizes on any backend; never prints ok: true")
+    args = ap.parse_args()
+
+    devs = jax.devices()
+    device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
+              "count": args.chips}
+    on_tpu = device["platform"] == "tpu"
+    if not on_tpu and not args.rehearse:
+        print(f"chip_smoke: JAX found no TPU (platform "
+              f"{device['platform']!r}); nothing was run", file=sys.stderr)
+        return 2
+    if len(devs) < args.chips:
+        print(f"chip_smoke: --chips {args.chips} but JAX sees {len(devs)} "
+              "device(s)", file=sys.stderr)
+        return 2
+
+    ok = False
+    try:
+        run(args, device)
+        ok = on_tpu and not args.rehearse
+    finally:
+        sys.stderr.flush()
+        print(json.dumps({"ok": ok, "device": device}), flush=True)
+    return 0 if ok else 1
+
+
+if __name__ == "__main__":
+    sys.exit(main())

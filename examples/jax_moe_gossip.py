@@ -182,8 +182,6 @@ def main():
             # seeding makes every grad the mean-over-mesh grad — module
             # docstring), but the replication checker cannot infer that
             # through the optax momentum update, so tell it to trust us
-            # (check_vma on jax >= 0.5; the compat shim in
-            # bluefog_tpu/__init__.py maps it to check_rep on 0.4.x)
             check_vma=False,
         )
     )

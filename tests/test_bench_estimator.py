@@ -35,7 +35,7 @@ def test_recovers_slope_exactly_despite_constant():
 
 def test_constant_can_dwarf_the_signal():
     # 300 ms constant vs 5 ms/call — the regime that broke RTT
-    # subtraction (docs/STATUS.md): the slope must still be exact
+    # subtraction: the slope must still be exact
     region = _region_fn(per_call=0.005, constant=0.3)
     t, fb = paired_slope(region, 20, "t", lambda: 0.25)
     assert t == pytest.approx(0.005)

@@ -175,6 +175,24 @@ def _weight_matrix(topo: nx.DiGraph) -> np.ndarray:
     return W
 
 
+def _report_jax_backend(rank, size):
+    import jax
+    import jax.numpy as jnp
+
+    jnp.zeros(1).block_until_ready()  # what takes the chip on a TPU host
+    return os.environ.get("JAX_PLATFORMS"), jax.default_backend()
+
+
+def test_spawn_children_initialise_only_the_cpu_backend(monkeypatch):
+    """One process holds the chip: children of a parent whose environment
+    would hand them the TPU are pinned to the CPU backend by spawn itself,
+    and the parent's environment is left as it was."""
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    out = islands.spawn(_report_jax_backend, 2)
+    assert out == [("cpu", "cpu")] * 2
+    assert os.environ["JAX_PLATFORMS"] == "tpu"
+
+
 def test_island_deterministic_suite():
     """Barriered diffusion matches the analytic W^k trajectory; win_get
     pull-combine matches the closed form; deposit versions count."""
@@ -410,7 +428,7 @@ def test_island_tcp_transport_suite(monkeypatch, tmp_path):
 
 
 def _worker_exp2_suite(rank, size, steps):
-    """np=4 e2e over the exp2 topology (VERDICT round-6 ask: multi-process
+    """np=4 e2e over the exp2 topology (round-6 ask: multi-process
     evidence past np=2): barriered weighted diffusion through the v2
     chunked transport's put_dual/update_fused fast path, then the
     accumulate idiom with an atomic reset drain."""

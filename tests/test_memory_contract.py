@@ -43,8 +43,8 @@ GB = 1e9
 
 @pytest.fixture(autouse=True)
 def no_persistent_compile_cache():
-    # bench.py enables the persistent compilation cache at import time
-    # (tests/test_bench_estimator.py pulls it in), and executables
+    # an entry point's main() may have turned the persistent compilation
+    # cache on in this process (bench.use_compile_cache), and executables
     # deserialized from that cache report alias_size_in_bytes == 0 —
     # every aliasing assertion below would fail in-suite while passing
     # in isolation.  These contracts need a real compile.  Clearing the

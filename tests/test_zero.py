@@ -379,7 +379,7 @@ def test_zero_adamw_weight_decay_matches_optax_adamw(devices):
 @pytest.mark.skip(
     reason="environmental SIGSEGV: restore_like onto fresh sharded placements "
     "crashes the forked XLA CPU client in this container (multiprocess-on-CPU "
-    "teardown, not a product bug) — see docs/STATUS.md"
+    "teardown, not a product bug)"
 )
 def test_zero_state_checkpoint_resume(devices, tmp_path):
     """Exact resume of SHARDED state: save after 2 steps, restore onto
