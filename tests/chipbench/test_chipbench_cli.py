@@ -1,0 +1,85 @@
+"""`python -m chipbench` as the driver starts it, at the `rehearsal` sizes on
+CPU devices: one child per job kind, and the refusals that print no result.
+A rehearsal walks the control flow; it is never a measurement."""
+
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+sys.path.insert(0, REPO)
+
+from chipbench import manifest  # noqa: E402
+
+
+def _chipbench(*args, devices=1):
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}")
+    env.pop("BENCH_RUN", None)
+    return subprocess.run([sys.executable, "-m", "chipbench", *args], cwd=REPO,
+                          env=env, capture_output=True, text=True, timeout=120)
+
+
+def _result(proc):
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    lines = proc.stdout.strip().splitlines()
+    return json.loads(lines[-1]), lines[:-1]
+
+
+def test_pushsum_rehearsal_prints_the_contracts_object_and_nothing_else():
+    proc = _chipbench("--workload", "bert-base-pushsum-1chip", "--seed",
+                      str(2**31 + 77), "--seconds", "1", "--trace", "0", "--rehearse")
+    result, earlier = _result(proc)
+    assert set(result) == {"correct", "attempted", "failed", "metrics", "device"}
+    assert result["correct"] is True and result["failed"] == 0
+    assert result["attempted"] > 10
+    assert set(result["metrics"]) == {"train_samples_s_chip", "step_ms_p95", "setup_s"}
+    for m in manifest.load_manifest()["end_to_end"]:
+        got = result["metrics"][m["name"]]
+        assert set(got) == {"value", "unit"} and got["unit"] == m["unit"]
+        assert isinstance(got["value"], float) and got["value"] > 0
+    assert result["device"] == {"platform": "cpu", "kind": "cpu", "count": 1,
+                                "memory_peak_bytes": 0}
+    # every number compared is printed beside its limit, on an earlier line
+    checks = [l for l in earlier if l.startswith("chipbench: check ")]
+    for name in ("loss_gap", "grad_norm_gap", "delta_norm_gap", "change1_rel_l2",
+                 "assoc_p_gap"):
+        assert any(name in l and "(limit " in l and " ok" in l for l in checks), name
+    assert any("step-time samples" in l for l in earlier)
+    assert any("compiles in window 0" in l for l in earlier)
+
+
+def test_four_rank_traced_rehearsal_names_no_share_of_a_peak_and_no_idle_share():
+    proc = _chipbench("--workload", "resnet50-atc-exp2-4chip", "--seed", "4",
+                      "--seconds", "1", "--trace", "1", "--rehearse", devices=4)
+    result, earlier = _result(proc)
+    assert set(result) == {"correct", "attempted", "failed", "metrics", "device"}
+    assert result["correct"] is True and result["device"]["count"] == 4
+    assert result["device"]["platform"] == "cpu"
+    assert "busy_s" not in result["device"] and "window_s" not in result["device"]
+    cell = manifest.resolve("resnet50-atc-exp2-4chip")
+    assert set(result["metrics"]) <= {m["name"] for m in cell.per_layer}
+    # the host's own spans are read anywhere; what only a chip's trace or a
+    # chip's peak can give is left out of a rehearsal's line
+    assert {"host_dispatch_ms_per_step", "host_dispatch_ms_p95"} <= set(result["metrics"])
+    for name in ("mfu_pct", "idle_pct", "peak_hbm_gb", "atc_over_allreduce",
+                 "compute_ms_per_step", "collective_ms_per_step"):
+        assert name not in result["metrics"], name
+    assert any('"collective_permute_in_lowered_step": true' in l for l in earlier)
+    assert any("leaves on 4 of 4 device(s)" in l for l in earlier)
+
+
+@pytest.mark.parametrize("args,message", [
+    (["--workload", "resnet50-atc-1chip", "--seed", "1", "--seconds", "1",
+      "--trace", "0"], "found no TPU"),
+    (["--workload", "no-such-cell", "--seed", "1", "--seconds", "1",
+      "--trace", "0", "--rehearse"], "no workload named"),
+])
+def test_refusals_exit_2_and_print_no_result(args, message):
+    proc = _chipbench(*args)
+    assert proc.returncode == 2
+    assert proc.stdout.strip() == ""
+    assert message in proc.stderr

@@ -1,0 +1,159 @@
+"""The plain references against the program at the `rehearsal` sizes, the
+mixing matrices against the program's topologies, and the controls: a step or
+a payload computed in a lower precision than the configuration states has to
+fail the comparison that decides `correct`, and so has a timed path that is
+broken underneath."""
+
+import argparse
+import os
+import sys
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import bluefog_tpu as bf
+
+REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+sys.path.insert(0, REPO)
+
+from chipbench import check, control, manifest, runner, seeded  # noqa: E402
+
+
+def test_mixing_matrices_are_the_topologies_written_from_their_definitions():
+    from bluefog_tpu import topology_util
+
+    exp2 = manifest.load_module(os.path.join(REPO, "chipbench/mixing/exp2.py"))
+    for n in (1, 2, 4, 8):
+        W = topology_util.GetWeightMatrix(topology_util.ExponentialTwoGraph(n))
+        np.testing.assert_allclose(exp2.matrix(n), W, atol=1e-12)
+    np.testing.assert_allclose(exp2.matrix(4)[0], [1 / 3, 0, 1 / 3, 1 / 3])
+    ring = manifest.load_module(os.path.join(REPO, "chipbench/mixing/ring1_pushsum.py"))
+    assert ring.matrix(1).tolist() == [[0.5]]  # no edge: the deposit is dropped
+    M = ring.matrix(4)
+    np.testing.assert_allclose(M.sum(axis=0), 1.0)  # column-stochastic
+    assert M[1, 0] == 0.5 and M[0, 0] == 0.5 and M[0, 3] == 0.5 and M[2, 0] == 0
+    # the edges are the program's ring: i -> i+1
+    G = topology_util.RingGraph(4, connect_style=1)
+    assert sorted(G.edges()) == sorted(
+        (i, j) for i in range(4) for j in range(4) if i != j and M[j, i] > 0)
+
+
+def test_mix_is_M_x_over_M_1_and_a_rounded_payload_shows():
+    x = np.random.default_rng(0).normal(size=(4, 5, 3)).astype(np.float32)
+    ring = manifest.load_module(os.path.join(REPO, "chipbench/mixing/ring1_pushsum.py"))
+    got, p = check.mix(ring.matrix(4), x)
+    np.testing.assert_allclose(p, 1.0)
+    x64 = x.astype(np.float64)
+    np.testing.assert_allclose(got[1], 0.5 * x64[1] + 0.5 * x64[0], rtol=1e-12)
+    one, p1 = check.mix(ring.matrix(1), x[:1])
+    assert p1.tolist() == [0.5]
+    np.testing.assert_array_equal(one, x[:1])  # (x/2) / (1/2)
+    low, _ = check.mix(ring.matrix(4), x, lower_payload=True)
+    rel = np.linalg.norm(low - got) / np.linalg.norm(got)
+    assert 1e-4 < rel < 1e-2  # bfloat16 keeps 8 bits of mantissa
+    own, _ = check.mix(ring.matrix(1), x[:1], lower_payload=True,
+                       payload_includes_self=True)
+    assert np.linalg.norm(own - one) > 0
+
+
+@pytest.mark.parametrize("config", ["resnet50", "bert-base"])
+def test_reference_agrees_with_the_program_at_rehearsal_sizes(config):
+    import optax
+
+    cell = manifest.resolve(
+        {"resnet50": "resnet50-atc-1chip", "bert-base": "bert-base-pushsum-1chip"}[config])
+    sizes = cell.sizes(rehearse=True)
+    ref = cell.module("reference")
+    program = cell.module("program").build(sizes)
+    params, stats = seeded.make_weights(ref, sizes, seed=7)
+    (x, y), = seeded.make_batches(ref, sizes, 7, ranks=1, pool=1)
+    x, y = x[0], y[0]
+
+    def program_loss(p):
+        variables = {"params": seeded.nest(p)}
+        if program["has_batch_stats"]:
+            variables["batch_stats"] = seeded.nest(stats)
+            logits, _ = program["apply_fn"](variables, x, mutable=["batch_stats"])
+        else:
+            logits = program["apply_fn"](variables, x)
+        return optax.softmax_cross_entropy_with_integer_labels(logits, y).mean()
+
+    lp, gp = jax.jit(jax.value_and_grad(program_loss))(params)
+    (lr, new_stats), gr = jax.jit(jax.value_and_grad(
+        lambda p: ref.loss_fn(p, stats, x, y, sizes), has_aux=True))(params)
+    assert abs(float(lp) - float(lr)) < 2e-3
+    assert set(gp) == set(gr) == set(ref.param_shapes(sizes)[0])
+    for path in gr:
+        a, b = np.asarray(gp[path], np.float64), np.asarray(gr[path], np.float64)
+        scale = max(np.linalg.norm(b), 1e-3)
+        assert np.linalg.norm(a - b) / scale < 0.05, path
+    if stats:
+        assert set(new_stats) == set(stats)
+
+
+def test_seeded_inputs_repeat_for_a_seed_and_differ_between_seeds():
+    cell = manifest.resolve("bert-base-pushsum-1chip")
+    sizes, ref = cell.sizes(rehearse=True), cell.module("reference")
+    big = 2**31 + 12345  # the driver's seeds pass 32 signed bits
+    a = seeded.make_batches(ref, sizes, big, ranks=2, pool=2)
+    b = seeded.make_batches(ref, sizes, big, ranks=2, pool=2)
+    c = seeded.make_batches(ref, sizes, big + 1, ranks=2, pool=2)
+    np.testing.assert_array_equal(a[1][0], b[1][0])
+    assert not np.array_equal(a[0][0], a[1][0]) and not np.array_equal(a[0][0], c[0][0])
+    x = np.asarray(a[0][0]).reshape(-1, sizes["seq_len"])
+    assert len({row.tobytes() for row in x}) == len(x)  # rows all differ
+
+
+@pytest.fixture
+def pushsum_cell():
+    return manifest.resolve("bert-base-pushsum-1chip")
+
+
+def test_sound_readings_pass_and_both_controls_fail(pushsum_cell):
+    """chipbench.control at the rehearsal sizes, one CPU device: the program
+    against the reference is inside every limit; the reference computed in
+    float8, and the payload rounded to bfloat16, each fail at least one."""
+    ses = runner.Session(pushsum_cell, rehearse=True)
+    try:
+        row = control.readings(ses, 2**31 + 5, ["step", "payload"])
+    finally:
+        bf.shutdown()
+    limits = ses.reference.LIMITS
+
+    def failed(part):
+        return [k for k, v in row[part].items() if k in limits and not v <= limits[k]]
+
+    assert failed("sound") == [], row["sound"]
+    assert failed("control_step"), row["control_step"]
+    assert failed("control_payload"), row["control_payload"]
+
+
+class _Frozen:
+    """A timed path broken underneath: the step runs and returns a loss, but
+    the state it leaves behind is the state it was given."""
+
+    def __init__(self, job):
+        self.job = job
+
+    def __getattr__(self, name):
+        return getattr(self.job, name)
+
+    def step(self, k):
+        before = self.job.state
+        out = self.job.step(k)
+        self.job.state = before
+        return out
+
+
+def test_a_step_that_returns_its_state_unchanged_is_not_correct(pushsum_cell, capsys):
+    args = argparse.Namespace(workload=pushsum_cell.name, seed=9, seconds=0.3,
+                              trace=0, rehearse=True)
+    result = runner.run(args, time.perf_counter(), pushsum_cell, wrap_job=_Frozen)
+    out = capsys.readouterr().out
+    assert result["correct"] is False
+    assert result["failed"] == 0 and result["attempted"] > 0  # it ran; it is wrong
+    assert "NOT OK" in out and "check delta_norm_gap" in out
+    assert set(result) == {"correct", "attempted", "failed", "metrics", "device"}
