@@ -79,14 +79,12 @@ class TimelineWriter {
     std::lock_guard<std::mutex> lk(mu_);
     events_.push_back(Event{name, ts_us, dur_us, tid, false, 0.0});
     dirty_ = true;
-    cv_.notify_all();
   }
 
   void Counter(const char* name, double ts_us, double value) {
     std::lock_guard<std::mutex> lk(mu_);
     events_.push_back(Event{name, ts_us, 0.0, 0, true, value});
     dirty_ = true;
-    cv_.notify_all();
   }
 
   void Flush() { WriteFile(); }
@@ -94,11 +92,12 @@ class TimelineWriter {
  private:
   void Loop() {
     // Periodic background flush, like the reference's writer thread [U]:
-    // the trace survives a crashed run without per-event file I/O.
+    // the trace survives a crashed run without per-event file I/O.  Only
+    // stop_ ends the wait early: waking on every event rewrote the whole
+    // file per event, and cost the push-sum round 1-5 % (PERF.md, PR 26).
     std::unique_lock<std::mutex> lk(mu_);
     while (!stop_) {
-      cv_.wait_for(lk, std::chrono::seconds(2),
-                   [this] { return stop_ || dirty_; });
+      cv_.wait_for(lk, std::chrono::seconds(2), [this] { return stop_; });
       if (stop_) break;
       if (!dirty_) continue;
       dirty_ = false;

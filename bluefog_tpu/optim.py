@@ -152,9 +152,11 @@ def adapt_then_combine_spmd(
     def update(grads, state, params=None):
         if params is None:
             raise ValueError("ATC requires params")
-        updates, base_state = base.update(grads, state.base, params)
-        adapted = optax.apply_updates(params, updates)
-        combined = maybe_comm(adapted, state.step)
+        with jax.named_scope("optimizer_update"):
+            updates, base_state = base.update(grads, state.base, params)
+            adapted = optax.apply_updates(params, updates)
+        with jax.named_scope("gossip_combine"):
+            combined = maybe_comm(adapted, state.step)
         out = jax.tree_util.tree_map(lambda c, p: (c - p).astype(p.dtype), combined, params)
         return out, GossipState(base=base_state, step=state.step + 1)
 
@@ -176,8 +178,10 @@ def adapt_with_combine_spmd(
     def update(grads, state, params=None):
         if params is None:
             raise ValueError("AWC requires params")
-        updates, base_state = base.update(grads, state.base, params)
-        combined = maybe_comm(params, state.step)
+        with jax.named_scope("optimizer_update"):
+            updates, base_state = base.update(grads, state.base, params)
+        with jax.named_scope("gossip_combine"):
+            combined = maybe_comm(params, state.step)
         out = jax.tree_util.tree_map(
             lambda c, u, p: (c + u - p).astype(p.dtype), combined, updates, params
         )
@@ -200,8 +204,10 @@ def gradient_allreduce_spmd(
         return GossipState(base=base.init(params), step=jnp.zeros((), jnp.int32))
 
     def update(grads, state, params=None):
-        avg = comm(grads, state.step)
-        updates, base_state = base.update(avg, state.base, params)
+        with jax.named_scope("gradient_allreduce"):
+            avg = comm(grads, state.step)
+        with jax.named_scope("optimizer_update"):
+            updates, base_state = base.update(avg, state.base, params)
         return updates, GossipState(base=base_state, step=state.step + 1)
 
     return optax.GradientTransformation(init, update)
@@ -334,9 +340,8 @@ class _EagerDistributedOptimizer:
             reg.counter(
                 "optim.steps", optimizer=self._mode,
                 comm=self.communication_type.name).inc()
-        # the whole fused step is one dispatch, so the step span is the
-        # BLUEFOG_TIMELINE signal here (per-op spans exist only on the
-        # eager op path)
+        # the whole fused step is one dispatch, so its one span is this
+        # one (per-op spans exist only on the eager op path)
         with timeline_context(
             f"optimizer_step_{self._mode}_{self.communication_type.name}"
         ):

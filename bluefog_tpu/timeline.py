@@ -1,16 +1,24 @@
-"""Timeline: named activity spans + chrome-trace output.
+"""Timeline: the library's one span recorder + chrome-trace output.
 
 TPU-native sibling of the reference's ``bluefog/common/timeline.h/.cc`` [U]
 (SURVEY.md §5.1): the reference's background loop stamps per-tensor activity
 spans into a Chrome-tracing JSON file when ``BLUEFOG_TIMELINE=<path>`` is
-set.  Here spans wrap op dispatch on the controller thread and are emitted
-two ways at once:
+set.  Here :func:`timeline_context` wraps every public op's dispatch (and,
+beneath a window op, every compiled-program call the library makes).  A span
+is recorded while a ``jax.profiler`` session is active or while
+``BLUEFOG_TIMELINE`` is set, three ways at once:
 
-- ``jax.profiler.TraceAnnotation`` so spans show up inside XLA/TPU profiles
-  (the idiomatic TPU path — device-side timing comes from ``jax.profiler``).
+- in memory, as a :class:`Span` on ``time.perf_counter`` with the id of the
+  span that was open on the same thread when it began; :func:`spans` reads
+  them back (a bounded ring, emptied when a profiler session begins, so the
+  spans cover exactly the steps the device trace covers);
+- ``jax.profiler.TraceAnnotation("bluefog/<name>")`` so the same spans show
+  up inside XLA/TPU profiles, on the device trace's clock;
 - a Chrome-tracing JSON file (same format the reference emits) when
   ``BLUEFOG_TIMELINE`` is set, written by the native C++ writer
   (``cbluefog`` — sibling of ``timeline.cc``) with a pure-Python fallback.
+
+Otherwise a span costs one ``is_enabled()`` check and reads no clock.
 
 ``timeline_start_activity`` / ``timeline_end_activity`` mirror the
 reference's custom-span toggles [U].
@@ -19,13 +27,14 @@ reference's custom-span toggles [U].
 from __future__ import annotations
 
 import atexit
-import contextlib
+import collections
+import itertools
 import json
 import os
 import signal
 import threading
 import time
-from typing import Optional
+from typing import List, NamedTuple, Optional, Union
 
 import jax.profiler
 
@@ -35,6 +44,8 @@ __all__ = [
     "timeline_start_activity",
     "timeline_end_activity",
     "timeline_context",
+    "spans",
+    "Span",
     "TimelineWriter",
 ]
 
@@ -162,7 +173,9 @@ class TimelineWriter:
                 logger.warning("timeline flush failed: %s", e)
 
 
-_writer: Optional[TimelineWriter] = None
+# None until the environment has been read, then the writer, or False where
+# BLUEFOG_TIMELINE is unset: the environment is read once, not per span
+_writer: Union[TimelineWriter, None, bool] = None
 _open_spans = {}
 
 
@@ -170,9 +183,8 @@ def _get_writer() -> Optional[TimelineWriter]:
     global _writer
     if _writer is None:
         path = os.environ.get("BLUEFOG_TIMELINE")
-        if path:
-            _writer = TimelineWriter(path)
-    return _writer
+        _writer = TimelineWriter(path) if path else False
+    return _writer or None
 
 
 def timeline_start_activity(name: str, category: str = "custom") -> bool:
@@ -195,20 +207,97 @@ def timeline_end_activity(name: str, category: str = "custom") -> bool:
     return w is not None
 
 
-@contextlib.contextmanager
-def timeline_context(name: str):
-    """Span around an op dispatch; also a ``jax.profiler`` annotation so the
-    span is visible in TPU traces.
+class Span(NamedTuple):
+    """One recorded span.  ``parent`` is the id of the span that was open on
+    the same thread when this one began (None at top level); ``start`` and
+    ``end`` are seconds on ``time.perf_counter``; ``nbytes`` is what the op
+    was handed to move (0 where it is handed nothing)."""
 
-    Spans record with the CALLING THREAD's id as the chrome-trace tid, so
-    background work (e.g. the overlap optimizer's gossip thread) renders
-    on its own track, visually parallel to main-thread spans."""
-    start = time.perf_counter_ns()
-    with jax.profiler.TraceAnnotation(f"bluefog/{name}"):
-        yield
-    w = _get_writer()
-    if w is not None:
-        t0_us = (start - w._t0) / 1e3
-        dur_us = (time.perf_counter_ns() - start) / 1e3
-        w.record(name, t0_us, dur_us,
-                 tid=threading.get_ident() & 0x7FFFFFFF)
+    id: int
+    parent: Optional[int]
+    name: str
+    start: float
+    end: float
+    nbytes: int
+
+
+RING = 1 << 16  # spans kept: a long run under BLUEFOG_TIMELINE must not grow
+
+_profiling = jax.profiler.TraceAnnotation.is_enabled
+_ring = collections.deque(maxlen=RING)
+_ids = itertools.count(1)
+_open = threading.local()  # .stack: ids of the spans open on this thread
+_profiled = False  # whether a profiler session was live at the last look
+
+
+def _session_edge() -> bool:
+    """Whether a profiler session is live; one that has just begun empties
+    the ring.  ``is_enabled()`` is all JAX tells, so two sessions are told
+    apart by a span or a read of :func:`spans` that falls between them."""
+    global _profiled
+    profiled = _profiling()
+    if profiled != _profiled:
+        _profiled = profiled
+        if profiled:
+            _ring.clear()
+    return profiled
+
+
+def spans() -> List[Span]:
+    """The spans recorded since the latest profiler session began (under
+    ``BLUEFOG_TIMELINE`` alone: since the process started), by start time,
+    the newest ``RING`` of them."""
+    _session_edge()
+    return sorted(_ring.copy())
+
+
+class timeline_context:
+    """Span around an op dispatch: ``with timeline_context("win_put") as
+    span``.  While nothing records, ``span`` is None and no clock is read;
+    while recording, the op sets ``span.nbytes`` where it is handed a tensor
+    to move.
+
+    A span belongs to the CALLING THREAD: its parent is the span open on
+    that thread, and its chrome-trace tid is the thread's id, so background
+    work (e.g. the overlap optimizer's gossip thread) renders on its own
+    track, visually parallel to main-thread spans."""
+
+    __slots__ = ("name", "nbytes", "_id", "_parent", "_start", "_annotation")
+
+    def __init__(self, name: str):
+        self.name = name
+        self._id = None
+
+    def __enter__(self):
+        profiled = _session_edge()
+        if not (profiled or (_get_writer() if _writer is None else _writer)):
+            return None
+        try:
+            stack = _open.stack
+        except AttributeError:
+            stack = _open.stack = []
+        self._parent = stack[-1] if stack else None
+        self._id = next(_ids)
+        stack.append(self._id)
+        self.nbytes = 0
+        self._annotation = None
+        if profiled:
+            self._annotation = jax.profiler.TraceAnnotation(f"bluefog/{self.name}")
+            self._annotation.__enter__()
+        self._start = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        if self._id is None:
+            return
+        end = time.perf_counter()
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+        _open.stack.pop()
+        _ring.append(Span(self._id, self._parent, self.name, self._start, end,
+                          self.nbytes))
+        w = _writer
+        if w:
+            w.record(self.name, self._start * 1e6 - w._t0 / 1e3,
+                     (end - self._start) * 1e6,
+                     tid=threading.get_ident() & 0x7FFFFFFF)

@@ -182,9 +182,10 @@ def make_decentralized_train_step(
                 )
                 return loss_fn(logits, y), (logits, mut["batch_stats"])
 
-            (loss, (logits, new_bs)), grads = jax.value_and_grad(
-                loss_of, has_aux=True
-            )(p)
+            with jax.named_scope("forward_backward"):
+                (loss, (logits, new_bs)), grads = jax.value_and_grad(
+                    loss_of, has_aux=True
+                )(p)
         else:
 
             def loss_of(p_):
@@ -194,9 +195,13 @@ def make_decentralized_train_step(
                     logits = apply_fn({"params": p_}, x)
                 return loss_fn(logits, y), logits
 
-            (loss, logits), grads = jax.value_and_grad(loss_of, has_aux=True)(p)
+            with jax.named_scope("forward_backward"):
+                (loss, logits), grads = jax.value_and_grad(
+                    loss_of, has_aux=True
+                )(p)
             new_bs = bs
 
+        # tx.update opens the optimizer-update and gossip scopes of optim.py
         updates, new_os = tx.update(grads, os_, p)
         new_p = optax.apply_updates(p, updates)
         if logits.ndim >= 2:
@@ -278,31 +283,32 @@ def make_decentralized_train_step(
     compiled = {}
 
     def step_fn(params, batch_stats, opt_state, batch, labels):
-        if steps_per_call > 1:
-            # a [ranks, B, ...] batch here would silently shard the RANK
-            # axis as the sub-step axis and train on wrong slices
-            _check_substep_axis((batch, labels))
-        key = jax.tree_util.tree_structure(opt_state)
-        if key not in compiled:
-            os_spec = _opt_state_spec(opt_state)
-            compiled[key] = jax.jit(
-                jax.shard_map(
-                    body,
-                    mesh=mesh,
-                    in_specs=(spec, spec, os_spec, data_spec, data_spec),
-                    out_specs=(spec, spec, os_spec, spec, spec),
-                ),
-                donate_argnums=(0, 1, 2) if donate else (),
-            )
-        reg = _telemetry.get_registry()
-        if reg.enabled:
-            # one host call may run several fused sub-steps
-            reg.counter("train.steps").add(max(1, int(steps_per_call)))
-        # step-level span: jitted training records no per-op host spans, so
-        # this is where BLUEFOG_TIMELINE traces come from (the reference's
-        # per-tensor spans are a background-thread artifact; dispatch of the
-        # whole fused step is the honest TPU equivalent)
+        # the step's one span (jitted training records no per-op host spans):
+        # all the host work of a call, from the cache lookup on the state's
+        # structure to the dispatch of the fused program.  The reference's
+        # per-tensor spans are a background-thread artifact; this is the
+        # honest TPU equivalent
         with timeline_context("train_step"):
+            if steps_per_call > 1:
+                # a [ranks, B, ...] batch here would silently shard the RANK
+                # axis as the sub-step axis and train on wrong slices
+                _check_substep_axis((batch, labels))
+            key = jax.tree_util.tree_structure(opt_state)
+            if key not in compiled:
+                os_spec = _opt_state_spec(opt_state)
+                compiled[key] = jax.jit(
+                    jax.shard_map(
+                        body,
+                        mesh=mesh,
+                        in_specs=(spec, spec, os_spec, data_spec, data_spec),
+                        out_specs=(spec, spec, os_spec, spec, spec),
+                    ),
+                    donate_argnums=(0, 1, 2) if donate else (),
+                )
+            reg = _telemetry.get_registry()
+            if reg.enabled:
+                # one host call may run several fused sub-steps
+                reg.counter("train.steps").add(max(1, int(steps_per_call)))
             return compiled[key](params, batch_stats, opt_state, batch, labels)
 
     return init_fn, step_fn

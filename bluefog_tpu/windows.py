@@ -75,7 +75,6 @@ __all__ = [
     "turn_on_win_ops_with_associated_p",
     "turn_off_win_ops_with_associated_p",
     "record_win_ops",
-    "note_win_op",
     "degraded_update_weights",
 ]
 
@@ -123,11 +122,11 @@ def _log_op(op: str, name: Optional[str]) -> None:
     _telemetry.note_op(op, name)
 
 
-def note_win_op(op: str, name: Optional[str]) -> None:
-    """Deprecated shim: window ops from other modules now publish through
-    :func:`bluefog_tpu.telemetry.note_op` directly; kept so existing
-    callers keep feeding the active ``record_win_ops()`` trace."""
-    _telemetry.note_op(op, name)
+def _note_nbytes(span, tensor) -> None:
+    """On a live span, the bytes of the array or leaves the op was handed
+    (``span`` is None while nothing records: nothing is computed then)."""
+    if span is not None:
+        span.nbytes = sum(l.nbytes for l in jax.tree_util.tree_leaves(tensor))
 
 
 class _Window:
@@ -255,6 +254,16 @@ def _exchange_body(plan, accumulate, with_p, x, mail0, ver0, p_self, pm0,
     return mail0, ver0, pm0
 
 
+# The names a profile shows the window programs under: "jit_" + the name of
+# the function handed to jax.jit.  chipbench's push-sum cell finds the window
+# programs by these (chipbench/jobs/eager_window_pushsum.py: WINDOW_PROGRAMS,
+# `^jit_spmd`, `^jit__combine`), so `spmd` below and `_combine` keep their
+# names until the patterns move with them (ROADMAP R6);
+# tests/test_timeline_spans.py lowers each program and holds it to these.
+EXCHANGE_PROGRAM = "jit_spmd"  # _build_exchange and _build_put_update
+COMBINE_PROGRAM = "jit__combine"  # win_update on a bare-array window
+
+
 def _build_exchange(plan: CommPlan, accumulate: bool, with_p: bool,
                     donate: bool = True):
     """Jitted rank-major exchange (see :func:`_exchange_body`).
@@ -330,21 +339,21 @@ def _build_put_update(plan: CommPlan, accumulate: bool, with_p: bool, wdt,
 
 
 def _exchange(
-    win: _Window, x, scales: np.ndarray, active: np.ndarray, accumulate: bool
+    win: _Window, x, scales: np.ndarray, active: np.ndarray, accumulate: bool,
+    op: str,
 ) -> None:
+    """``x`` is in the window's dtype (the callers cast; ``win_get`` sends
+    the exposed tensor).  ``op`` names the public call this runs under: the
+    program call's span is ``<op>/exchange``."""
     ctx = _ctx()
     with_p = ctx.win_associated_p_enabled
     key = ("win_exchange", win.plan, accumulate, with_p, win.dtype, win.shape[1:])
     f = ctx.jit_cache(key, lambda: _build_exchange(win.plan, accumulate, with_p))
-    mail, versions, p_mail = f(
-        _cast_to_window_dtype(win, win.name, x),
-        win.mail,
-        win.versions,
-        win.p_self,
-        win.p_mail,
-        jnp.asarray(scales),
-        jnp.asarray(active),
-    )
+    scales, active = jnp.asarray(scales), jnp.asarray(active)
+    with timeline_context(f"{op}/exchange"):
+        mail, versions, p_mail = f(
+            x, win.mail, win.versions, win.p_self, win.p_mail, scales, active
+        )
     # always reassign: the jit donated the old p_mail buffer, so the
     # previous win.p_mail is invalid even when the p machinery is off
     win.mail, win.versions, win.p_mail = mail, versions, p_mail
@@ -457,17 +466,20 @@ def _fusion_pack_tree(meta, tree, n):
 
 
 def _pack_input(name, tensor):
-    """Pack a pytree op input when ``name`` is a fused window."""
+    """Pack a pytree op input when ``name`` is a fused window (one program;
+    ``win_set_exposed``, its only caller, shows it as ``.../pack``)."""
     meta = _ctx().win_fusion.get(name)
     if meta is None:
         return tensor
-    return _fusion_pack_tree(meta, tensor, _ctx().size)
+    with timeline_context("win_set_exposed/pack"):
+        return _fusion_pack_tree(meta, tensor, _ctx().size)
 
 
-def _fused_exchange(win, name, meta, tree, scales, active, accumulate):
+def _fused_exchange(win, name, meta, tree, scales, active, accumulate, op):
     """Pack + exchange in ONE compiled program (fused windows): leaves go
     in, the packed exposure comes back alongside the new mailbox state —
-    a separate eager pack would cost an extra dispatch per gossip round."""
+    a separate eager pack would cost an extra dispatch per gossip round.
+    Its span is ``<op>/exchange``."""
     ctx = _ctx()
     leaves, treedef = jax.tree_util.tree_flatten(tree)
     if treedef != meta.treedef:
@@ -495,10 +507,12 @@ def _fused_exchange(win, name, meta, tree, scales, active, accumulate):
         return jax.jit(f, donate_argnums=(1, 2, 4))
 
     f = ctx.jit_cache(key, build)
-    x, mail, versions, p_mail = f(
-        leaves, win.mail, win.versions, win.p_self, win.p_mail,
-        jnp.asarray(scales), jnp.asarray(active),
-    )
+    scales, active = jnp.asarray(scales), jnp.asarray(active)
+    with timeline_context(f"{op}/exchange"):
+        x, mail, versions, p_mail = f(
+            leaves, win.mail, win.versions, win.p_self, win.p_mail,
+            scales, active,
+        )
     win.self_tensor = x
     # always reassign (the old p_mail buffer was donated)
     win.mail, win.versions, win.p_mail = mail, versions, p_mail
@@ -540,8 +554,9 @@ def win_free(name: Optional[str] = None) -> bool:
     return ctx.windows.pop(name, None) is not None
 
 
-def _cast_to_window_dtype(win, name, tensor):
-    """Eager cast with a CLEAR multi-process error.
+def _cast_to_window_dtype(win, name, tensor, op):
+    """Eager cast with a CLEAR multi-process error; a cast that converts is
+    a program launch and gets the span ``<op>/cast``.
 
     In the multi-process non-fused path the input is a global
     non-fully-addressable array; an eager ``convert_element_type`` on it
@@ -549,14 +564,17 @@ def _cast_to_window_dtype(win, name, tensor):
     (the fused path avoids this by casting inside the compiled program).
     """
     t = jnp.asarray(tensor) if not isinstance(tensor, jax.Array) else tensor
-    if t.dtype != win.dtype and not getattr(t, "is_fully_addressable", True):
+    if t.dtype == win.dtype:
+        return t
+    if not getattr(t, "is_fully_addressable", True):
         raise ValueError(
             f"window '{name}' holds {win.dtype} but the input is {t.dtype}: "
             "eager dtype casts on non-fully-addressable (multi-process "
             "global) arrays are not supported — cast the input to the "
             "window dtype before the call, or use a fused (pytree) window"
         )
-    return jnp.asarray(t, dtype=win.dtype)
+    with timeline_context(f"{op}/cast"):
+        return jnp.asarray(t, dtype=win.dtype)
 
 
 def win_put(tensor, name: str, dst_weights: WeightsArg = None) -> bool:
@@ -567,18 +585,20 @@ def win_put(tensor, name: str, dst_weights: WeightsArg = None) -> bool:
     Also refreshes the window's exposed tensor: upstream the window aliases
     the tensor's memory, so the put value *is* the current exposure.
     """
-    with timeline_context("win_put"):
+    with timeline_context("win_put") as span:
         _log_op("win_put", name)
         win = _win(name)
         tensor = basics.to_rank_major_global(tensor)
+        _note_nbytes(span, tensor)
         scales, active = _class_scales(win.plan, dst_weights, side="send")
         meta = _ctx().win_fusion.get(name)
         if meta is not None:
             _fused_exchange(win, name, meta, tensor, scales, active,
-                            accumulate=False)
+                            accumulate=False, op="win_put")
         else:
-            win.self_tensor = _cast_to_window_dtype(win, name, tensor)
-            _exchange(win, tensor, scales, active, accumulate=False)
+            win.self_tensor = _cast_to_window_dtype(win, name, tensor, "win_put")
+            _exchange(win, win.self_tensor, scales, active, accumulate=False,
+                      op="win_put")
     return True
 
 
@@ -603,18 +623,21 @@ def win_put_nonblocking(tensor, name: str, dst_weights: WeightsArg = None):
 def win_accumulate(tensor, name: str, dst_weights: WeightsArg = None) -> bool:
     """Like win_put but adds into the destination slot (reference
     ``bf.win_accumulate`` — MPI_Accumulate path [U])."""
-    with timeline_context("win_accumulate"):
+    with timeline_context("win_accumulate") as span:
         _log_op("win_accumulate", name)
         win = _win(name)
         tensor = basics.to_rank_major_global(tensor)
+        _note_nbytes(span, tensor)
         scales, active = _class_scales(win.plan, dst_weights, side="send")
         meta = _ctx().win_fusion.get(name)
         if meta is not None:
             _fused_exchange(win, name, meta, tensor, scales, active,
-                            accumulate=True)
+                            accumulate=True, op="win_accumulate")
         else:
-            win.self_tensor = _cast_to_window_dtype(win, name, tensor)
-            _exchange(win, tensor, scales, active, accumulate=True)
+            win.self_tensor = _cast_to_window_dtype(
+                win, name, tensor, "win_accumulate")
+            _exchange(win, win.self_tensor, scales, active, accumulate=True,
+                      op="win_accumulate")
     return True
 
 
@@ -674,7 +697,8 @@ def win_get(name: str, src_weights: WeightsArg = None) -> bool:
         for c, cls in enumerate(win.plan.classes):
             for s, d in cls.perm:
                 send[c, s] = recv[c, d]
-        _exchange(win, win.self_tensor, send, active, accumulate=False)
+        _exchange(win, win.self_tensor, send, active, accumulate=False,
+                  op="win_get")
     return True
 
 
@@ -685,9 +709,12 @@ def win_get_nonblocking(name: str, src_weights: WeightsArg = None):
     return Handle(_completion_probe(_win(name).mail))
 
 
-def _reset_mailbox(win: _Window) -> None:
-    win.mail = jnp.zeros_like(win.mail)
-    win.p_mail = jnp.zeros_like(win.p_mail)
+def _reset_mailbox(win: _Window, op: str) -> None:
+    """One span, ``<op>/reset``, over the two programs (a ``zeros_like``
+    each) that clear the mailbox and its associated p."""
+    with timeline_context(f"{op}/reset"):
+        win.mail = jnp.zeros_like(win.mail)
+        win.p_mail = jnp.zeros_like(win.p_mail)
 
 
 def _update_weights(win: _Window, self_weight, neighbor_weights):
@@ -808,32 +835,24 @@ def win_update(
                 return jax.jit(f)
 
             f = ctx.jit_cache(key, build)
-        if meta is None:
-            combined, p_self = f(
-                win.self_tensor,
-                win.mail,
-                win.p_self,
-                win.p_mail,
-                jnp.asarray(wmat),
-                jnp.asarray(swvec),
-                wdt=wdt,
-                with_p=with_p,
-            )
-            leaves = None
-        else:
-            combined, p_self, leaves = f(
-                win.self_tensor,
-                win.mail,
-                win.p_self,
-                win.p_mail,
-                jnp.asarray(wmat),
-                jnp.asarray(swvec),
-            )
+        wmat, swvec = jnp.asarray(wmat), jnp.asarray(swvec)
+        with timeline_context("win_update/combine"):
+            if meta is None:
+                combined, p_self = f(
+                    win.self_tensor, win.mail, win.p_self, win.p_mail,
+                    wmat, swvec, wdt=wdt, with_p=with_p,
+                )
+                leaves = None
+            else:
+                combined, p_self, leaves = f(
+                    win.self_tensor, win.mail, win.p_self, win.p_mail,
+                    wmat, swvec,
+                )
         win.self_tensor = combined
         if with_p:
             win.p_self = p_self
         if reset:
-            _reset_mailbox(win)
+            _reset_mailbox(win, "win_update")
         if meta is not None:
             tree = jax.tree_util.tree_unflatten(meta.treedef, leaves)
             if clone:
@@ -861,11 +880,12 @@ def win_put_update(
     because under the mailbox emulation the pair always executes back to
     back, and one dispatch lets XLA schedule the exchange with the combine.
     """
-    with timeline_context("win_put_update"):
+    with timeline_context("win_put_update") as span:
         _log_op("win_put_update", name)
         ctx = _ctx()
         win = _win(name)
         tensor = basics.to_rank_major_global(tensor)
+        _note_nbytes(span, tensor)
         meta = ctx.win_fusion.get(name)
         if meta is not None:
             leaves, treedef = jax.tree_util.tree_flatten(tensor)
@@ -877,7 +897,7 @@ def win_put_update(
             _check_fused_leaves(meta, leaves, ctx.size)
             t = leaves  # packed inside the compiled program below
         else:
-            t = _cast_to_window_dtype(win, name, tensor)
+            t = _cast_to_window_dtype(win, name, tensor, "win_put_update")
         if dst_weights is None and self_weight is None and neighbor_weights is None:
             # the optimizer hot path: the four weight arrays are constant
             # per window, so build + upload them once
@@ -920,10 +940,11 @@ def win_put_update(
             return jax.jit(f, donate_argnums=(1, 2, 3, 4))
 
         f = ctx.jit_cache(key, build)
-        out = f(
-            t, win.mail, win.versions, win.p_self, win.p_mail,
-            scales_d, active_d, wmat_d, swvec_d,
-        )
+        with timeline_context("win_put_update/put_update"):
+            out = f(
+                t, win.mail, win.versions, win.p_self, win.p_mail,
+                scales_d, active_d, wmat_d, swvec_d,
+            )
         combined, mail, versions, p_mail, p_self = out[:5]
         win.self_tensor = combined
         win.mail, win.versions = mail, versions
@@ -932,7 +953,7 @@ def win_put_update(
         # (the returned values are passthroughs in that case)
         win.p_mail, win.p_self = p_mail, p_self
         if reset:
-            _reset_mailbox(win)
+            _reset_mailbox(win, "win_put_update")
         if meta is not None:
             return jax.tree_util.tree_unflatten(meta.treedef, out[5])
         return combined
@@ -1003,7 +1024,8 @@ def win_associated_p(name: str) -> jnp.ndarray:
     Returns a COPY: the window's own p buffer is donated by the next
     window op, so handing out the live reference would leave the caller
     holding a deleted array."""
-    return jnp.array(_win(name).p_self)
+    with timeline_context("win_associated_p"):
+        return jnp.array(_win(name).p_self)
 
 
 def win_set_exposed(name: str, tensor, associated_p=None) -> None:
@@ -1012,17 +1034,19 @@ def win_set_exposed(name: str, tensor, associated_p=None) -> None:
     the caller stores x/p back as the new x and resets p to 1.  The reference
     gets this for free because its windows alias the torch tensor [U]; the
     mailbox emulation needs an explicit setter."""
-    _log_op("win_set_exposed", name)
-    win = _win(name)
-    tensor = basics.to_rank_major_global(tensor)
-    t = jnp.asarray(_pack_input(name, tensor), dtype=win.dtype)
-    if t.shape != win.shape:
-        raise ValueError(f"shape {t.shape} != window shape {win.shape}")
-    win.self_tensor = t
-    if associated_p is not None:
-        win.p_self = jnp.broadcast_to(
-            jnp.asarray(associated_p, jnp.float32), win.p_self.shape
-        )
+    with timeline_context("win_set_exposed") as span:
+        _log_op("win_set_exposed", name)
+        win = _win(name)
+        tensor = basics.to_rank_major_global(tensor)
+        _note_nbytes(span, tensor)
+        t = jnp.asarray(_pack_input(name, tensor), dtype=win.dtype)
+        if t.shape != win.shape:
+            raise ValueError(f"shape {t.shape} != window shape {win.shape}")
+        win.self_tensor = t
+        if associated_p is not None:
+            win.p_self = jnp.broadcast_to(
+                jnp.asarray(associated_p, jnp.float32), win.p_self.shape
+            )
 
 
 def turn_on_win_ops_with_associated_p() -> None:
