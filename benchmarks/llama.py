@@ -109,10 +109,6 @@ def main():
     ap.add_argument("--seq", type=int, default=0,
                     help="override the preset sequence length (long-context "
                     "runs; pair with --batch to keep tokens/step sane)")
-    ap.add_argument("--fuse", action="store_true",
-                    help="gossip the param tree through the fusion buffer "
-                    "(one ppermute per shift class per dtype group; "
-                    "costs a params-sized pack+unpack per round)")
     ap.add_argument("--optimizer", default=None,
                     choices=[None, "adamw", "sgdm", "sgdm_bf16",
                              "adafactor"],
@@ -204,9 +200,6 @@ def main():
         init_fn, step_fn = make_decentralized_train_step(
             lm_apply, opt, ctx.mesh,
             communication_type=comm, plan=plan, loss_fn=lm_loss,
-            # the allreduce baseline phase has no fusion buffer (and
-            # make_spmd_comm_fn raises rather than silently dropping it)
-            comm_fuse=args.fuse and comm == CommunicationType.neighbor_allreduce,
         )
         p = jax.device_put(params_host, basics.rank_major_sharding(ctx))
         opt_state = init_fn(p)

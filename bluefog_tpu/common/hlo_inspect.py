@@ -108,6 +108,53 @@ def collective_counts(compiled_text: str) -> Counter:
     return counts
 
 
+_COMPUTATION_RE = re.compile(r"^(ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$")
+_CALLS_RE = re.compile(r"calls=%?([\w.\-]+)")
+
+
+def entry_schedule(compiled_text: str) -> str:
+    """Where the collective-permutes sit among the convolutions of the
+    scheduled ENTRY computation of ``compiled.as_text()``: one letter per
+    instruction of interest, in program order -- ``S`` a
+    ``collective-permute-start``, ``D`` its ``-done``, ``C`` a convolution
+    or a fusion that calls one.  The text of a compiled module is its
+    schedule, so ``marks.rfind("C")`` is the last convolution and the
+    ``S`` before it are the starts that travel under the backward pass.
+    Positions, never times: nothing ran."""
+    bodies, entry, cur = {}, None, None
+    for line in compiled_text.splitlines():
+        m = None if line.startswith(" ") else _COMPUTATION_RE.match(line)
+        if m:
+            cur = m.group(2)
+            bodies[cur] = []
+            if m.group(1):
+                entry = cur
+        elif cur is not None:
+            bodies[cur].append(line)
+    convs = {name for name, body in bodies.items()
+             if any(" convolution(" in l for l in body)}
+    marks = []
+    for line in bodies.get(entry, ()):
+        calls = _CALLS_RE.search(line)
+        if " collective-permute-start(" in line:
+            marks.append("S")
+        elif " collective-permute-done(" in line:
+            marks.append("D")
+        elif " convolution(" in line or (calls and calls.group(1) in convs):
+            marks.append("C")
+    return "".join(marks)
+
+
+def most_outstanding(marks: str) -> int:
+    """The most permutes ever started and not yet done along
+    :func:`entry_schedule`'s marks."""
+    out = most = 0
+    for c in marks:
+        out += (c == "S") - (c == "D")
+        most = max(most, out)
+    return most
+
+
 def memory_bytes(compiled) -> dict:
     """Per-DEVICE byte accounting from XLA's buffer assignment.
 

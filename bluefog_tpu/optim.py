@@ -2,10 +2,15 @@
 
 TPU-native sibling of the reference's ``bluefog/torch/optimizers.py`` [U]
 (SURVEY.md §2.2, §3.3).  The reference hooks per-parameter backward callbacks
-to overlap nonblocking gossip with backprop; under XLA the same overlap falls
-out of putting the gossip *inside* the jitted train step — the compiler
-schedules collectives concurrently with compute (SURVEY.md §3.3 TPU mapping),
-so the whole hook/handle machinery dissolves into pure functions.
+to overlap nonblocking gossip with backprop; under XLA the gossip sits
+*inside* the jitted train step and the compiler schedules it (SURVEY.md §3.3
+TPU mapping), so the hook/handle machinery dissolves into pure functions.
+The overlap does not fall out by itself: the TPU scheduler keeps five
+collective-permutes in flight, so of a permute per leaf all but five wait
+behind the last gradient.  The train step therefore tells the gossip the
+order in which the backward pass produces the gradients, and the gossip
+packs the leaves into that few buckets (``ops_spmd.neighbor_allreduce``,
+``training.make_decentralized_train_step``; PERF.md section 6, PR 27).
 
 Two layers:
 
@@ -77,27 +82,15 @@ def make_spmd_comm_fn(
     axis_name: str = NODES_AXIS,
     machines_axis: str = MACHINES_AXIS,
     local_axis: str = LOCAL_AXIS,
-    fuse: bool = False,
 ) -> CommFn:
     """Build the in-SPMD communication function for a CommunicationType.
 
-    ``fuse`` forwards to :func:`ops_spmd.neighbor_allreduce`'s fusion
-    buffer (one ppermute per shift class per dtype group).  Default off
-    for the training path: packing a large param tree materializes a
-    params-sized pack+unpack per round, trading HBM bandwidth for
-    collective count — the right side of that trade depends on leaf
-    count and interconnect latency, so it is a measured knob, not a
-    default (the exact
-    methods in :mod:`bluefog_tpu.algorithms`, whose trees are small and
-    carry an odd-shaped push-sum scalar, use it unconditionally)."""
-    if fuse and comm_type != CommunicationType.neighbor_allreduce:
-        # silently dropping the flag would poison an A/B (same rationale
-        # as llama.py's --remat-policy guard): only the neighbor path
-        # implements the fusion buffer today
-        raise ValueError(
-            f"fuse=True is only implemented for neighbor_allreduce, "
-            f"not {comm_type}"
-        )
+    The function takes the pytree.  ``neighbor_allreduce``'s also takes, by
+    keyword, an ``order``: a pytree like it of the ranks at which its leaves
+    are ready, by which it packs them into the few buckets the TPU scheduler
+    will keep in flight (:func:`ops_spmd.neighbor_allreduce`); without one
+    it permutes leaf by leaf, and the TPU compiler merges none of those
+    permutes."""
     if comm_type == CommunicationType.empty:
         return lambda x: x
     if comm_type == CommunicationType.allreduce:
@@ -105,8 +98,8 @@ def make_spmd_comm_fn(
     if comm_type == CommunicationType.neighbor_allreduce:
         if plan is None:
             raise ValueError("neighbor_allreduce needs a CommPlan")
-        return lambda x: ops_spmd.neighbor_allreduce(x, plan, axis_name,
-                                                     fuse=fuse)
+        return lambda x, order=None: ops_spmd.neighbor_allreduce(
+            x, plan, axis_name, order=order)
     if comm_type == CommunicationType.hierarchical_neighbor_allreduce:
         if machine_plan is None:
             raise ValueError("hierarchical_neighbor_allreduce needs a machine CommPlan")
@@ -125,10 +118,11 @@ def _every_k(comm_fn: CommFn, k: int) -> Callable[[Any, jnp.ndarray], Any]:
     """Communicate only on every k-th call (reference
     ``num_steps_per_communication`` [U]); k==1 avoids the cond entirely."""
     if k <= 1:
-        return lambda x, step: comm_fn(x)
+        return lambda x, step, **kw: comm_fn(x, **kw)
 
-    def maybe(x, step):
-        return jax.lax.cond((step + 1) % k == 0, comm_fn, lambda t: t, x)
+    def maybe(x, step, **kw):
+        return jax.lax.cond((step + 1) % k == 0,
+                            functools.partial(comm_fn, **kw), lambda t: t, x)
 
     return maybe
 
@@ -149,18 +143,22 @@ def adapt_then_combine_spmd(
     def init(params):
         return GossipState(base=base.init(params), step=jnp.zeros((), jnp.int32))
 
-    def update(grads, state, params=None):
+    def update(grads, state, params=None, *, grad_order=None):
         if params is None:
             raise ValueError("ATC requires params")
         with jax.named_scope("optimizer_update"):
             updates, base_state = base.update(grads, state.base, params)
             adapted = optax.apply_updates(params, updates)
+        # an adapted leaf is ready when its gradient is, so the order of the
+        # gradients is the order of what is gossiped; only a caller whose
+        # comm_fn takes one (make_spmd_comm_fn's neighbor_allreduce) gives it
+        kw = {} if grad_order is None else {"order": grad_order}
         with jax.named_scope("gossip_combine"):
-            combined = maybe_comm(adapted, state.step)
+            combined = maybe_comm(adapted, state.step, **kw)
         out = jax.tree_util.tree_map(lambda c, p: (c - p).astype(p.dtype), combined, params)
         return out, GossipState(base=base_state, step=state.step + 1)
 
-    return optax.GradientTransformation(init, update)
+    return optax.GradientTransformationExtraArgs(init, update)
 
 
 def adapt_with_combine_spmd(

@@ -21,11 +21,13 @@ from typing import Any, Callable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import optax
+from jax.extend.core import Literal
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from bluefog_tpu.core import basics
 from bluefog_tpu.core.basics import LOCAL_AXIS, MACHINES_AXIS, NODES_AXIS
 from bluefog_tpu.core.plan import CommPlan
+from bluefog_tpu.ops_spmd import gossip_grouping
 from bluefog_tpu.optim import (
     CommunicationType,
     adapt_then_combine_spmd,
@@ -101,6 +103,50 @@ def make_lm_loss_fns(model):
     return apply_fn, loss_fn
 
 
+def _producer_ranks(jaxpr) -> list:
+    """For each output of ``jaxpr``, the index of the equation that produces
+    it: the order in which a program that runs the equations in turn has its
+    results ready.  Outputs of one equation (a scanned stack, a remat block)
+    share a rank; an input or a literal passed through is ready at once."""
+    produced = {}
+    for k, eqn in enumerate(jaxpr.eqns):
+        for v in eqn.outvars:
+            produced[v] = k
+    return [-1 if isinstance(v, Literal) else produced.get(v, -1)
+            for v in jaxpr.outvars]
+
+
+def _value_and_grad_in_order(loss_of, p):
+    """``jax.value_and_grad(loss_of, has_aux=True)(p)``, and a pytree like
+    ``p`` that gives each gradient the rank at which the backward pass has
+    it ready.  ``loss_of`` is traced once, as an inner ``jit``: the order is
+    read off its jaxpr, and the call that follows finds that trace cached
+    and adds one equation to the step.  (Evaluating the jaxpr equation by
+    equation instead cost the first call 6 s on the chip's host.)"""
+    vg = jax.jit(jax.value_and_grad(loss_of, has_aux=True))
+    ranks = _producer_ranks(vg.trace(p).jaxpr.jaxpr)
+    out = vg(p)
+    grads, treedef = jax.tree_util.tree_flatten(out[1])
+    ranks = ranks[len(ranks) - len(grads):]  # the outputs end in the gradients
+    return out, jax.tree_util.tree_unflatten(treedef, ranks)
+
+
+def _note_gossip_grouping(params, order, plan):
+    """At trace time: the gauges that say how this step's gossip groups its
+    leaves into permutes (``ops_spmd.gossip_grouping`` on the same inputs)."""
+    reg = _telemetry.get_registry()
+    if not reg.enabled:
+        return
+    g = gossip_grouping(
+        jax.tree_util.tree_leaves(params),
+        None if order is None else jax.tree_util.tree_leaves(order),
+        len(plan.classes))
+    reg.gauge("gossip.leaves").set(g.leaves)
+    reg.gauge("gossip.buckets").set(len(g.buckets))
+    reg.gauge("gossip.permutes").set(g.permutes)
+    reg.gauge("gossip.packed_bytes").set(g.packed_bytes)
+
+
 def make_decentralized_train_step(
     apply_fn: Callable,
     base_optimizer: optax.GradientTransformation,
@@ -115,7 +161,6 @@ def make_decentralized_train_step(
     num_steps_per_communication: int = 1,
     donate: bool = True,
     steps_per_call: int = 1,
-    comm_fuse: bool = False,
 ):
     """Build ``(init_fn, step_fn)`` for decentralized training on ``mesh``.
 
@@ -134,9 +179,13 @@ def make_decentralized_train_step(
     loss/acc are the last sub-step's.  Where each dispatch carries a fixed
     cost this amortizes it, at the price of k× compile time.
 
-    ``comm_fuse`` forwards to the gossip's fusion buffer (one ppermute per
-    shift class per dtype group instead of per leaf) — a measured knob,
-    see :func:`bluefog_tpu.optim.make_spmd_comm_fn`.
+    An ATC step that gossips over at least one shift class reads, from its
+    own jaxpr, the order in which the backward pass produces the gradients,
+    and hands it to the gossip, which packs the leaves into the few buckets
+    the TPU scheduler will keep in flight
+    (:func:`bluefog_tpu.ops_spmd.neighbor_allreduce`): the large early
+    bucket travels while the backward pass still runs.  The values are the
+    same, and every other step keeps one permute per leaf.
     """
     apply_takes_labels = apply_accepts_labels(apply_fn)
 
@@ -149,21 +198,24 @@ def make_decentralized_train_step(
         axis_name = NODES_AXIS
 
     if communication_type == CommunicationType.allreduce:
-        if comm_fuse:
-            # this branch never reaches make_spmd_comm_fn's guard, so it
-            # must raise itself — a silently dropped flag poisons A/Bs
-            raise ValueError(
-                "comm_fuse=True is only implemented for "
-                "neighbor_allreduce, not CommunicationType.allreduce"
-            )
         tx = gradient_allreduce_spmd(
             base_optimizer, axis_name, num_steps_per_communication
         )
     else:
-        comm_fn = make_spmd_comm_fn(communication_type, plan, machine_plan,
-                                    fuse=comm_fuse)
+        comm_fn = make_spmd_comm_fn(communication_type, plan, machine_plan)
         builder = {"atc": adapt_then_combine_spmd, "awc": adapt_with_combine_spmd}[mode]
         tx = builder(base_optimizer, comm_fn, num_steps_per_communication)
+    # only ATC's combine waits for the gradients (AWC mixes the parameters
+    # the step came in with), and a plan without a shift class sends nothing
+    orders_gossip = (
+        communication_type == CommunicationType.neighbor_allreduce
+        and mode == "atc" and plan is not None and len(plan.classes) > 0
+    )
+
+    def value_and_grad(loss_of, p):
+        if orders_gossip:
+            return _value_and_grad_in_order(loss_of, p)
+        return jax.value_and_grad(loss_of, has_aux=True)(p), None
 
     def local_step(params, batch_stats, opt_state, batch, labels):
         # strip the local rank-major axis (length 1 per device)
@@ -183,9 +235,8 @@ def make_decentralized_train_step(
                 return loss_fn(logits, y), (logits, mut["batch_stats"])
 
             with jax.named_scope("forward_backward"):
-                (loss, (logits, new_bs)), grads = jax.value_and_grad(
-                    loss_of, has_aux=True
-                )(p)
+                ((loss, (logits, new_bs)), grads), order = value_and_grad(
+                    loss_of, p)
         else:
 
             def loss_of(p_):
@@ -196,13 +247,16 @@ def make_decentralized_train_step(
                 return loss_fn(logits, y), logits
 
             with jax.named_scope("forward_backward"):
-                (loss, logits), grads = jax.value_and_grad(
-                    loss_of, has_aux=True
-                )(p)
+                ((loss, logits), grads), order = value_and_grad(loss_of, p)
             new_bs = bs
 
         # tx.update opens the optimizer-update and gossip scopes of optim.py
-        updates, new_os = tx.update(grads, os_, p)
+        if order is None:
+            updates, new_os = tx.update(grads, os_, p)
+        else:
+            updates, new_os = tx.update(grads, os_, p, grad_order=order)
+        if communication_type == CommunicationType.neighbor_allreduce:
+            _note_gossip_grouping(p, order, plan)
         new_p = optax.apply_updates(p, updates)
         if logits.ndim >= 2:
             acc = jnp.mean((jnp.argmax(logits, -1) == y).astype(jnp.float32))

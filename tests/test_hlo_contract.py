@@ -428,9 +428,10 @@ def test_push_diging_directed_ring_is_one_permute():
     AND the y-tracker all ride one ``fuse=True`` column-stochastic round
     over the single shift class — exactly ONE collective-permute, zero
     all-gathers, for full exact directed optimization.  (Unfused, the
-    odd-shaped v rides its own permute: XLA's combiner merges the two
-    same-shaped tree leaves but not the scalar — measured 2 permutes —
-    which is exactly why the fusion buffer is guaranteed in code.)"""
+    odd-shaped v rides its own permute: the CPU backend's combiner merges
+    the two same-shaped tree leaves but not the scalar — measured 2
+    permutes; the TPU compiler merges none at all (PERF.md section 6,
+    PR 27) — which is exactly why the fusion buffer is guaranteed in code.)"""
     import networkx as nx
 
     from bluefog_tpu import algorithms

@@ -109,11 +109,34 @@ def test_neighbor_allreduce_preserves_average():
     assert np.asarray(out).std(axis=0).max() < np.asarray(x).std(axis=0).max() * 0.2
 
 
-def test_neighbor_allreduce_fused_matches_unfused():
-    """``fuse=True`` (the SPMD fusion buffer) must be bit-for-bit exact vs
-    the per-leaf path on a mixed-shape, mixed-dtype pytree — including an
-    awkward scalar-shaped leaf (the push-sum weight case) and an int leaf
-    that accumulates in f32."""
+# (id, topology, MAX_PERMUTES_OUTSTANDING or None for the module's own, fuse,
+#  order, buckets expected).  The tree below has three dtypes; exp2(8) has
+# three shift classes and ring(8) two, so B = (MAX - 1) // classes.
+_GROUPINGS = [
+    pytest.param(tu.ExponentialTwoGraph, None, True, None, 3, id="fuse"),
+    pytest.param(tu.ExponentialTwoGraph, None, False, "shuffled", 3,
+                 id="order-B1-one-bucket-per-dtype"),
+    pytest.param(tu.RingGraph, None, False, "shuffled", 3,
+                 id="order-B2-ring-fewer-than-dtypes"),
+    pytest.param(tu.ExponentialTwoGraph, 13, False, "shuffled", 4,
+                 id="order-B4-one-split"),
+    pytest.param(tu.ExponentialTwoGraph, 16, False, "shuffled", 5,
+                 id="order-B5-two-splits"),
+    # ties fall back on flatten order (b, v, w): w holds two thirds of the
+    # elements and comes last, so it is the tail, however small TAIL_SHARE is
+    pytest.param(tu.ExponentialTwoGraph, 13, False, "tied", 4,
+                 id="order-B4-tied-ranks"),
+]
+
+
+@pytest.mark.parametrize("graph,max_out,fuse,order,n_buckets", _GROUPINGS)
+def test_neighbor_allreduce_grouped_matches_per_leaf(
+        monkeypatch, graph, max_out, fuse, order, n_buckets):
+    """Every grouping of the leaves into permutes (``fuse=True``, and an
+    ``order`` at several bucket counts, shuffled or tied) must be bit-for-bit
+    exact vs the per-leaf path on a mixed-shape, mixed-dtype pytree —
+    including an awkward scalar-shaped leaf (the push-sum weight case) and
+    an int leaf that accumulates in f32."""
     import jax
     from jax.sharding import PartitionSpec as P
 
@@ -121,8 +144,10 @@ def test_neighbor_allreduce_fused_matches_unfused():
     from bluefog_tpu.core import basics
     from bluefog_tpu.core.basics import NODES_AXIS
 
-    bf.set_topology(tu.ExponentialTwoGraph(SIZE))
+    bf.set_topology(graph(SIZE))
     ctx = basics.context()
+    if max_out is not None:
+        monkeypatch.setattr(ops_spmd, "MAX_PERMUTES_OUTSTANDING", max_out)
     rng = np.random.default_rng(3)
     tree = {
         "w": jnp.asarray(rng.normal(size=(SIZE, 3, 4)), jnp.float32),
@@ -132,19 +157,25 @@ def test_neighbor_allreduce_fused_matches_unfused():
         "n": jnp.arange(SIZE, dtype=jnp.int32)[:, None] * jnp.ones(
             (SIZE, 3), jnp.int32),
     }
+    # ready in neither key order nor its reverse; or all at once
+    ranks = {"shuffled": {"w": 7, "b": 0, "v": 9, "h": 4, "n": 2},
+             "tied": dict.fromkeys(tree, 0), None: None}[order]
 
-    def run(fuse):
+    def run(**grouping):
         spmd = lambda t: ops_spmd.neighbor_allreduce(
-            t, ctx.plan, NODES_AXIS, fuse=fuse)
-        fn = jax.shard_map(spmd, mesh=ctx.mesh, in_specs=P(NODES_AXIS),
-                           out_specs=P(NODES_AXIS))
-        return fn(tree)
+            t, ctx.plan, NODES_AXIS, **grouping)
+        fn = jax.jit(jax.shard_map(spmd, mesh=ctx.mesh, in_specs=P(NODES_AXIS),
+                                   out_specs=P(NODES_AXIS)))
+        return fn(tree), fn.lower(tree).as_text().count("collective_permute")
 
-    fused, plain = run(True), run(False)
+    (grouped, permutes), (plain, per_leaf) = run(fuse=fuse, order=ranks), run()
+    n_classes = len(ctx.plan.classes)
+    assert per_leaf == len(tree) * n_classes
+    assert permutes == n_buckets * n_classes
     for key in tree:
-        assert fused[key].dtype == plain[key].dtype, key
+        assert grouped[key].dtype == plain[key].dtype, key
         np.testing.assert_array_equal(
-            np.asarray(fused[key]), np.asarray(plain[key]), err_msg=key)
+            np.asarray(grouped[key]), np.asarray(plain[key]), err_msg=key)
 
 
 def test_neighbor_allreduce_dynamic_src():

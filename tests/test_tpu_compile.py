@@ -20,11 +20,16 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
+import optax
+
 import bluefog_tpu as bf
-from bluefog_tpu import ops_spmd, topology_util
+from bluefog_tpu import models, ops_spmd, topology_util
+from bluefog_tpu.common.hlo_inspect import entry_schedule, most_outstanding
 from bluefog_tpu.core import basics
 from bluefog_tpu.core.basics import NODES_AXIS
 from bluefog_tpu.kernels.flash_attention import flash_attention
+from bluefog_tpu.models.resnet import BottleneckBlock
+from bluefog_tpu.training import make_decentralized_train_step
 
 
 @pytest.fixture(scope="module")
@@ -106,3 +111,65 @@ def test_exp2_neighbor_allreduce_compiles_to_permutes_on_2x2(topo):
     # exp2(4): every rank hears from i-1 and i-2
     assert "collective-permute" in text
     assert "all-reduce" not in text
+
+
+def _atc_step_on_2x2(topo):
+    """The ATC train step over exp2(4) on the described mesh, at the sizes of
+    chipbench/configs/resnet50.json's ``rehearsal`` (compiles in seconds):
+    (compiled text, number of shift classes, number of leaves)."""
+    model = models.ResNet(stage_sizes=[1, 1], block_cls=BottleneckBlock,
+                          num_classes=10, num_filters=8)
+    bf.init(devices=topo.devices)
+    try:
+        bf.set_topology(topology_util.ExponentialTwoGraph(4))
+        ctx = basics.context()
+        ranks = NamedSharding(ctx.mesh, P(NODES_AXIS))
+        every = NamedSharding(ctx.mesh, P())
+        rank_major = lambda t: jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct((4,) + a.shape, a.dtype, sharding=ranks), t)
+        v = jax.eval_shape(lambda: model.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 32, 32, 3))))
+        init_fn, step_fn = make_decentralized_train_step(
+            model.apply, optax.sgd(0.1, momentum=0.9), ctx.mesh, plan=ctx.plan,
+            has_batch_stats=True)
+        params, stats = rank_major(v["params"]), rank_major(v["batch_stats"])
+        opt = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(
+                a.shape, a.dtype, sharding=ranks if a.ndim else every),
+            jax.eval_shape(init_fn, params))
+        x = jax.ShapeDtypeStruct((4, 4, 32, 32, 3), jnp.float32, sharding=ranks)
+        y = jax.ShapeDtypeStruct((4, 4), jnp.int32, sharding=ranks)
+        text = jax.jit(step_fn, donate_argnums=(0, 1, 2)).lower(
+            params, stats, opt, x, y).compile().as_text()
+        return text, len(ctx.plan.classes), len(jax.tree_util.tree_leaves(params))
+    finally:
+        bf.shutdown()
+
+
+def test_atc_step_starts_its_first_bucket_under_the_backward_pass(topo):
+    """As many permutes as the scheduler will hold, and the bucket that is
+    ready first starts before the last convolution of the scheduled program:
+    its bytes travel while the backward pass still runs."""
+    text, classes, _ = _atc_step_on_2x2(topo)
+    buckets = (ops_spmd.MAX_PERMUTES_OUTSTANDING - 1) // classes
+    marks = entry_schedule(text)
+    assert marks.count("S") == marks.count("D") == classes * buckets == 4
+    assert marks[:marks.rfind("C")].count("S") == classes  # the first bucket's
+    assert "all-reduce" not in text
+
+
+def test_per_leaf_step_parks_its_permutes_behind_the_backward_pass(
+        topo, monkeypatch):
+    """What the grouping is for, and the limit its constant is read from:
+    with a permute per leaf the scheduler keeps ``MAX_PERMUTES_OUTSTANDING``
+    in flight and no more, so only that many starts precede the last
+    convolution.  A compiler that lifts the limit fails here."""
+    real = ops_spmd.neighbor_allreduce
+    monkeypatch.setattr(ops_spmd, "neighbor_allreduce",
+                        lambda *a, order=None, **k: real(*a, **k))
+    text, classes, leaves = _atc_step_on_2x2(topo)
+    marks = entry_schedule(text)
+    assert marks.count("S") == marks.count("D") == classes * leaves
+    assert most_outstanding(marks) == ops_spmd.MAX_PERMUTES_OUTSTANDING
+    early = marks[:marks.rfind("C")].count("S")
+    assert 0 < early <= ops_spmd.MAX_PERMUTES_OUTSTANDING
