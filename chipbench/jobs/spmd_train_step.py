@@ -1,6 +1,9 @@
 """Job kind `spmd_train_step`: the jitted SPMD step a user gets from
 `training.make_decentralized_train_step` with its defaults (donate=True,
-comm_fuse=False, steps_per_call=1), ATC or allreduce as the mix says.
+steps_per_call=1), ATC or allreduce as the mix says.  The loss is the
+library's default (softmax cross-entropy of the logits `apply_fn` returns)
+unless `program/<config>.py` gives a `loss_fn` of its own: a language model
+whose `apply_fn` returns the chunked scalar loss hands over the identity.
 Copied from chip_smoke._ResNetJob (sound: ran on the chip in PR 23)."""
 
 import jax
@@ -13,6 +16,8 @@ from chipbench import optimizers, seeded
 # what the trace shows of this job (one XLA program per step)
 STEP_ANCHOR = r"^jit_local_step"
 WINDOW_PROGRAMS = ()
+# the library's own spans that a step of this job records
+PROGRAM_SPANS = ("train_step",)
 
 
 class Job:
@@ -22,11 +27,12 @@ class Job:
         self.comm = CommunicationType[mix["communication_type"]]
         gossips = self.comm == CommunicationType.neighbor_allreduce
         program = spec.program
+        own_loss = {"loss_fn": program["loss_fn"]} if "loss_fn" in program else {}
         init_fn, self.step_fn = make_decentralized_train_step(
             program["apply_fn"], optimizers.make(spec.opt_spec), ctx.mesh,
             communication_type=self.comm, plan=ctx.plan if gossips else None,
             mode=mix.get("mode", "atc"),
-            has_batch_stats=program["has_batch_stats"])
+            has_batch_stats=program["has_batch_stats"], **own_loss)
         params = seeded.nest(spec.params)
         stats = seeded.nest(spec.stats)
         self.state = (params, stats, init_fn(params))

@@ -12,6 +12,9 @@ import os
 from dataclasses import dataclass, field
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+# what a traffic mix may lay over its configuration's sizes: the shape of the
+# traffic, never a width of the model
+MIX_SIZES = ("per_rank_batch", "seq_len")
 
 
 class ManifestError(Exception):
@@ -73,9 +76,11 @@ class Cell:
         return load_module(os.path.join(self.bench_dir, kind, metric_name + ".py"))
 
     def sizes(self, rehearse=False):
-        """The sizes that are run: the configuration's, or its rehearsal
-        block laid over them."""
+        """The sizes that are run: the configuration's, the mix's `sizes`
+        block laid over them, and in a rehearsal the configuration's
+        `rehearsal` block over both, so that a rehearsal stays tiny."""
         sizes = dict(self.config["sizes"])
+        sizes.update(self.mix.get("sizes", {}))
         if rehearse:
             sizes.update(self.config["rehearsal"])
         return sizes
@@ -97,7 +102,13 @@ def resolve(workload, root=REPO, manifest=None):
     c = _entry(manifest["configs"], w["config"], "config")
     bench_dir = os.path.join(root, manifest["paths"][0])
     config = load_json(os.path.join(root, c["file"]))
-    mix = load_json(os.path.join(bench_dir, "traffic", w["traffic"] + ".json"))
+    mix_path = os.path.join(bench_dir, "traffic", w["traffic"] + ".json")
+    mix = load_json(mix_path)
+    stray = sorted(set(mix.get("sizes", {})) - set(MIX_SIZES))
+    if stray:
+        raise ManifestError(
+            f"{mix_path}: a mix's `sizes` may hold only {', '.join(MIX_SIZES)}; "
+            f"it has {', '.join(stray)}")
     files = {
         "reference": os.path.join(bench_dir, "reference", w["config"] + ".py"),
         "program": os.path.join(bench_dir, "program", w["config"] + ".py"),

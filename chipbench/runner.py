@@ -293,9 +293,18 @@ def run(args, t0, cell, wrap_job=None):
     _log(f"check structure: {json.dumps(structure)}; leaves on {placed} of "
          f"{chips} device(s); compiles in window {compiles_window[0]}; "
          f"reference took {time.perf_counter() - t_ref:.3f} s")
-    correct = bool(agrees and structure["ok"] and placed == chips
-                   and compiles_window[0] == 0 and win["failed"] == 0
-                   and warm["failed"] == 0 and np.isfinite(got["losses"]).all())
+    # every number that decides `correct`, beside its limit
+    structural = {
+        "structure_mismatch": int(not structure["ok"]),
+        "chips_without_leaves": chips - placed,
+        "compiles_in_window": compiles_window[0],
+        "failed_steps": win["failed"] + warm["failed"],
+        "nonfinite_checked_losses": int(np.sum(~np.isfinite(got["losses"]))),
+    }
+    correct = bool(agrees and not any(structural.values()))
+    checks = {name: {"value": v["value"] if math.isfinite(v["value"]) else None,
+                     "limit": v["limit"]} for name, v in compared.items()}
+    checks.update({name: {"value": v, "limit": 0} for name, v in structural.items()})
 
     # ---- the result line -------------------------------------------------
     bench_run = {
@@ -322,6 +331,7 @@ def run(args, t0, cell, wrap_job=None):
         dev["busy_s"] = traced["busy_s"]
         dev["window_s"] = traced["window_s"]
         result["breakdown"] = traced["breakdown"]
+    result["checks"] = checks  # last in the line: the ledger keeps its end
     bf.shutdown()
     return result
 
@@ -358,6 +368,9 @@ def main(t0=None):
               f"{len(devs)}", file=sys.stderr)
         return 2
     result = run(args, t0, cell)
+    for name, c in result["checks"].items():  # the last lines on stderr
+        print(f"chipbench: check {name}: {c['value']} (limit {c['limit']})",
+              file=sys.stderr)
     sys.stderr.flush()
     print(json.dumps(result), flush=True)
     return 0

@@ -24,6 +24,7 @@ COLLECTIVE = re.compile(
     r"collective-broadcast|send|recv)")
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 TOP = 10
+NAME_CUT = 96  # an op's name is the start of its HLO text
 
 
 # -- interval arithmetic ----------------------------------------------------
@@ -109,7 +110,7 @@ def top_ops(events, devices):
     """Device seconds by op, mean per device: [[name, seconds], ...]."""
     total = collections.Counter()
     for name, s, e in events:
-        total[name[:96]] += e - s
+        total[name[:NAME_CUT]] += e - s
     return [[n, v / max(devices, 1)] for n, v in total.most_common(TOP)]
 
 
@@ -162,9 +163,23 @@ def _worst(per_device):
     return max(meds) if meds else None
 
 
+def _ops_ms(sorted_ops, steps):
+    """{op name: [its device ms in each step]}: every op of the `XLA Ops`
+    line (a collective's own start and done ops too, not its time in
+    flight), summed by name as `top_ops` cuts it, counted in the step it
+    starts in and clipped to it."""
+    by_name = collections.defaultdict(lambda: [0.0] * len(steps))
+    for k, (a, b) in enumerate(steps):
+        for s, e, name in _started_in(sorted_ops, a, b):
+            by_name[name[:NAME_CUT]][k] += 1e3 * (min(e, b) - s)
+    return by_name
+
+
 def reduce(trace, step_anchor, window_programs=()):
     """Per-layer numbers of a traced window.  Times in the result are
-    seconds, except the `*_ms` per-step values."""
+    seconds, except the `*_ms` per-step values.  `ops_ms_per_step` is what a
+    reader of one kernel looks its name up in: {op name: device ms per step},
+    per device the median over steps, then the largest over devices."""
     anchor = re.compile(step_anchor)
     window = [re.compile(p) for p in window_programs]
     devs = trace["devices"]
@@ -174,6 +189,7 @@ def reduce(trace, step_anchor, window_programs=()):
     hi = max(max(e for _, _, e in d["ops"]) for d in devs.values())
     busy_s, idle, all_ops = [], [], []
     compute_ms, coll_ms, exposed_ms, window_ms, launches = [], [], [], [], []
+    ops_ms = collections.defaultdict(list)
     n_steps = 0
     for dev in devs.values():
         ops = dev["ops"]
@@ -186,6 +202,9 @@ def reduce(trace, step_anchor, window_programs=()):
         compute = sorted((s, e) for n, s, e in ops if not COLLECTIVE.match(n))
         coll = sorted((s, e) for n, s, e in ops + dev["async"] if COLLECTIVE.match(n))
         mods = sorted((s, e, n) for n, s, e in dev["modules"])
+        for name, per_step in _ops_ms(sorted((s, e, n) for n, s, e in ops),
+                                      steps).items():
+            ops_ms[name].append(per_step)
         c_ms, k_ms, x_ms, w_ms, l_n = [], [], [], [], []
         for a, b in steps:
             comp = clip(_started_in(compute, a, b), a, b)
@@ -212,6 +231,7 @@ def reduce(trace, step_anchor, window_programs=()):
         "collective_exposed_ms_per_step": _worst(exposed_ms),
         "window_device_ms_per_round": _worst(window_ms),
         "launches_per_round": _worst(launches),
+        "ops_ms_per_step": {name: _worst(v) for name, v in sorted(ops_ms.items())},
         "breakdown": {
             "device_ops": top_ops(all_ops, len(devs)),
             "idle_gaps": attribute_gaps(idle, trace["host"]),

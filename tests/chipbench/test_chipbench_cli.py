@@ -23,6 +23,9 @@ def _chipbench(*args, devices=1):
                           env=env, capture_output=True, text=True, timeout=120)
 
 
+RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device", "checks"}
+
+
 def _result(proc):
     assert proc.returncode == 0, proc.stderr[-2000:]
     lines = proc.stdout.strip().splitlines()
@@ -33,7 +36,7 @@ def test_pushsum_rehearsal_prints_the_contracts_object_and_nothing_else():
     proc = _chipbench("--workload", "bert-base-pushsum-1chip", "--seed",
                       str(2**31 + 77), "--seconds", "1", "--trace", "0", "--rehearse")
     result, earlier = _result(proc)
-    assert set(result) == {"correct", "attempted", "failed", "metrics", "device"}
+    assert set(result) == RESULT_KEYS
     assert result["correct"] is True and result["failed"] == 0
     assert result["attempted"] > 10
     assert set(result["metrics"]) == {"train_samples_s_chip", "step_ms_p95", "setup_s"}
@@ -56,7 +59,7 @@ def test_four_rank_traced_rehearsal_names_no_share_of_a_peak_and_no_idle_share()
     proc = _chipbench("--workload", "resnet50-atc-exp2-4chip", "--seed", "4",
                       "--seconds", "1", "--trace", "1", "--rehearse", devices=4)
     result, earlier = _result(proc)
-    assert set(result) == {"correct", "attempted", "failed", "metrics", "device"}
+    assert set(result) == RESULT_KEYS
     assert result["correct"] is True and result["device"]["count"] == 4
     assert result["device"]["platform"] == "cpu"
     assert "busy_s" not in result["device"] and "window_s" not in result["device"]
@@ -70,6 +73,36 @@ def test_four_rank_traced_rehearsal_names_no_share_of_a_peak_and_no_idle_share()
         assert name not in result["metrics"], name
     assert any('"collective_permute_in_lowered_step": true' in l for l in earlier)
     assert any("leaves on 4 of 4 device(s)" in l for l in earlier)
+
+
+def test_every_number_compared_closes_stderr_and_the_result_line():
+    """The contract's record of a run that is not correct keeps the end of
+    stderr and the end of the last line: both end with each number compared
+    beside its limit."""
+    proc = _chipbench("--workload", "bert-base-atc-b128-1chip", "--seed",
+                      str(2**31 + 28), "--seconds", "1", "--trace", "0", "--rehearse")
+    result, earlier = _result(proc)
+    assert list(result) == ["correct", "attempted", "failed", "metrics", "device",
+                            "checks"]  # `checks` comes last
+    assert result["correct"] is True and result["failed"] == 0
+    assert set(result["metrics"]) == {"train_samples_s_chip", "step_ms_p95", "setup_s"}
+    checks = result["checks"]
+    limits = manifest.resolve("bert-base-atc-b128-1chip").module("reference").LIMITS
+    assert {name: checks[name]["limit"] for name in limits} == limits
+    assert all(checks[name]["value"] <= limits[name] for name in limits)
+    for name in ("structure_mismatch", "chips_without_leaves", "compiles_in_window",
+                 "failed_steps", "nonfinite_checked_losses"):
+        assert checks[name] == {"value": 0, "limit": 0}
+    last = proc.stderr.strip().splitlines()[-len(checks):]
+    assert [l.split()[2].rstrip(":") for l in last] == list(checks)
+    assert all(l.startswith("chipbench: check ") and "(limit " in l for l in last)
+    # the jitted step on one rank: no neighbour, so no permute in the step
+    assert any('"collective_permute_in_lowered_step": false' in l for l in earlier)
+    # the mix's batch is laid over the configuration's, the rehearsal's over both
+    cell = manifest.resolve("bert-base-atc-b128-1chip")
+    assert cell.sizes()["per_rank_batch"] == 128
+    assert cell.sizes(rehearse=True)["per_rank_batch"] == 4
+    assert cell.mix["optimizer"]["name"] == "adamw"
 
 
 @pytest.mark.parametrize("args,message", [

@@ -81,13 +81,26 @@ def test_metrics_bounds_and_what_each_layer_metric_moves(m):
         assert any(c in p.get("workloads", cells) for p in m["per_layer"])
 
 
+def _states_its_cut(config):
+    """A cut is stated, not hidden: each item of `reduced` begins with the key
+    of `sizes` it cuts, and the deployment that the cut stands for is named."""
+    reduced = config["reduced"]
+    assert isinstance(reduced, list)
+    for item in reduced:
+        assert isinstance(item, str) and any(
+            re.match(re.escape(key) + r"\b", item) for key in config["sizes"]), item
+    if reduced:
+        assert isinstance(config["deployment"], str) and config["deployment"]
+
+
 def test_every_workload_resolves_to_its_files(m):
     for w in m["workloads"]:
         cell = manifest.resolve(w["name"])
         assert cell.chips == w["chips"]
         assert set(cell.files) == {"reference", "program", "flops", "job", "mixing"}
         assert all(os.path.isfile(f) for f in cell.files.values())
-        assert cell.config["reduced"] == [] and "rehearsal" in cell.config
+        _states_its_cut(cell.config)
+        assert "rehearsal" in cell.config
         assert cell.sizes(rehearse=True) != cell.sizes()
         for metric in cell.per_layer:
             assert callable(cell.reader(metric["name"]).read)
@@ -120,6 +133,39 @@ def test_a_name_without_a_file_is_an_error_that_names_the_path(tmp_path, m):
         manifest.resolve("no-such-cell", root=str(root))
 
 
+def test_a_mix_lays_its_sizes_over_the_configurations(m):
+    cell = manifest.resolve("bert-base-atc-b128-1chip")
+    config = cell.config["sizes"]
+    assert cell.mix["sizes"] == {"per_rank_batch": 128}
+    assert cell.sizes() == dict(config, per_rank_batch=128)
+    assert config["per_rank_batch"] == 32  # the configuration's file is as it was
+    # a rehearsal lays the configuration's tiny block over both
+    assert cell.sizes(rehearse=True) == dict(config, **cell.config["rehearsal"])
+    # the cells without such a block resolve to the configuration's sizes
+    for name in ("resnet50-atc-1chip", "bert-base-pushsum-1chip",
+                 "resnet50-atc-exp2-4chip"):
+        other = manifest.resolve(name)
+        assert "sizes" not in other.mix and other.sizes() == other.config["sizes"]
+
+
+@pytest.mark.parametrize("block,stray", [
+    ({"per_rank_batch": 8, "hidden_size": 64}, "hidden_size"),
+    ({"num_hidden_layers": 2}, "num_hidden_layers"),
+])
+def test_a_mix_may_not_lay_a_width_or_a_depth(tmp_path, m, block, stray):
+    root = tmp_path / "checkout"
+    shutil.copytree(os.path.join(REPO, "chipbench"), root / "chipbench")
+    shutil.copy(os.path.join(REPO, "BENCHMARK.json"), root / "BENCHMARK.json")
+    mix_file = root / "chipbench" / "traffic" / "atc-b128-1chip.json"
+    mix = json.loads(mix_file.read_text())
+    mix_file.write_text(json.dumps(dict(mix, sizes=block)))
+    with pytest.raises(manifest.ManifestError, match=rf"atc-b128-1chip\.json.*{stray}"):
+        manifest.resolve("bert-base-atc-b128-1chip", root=str(root))
+    mix_file.write_text(json.dumps(dict(mix, sizes={"seq_len": 64})))
+    assert manifest.resolve("bert-base-atc-b128-1chip", root=str(root)) \
+        .sizes()["seq_len"] == 64
+
+
 def test_a_later_pr_adds_files_and_entries_and_edits_nothing(tmp_path, m):
     root = tmp_path / "checkout"
     bench = root / "chipbench"
@@ -127,13 +173,16 @@ def test_a_later_pr_adds_files_and_entries_and_edits_nothing(tmp_path, m):
     before = {p: p.read_bytes() for p in bench.rglob("*") if p.is_file()}
     # one new file of each kind ...
     (bench / "configs" / "toy.json").write_text(json.dumps({
-        "name": "toy", "source": "a paper", "sizes": {"width": 8, "per_rank_batch": 2},
-        "optimizer": {"name": "sgd", "learning_rate": 0.1}, "reduced": [],
+        "name": "toy", "source": "a paper",
+        "sizes": {"width": 8, "num_layers": 4, "per_rank_batch": 2},
+        "optimizer": {"name": "sgd", "learning_rate": 0.1},
+        "reduced": ["num_layers"], "published": {"num_layers": 48},
+        "deployment": "4 of 48 layers: one pipeline stage of twelve",
         "assumed": [], "rehearsal": {"width": 2}}))
     for kind in ("reference", "program", "flops"):
         (bench / kind / "toy.py").write_text("MARK = %r\n" % kind)
     (bench / "traffic" / "toy-mix.json").write_text(json.dumps({
-        "job": "toy_job", "mixing": "toy_mixing",
+        "job": "toy_job", "mixing": "toy_mixing", "sizes": {"per_rank_batch": 6},
         "topology": {"graph": "RingGraph", "kwargs": {}}}))
     (bench / "jobs" / "toy_job.py").write_text("class Job:\n    pass\n")
     (bench / "mixing" / "toy_mixing.py").write_text(
@@ -142,7 +191,8 @@ def test_a_later_pr_adds_files_and_entries_and_edits_nothing(tmp_path, m):
         "def read(run):\n    return run.get('toy')\n")
     # ... and entries appended to the manifest
     new = json.loads(json.dumps(m))
-    new["configs"].append({"name": "toy", "source": "a paper", "reduced": [],
+    new["configs"].append({"name": "toy", "source": "a paper",
+                           "reduced": ["num_layers"],
                            "file": "chipbench/configs/toy.json", "why": "test"})
     new["workloads"].append({"name": "toy-cell", "config": "toy",
                              "traffic": "toy-mix", "chips": 1, "why": "test"})
@@ -152,8 +202,15 @@ def test_a_later_pr_adds_files_and_entries_and_edits_nothing(tmp_path, m):
                              "workloads": ["toy-cell"]})
     (root / "BENCHMARK.json").write_text(json.dumps(new))
     cell = manifest.resolve("toy-cell", root=str(root))
-    assert cell.sizes() == {"width": 8, "per_rank_batch": 2}
-    assert cell.sizes(rehearse=True)["width"] == 2
+    assert cell.sizes() == {"width": 8, "num_layers": 4, "per_rank_batch": 6}
+    assert cell.sizes(rehearse=True) == {"width": 2, "num_layers": 4,
+                                         "per_rank_batch": 6}
+    assert cell.config["reduced"] == ["num_layers"]
+    _states_its_cut(cell.config)  # as every cell of the manifest is held to
+    with pytest.raises(AssertionError):
+        _states_its_cut(dict(cell.config, reduced=["layers_kept"]))
+    with pytest.raises(KeyError):
+        _states_its_cut({k: v for k, v in cell.config.items() if k != "deployment"})
     assert cell.module("job").Job and cell.module("reference").MARK == "reference"
     assert cell.module("mixing").matrix(3).shape == (3, 3)
     names = [p["name"] for p in cell.per_layer]

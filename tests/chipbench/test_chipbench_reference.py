@@ -59,27 +59,46 @@ def test_mix_is_M_x_over_M_1_and_a_rounded_payload_shows():
     assert np.linalg.norm(own - one) > 0
 
 
-@pytest.mark.parametrize("config", ["resnet50", "bert-base"])
-def test_reference_agrees_with_the_program_at_rehearsal_sizes(config):
+def _first_cell_of_every_configuration():
+    """{configuration: the first cell that names it}, from the manifest, so
+    that a new configuration is a new case."""
+    first = {}
+    for w in manifest.load_manifest()["workloads"]:
+        first.setdefault(w["config"], w["name"])
+    return first
+
+
+FIRST_CELLS = _first_cell_of_every_configuration()
+
+
+@pytest.mark.parametrize("workload", FIRST_CELLS.values(), ids=list(FIRST_CELLS))
+def test_reference_agrees_with_the_program_at_rehearsal_sizes(workload):
     import optax
 
-    cell = manifest.resolve(
-        {"resnet50": "resnet50-atc-1chip", "bert-base": "bert-base-pushsum-1chip"}[config])
+    from bluefog_tpu.training import apply_accepts_labels
+
+    cell = manifest.resolve(workload)
     sizes = cell.sizes(rehearse=True)
     ref = cell.module("reference")
     program = cell.module("program").build(sizes)
     params, stats = seeded.make_weights(ref, sizes, seed=7)
     (x, y), = seeded.make_batches(ref, sizes, 7, ranks=1, pool=1)
     x, y = x[0], y[0]
+    # the program's own loss where it gives one (a model that returns the
+    # chunked scalar loss hands over the identity), as the job passes it on
+    loss_of = program.get("loss_fn", lambda logits, labels: (
+        optax.softmax_cross_entropy_with_integer_labels(logits, labels).mean()))
 
     def program_loss(p):
         variables = {"params": seeded.nest(p)}
         if program["has_batch_stats"]:
             variables["batch_stats"] = seeded.nest(stats)
-            logits, _ = program["apply_fn"](variables, x, mutable=["batch_stats"])
+            out, _ = program["apply_fn"](variables, x, mutable=["batch_stats"])
+        elif apply_accepts_labels(program["apply_fn"]):
+            out = program["apply_fn"](variables, x, labels=y)
         else:
-            logits = program["apply_fn"](variables, x)
-        return optax.softmax_cross_entropy_with_integer_labels(logits, y).mean()
+            out = program["apply_fn"](variables, x)
+        return loss_of(out, y)
 
     lp, gp = jax.jit(jax.value_and_grad(program_loss))(params)
     (lr, new_stats), gr = jax.jit(jax.value_and_grad(
@@ -107,18 +126,19 @@ def test_seeded_inputs_repeat_for_a_seed_and_differ_between_seeds():
     assert len({row.tobytes() for row in x}) == len(x)  # rows all differ
 
 
-@pytest.fixture
-def pushsum_cell():
-    return manifest.resolve("bert-base-pushsum-1chip")
-
-
-def test_sound_readings_pass_and_both_controls_fail(pushsum_cell):
+@pytest.mark.parametrize("workload,controls", [
+    ("bert-base-pushsum-1chip", ["step", "payload"]),
+    # exp2(1) has no edge: nothing travels, so there is no payload to round
+    ("bert-base-atc-b128-1chip", ["step"]),
+])
+def test_sound_readings_pass_and_the_controls_fail(workload, controls):
     """chipbench.control at the rehearsal sizes, one CPU device: the program
     against the reference is inside every limit; the reference computed in
-    float8, and the payload rounded to bfloat16, each fail at least one."""
-    ses = runner.Session(pushsum_cell, rehearse=True)
+    float8, and where a payload travels the payload rounded to bfloat16, each
+    fail at least one."""
+    ses = runner.Session(manifest.resolve(workload), rehearse=True)
     try:
-        row = control.readings(ses, 2**31 + 5, ["step", "payload"])
+        row = control.readings(ses, 2**31 + 5, controls)
     finally:
         bf.shutdown()
     limits = ses.reference.LIMITS
@@ -127,13 +147,14 @@ def test_sound_readings_pass_and_both_controls_fail(pushsum_cell):
         return [k for k, v in row[part].items() if k in limits and not v <= limits[k]]
 
     assert failed("sound") == [], row["sound"]
-    assert failed("control_step"), row["control_step"]
-    assert failed("control_payload"), row["control_payload"]
+    for name in controls:
+        assert failed("control_" + name), row["control_" + name]
 
 
 class _Frozen:
     """A timed path broken underneath: the step runs and returns a loss, but
-    the state it leaves behind is the state it was given."""
+    the state it leaves behind is the state it was given (a copy of it: the
+    jitted step donates its own)."""
 
     def __init__(self, job):
         self.job = job
@@ -142,18 +163,24 @@ class _Frozen:
         return getattr(self.job, name)
 
     def step(self, k):
-        before = self.job.state
+        before = jax.tree_util.tree_map(jnp.copy, self.job.state)
         out = self.job.step(k)
         self.job.state = before
         return out
 
 
-def test_a_step_that_returns_its_state_unchanged_is_not_correct(pushsum_cell, capsys):
-    args = argparse.Namespace(workload=pushsum_cell.name, seed=9, seconds=0.3,
+@pytest.mark.parametrize("workload", ["bert-base-pushsum-1chip",
+                                      "bert-base-atc-b128-1chip"])
+def test_a_step_that_returns_its_state_unchanged_is_not_correct(workload, capsys):
+    cell = manifest.resolve(workload)
+    args = argparse.Namespace(workload=cell.name, seed=9, seconds=0.3,
                               trace=0, rehearse=True)
-    result = runner.run(args, time.perf_counter(), pushsum_cell, wrap_job=_Frozen)
+    result = runner.run(args, time.perf_counter(), cell, wrap_job=_Frozen)
     out = capsys.readouterr().out
     assert result["correct"] is False
     assert result["failed"] == 0 and result["attempted"] > 0  # it ran; it is wrong
     assert "NOT OK" in out and "check delta_norm_gap" in out
-    assert set(result) == {"correct", "attempted", "failed", "metrics", "device"}
+    assert set(result) == {"correct", "attempted", "failed", "metrics", "device",
+                           "checks"}
+    assert result["checks"]["delta_norm_gap"]["value"] \
+        > result["checks"]["delta_norm_gap"]["limit"]

@@ -1,6 +1,7 @@
 """trace_reduce's interval arithmetic on synthetic event lists, and the
 reduction of a small trace recorded on the chip (tests/chipbench/data)."""
 
+import json
 import os
 import sys
 
@@ -9,7 +10,7 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 sys.path.insert(0, REPO)
 
-from chipbench import spans, trace_reduce as tr  # noqa: E402
+from chipbench import manifest, spans, trace_reduce as tr  # noqa: E402
 
 RECORDED = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data",
                         "tiny_v5e.xplane.pb")
@@ -108,6 +109,30 @@ def test_reduce_counts_window_programs_and_launches_per_round():
     assert r["collective_ms_per_step"] == 0
 
 
+def test_ops_ms_per_step_is_the_median_step_of_the_slowest_device_by_name():
+    r = tr.reduce(_synthetic(), r"^jit_local_step")
+    ops = r["ops_ms_per_step"]
+    assert list(ops) == sorted(ops) and len(ops) == 3
+    assert ops["%fusion.1 = f32[8] fusion()"] == pytest.approx(6.0)
+    assert ops["%fusion.2 = f32[8] fusion()"] == pytest.approx(1.0)
+    # a collective's own op, not its 3-4 ms in flight (the `async` line)
+    assert ops["%collective-permute-start.1 = f32[8]"] == pytest.approx(0.1)
+    # names are cut as `breakdown` cuts them; an op that runs in one step of
+    # the three kept reads the median, 0; one that runs past the step's end
+    # is clipped to the step
+    trace = _synthetic(devices=1)
+    long_name = "%fusion.9 = " + "x" * 200
+    trace["devices"][0]["ops"] += [(long_name, 0.0225, 0.0226),
+                                   ("%late = f32[]", 0.0395, 0.0415)]
+    ops = tr.reduce(trace, r"^jit_local_step")["ops_ms_per_step"]
+    assert ops[long_name[:96]] == 0 and long_name not in ops
+    assert ops["%late = f32[]"] == 0  # one step of three: the median is 0
+    two = _synthetic(devices=1, steps=3)  # two whole steps: both kept
+    two["devices"][0]["ops"].append(("%late = f32[]", 0.0095, 0.0115))
+    assert tr.reduce(two, r"^jit_local_step")["ops_ms_per_step"]["%late = f32[]"] \
+        == pytest.approx(0.25)  # (0.5 ms inside its step + 0) / 2
+
+
 def test_reduce_without_a_device_plane_finds_nothing():
     assert tr.reduce({"devices": {}, "host": []}, r"^x") is None
 
@@ -132,3 +157,40 @@ def test_reduce_reads_a_trace_recorded_on_the_chip():
     assert 0 < r["busy_s"] <= r["window_s"]
     assert r["compute_ms_per_step"] > 0 and r["collective_ms_per_step"] == 0
     assert r["breakdown"]["device_ops"] and r["breakdown"]["idle_gaps"]
+
+
+@pytest.mark.skipif(not os.path.isfile(RECORDED),
+                    reason="no recorded trace in tests/chipbench/data")
+def test_every_value_read_before_ops_ms_per_step_is_unchanged_to_the_last_digit():
+    """tiny_v5e.reduced.json is `reduce` of the recorded trace as the commit
+    before `ops_ms_per_step` computed it (PR 27's tree)."""
+    r = tr.reduce(tr.load(RECORDED, spans.NAMES), r"^jit_tiny_step")
+    by_name = r.pop("ops_ms_per_step")
+    with open(RECORDED.replace(".xplane.pb", ".reduced.json")) as f:
+        before = json.load(f)
+    assert json.loads(json.dumps(r)) == before
+    # over names it sums to the compute of a step plus the collectives' own
+    # ops (none in this program), within the rounding of a median per name
+    assert r["collective_ms_per_step"] == 0
+    assert sum(by_name.values()) == pytest.approx(r["compute_ms_per_step"], rel=5e-3)
+    assert len(by_name) == 4 and all(len(name) <= 96 for name in by_name)
+
+
+@pytest.mark.skipif(not os.path.isfile(RECORDED),
+                    reason="no recorded trace in tests/chipbench/data")
+def test_a_reader_added_as_a_file_sees_a_kernel_by_name(tmp_path):
+    """What a later PR's `<kernel>_roofline` reader does: look the kernel up
+    by name among all the ops of a step, not among the ten largest."""
+    reader = tmp_path / "toy_kernel_ms.py"
+    reader.write_text(
+        "def read(run):\n"
+        "    ops = (run['trace'] or {}).get('ops_ms_per_step', {})\n"
+        "    found = [ms for name, ms in ops.items() if name.startswith(run['kernel'])]\n"
+        "    return sum(found) if found else None\n")
+    read = manifest.load_module(str(reader)).read
+    trace = tr.reduce(tr.load(RECORDED, spans.NAMES), r"^jit_tiny_step")
+    assert read({"trace": trace, "kernel": "%convolution_tanh_fusion"}) \
+        == pytest.approx(0.00388, rel=0.02)
+    assert read({"trace": trace, "kernel": "%copy-start"}) == pytest.approx(1.3e-5, rel=0.2)
+    assert read({"trace": trace, "kernel": "%no_such_kernel"}) is None
+    assert read({"trace": None, "kernel": "%copy-start"}) is None
