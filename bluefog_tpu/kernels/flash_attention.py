@@ -1,5 +1,20 @@
 """Flash attention: blockwise XLA forward/backward + a Pallas TPU kernel.
 
+**What the chip records hold (PR 29, `PERF.md` section 6; TPU v5e, one
+chip, a traced run of the cell `smallthinker-21b-a3b-atc-b2-s8k-1chip`:
+B2 S8192, 28 heads of 128, bfloat16, 1024 x 1024 blocks, device time a
+call, visible query-key pairs only counted as work):** the banded kernels
+(``window=4096``, 30 of the 64 tiles visited): forward 7.42 ms (97 TF/s,
+49 % of the bf16 peak), dK/dV 10.87 ms (133 TF/s, 67 %), dQ 8.22 ms (132
+TF/s, 67 %); the whole-sequence causal kernels (``window=None``, 36 tiles):
+forward 9.17 ms (105 TF/s), dK/dV 13.93 ms (138 TF/s), dQ 10.81 ms (134
+TF/s).  **Every other speed in this file (the "builder readings of
+2026-07": forward and training numbers, tok/s, the block and lane A/Bs) is
+older than the chip records, was taken on older code with another
+estimator at invented widths (D=64, 12-14 heads), and has not been
+re-measured: read them as the history of the choices, not as today's
+speeds.**
+
 Two interchangeable forwards behind one ``impl`` switch ("auto" default =
 the Pallas kernel): a hand Pallas kernel and an online-softmax blockwise
 computation in plain XLA (``impl="xla"``).  Forward-only standing (r4
@@ -49,6 +64,18 @@ end-to-end** on Llama-134M training (72.1k → 83.2k tok/s) and +6% at 1B.
 The XLA backward remains behind ``impl="xla"``.  The lse output is
 itself differentiable (its cotangent folds into the dS term), which is
 what lets ring attention's logsumexp *merge* train end-to-end.
+
+A causal *band* (``window=``, PR 29): query ``i`` sees key ``j`` iff ``0 <=
+i - j < window``.  With static equal offsets the three kernels' inner grid
+axis runs over the blocks the band touches and no others (:class:`_Band`:
+the index maps start at the band's first block, clamped, so a block
+outside the band is neither visited nor fetched); edge tiles are masked
+by one broadcast subtract and two compares, interior tiles not at all.
+With traced offsets (a ring hop) the dynamic-offset kernels mask on global
+positions and skip tiles outside the band at run time.  ``window=None``
+traces what it always traced (the interpret-mode lowering is the parent's
+byte for byte; the compiled kernel's serialized form carries source line
+numbers, which moved).
 
 On non-TPU platforms the same kernel runs in Pallas interpret mode (tests
 exercise the real kernel logic on the CPU mesh).
@@ -243,10 +270,92 @@ def _aligned_mask(s, block_q, block_k, delta):
     return jnp.where(col + delta <= row, s, _NEG_INF)
 
 
+def _visible(qpos, kpos, window):
+    """Causal visibility on global positions, banded when ``window`` is set
+    (``window=None`` traces exactly the compare it always did)."""
+    if window is None:
+        return kpos <= qpos
+    return (kpos <= qpos) & (qpos - kpos < window)
+
+
+def _and_in_window(seen, first_k, last_q, block_q, block_k, window):
+    """The dynamic-offset kernels' runtime skip, narrowed to the band: a
+    tile under the diagonal (``seen``) runs only if its first query is
+    within ``window`` of its last key."""
+    if window is None:
+        return seen
+    return seen & (last_q - (block_q - 1) - (first_k + block_k - 1) < window)
+
+
+def _band_mask(s, block_q, block_k, offset, window):
+    """Causal band mask of one tile: visible iff ``0 <= qpos - kpos <
+    window``, with ``offset`` = (first row's position - first column's), a
+    traced scalar.  One [bq,1] - [1,bk] broadcast subtract, two compares."""
+    row = lax.broadcasted_iota(jnp.int32, (block_q, 1), 0)
+    col = lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
+    d = offset + row - col
+    return jnp.where((d >= 0) & (d < window), s, _NEG_INF)
+
+
+class _Band:
+    """Which blocks a causal band ``0 <= i - j < window`` touches, for
+    statically aligned offsets (query i and key i at the same position).
+    The kernels' inner grid axis runs over the ``steps`` blocks a block of
+    the outer axis can touch, not over all of them: blocks wholly outside
+    the band are never visited, and never fetched.  All methods take a
+    program id (traced int32) or a Python int."""
+
+    def __init__(self, block_q, block_k, window, num_q, num_k):
+        self.bq, self.bk, self.w = block_q, block_k, window
+        self.num_q, self.num_k = num_q, num_k
+        # inner-axis lengths: the most blocks any outer block touches
+        self.k_steps = max(self.k_hi(i) - self.k_lo(i) + 1 for i in range(num_q))
+        self.q_steps = max(self.q_hi(j) - self.q_lo(j) + 1 for j in range(num_k))
+
+    # k blocks seen by q block i: positions [i*bq - w + 1, (i+1)*bq - 1]
+    def k_lo(self, i):
+        first = i * self.bq - self.w + 1
+        if isinstance(first, int):
+            return max(first, 0) // self.bk
+        return lax.div(jnp.maximum(first, 0), self.bk)
+
+    def k_hi(self, i):
+        last = (i + 1) * self.bq - 1
+        if isinstance(last, int):
+            return min(last // self.bk, self.num_k - 1)
+        return jnp.minimum(lax.div(last, self.bk), self.num_k - 1)
+
+    # q blocks that see k block j: positions [j*bk, (j+1)*bk - 1 + w - 1]
+    def q_lo(self, j):
+        first = j * self.bk
+        return first // self.bq if isinstance(first, int) else lax.div(first, self.bq)
+
+    def q_hi(self, j):
+        last = (j + 1) * self.bk + self.w - 2
+        if isinstance(last, int):
+            return min(last // self.bq, self.num_q - 1)
+        return jnp.minimum(lax.div(last, self.bq), self.num_q - 1)
+
+    def tile(self, iq, jk):
+        """(runs, interior, offset) of tile (q block iq, k block jk) known
+        to lie inside [lo, lo + steps): ``runs`` is false for the padding
+        steps past ``hi``; an interior tile needs no mask."""
+        r0, c0 = iq * self.bq, jk * self.bk
+        runs = ((c0 <= r0 + self.bq - 1) & (r0 - (c0 + self.bk - 1) < self.w)
+                & (iq < self.num_q) & (jk < self.num_k))
+        interior = (c0 + self.bk - 1 <= r0) & (r0 + self.bq - 1 - c0 < self.w)
+        return runs, interior, r0 - c0
+
+    def when(self, iq, jk, body):
+        runs, interior, _ = self.tile(iq, jk)
+        pl.when(runs & interior)(lambda: body(False))
+        pl.when(runs & jnp.logical_not(interior))(lambda: body(True))
+
+
 def _fwd_kernel(qs_ref, ks_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                 acc, m_ref, l_ref,
                 *, scale: float, block_q: int, block_k: int, causal: bool,
-                num_k: int, aligned_delta):
+                num_k: int, aligned_delta, window=None, band=None):
     """One (bh, iq, jk) program: fold k-block jk into the online softmax.
 
     ``aligned_delta`` (static int or None) enables the aligned fast path:
@@ -260,8 +369,11 @@ def _fwd_kernel(qs_ref, ks_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
     """
     iq = pl.program_id(1)
     jk = pl.program_id(2)
+    step = jk  # place on the inner grid axis
+    if band is not None:
+        jk = band.k_lo(iq) + step  # the k block this step holds
 
-    @pl.when(jk == 0)
+    @pl.when(step == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
@@ -282,14 +394,19 @@ def _fwd_kernel(qs_ref, ks_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
             s = s * scale
         sentinel_rows = False
         if masked:
-            if aligned_delta is None:
+            if band is not None:
+                s = _band_mask(s, block_q, block_k, band.tile(iq, jk)[2],
+                               band.w)
+                # a row of the band's lower-edge tile may see none of its keys
+                sentinel_rows = True
+            elif aligned_delta is None:
                 qpos = qs_ref[0, 0] + iq * block_q + lax.broadcasted_iota(
                     jnp.int32, (block_q, block_k), 0
                 )
                 kpos = ks_ref[0, 0] + jk * block_k + lax.broadcasted_iota(
                     jnp.int32, (block_q, block_k), 1
                 )
-                s = jnp.where(kpos <= qpos, s, _NEG_INF)
+                s = jnp.where(_visible(qpos, kpos, window), s, _NEG_INF)
                 sentinel_rows = True  # dynamic offsets: fully-masked rows possible
             else:
                 s = _aligned_mask(s, block_q, block_k, aligned_delta)
@@ -313,7 +430,9 @@ def _fwd_kernel(qs_ref, ks_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
 
-    if causal and aligned_delta is not None:
+    if band is not None:
+        band.when(iq, jk, _body)
+    elif causal and aligned_delta is not None:
         pl.when(jk < iq)(lambda: _body(False))
         pl.when(jk == iq)(lambda: _body(True))
     elif causal:
@@ -321,11 +440,12 @@ def _fwd_kernel(qs_ref, ks_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         # the offsets are dynamic, so this can't prune at compile time)
         first_k = ks_ref[0, 0] + jk * block_k
         last_q = qs_ref[0, 0] + (iq + 1) * block_q - 1
-        pl.when(first_k <= last_q)(lambda: _body(True))
+        pl.when(_and_in_window(first_k <= last_q, first_k, last_q, block_q,
+                               block_k, window))(lambda: _body(True))
     else:
         _body(False)
 
-    @pl.when(jk == num_k - 1)
+    @pl.when(step == num_k - 1)
     def _finish():
         l = l_ref[:, :1]
         o_ref[0] = (acc[...] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
@@ -353,8 +473,18 @@ def _aligned_or_none(tri_delta, causal, tq, tk, block_q, block_k):
     return None
 
 
+def _band_or_none(window, tri_delta, causal, tq, tk, block_q, block_k):
+    """The banded grid needs what the aligned fast path needs, at delta 0:
+    statically equal offsets and square shapes.  Otherwise a window is
+    masked on global positions by the dynamic-offset kernels, which skip
+    tiles outside the band at run time."""
+    if window is None or not causal or tri_delta != 0 or tq != tk:
+        return None
+    return _Band(block_q, block_k, window, tq // block_q, tk // block_k)
+
+
 def _flash_fwd(q, k, v, q_start, k_start, *, scale, causal, block_q, block_k,
-               interpret, tri_delta=None):
+               interpret, tri_delta=None, window=None):
     """q,k,v: [BH, T, D]; q_start/k_start: int32 scalars (global offsets).
 
     Returns (o [BH, Tq, D], lse [BH, Tq]).
@@ -368,6 +498,18 @@ def _flash_fwd(q, k, v, q_start, k_start, *, scale, causal, block_q, block_k,
 
     qs = jnp.asarray(q_start, jnp.int32).reshape(1, 1)
     ks = jnp.asarray(k_start, jnp.int32).reshape(1, 1)
+    kv_map = lambda b, i, j: (b, j, 0)
+    kernel_kw, call_kw, aligned = {}, {}, None
+    if window is None:
+        aligned = _aligned_or_none(tri_delta, causal, tq, tk, block_q, block_k)
+    else:
+        band = _band_or_none(window, tri_delta, causal, tq, tk, block_q, block_k)
+        kernel_kw = dict(window=window, band=band)
+        call_kw = dict(name="flash_fwd_window")  # as the device trace names it
+        if band is not None:
+            num_k = band.k_steps  # the inner axis visits the band only
+            kv_map = lambda b, i, j: (
+                b, jnp.minimum(band.k_lo(i) + j, band.k_hi(i)), 0)
     kernel = functools.partial(
         _fwd_kernel,
         scale=scale,
@@ -375,8 +517,8 @@ def _flash_fwd(q, k, v, q_start, k_start, *, scale, causal, block_q, block_k,
         block_k=block_k,
         causal=causal,
         num_k=num_k,
-        aligned_delta=_aligned_or_none(tri_delta, causal, tq, tk,
-                                       block_q, block_k),
+        aligned_delta=aligned,
+        **kernel_kw,
     )
     smem = pl.BlockSpec((1, 1), lambda b, i, j: (0, 0),
                         memory_space=pltpu.SMEM)
@@ -387,8 +529,8 @@ def _flash_fwd(q, k, v, q_start, k_start, *, scale, causal, block_q, block_k,
             smem,
             smem,
             _block_spec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            _block_spec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            _block_spec((1, block_k, d), lambda b, i, j: (b, j, 0)),
+            _block_spec((1, block_k, d), kv_map),
+            _block_spec((1, block_k, d), kv_map),
         ],
         out_specs=[
             _block_spec((1, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -404,12 +546,13 @@ def _flash_fwd(q, k, v, q_start, k_start, *, scale, causal, block_q, block_k,
             pltpu.VMEM((block_q, _LANES), jnp.float32),
         ],
         interpret=interpret,
+        **call_kw,
     )(qs, ks, q, k, v)
     return o, lse[:, :, 0]
 
 
 def _blockwise_fwd_xla(q, k, v, q_start, k_start, *, scale, causal, block_k,
-                       tri_delta):
+                       tri_delta, window=None):
     """Online-softmax blockwise forward in plain XLA; same math and
     (o, lse) contract as the Pallas kernel.
 
@@ -429,7 +572,9 @@ def _blockwise_fwd_xla(q, k, v, q_start, k_start, *, scale, causal, block_k,
     num_k = tk // block_k
     f32 = functools.partial(jnp.einsum, preferred_element_type=jnp.float32)
 
-    if _use_triangular(causal, tri_delta, tq, tk, num_k):
+    # a window is masked in the general loop below: every block runs (this
+    # is the fall-back; the Pallas kernels skip what the band leaves out)
+    if window is None and _use_triangular(causal, tri_delta, tq, tk, num_k):
         # triangular unroll: k block j touches only q rows >= j*block_k
         o = vma_full(q, q.shape, jnp.float32)
         m = vma_full(q, (bh, tq, 1), jnp.float32, _NEG_INF)
@@ -462,8 +607,9 @@ def _blockwise_fwd_xla(q, k, v, q_start, k_start, *, scale, causal, block_k,
             s = f32("bqd,bkd->bqk", q, kb) * scale
             if causal:
                 kpos = k_start + j * block_k + jnp.arange(block_k)
-                s = jnp.where((kpos[None, :] <= qpos[:, None])[None], s,
-                              _NEG_INF)
+                s = jnp.where(
+                    _visible(qpos[:, None], kpos[None, :], window)[None], s,
+                    _NEG_INF)
             m_new = jnp.maximum(m, s.max(-1, keepdims=True))
             alpha = jnp.exp(m - m_new)
             p = jnp.exp(s - m_new)
@@ -490,7 +636,8 @@ def _blockwise_fwd_xla(q, k, v, q_start, k_start, *, scale, causal, block_k,
 def _bwd_dkv_kernel(qs_ref, ks_ref, q_ref, g_ref, aux_ref,
                     k_ref, v_ref, dk_ref, dv_ref, dk_acc, dv_acc,
                     *, scale: float, block_q: int, block_k: int,
-                    causal: bool, num_q: int, aligned_delta, half: int):
+                    causal: bool, num_q: int, aligned_delta, half: int,
+                    window=None, band=None):
     """One (bh, jk, iq) program: fold q-block iq into dK/dV of k-block jk.
 
     Same recompute-from-lse trick as the XLA backward, but the
@@ -503,8 +650,11 @@ def _bwd_dkv_kernel(qs_ref, ks_ref, q_ref, g_ref, aux_ref,
     """
     jk = pl.program_id(1)
     iq = pl.program_id(2)
+    step = iq  # place on the inner grid axis
+    if band is not None:
+        iq = band.q_lo(jk) + step  # the q block this step holds
 
-    @pl.when(iq == 0)
+    @pl.when(step == 0)
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
@@ -524,12 +674,15 @@ def _bwd_dkv_kernel(qs_ref, ks_ref, q_ref, g_ref, aux_ref,
         if scale_scores:
             s = s * scale
         if masked:
-            if aligned_delta is None:
+            if band is not None:
+                s = _band_mask(s, block_q, block_k, band.tile(iq, jk)[2],
+                               band.w)
+            elif aligned_delta is None:
                 qpos = qs_ref[0, 0] + iq * block_q + lax.broadcasted_iota(
                     jnp.int32, (block_q, block_k), 0)
                 kpos = ks_ref[0, 0] + jk * block_k + lax.broadcasted_iota(
                     jnp.int32, (block_q, block_k), 1)
-                s = jnp.where(kpos <= qpos, s, _NEG_INF)
+                s = jnp.where(_visible(qpos, kpos, window), s, _NEG_INF)
             else:
                 s = _aligned_mask(s, block_q, block_k, aligned_delta)
             # masked entries (and whole sentinel-lse rows) exp to exactly 0
@@ -555,18 +708,21 @@ def _bwd_dkv_kernel(qs_ref, ks_ref, q_ref, g_ref, aux_ref,
             preferred_element_type=jnp.float32,
         )
 
-    if causal and aligned_delta is not None:
+    if band is not None:
+        band.when(iq, jk, _body)
+    elif causal and aligned_delta is not None:
         pl.when(iq > jk)(lambda: _body(False))
         pl.when(iq == jk)(lambda: _body(True))
     elif causal:
         # skip q blocks entirely above the diagonal (they reach no k row)
         last_q = qs_ref[0, 0] + (iq + 1) * block_q - 1
         first_k = ks_ref[0, 0] + jk * block_k
-        pl.when(last_q >= first_k)(lambda: _body(True))
+        pl.when(_and_in_window(last_q >= first_k, first_k, last_q, block_q,
+                               block_k, window))(lambda: _body(True))
     else:
         _body(False)
 
-    @pl.when(iq == num_q - 1)
+    @pl.when(step == num_q - 1)
     def _finish():
         dk_ref[0] = (dk_acc[...] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
@@ -575,14 +731,18 @@ def _bwd_dkv_kernel(qs_ref, ks_ref, q_ref, g_ref, aux_ref,
 def _bwd_dq_kernel(qs_ref, ks_ref, q_ref, g_ref, aux_ref,
                    k_ref, v_ref, dq_ref, dq_acc,
                    *, scale: float, block_q: int, block_k: int,
-                   causal: bool, num_k: int, aligned_delta, half: int):
+                   causal: bool, num_k: int, aligned_delta, half: int,
+                   window=None, band=None):
     """One (bh, iq, jk) program: fold k-block jk into dQ of q-block iq.
     ``aligned_delta``: see :func:`_fwd_kernel`; ``aux_ref``/``half``: see
     :func:`_bwd_dkv_kernel`."""
     iq = pl.program_id(1)
     jk = pl.program_id(2)
+    step = jk
+    if band is not None:
+        jk = band.k_lo(iq) + step
 
-    @pl.when(jk == 0)
+    @pl.when(step == 0)
     def _init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
@@ -601,12 +761,15 @@ def _bwd_dq_kernel(qs_ref, ks_ref, q_ref, g_ref, aux_ref,
         if scale_scores:
             s = s * scale
         if masked:
-            if aligned_delta is None:
+            if band is not None:
+                s = _band_mask(s, block_q, block_k, band.tile(iq, jk)[2],
+                               band.w)
+            elif aligned_delta is None:
                 qpos = qs_ref[0, 0] + iq * block_q + lax.broadcasted_iota(
                     jnp.int32, (block_q, block_k), 0)
                 kpos = ks_ref[0, 0] + jk * block_k + lax.broadcasted_iota(
                     jnp.int32, (block_q, block_k), 1)
-                s = jnp.where(kpos <= qpos, s, _NEG_INF)
+                s = jnp.where(_visible(qpos, kpos, window), s, _NEG_INF)
             else:
                 s = _aligned_mask(s, block_q, block_k, aligned_delta)
             p = _kexp(jnp.where(s > _MASK_THRESH, s - lse, _NEG_INF))
@@ -623,24 +786,27 @@ def _bwd_dq_kernel(qs_ref, ks_ref, q_ref, g_ref, aux_ref,
             preferred_element_type=jnp.float32,
         )
 
-    if causal and aligned_delta is not None:
+    if band is not None:
+        band.when(iq, jk, _body)
+    elif causal and aligned_delta is not None:
         pl.when(jk < iq)(lambda: _body(False))
         pl.when(jk == iq)(lambda: _body(True))
     elif causal:
         first_k = ks_ref[0, 0] + jk * block_k
         last_q = qs_ref[0, 0] + (iq + 1) * block_q - 1
-        pl.when(first_k <= last_q)(lambda: _body(True))
+        pl.when(_and_in_window(first_k <= last_q, first_k, last_q, block_q,
+                               block_k, window))(lambda: _body(True))
     else:
         _body(False)
 
-    @pl.when(jk == num_k - 1)
+    @pl.when(step == num_k - 1)
     def _finish():
         dq_ref[0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
 
 
 def _flash_bwd_pallas(q, k, v, lse, corr, q_start, k_start, g,
                       *, scale, causal, block_q, block_k, interpret,
-                      tri_delta=None):
+                      tri_delta=None, window=None):
     """dQ/dK/dV via two Pallas kernels; all [BH, T, D].
 
     ``corr`` is ``g_lse − rowsum(o·g)`` per q row (f32, [BH, Tq]) — the
@@ -652,7 +818,23 @@ def _flash_bwd_pallas(q, k, v, lse, corr, q_start, k_start, g,
     block_q = _fit_block(tq, block_q)
     block_k = _fit_block(tk, block_k)
     num_q, num_k = tq // block_q, tk // block_k
-    aligned = _aligned_or_none(tri_delta, causal, tq, tk, block_q, block_k)
+    # the inner grid axes and which block each of their steps holds: all of
+    # them in order, or with a band only those the outer block can touch
+    steps_q, steps_k = num_q, num_k
+    q_of = lambda j, i: i  # dK/dV kernel: grid (bh, k block, step)
+    k_of = lambda i, j: j  # dQ kernel: grid (bh, q block, step)
+    kernel_kw, dkv_kw, dq_kw, aligned = {}, {}, {}, None
+    if window is None:
+        aligned = _aligned_or_none(tri_delta, causal, tq, tk, block_q, block_k)
+    else:
+        band = _band_or_none(window, tri_delta, causal, tq, tk, block_q, block_k)
+        kernel_kw = dict(window=window, band=band)
+        dkv_kw = dict(name="flash_bwd_dkv_window")  # the device trace's names
+        dq_kw = dict(name="flash_bwd_dq_window")
+        if band is not None:
+            steps_q, steps_k = band.q_steps, band.k_steps
+            q_of = lambda j, i: jnp.minimum(band.q_lo(j) + i, band.q_hi(j))
+            k_of = lambda i, j: jnp.minimum(band.k_lo(i) + j, band.k_hi(i))
 
     qs = jnp.asarray(q_start, jnp.int32).reshape(1, 1)
     ks = jnp.asarray(k_start, jnp.int32).reshape(1, 1)
@@ -686,10 +868,11 @@ def _flash_bwd_pallas(q, k, v, lse, corr, q_start, k_start, g,
     dk, dv = pl.pallas_call(
         functools.partial(
             _bwd_dkv_kernel, scale=scale, block_q=block_q, block_k=block_k,
-            causal=causal, num_q=num_q, aligned_delta=aligned, half=half),
-        grid=(bh, num_k, num_q),
+            causal=causal, num_q=steps_q, aligned_delta=aligned, half=half,
+            **kernel_kw),
+        grid=(bh, num_k, steps_q),
         in_specs=[smem, smem,
-                  *rowspec(lambda j, i: i), *kvspec(lambda j, i: j)],
+                  *rowspec(q_of), *kvspec(lambda j, i: j)],
         out_specs=[
             _block_spec((1, block_k, d), lambda b, j, i: (b, j, 0)),
             _block_spec((1, block_k, d), lambda b, j, i: (b, j, 0)),
@@ -703,15 +886,17 @@ def _flash_bwd_pallas(q, k, v, lse, corr, q_start, k_start, g,
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
         interpret=interpret,
+        **dkv_kw,
     )(qs, ks, q, g, aux, k, v)
 
     dq, = pl.pallas_call(
         functools.partial(
             _bwd_dq_kernel, scale=scale, block_q=block_q, block_k=block_k,
-            causal=causal, num_k=num_k, aligned_delta=aligned, half=half),
-        grid=(bh, num_q, num_k),
+            causal=causal, num_k=steps_k, aligned_delta=aligned, half=half,
+            **kernel_kw),
+        grid=(bh, num_q, steps_k),
         in_specs=[smem, smem,
-                  *rowspec(lambda i, j: i), *kvspec(lambda i, j: j)],
+                  *rowspec(lambda i, j: i), *kvspec(k_of)],
         out_specs=[
             _block_spec((1, block_q, d), lambda b, i, j: (b, i, 0)),
         ],
@@ -722,12 +907,13 @@ def _flash_bwd_pallas(q, k, v, lse, corr, q_start, k_start, g,
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
         interpret=interpret,
+        **dq_kw,
     )(qs, ks, q, g, aux, k, v)
     return dq, dk, dv
 
 
 def _blockwise_bwd(q, k, v, o, lse, q_start, k_start, g, g_lse,
-                   *, scale, causal, block_k, tri_delta=None):
+                   *, scale, causal, block_k, tri_delta=None, window=None):
     """dQ/dK/dV via per-k-block recompute from lse; all [BH, T, D].
 
     ``g_lse`` is the lse output's cotangent: d lse/d s is the normalized
@@ -749,7 +935,7 @@ def _blockwise_bwd(q, k, v, o, lse, q_start, k_start, g, g_lse,
                     axis=-1, keepdims=True)  # [BH, Tq, 1]
     corr = g_lse.astype(jnp.float32)[..., None] - delta  # [BH, Tq, 1]
 
-    if _use_triangular(causal, tri_delta, tq, tk, num_k):
+    if window is None and _use_triangular(causal, tri_delta, tq, tk, num_k):
         # Triangular fast path: with zero offsets, k block j only reaches q
         # rows >= j*block_k — static slicing halves the causal bwd FLOPs
         # that the dynamic fori_loop below must spend on fully-masked rows.
@@ -784,7 +970,7 @@ def _blockwise_bwd(q, k, v, o, lse, q_start, k_start, g, g_lse,
         s = f32("bqd,bkd->bqk", q, kb) * scale
         if causal:
             kpos = k_start + j * block_k + jnp.arange(block_k)
-            mask = kpos[None, :] <= qpos[:, None]
+            mask = _visible(qpos[:, None], kpos[None, :], window)
             s = jnp.where(mask[None], s, _NEG_INF)
         p = jnp.exp(s - lse[..., None])  # normalized probs [BH, Tq, block_k]
         if causal:
@@ -805,7 +991,7 @@ def _blockwise_bwd(q, k, v, o, lse, q_start, k_start, g, g_lse,
 
 
 def _fwd_dispatch(q, k, v, q_start, k_start, *, scale, causal, block_q,
-                  block_k, interpret, tri_delta, impl):
+                  block_k, interpret, tri_delta, impl, window=None):
     """Choose the forward implementation (static): "pallas", "xla", or
     "auto" (= Pallas kernel; "xla" remains selectable).
 
@@ -824,37 +1010,39 @@ def _fwd_dispatch(q, k, v, q_start, k_start, *, scale, causal, block_q,
         return _blockwise_fwd_xla(
             q, k, v, q_start, k_start,
             scale=scale, causal=causal, block_k=block_k, tri_delta=tri_delta,
+            window=window,
         )
     return _flash_fwd(
         q, k, v, q_start, k_start,
         scale=scale, causal=causal, block_q=block_q, block_k=block_k,
-        interpret=interpret, tri_delta=tri_delta,
+        interpret=interpret, tri_delta=tri_delta, window=window,
     )
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10, 11))
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(5, 6, 7, 8, 9, 10, 11, 12))
 def _flash_core(q, k, v, q_start, k_start, scale, causal, block_q, block_k,
-                interpret, tri_delta, impl):
+                interpret, tri_delta, impl, window):
     """(o, lse) with offsets as float32 scalars (zero-cotangent slots)."""
     return _fwd_dispatch(
         q, k, v, q_start.astype(jnp.int32), k_start.astype(jnp.int32),
         scale=scale, causal=causal, block_q=block_q, block_k=block_k,
-        interpret=interpret, tri_delta=tri_delta, impl=impl,
+        interpret=interpret, tri_delta=tri_delta, impl=impl, window=window,
     )
 
 
 def _flash_core_fwd(q, k, v, q_start, k_start, scale, causal, block_q,
-                    block_k, interpret, tri_delta, impl):
+                    block_k, interpret, tri_delta, impl, window):
     o, lse = _fwd_dispatch(
         q, k, v, q_start.astype(jnp.int32), k_start.astype(jnp.int32),
         scale=scale, causal=causal, block_q=block_q, block_k=block_k,
-        interpret=interpret, tri_delta=tri_delta, impl=impl,
+        interpret=interpret, tri_delta=tri_delta, impl=impl, window=window,
     )
     return (o, lse), (q, k, v, o, lse, q_start, k_start)
 
 
 def _flash_core_bwd(scale, causal, block_q, block_k, interpret, tri_delta,
-                    impl, res, cts):
+                    impl, window, res, cts):
     q, k, v, o, lse, q_start, k_start = res
     g, g_lse = cts
     if impl == "xla":
@@ -862,6 +1050,7 @@ def _flash_core_bwd(scale, causal, block_q, block_k, interpret, tri_delta,
             q, k, v, o, lse,
             q_start.astype(jnp.int32), k_start.astype(jnp.int32), g, g_lse,
             scale=scale, causal=causal, block_k=block_k, tri_delta=tri_delta,
+            window=window,
         )
     else:
         # Pallas backward (default): probability/score tiles stay in VMEM.
@@ -879,7 +1068,7 @@ def _flash_core_bwd(scale, causal, block_q, block_k, interpret, tri_delta,
             q, k, v, lse, corr,
             q_start.astype(jnp.int32), k_start.astype(jnp.int32), g,
             scale=scale, causal=causal, block_q=bwd_bq, block_k=bwd_bk,
-            interpret=interpret, tri_delta=tri_delta,
+            interpret=interpret, tri_delta=tri_delta, window=window,
         )
     return dq, dk, dv, jnp.zeros_like(q_start), jnp.zeros_like(k_start)
 
@@ -899,6 +1088,7 @@ def flash_attention_with_lse(
     block_k: Optional[int] = None,
     interpret: Optional[bool] = None,
     impl: str = "auto",
+    window: Optional[int] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """(out, lse) for q, k, v of shape ``[B, T, H, D]``; lse ``[B, H, T]``.
 
@@ -911,9 +1101,17 @@ def flash_attention_with_lse(
     for the measured 13x training-throughput gap vs "xla"), "xla", or
     "pallas".  ``block_q`` only affects the Pallas kernel; the XLA path
     blocks on ``block_k`` alone.
+
+    ``window`` (static int, causal only): a causal band, query ``i`` sees
+    key ``j`` iff ``0 <= i - j < window`` on global positions.  With static
+    equal offsets and square shapes the Pallas kernels' inner grid axis
+    visits only the blocks the band touches; ``None`` is today's lowering.
     """
     if impl not in ("auto", "xla", "pallas"):
         raise ValueError(f"impl must be auto/xla/pallas, got {impl!r}")
+    if window is not None and (not causal or window < 1):
+        raise ValueError(
+            f"window={window!r} needs causal=True and window >= 1")
     if interpret is None:
         interpret = _default_interpret()
     b, tq, h, d = q.shape
@@ -932,7 +1130,7 @@ def flash_attention_with_lse(
     o, lse = _flash_core(
         fold(q), fold(k), fold(v),
         jnp.asarray(q_start, jnp.float32), jnp.asarray(k_start, jnp.float32),
-        scale, causal, block_q, block_k, interpret, tri_delta, impl,
+        scale, causal, block_q, block_k, interpret, tri_delta, impl, window,
     )
     o = o.reshape(b, h, tq, d).transpose(0, 2, 1, 3)
     return o, lse.reshape(b, h, tq)
@@ -948,6 +1146,7 @@ def flash_attention(
     block_k: Optional[int] = None,
     interpret: Optional[bool] = None,
     impl: str = "auto",
+    window: Optional[int] = None,
 ) -> jnp.ndarray:
     """Memory-efficient exact attention; q, k, v: ``[B, T, H, D]``.
 
@@ -956,7 +1155,7 @@ def flash_attention(
     """
     o, _ = flash_attention_with_lse(
         q, k, v, causal=causal, block_q=block_q, block_k=block_k,
-        interpret=interpret, impl=impl,
+        interpret=interpret, impl=impl, window=window,
     )
     return o
 
@@ -967,6 +1166,7 @@ def make_flash_attention_fn(
     block_k: Optional[int] = None,
     interpret: Optional[bool] = None,
     impl: str = "auto",
+    window: Optional[int] = None,
 ) -> Callable:
     """``attention_fn`` for :class:`bluefog_tpu.models.transformer.LlamaLM`."""
     return functools.partial(
@@ -976,4 +1176,5 @@ def make_flash_attention_fn(
         block_k=block_k,
         interpret=interpret,
         impl=impl,
+        window=window,
     )

@@ -15,7 +15,7 @@ from __future__ import annotations
 
 import math
 from functools import partial
-from typing import Any, Callable, Optional
+from typing import Any, Callable, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -25,6 +25,7 @@ from jax.ad_checkpoint import checkpoint_name
 __all__ = [
     "BertEncoder",
     "LlamaLM",
+    "MixedAttentionMoELM",
     "dense_attention",
     "chunked_softmax_cross_entropy",
 ]
@@ -110,11 +111,12 @@ class BertEncoder(nn.Module):
 # --------------------------------------------------------------------------
 
 
-def _rotary(x, positions):
-    """Rotary position embedding; x: [B, T, H, D], positions: [T]."""
+def _rotary(x, positions, base=10000.0):
+    """Rotary position embedding (half-split convention); x: [B, T, H, D],
+    positions: [T]."""
     d = x.shape[-1]
     half = d // 2
-    freqs = 1.0 / (10000.0 ** (jnp.arange(half, dtype=jnp.float32) / half))
+    freqs = 1.0 / (base ** (jnp.arange(half, dtype=jnp.float32) / half))
     angles = positions[:, None].astype(jnp.float32) * freqs[None, :]  # [T, half]
     cos = jnp.cos(angles)[None, :, None, :]
     sin = jnp.sin(angles)[None, :, None, :]
@@ -142,11 +144,12 @@ class _DecoderBlock(nn.Module):
     dtype: Any
     attention_fn: Optional[Callable] = None  # (q, k, v) -> out, e.g. ring attn
     num_kv_heads: Optional[int] = None  # grouped-query attention (GQA)
+    head_dim: Optional[int] = None  # None: hidden // heads
 
     @nn.compact
     def __call__(self, x, positions):
         d = x.shape[-1]
-        hd = d // self.num_heads
+        hd = self.head_dim or d // self.num_heads
         kvh = self.num_kv_heads or self.num_heads
         if self.num_heads % kvh:
             raise ValueError(
@@ -174,7 +177,7 @@ class _DecoderBlock(nn.Module):
         # named for remat_policy="attn" (save these ~B*T*d bf16 outputs,
         # recompute everything else — see _remat_block)
         att = checkpoint_name(att, "attn_out")
-        att = att.reshape(att.shape[:2] + (d,))
+        att = att.reshape(att.shape[:2] + (self.num_heads * hd,))
         x = x + nn.Dense(d, use_bias=False, dtype=self.dtype)(att)
         h = RMSNorm(dtype=self.dtype)(x)
         gate = nn.Dense(self.dff, use_bias=False, dtype=self.dtype)(h)
@@ -571,3 +574,138 @@ class LlamaLM(nn.Module):
                 logits[:, :-1], labels[:, 1:, None], axis=-1
             )[..., 0]
         return (lse - tgt).mean()
+
+
+# --------------------------------------------------------------------------
+# Decoder with mixed attention (window + rotary / global without position)
+# and a top-k expert layer that is told which experts it holds
+# --------------------------------------------------------------------------
+
+
+class _MixedBlock(nn.Module):
+    """One layer: the router reads the layer's input, before the attention;
+    grouped-query attention, a causal band with rotary (``window`` set) or
+    causal over the whole sequence with no position signal at all
+    (``window=None``); then this share's part of the top-k expert layer."""
+
+    num_heads: int
+    num_kv_heads: int
+    head_dim: int
+    window: Optional[int]
+    rope_base: float
+    num_experts: int
+    top_k: int
+    experts_held: Tuple[int, ...]
+    expert_dff: int
+    dtype: Any
+    attention_fn: Callable  # (q, k, v, window=) -> out, causal
+
+    @nn.compact
+    def __call__(self, x, positions):
+        # imported here, not with the module: the encoder and the dense
+        # decoder above need neither, and Pallas costs their users 1.3 s
+        from bluefog_tpu.parallel.expert import held_topk_experts, route_topk
+
+        B, T, d = x.shape
+        hd, H, kvh = self.head_dim, self.num_heads, self.num_kv_heads
+        init = nn.initializers.normal(0.02)
+        router = self.param("router", init, (d, self.num_experts), jnp.float32)
+        experts, weights = route_topk(x.reshape(B * T, d), router, self.top_k)
+
+        h = RMSNorm(dtype=self.dtype, name="attn_norm")(x)
+        q = nn.DenseGeneral((H, hd), use_bias=False, dtype=self.dtype, name="q")(h)
+        k = nn.DenseGeneral((kvh, hd), use_bias=False, dtype=self.dtype, name="k")(h)
+        v = nn.DenseGeneral((kvh, hd), use_bias=False, dtype=self.dtype, name="v")(h)
+        if self.window is not None:
+            q = _rotary(q, positions, self.rope_base)
+            k = _rotary(k, positions, self.rope_base)
+        k = jnp.repeat(k, H // kvh, axis=2)
+        v = jnp.repeat(v, H // kvh, axis=2)
+        scope = "attention_global" if self.window is None else "attention_window"
+        with jax.named_scope(scope):
+            att = self.attention_fn(q, k, v, window=self.window)
+        att = att.reshape(B, T, H * hd)
+        x = x + nn.Dense(d, use_bias=False, dtype=self.dtype, name="o")(att)
+
+        m = RMSNorm(dtype=self.dtype, name="ffn_norm")(x)
+        n_held, f = len(self.experts_held), self.expert_dff
+        stacks = {
+            "wg": self.param("wg", init, (n_held, d, f), jnp.float32),
+            "wu": self.param("wu", init, (n_held, d, f), jnp.float32),
+            "wd": self.param("wd", init, (n_held, f, d), jnp.float32),
+        }
+        y = held_topk_experts(m.reshape(B * T, d), experts, weights, stacks,
+                              self.experts_held, self.num_experts)
+        return x + y.reshape(B, T, d)
+
+
+class MixedAttentionMoELM(nn.Module):
+    """Decoder-only LM whose layers differ by kind and whose feed-forward
+    is a top-k expert layer cut to the experts this chip holds.
+
+    ``layer_windows`` gives one entry a layer: an int is a causal band of
+    that many keys with rotary position (base ``rope_base``) on q and k;
+    ``None`` is causal attention over the whole sequence with no position
+    signal.  ``head_dim`` is independent of ``hidden_size / num_heads``;
+    ``num_kv_heads`` divides ``num_heads``.  The router is ``num_experts``
+    wide and picks ``top_k``; ``experts_held`` names the experts whose
+    weights live here, and what the others would add is left out (see
+    :func:`bluefog_tpu.parallel.expert.held_topk_experts`).  RMSNorm
+    (eps 1e-6), no bias anywhere, embedding and head untied.
+
+    With ``labels`` it returns the shifted next-token loss through
+    :func:`chunked_softmax_cross_entropy` (``head_chunks`` chunks), so
+    :func:`bluefog_tpu.training.make_lm_loss_fns`' identity loss serves it;
+    without, float32 logits.
+    """
+
+    vocab_size: int
+    hidden_size: int
+    num_heads: int
+    num_kv_heads: int
+    head_dim: int
+    layer_windows: Tuple[Optional[int], ...]
+    num_experts: int
+    top_k: int
+    experts_held: Tuple[int, ...]
+    expert_dff: int
+    rope_base: float = 10000.0
+    head_chunks: int = 1
+    dtype: Any = jnp.bfloat16
+    attention_fn: Optional[Callable] = None  # None: the flash kernels
+
+    @nn.compact
+    def __call__(self, input_ids, positions=None, labels=None):
+        from bluefog_tpu.kernels.flash_attention import flash_attention
+        from bluefog_tpu.telemetry import registry as _telemetry
+
+        if self.num_heads % self.num_kv_heads:
+            raise ValueError(
+                f"num_heads {self.num_heads} not divisible by "
+                f"num_kv_heads {self.num_kv_heads}")
+        windows = tuple(self.layer_windows)
+        reg = _telemetry.get_registry()
+        if reg.enabled:
+            banded = [w for w in windows if w is not None]
+            reg.gauge("attention.window").set(max(banded, default=0))
+            reg.gauge("attention.layers_window").set(len(banded))
+            reg.gauge("attention.layers_global").set(len(windows) - len(banded))
+        T = input_ids.shape[1]
+        if positions is None:
+            positions = jnp.arange(T)
+        attention_fn = self.attention_fn or partial(flash_attention, causal=True)
+        x = nn.Embed(self.vocab_size, self.hidden_size, dtype=self.dtype,
+                     embedding_init=nn.initializers.normal(0.02),
+                     name="embed")(input_ids)
+        for i, window in enumerate(windows):
+            x = _MixedBlock(
+                self.num_heads, self.num_kv_heads, self.head_dim, window,
+                self.rope_base, self.num_experts, self.top_k,
+                tuple(self.experts_held), self.expert_dff, self.dtype,
+                attention_fn, name=f"layer_{i}")(x, positions)
+        x = RMSNorm(dtype=jnp.float32, name="final_norm")(x)
+        kernel = _HeadKernel(self.vocab_size, name="head")(self.hidden_size)
+        if labels is None:
+            return _head_matmul(x, kernel, jnp.float32)
+        return chunked_softmax_cross_entropy(
+            x, kernel, labels, max(self.head_chunks, 1))

@@ -14,20 +14,32 @@ shapes), capacity is static (``capacity_factor``), overflow tokens pass
 through the residual untouched (standard Switch behavior).  Everything is
 differentiable, including the router (gate probability scales the expert
 output, the straight-through-free Switch estimator).
+
+Beside it, the layer a chip's *share* of an expert-parallel deployment runs
+(:func:`route_topk`, :func:`held_topk_experts`): it is told which experts
+it holds, routes over all of them, keeps every assignment to an expert it
+holds (no capacity, nothing dropped) and computes its own experts' part of
+the result with a grouped matrix product over rows sorted by expert.  What
+the experts held elsewhere would add is left out: on one chip the layer
+runs without its exchange, and nothing stands in for the absent chips.
 """
 
 from __future__ import annotations
 
 import math
+from functools import partial
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
-from bluefog_tpu.parallel._util import resolve_axis_size
+from bluefog_tpu.parallel._util import resolve_axis_size, vma_full
+from bluefog_tpu.telemetry import registry as _telemetry
 
-__all__ = ["switch_moe", "init_moe_params", "EP_AXIS"]
+__all__ = ["switch_moe", "init_moe_params", "EP_AXIS", "route_topk",
+           "held_topk_experts"]
 
 EP_AXIS = "ep"
 
@@ -116,3 +128,233 @@ def switch_moe(
     mean_prob = probs.mean(axis=0)
     aux = lax.pmean(E * jnp.sum(frac * mean_prob), axis_name)
     return out, aux
+
+
+# --------------------------------------------------------------------------
+# top-k experts, told which experts they hold; nothing dropped
+# --------------------------------------------------------------------------
+
+
+def route_topk(x, router, top_k: int):
+    """Top-k routing over all the experts the router knows.
+
+    ``x [T, d]`` is what the router reads; ``router [d, E]``.  Logits, top-k
+    and the softmax over the k chosen logits run in float32 (softmax over
+    all E, top-k, renormalised, is the same number).  Returns ``(experts
+    [T, k] int32, weights [T, k] float32)``; the weights carry the router's
+    gradient."""
+    with jax.named_scope("moe_route"):
+        logits = jnp.einsum("td,de->te", x.astype(jnp.float32),
+                            router.astype(jnp.float32),
+                            precision=lax.Precision.HIGHEST)
+        top, experts = lax.top_k(logits, top_k)
+        return experts, jax.nn.softmax(top, axis=-1)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _grouped_matmul(x, w, group_sizes, dtype):
+    """``y[r] = x[r] @ w[group of r]`` for rows sorted by group, as
+    ``lax.ragged_dot``: operands in ``dtype``, accumulators and results
+    float32, in both directions (the contract of
+    ``models.transformer._bf16_matmul_f32_acc``): the cotangent is rounded
+    to ``dtype`` and both transposes are grouped products again.  ``x [R,
+    k]``, ``w [G, k, n]`` (any float type), ``group_sizes [G]``."""
+    return _grouped_matmul_fwd(x, w, group_sizes, dtype)[0]
+
+
+def _grouped_matmul_fwd(x, w, group_sizes, dtype):
+    xb, wb = x.astype(dtype), w.astype(dtype)
+    y = lax.ragged_dot(xb, wb, group_sizes, preferred_element_type=jnp.float32)
+    # zero-size markers carry the primal types to the backward pass
+    return y, (xb, wb, group_sizes, jnp.zeros((0,), x.dtype), jnp.zeros((0,), w.dtype))
+
+
+_ROWS_CONTRACTED = lax.RaggedDotDimensionNumbers(
+    dot_dimension_numbers=(((0,), (0,)), ((), ())),
+    lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
+
+
+def _grouped_matmul_bwd(dtype, res, g):
+    xb, wb, group_sizes, x_like, w_like = res
+    gb = g.astype(dtype)
+    dx = lax.ragged_dot(gb, wb.transpose(0, 2, 1), group_sizes,
+                        preferred_element_type=jnp.float32)
+    # dw[e] = x[rows of e]^T g[rows of e]: the rows are the ragged dimension
+    dw = lax.ragged_dot_general(xb, gb, group_sizes, _ROWS_CONTRACTED,
+                                preferred_element_type=jnp.float32)
+    return dx.astype(x_like.dtype), dw.astype(w_like.dtype), None
+
+
+_grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
+
+
+# rows of the sorted buffer one pass computes: the grain at which the
+# layer's cost follows the load of the experts held.  Every pass pays about
+# 2.5 ms that its rows do not (twelve grouped products at 0.2 ms each before
+# their first tile, three float32 sums of the stacks' gradients): read at
+# 4096 rows a pass, where they were half of a pass (my chip runs, PR 29).
+# 16,384 is a step's tokens in the benchmark's cell: one pass at even
+# routing (12,288 rows), six if every token picks six experts held here.
+PASS_ROWS = 16384
+
+
+def _pass_rows(tokens: int, top_k: int, held: int) -> int:
+    """:data:`PASS_ROWS`, or all the rows there can be if that is fewer."""
+    worst = tokens * min(top_k, held)
+    return min(PASS_ROWS, -(-worst // 8) * 8)
+
+
+def _passes(ends, rows):
+    """As many passes as the rows assigned here ask for (traced)."""
+    return (ends[-1] + rows - 1) // rows
+
+
+def _pass(i, rows, k, order, starts, ends):
+    """Pass ``i`` covers the sorted rows ``[i * rows, (i + 1) * rows)``:
+    their first row, their tokens, which of them are assigned to an expert
+    held here, and how many rows of each expert the pass holds."""
+    first = i * rows
+    tok = lax.dynamic_slice_in_dim(order, first, rows) // k
+    valid = (first + jnp.arange(rows)) < ends[-1]
+    sizes = (jnp.clip(ends, first, first + rows)
+             - jnp.clip(starts, first, first + rows)).astype(jnp.int32)
+    return first, tok, valid, sizes
+
+
+def _pass_rows_out(xs, w_rows, wg, wu, wd, sizes, valid, dtype):
+    """The rows of one pass through their experts, weighted: ``[rows, d]``
+    float32.  Rows past the last group belong to no expert: the grouped
+    product leaves them undefined, so they are cut off on both sides and
+    carry no cotangent."""
+    xs = jnp.where(valid[:, None], xs, 0)
+    hg = _grouped_matmul(xs, wg, sizes, dtype)
+    hu = _grouped_matmul(xs, wu, sizes, dtype)
+    y = _grouped_matmul((jax.nn.relu(hg) * hu).astype(dtype), wd, sizes, dtype)
+    return jnp.where(valid[:, None], y, 0.0) * jnp.where(valid, w_rows, 0.0)[:, None]
+
+
+def _by_lanes(shape):
+    """``[T, d]`` as ``[T, d / 128, 128]`` where the lanes divide ``d``: a
+    row is then a tile-aligned block of its own, and a scatter-add of rows
+    into it read 3.6 ms where the flat form read 7.4 (12,288 rows of 2560,
+    my chip runs, PR 29)."""
+    T, d = shape
+    return (T, d // 128, 128) if d % 128 == 0 else (T, d)
+
+
+def _add_rows(acc, tok, rows):
+    """``acc[tok] += rows`` with ``acc`` kept :func:`_by_lanes`."""
+    return acc.at[tok].add(rows.astype(acc.dtype).reshape((-1,) + acc.shape[1:]))
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(8, 9))
+def _held_passes(m, w_sorted, wg, wu, wd, order, starts, ends, k, rows):
+    """``out[t] = sum over t's assignments a to experts held of w[a]
+    E(m[t])``, float32, computed in as many passes of ``rows`` sorted rows as
+    there are assignments (a loop whose length is the load's, which reverse
+    mode cannot differentiate: hence the rule below, which walks the same
+    passes and lets ``jax.vjp`` differentiate each).  ``w_sorted`` are the
+    weights in sorted order.  Its residuals are its inputs: every pass is
+    recomputed in the backward pass."""
+    return _held_passes_fwd(m, w_sorted, wg, wu, wd, order, starts, ends, k, rows)[0]
+
+
+def _held_passes_fwd(m, w_sorted, wg, wu, wd, order, starts, ends, k, rows):
+    def body(i, out):
+        first, tok, valid, sizes = _pass(i, rows, k, order, starts, ends)
+        w_rows = lax.dynamic_slice_in_dim(w_sorted, first, rows)
+        y = _pass_rows_out(m[tok], w_rows, wg, wu, wd, sizes, valid, m.dtype)
+        return _add_rows(out, tok, y)
+
+    out = lax.fori_loop(0, _passes(ends, rows), body,
+                        vma_full(m, _by_lanes(m.shape), jnp.float32))
+    return out.reshape(m.shape), (m, w_sorted, wg, wu, wd, order, starts, ends)
+
+
+def _held_passes_bwd(k, rows, res, g):
+    m, w_sorted, wg, wu, wd, order, starts, ends = res
+
+    def body(i, acc):
+        dm, dw_sorted, dwg, dwu, dwd = acc
+        first, tok, valid, sizes = _pass(i, rows, k, order, starts, ends)
+        _, vjp = jax.vjp(
+            lambda xs, w_rows, wg, wu, wd: _pass_rows_out(
+                xs, w_rows, wg, wu, wd, sizes, valid, m.dtype),
+            m[tok], lax.dynamic_slice_in_dim(w_sorted, first, rows), wg, wu, wd)
+        dxs, dw_rows, g1, g2, g3 = vjp(g[tok])
+        return (_add_rows(dm, tok, dxs),
+                lax.dynamic_update_slice_in_dim(dw_sorted, dw_rows, first, 0),
+                dwg + g1, dwu + g2, dwd + g3)
+
+    zeros = lambda shape: vma_full(g, shape, jnp.float32)  # typed as under shard_map
+    dm, dw_sorted, dwg, dwu, dwd = lax.fori_loop(0, _passes(ends, rows), body, (
+        zeros(_by_lanes(m.shape)), zeros(w_sorted.shape), zeros(wg.shape),
+        zeros(wu.shape), zeros(wd.shape)))
+    return (dm.reshape(m.shape).astype(m.dtype), dw_sorted.astype(w_sorted.dtype),
+            dwg.astype(wg.dtype), dwu.astype(wu.dtype), dwd.astype(wd.dtype),
+            None, None, None)
+
+
+_held_passes.defvjp(_held_passes_fwd, _held_passes_bwd)
+
+
+def held_topk_experts(m, experts, weights, params, held, num_experts: int,
+                      *, rows: Optional[int] = None):
+    """This share's part of a top-k expert layer, with no token dropped.
+
+    ``m [T, d]``: what the experts read.  ``experts`` / ``weights``:
+    :func:`route_topk`'s result, over all ``num_experts`` experts.  ``held``:
+    the global ids of the experts this share holds, in the order of the
+    leading axis of ``params``: ``wg``, ``wu`` ``[H, d, f]`` and ``wd``
+    ``[H, f, d]``, gated ReLU: ``E_e(m) = (relu(m wg) * (m wu)) wd``.
+
+    Returns ``[T, d]``: for every token the sum, over those of its top-k
+    experts that are held here, of ``w_e E_e(m)``; the other shares'
+    terms are left out.
+
+    How: the ``T * k`` assignments are sorted by expert held (those to
+    experts held elsewhere last), and the sorted rows that are assigned
+    here are computed in passes of ``rows`` rows (default
+    :data:`PASS_ROWS`): gather, three grouped products (``lax.ragged_dot``
+    with the pass's own group sizes; operands in ``m``'s type, float32
+    accumulators), scatter-add by token.  There are as many passes as the
+    load asks for, ``ceil(assigned / rows)``, so the layer's cost follows
+    the load of the experts held, and a pile-up on one expert costs time
+    and never a token."""
+    T, d = m.shape
+    k = experts.shape[1]
+    held = tuple(int(e) for e in held)
+    H = len(held)
+    total = int(num_experts)
+    if not held or min(held) < 0 or max(held) >= total or len(set(held)) != H:
+        raise ValueError(
+            f"held={held} must be distinct expert ids in [0, {total})")
+    if params["wg"].shape[0] != H:
+        raise ValueError(
+            f"params hold {params['wg'].shape[0]} experts, held names {H}")
+    if rows is None:
+        rows = _pass_rows(T, k, H)
+    A = T * k
+    reg = _telemetry.get_registry()
+    if reg.enabled:
+        reg.gauge("moe.experts_held").set(H)
+        reg.gauge("moe.experts_total").set(total)
+        reg.gauge("moe.top_k").set(k)
+        reg.gauge("moe.buffer_rows").set(rows)
+
+    with jax.named_scope("moe_experts"):
+        # global expert id -> its place here, H for "held elsewhere"
+        place = np.full((total,), H, np.int32)
+        place[list(held)] = np.arange(H)
+        group = jnp.asarray(place)[experts].reshape(A)        # [A] in 0..H
+        order = jnp.argsort(group, stable=True)               # held ones first
+        sizes = jnp.bincount(group, length=H + 1)[:H].astype(jnp.int32)
+        ends = jnp.cumsum(sizes)
+        # the last pass may reach past the A assignments: never past this
+        most = -(-T * min(k, H) // rows) * rows
+        if most > A:  # the padding names assignment 0; its rows are not valid
+            order = jnp.concatenate([order, jnp.zeros((most - A,), order.dtype)])
+        out = _held_passes(m, weights.reshape(A)[order], params["wg"],
+                           params["wu"], params["wd"], order, ends - sizes, ends,
+                           k, rows)
+        return out.astype(m.dtype)

@@ -14,8 +14,10 @@ phase that fails raises: the traceback goes to stderr, the last line says
     python chip_smoke.py             one chip: ops/windows parity, ResNet-50
                                      b128 224² ATC + allreduce steps, a
                                      decoder step with the compiled Pallas
-                                     flash kernel vs dense attention, and
-                                     the block_until_ready probe
+                                     flash kernel vs dense attention, the
+                                     dropless expert layer under a pile-up
+                                     vs the benchmark's plain reference,
+                                     and the block_until_ready probe
     python chip_smoke.py --chips 4   four chips, only what exists across
                                      chips: parity on exp2(4), placement,
                                      ResNet-50 ATC vs allreduce, contraction
@@ -63,6 +65,10 @@ FULL = dict(
     # benchmarks/llama.py preset "small": hidden 768, 12 heads of 64
     decoder=dict(vocab=32000, hidden=768, layers=12, heads=12, dff=2048,
                  seq=2048, batch=8, head_chunks=8, logits_rows=2),
+    # one layer of chipbench's `smallthinker-21b-a3b` at its cell's 2 x 8192
+    # tokens; rows None: the pass the program chooses (16,384 sorted rows)
+    experts=dict(tokens=16384, hidden=2560, dff=768, experts=64, top_k=6,
+                 held=8, rows=None),
     probe=dict(dim=4096, iters=512),
 )
 TINY = dict(
@@ -70,6 +76,8 @@ TINY = dict(
     resnet=dict(model="ResNet18", classes=10, img=16, batch=2),
     decoder=dict(vocab=256, hidden=64, layers=2, heads=4, dff=128,
                  seq=128, batch=2, head_chunks=2, logits_rows=1),
+    experts=dict(tokens=96, hidden=128, dff=8, experts=64, top_k=6, held=8,
+                 rows=64),
     probe=dict(dim=128, iters=8),
 )
 
@@ -82,6 +90,14 @@ TINY = dict(
 LOGITS_L2_RTOL = 3e-2
 LOGITS_MAX_RTOL = 5e-2
 LOSS_ATOL = 5e-3
+
+# the expert layer (bf16 operands, float32 accumulators) against the plain
+# reference (float32, `highest`) on tokens and stacks that bf16 holds exactly:
+# relative L2 of the output and of each gradient.  What is left is the
+# layer's own rounding of the hidden units and the cotangents: 2.3e-3 to
+# 3.3e-3 on the v5e (PERF.md section 6, PR 29).  A pass that is left out, counted twice or
+# scattered to the wrong tokens moves them by 0.2 or more.
+EXPERTS_L2_RTOL = 1.5e-2
 
 
 class _CompileClock:
@@ -436,6 +452,108 @@ def phase_decoder(cfg, seed, on_tpu, clock, steps=3):
 
 
 # ---------------------------------------------------------------------------
+# phase: the dropless expert layer under a pile-up vs the plain reference
+# ---------------------------------------------------------------------------
+
+
+def phase_experts_piled(cfg, seed, clock):
+    """`held_topk_experts` where the benchmark's decoder cell spends its
+    window and its three checked steps never go: several passes of the
+    loop, forward and in the backward rule, the last one partly filled.  The
+    router is pushed towards the experts held (as it learns to be in that
+    cell), the tokens differ; output and gradients (tokens, router, the
+    three stacks) against `expert_terms` of chipbench's plain reference,
+    which applies every expert held to every token.  Also the same layer in
+    one pass of every row there could be: what the loop adds, alone."""
+    from bluefog_tpu.parallel import expert as ep
+    from chipbench import manifest
+
+    t0 = time.perf_counter()
+    reference = manifest.load_module(os.path.join(
+        os.path.dirname(os.path.abspath(__file__)), "chipbench", "reference",
+        "smallthinker-21b-a3b.py"))
+    T, d, f = cfg["tokens"], cfg["hidden"], cfg["dff"]
+    E, k, H = cfg["experts"], cfg["top_k"], cfg["held"]
+    ks = jax.random.split(jax.random.PRNGKey(seed), 8)
+    # logits of unit spread; the experts held 2.5 higher, through a direction
+    # `u` that every token shares, so that the push has a gradient too
+    u = jax.random.normal(ks[0], (d,)) / d ** 0.5
+    lean = 0.6 * d ** 0.5
+    x = jax.random.normal(ks[1], (T, d)) + lean * u
+    router = jax.random.normal(ks[2], (d, E)) / d ** 0.5
+    router = router.at[:, :H].add((2.5 / lean / jnp.sum(u * u)) * u[:, None])
+    # Both sides get tokens and stacks that bf16 holds exactly, so the layer's
+    # own cast rounds nothing.  With float32 stacks 0.1 % of the gates' signs
+    # flip under that cast and ReLU's derivative is a step: `wg` then reads
+    # 3.2e-2 and the tokens 2.3e-2, in one pass as in five (first v5e run).
+    def held_exactly(key, shape, std):
+        a = std * jax.random.normal(key, shape)
+        return a.astype(jnp.bfloat16).astype(jnp.float32)
+
+    args = {"m": held_exactly(ks[3], (T, d), 1.0),
+            "router": router,
+            "wg": held_exactly(ks[4], (H, d, f), d ** -0.5),
+            "wu": held_exactly(ks[5], (H, d, f), d ** -0.5),
+            "wd": held_exactly(ks[6], (H, f, d), f ** -0.5)}
+    cot = jax.random.normal(ks[7], (T, d))
+
+    def ours(a, x, rows):
+        experts, weights = ep.route_topk(x, a["router"], k)
+        y = ep.held_topk_experts(
+            a["m"].astype(jnp.bfloat16), experts, weights,
+            {n: a[n] for n in ("wg", "wu", "wd")}, range(H), E, rows=rows)
+        return y.astype(jnp.float32)
+
+    def plain(a, x):
+        r = jnp.einsum("td,de->te", x, a["router"], precision="highest")
+        p = {("l", n): a[n] for n in ("wg", "wu", "wd")}
+        return reference.expert_terms(
+            a["m"], r, p, "l", {"moe_num_active_primary_experts": k}, False,
+            tuple(range(H)))
+
+    def out_and_grads(fn):
+        # tokens and cotangent are arguments: closed over, each is a 170 MB
+        # constant in every program
+        @jax.jit
+        def run(a, x, cot):
+            y, vjp = jax.vjp(lambda a: fn(a, x), a)
+            return dict(vjp(cot)[0], out=y)
+        return run(args, x, cot)
+
+    rows = cfg["rows"] or ep._pass_rows(T, k, H)
+    assigned = int(jnp.sum(ep.route_topk(x, router, k)[0] < H))
+    passes = -(-assigned // rows)
+    assert passes > 1 and assigned % rows, (
+        f"{assigned} rows in passes of {rows}: not the loop's several passes "
+        "with a last one partly filled")
+    want = out_and_grads(plain)
+
+    def gaps(got):
+        return {n: float(jnp.linalg.norm(got[n] - want[n])
+                         / jnp.linalg.norm(want[n])) for n in sorted(want)}
+
+    looped = out_and_grads(lambda a, x: ours(a, x, rows))
+    one_pass = out_and_grads(lambda a, x: ours(a, x, T * min(k, H)))
+    rel, rel_one = gaps(looped), gaps(one_pass)
+    stats = jax.devices()[0].memory_stats() or {}
+    _emit("experts_piled_vs_reference", t0, clock, tokens=T, hidden=d, dff=f,
+          experts=E, top_k=k, held=H, rows_per_pass=rows,
+          rows_assigned_here=assigned, rows_at_even_routing=T * k * H // E,
+          passes=passes,
+          compared="output and gradients (tokens, router, wg, wu, wd) of "
+                   "held_topk_experts, bf16 operands, against expert_terms "
+                   "of chipbench/reference/smallthinker-21b-a3b.py, float32 "
+                   "highest: relative L2",
+          rel_l2=rel, rel_l2_in_one_pass=rel_one, rel_l2_tol=EXPERTS_L2_RTOL,
+          peak_bytes_in_use=stats.get("peak_bytes_in_use"))
+    for name, gap in rel.items():
+        assert gap <= EXPERTS_L2_RTOL, (
+            f"{name}: {gap} from the plain reference in relative L2 over "
+            f"{passes} passes ({rel_one[name]} in one pass)")
+    return {"passes": passes, "rel_l2": rel, "rel_l2_in_one_pass": rel_one}
+
+
+# ---------------------------------------------------------------------------
 # phase: does block_until_ready block here?
 # ---------------------------------------------------------------------------
 
@@ -525,6 +643,7 @@ def run(args, device):
     else:
         del job
         phase_decoder(sizes["decoder"], args.seed, on_tpu, clock)
+        phase_experts_piled(sizes["experts"], args.seed, clock)
         phase_sync_probe(sizes["probe"], args.seed, clock)
     bf.shutdown()
 

@@ -1,0 +1,198 @@
+"""The top-k expert layer that is told which experts it holds
+(`parallel.expert.route_topk`, `held_topk_experts`): the shares add up to the
+uncut layer of the configuration's plain reference, no assignment is lost
+when one expert gets every token, gradients reach the router and the experts,
+and the trace-time gauges say what was built."""
+
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from bluefog_tpu import telemetry
+from bluefog_tpu.parallel import expert as ep
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+from chipbench import manifest  # noqa: E402
+
+T, D, F, E, K = 96, 16, 8, 64, 6  # the configuration's 64 experts, top-6
+
+
+@pytest.fixture(scope="module")
+def reference():
+    return manifest.load_module(os.path.join(
+        REPO, "chipbench", "reference", "smallthinker-21b-a3b.py"))
+
+
+@pytest.fixture(scope="module")
+def layer():
+    ks = jax.random.split(jax.random.PRNGKey(3), 6)
+    return {
+        "x": jax.random.normal(ks[0], (T, D)), "m": jax.random.normal(ks[1], (T, D)),
+        "router": jax.random.normal(ks[2], (D, E)),
+        "wg": 0.3 * jax.random.normal(ks[3], (E, D, F)),
+        "wu": 0.3 * jax.random.normal(ks[4], (E, D, F)),
+        "wd": 0.3 * jax.random.normal(ks[5], (E, F, D)),
+    }
+
+
+def share(layer, held, rows=None):
+    held = list(held)
+    experts, weights = ep.route_topk(layer["x"], layer["router"], K)
+    stacks = {n: layer[n][jnp.asarray(held)] for n in ("wg", "wu", "wd")}
+    return ep.held_topk_experts(layer["m"], experts, weights, stacks, held, E,
+                                rows=rows)
+
+
+def uncut(reference, layer):
+    """The plain reference's expert terms with all 64 experts held."""
+    sizes = {"moe_num_active_primary_experts": K}
+    p = {("l", n): layer[n] for n in ("wg", "wu", "wd")}
+    r = layer["x"] @ layer["router"]
+    return reference.expert_terms(layer["m"], r, p, "l", sizes, False, tuple(range(E)))
+
+
+@pytest.mark.parametrize("rows", [None, 16])
+def test_the_eight_shares_add_up_to_the_uncut_layer(reference, layer, rows):
+    """Each share holds 8 of the 64 experts and routes over all 64; the
+    eight shares' expert terms, the residual counted once, are the uncut
+    64-expert reference's layer output."""
+    shares = [share(layer, range(8 * s, 8 * s + 8), rows) for s in range(8)]
+    whole = layer["m"] + sum(shares)  # the residual once
+    want = layer["m"] + uncut(reference, layer)
+    np.testing.assert_allclose(whole, want, atol=2e-5)
+    # a share alone leaves the others' terms out: it is not the whole
+    assert float(jnp.max(jnp.abs(shares[0] - uncut(reference, layer)))) > 1e-2
+
+
+def test_no_assignment_is_lost_when_one_expert_gets_every_token(layer):
+    """Identical tokens: all T pile up on the same six experts, four of them
+    held here.  Every one of the 4 T = 384 assignments is computed, in six
+    passes of 64 rows and in the default buffer alike."""
+    piled = dict(layer, x=jnp.broadcast_to(layer["x"][:1], (T, D)),
+                 m=jnp.broadcast_to(layer["m"][:1], (T, D)))
+    experts, weights = ep.route_topk(piled["x"], piled["router"], K)
+    chosen = [int(e) for e in np.asarray(experts[0])]
+    assert all((np.asarray(experts) == np.asarray(chosen)).all(axis=1))
+    held = chosen[:4] + [e for e in range(E) if e not in chosen][:4]
+    m0 = piled["m"][0]
+    want = sum(
+        weights[0, j] * ((jax.nn.relu(m0 @ layer["wg"][e]) * (m0 @ layer["wu"][e]))
+                         @ layer["wd"][e])
+        for j, e in enumerate(chosen) if e in held)
+    for rows in (None, 64):
+        out = share(piled, held, rows)
+        np.testing.assert_allclose(out, jnp.broadcast_to(want, (T, D)), atol=1e-5)
+
+
+def test_chip_smokes_pile_up_witness_walks_several_passes():
+    """`chip_smoke.phase_experts_piled` is how the loop's several passes are
+    held against the plain reference at the benchmark's sizes on the chip;
+    here its rehearsal: tokens that differ, a router pushed towards the
+    experts held, seven passes of 64 rows with the last one partly filled,
+    output and every gradient within the phase's own tolerance, and the loop
+    no further from the reference than one pass of all the rows is."""
+    import chip_smoke
+
+    got = chip_smoke.phase_experts_piled(
+        chip_smoke.TINY["experts"], 0, chip_smoke._CompileClock())
+    assert got["passes"] == 7
+    assert sorted(got["rel_l2"]) == ["m", "out", "router", "wd", "wg", "wu"]
+    for name, gap in got["rel_l2"].items():
+        assert gap <= chip_smoke.EXPERTS_L2_RTOL
+        assert abs(gap - got["rel_l2_in_one_pass"][name]) < 1e-4
+
+
+def test_gradients_reach_router_and_held_experts(reference, layer):
+    def ours(layer):
+        return jnp.sum(sum(share(layer, range(8 * s, 8 * s + 8), 32)
+                           for s in range(8)) ** 2)
+
+    def plain(layer):
+        return jnp.sum(uncut(reference, layer) ** 2)
+
+    got, want = jax.grad(ours)(layer), jax.grad(plain)(layer)
+    for name in ("router", "m", "wg", "wu", "wd"):
+        scale = float(jnp.max(jnp.abs(want[name])))
+        assert scale > 0
+        np.testing.assert_allclose(got[name], want[name], atol=1e-5 * scale + 1e-7)
+
+
+def test_a_width_the_lanes_divide_is_added_up_by_lanes(reference):
+    """At a hidden size that 128 divides the token sums are kept as
+    [T, d / 128, 128] (a row is a tile-aligned block): the same numbers,
+    values and gradients, as the plain reference's."""
+    d = 256
+    ks = jax.random.split(jax.random.PRNGKey(5), 6)
+    wide = {"x": jax.random.normal(ks[0], (T, d)), "m": jax.random.normal(ks[1], (T, d)),
+            "router": jax.random.normal(ks[2], (d, E)),
+            "wg": 0.1 * jax.random.normal(ks[3], (E, d, F)),
+            "wu": 0.1 * jax.random.normal(ks[4], (E, d, F)),
+            "wd": 0.1 * jax.random.normal(ks[5], (E, F, d))}
+    assert ep._by_lanes((T, d)) == (T, 2, 128) and ep._by_lanes((T, D)) == (T, D)
+
+    def ours(layer):
+        return sum(share(layer, range(8 * s, 8 * s + 8), 40) for s in range(8))
+
+    np.testing.assert_allclose(ours(wide), uncut(reference, wide), atol=2e-5)
+    got = jax.grad(lambda l: jnp.sum(ours(l) ** 2))(wide)
+    want = jax.grad(lambda l: jnp.sum(uncut(reference, l) ** 2))(wide)
+    for name in ("router", "m", "wg", "wu", "wd"):
+        scale = float(jnp.max(jnp.abs(want[name])))
+        np.testing.assert_allclose(got[name], want[name], atol=2e-5 * scale + 1e-7)
+
+
+def test_route_topk_weights_are_the_softmax_over_all_renormalised(layer):
+    experts, weights = ep.route_topk(layer["x"], layer["router"], K)
+    probs = jax.nn.softmax(layer["x"] @ layer["router"], axis=-1)
+    top = jnp.take_along_axis(probs, experts, axis=1)
+    np.testing.assert_allclose(weights, top / top.sum(-1, keepdims=True), rtol=1e-5)
+    assert experts.shape == (T, K) and float(weights.sum(-1).min()) > 0.999
+
+
+def test_held_must_name_the_stacks(layer):
+    experts, weights = ep.route_topk(layer["x"], layer["router"], K)
+    stacks = {n: layer[n][:8] for n in ("wg", "wu", "wd")}
+    with pytest.raises(ValueError, match="held"):
+        ep.held_topk_experts(layer["m"], experts, weights, stacks, [0, 1, 2], E)
+    with pytest.raises(ValueError, match="distinct"):
+        ep.held_topk_experts(layer["m"], experts, weights, stacks, [0] * 8, E)
+
+
+def test_gauges_say_what_was_built(monkeypatch, tmp_path):
+    """`moe.*` and `attention.*` at trace time, as `gossip.*` are: one trace
+    of the rehearsal-sized decoder, nothing compiled."""
+    from functools import partial
+
+    from bluefog_tpu.kernels.flash_attention import flash_attention
+    from bluefog_tpu.models.transformer import MixedAttentionMoELM
+
+    monkeypatch.setenv("BFTPU_TELEMETRY", str(tmp_path))
+    telemetry.reset()
+    try:
+        model = MixedAttentionMoELM(
+            vocab_size=128, hidden_size=64, num_heads=4, num_kv_heads=2, head_dim=16,
+            layer_windows=(None, 24, 24, 24), num_experts=16, top_k=3,
+            experts_held=(0, 1, 2, 3), expert_dff=32, rope_base=1.5e6, head_chunks=2,
+            attention_fn=partial(flash_attention, causal=True, block_q=16, block_k=16))
+        ids = jax.ShapeDtypeStruct((2, 64), jnp.int32)
+        v = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0),
+                                              jnp.zeros((2, 64), jnp.int32)))
+        jax.eval_shape(lambda v, i: model.apply(v, i, labels=i), v, ids)
+        gauges = {g["name"]: g["value"] for g in
+                  telemetry.get_registry().snapshot()["gauges"]}
+    finally:
+        telemetry.reset()
+    assert gauges == {
+        "moe.experts_held": 4, "moe.experts_total": 16, "moe.top_k": 3,
+        "moe.buffer_rows": 384,  # 128 tokens x min(3, 4): under a pass
+        "attention.window": 24, "attention.layers_window": 3,
+        "attention.layers_global": 1}
+    # the benchmark's layer: passes of 16,384 sorted rows, one at even routing
+    # (12,288 rows expected), six if every token picks six experts held here
+    assert ep._pass_rows(16384, 6, 8) == ep.PASS_ROWS == 16384

@@ -91,6 +91,56 @@ def test_flash_backward_compiles_for_v5e(one_chip, shape, dtype):
     assert compiled.as_text().count("tpu_custom_call") == 3
 
 
+# the benchmark's window layer (smallthinker-21b-a3b: B2 S8192, 28 heads of
+# 128, window 4096), a window shorter than a block and one past the sequence
+@pytest.mark.parametrize("shape,window", [
+    pytest.param((2, 8192, 28, 128), 4096, id="B2-T8192-H28-D128-W4096"),
+    pytest.param((2, 2048, 4, 128), 256, id="B2-T2048-H4-D128-W256"),
+    pytest.param((2, 2048, 4, 128), 4096, id="B2-T2048-H4-D128-W4096"),
+])
+def test_banded_flash_kernels_compile_for_v5e(one_chip, shape, window):
+    """Forward, dK/dV and dQ with a causal band: the inner grid axis and the
+    block index maps are the band's (clamped `lax.div` arithmetic on program
+    ids), which interpret mode cannot refuse and Mosaic can."""
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(
+            q, k, v, causal=True, interpret=False, impl="pallas",
+            window=window).astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(x, x, x).compile().as_text()
+    assert text.count("tpu_custom_call") == 3
+    for name in ("flash_fwd_window", "flash_bwd_dkv_window", "flash_bwd_dq_window"):
+        assert name in text  # the names the benchmark's readers look up
+
+
+def test_grouped_expert_products_compile_to_xlas_kernel_for_v5e(one_chip):
+    """`held_topk_experts` at the benchmark's sizes: the three grouped
+    products of a pass and their transposes are XLA's own grouped-matmul
+    kernel (`ragged-dot`), not a dense product per expert."""
+    from bluefog_tpu.parallel.expert import held_topk_experts, route_topk
+
+    T, d, f, E, held = 16384, 2560, 768, 64, 8
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(x, m, router, wg, wu, wd):
+        experts, weights = route_topk(x, router, 6)
+        y = held_topk_experts(m, experts, weights, {"wg": wg, "wu": wu, "wd": wd},
+                              range(held), E)
+        return jnp.sum(y.astype(jnp.float32))
+
+    args = (spec((T, d), jnp.bfloat16), spec((T, d), jnp.bfloat16),
+            spec((d, E), jnp.float32), spec((held, d, f), jnp.float32),
+            spec((held, d, f), jnp.float32), spec((held, f, d), jnp.float32))
+    compiled = jax.jit(jax.grad(loss, argnums=(1, 2, 3, 4, 5))).lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count("ragged-dot-none") >= 9  # 3 forward, 3 recomputed... and 6 transposes
+    assert compiled.memory_analysis().temp_size_in_bytes < 3e9
+
+
 def test_exp2_neighbor_allreduce_compiles_to_permutes_on_2x2(topo):
     """The gossip collective over the real four-chip mesh: ``bf.init`` takes
     the described devices, the exp2(4) plan lowers to collective-permutes."""
