@@ -93,6 +93,75 @@ MAX_PERMUTES_OUTSTANDING = 5
 TAIL_SHARE = 0.05
 
 
+# A TPU keeps an array of two or more dimensions as tiles of 8 rows by 128
+# columns of its last two dimensions, row of tiles by row of tiles, the
+# leading dimensions outermost, and pads each of the two up to whole tiles.
+# So a leaf whose last two dimensions are whole tiles (every matrix and
+# convolution kernel of the models here but the first layers and the head)
+# holds no padding, and its tiles lie in memory as 8 rows of all the leading
+# dimensions together.  2-byte elements too (the v5e compiler's
+# `T(8,128)(2,1)`: 8 rows, two to a word; 16-row tiles compile to a pass each
+# way), so the count is in elements, whatever the dtype.
+# tests/test_tpu_compile.py notices a compiler that tiles otherwise.
+_TILE = (8, 128)
+
+
+def _tileable(leaf) -> bool:
+    """Whether ``leaf`` (anything with ``shape``) is whole tiles: its last
+    dimension whole lanes, the one before whole sublanes.  (``[N, 4, 128]``
+    is not, although its rows together are a multiple of 8: the TPU pads the
+    4 to 8 under every ``N``, and taking the padding out is a pass.)"""
+    shape = leaf.shape
+    return (len(shape) >= 2 and shape[-1] % _TILE[1] == 0
+            and shape[-2] % _TILE[0] == 0)
+
+
+def _tile_grid(shape):
+    """(rows of tiles, tiles a row) of a shape that is whole tiles."""
+    return math.prod(shape[:-1]) // _TILE[0], shape[-1] // _TILE[1]
+
+
+def _pack_bucket(leaves):
+    """``leaves`` (of one dtype) as one ``[n, 8, 128]`` buffer of tiles: every
+    leaf that is whole tiles in the order the TPU keeps its tiles in memory
+    (a bitcast there), then all the others raveled one after another and
+    zero-padded together to whole tiles."""
+    sub, lanes = _TILE
+    parts, ragged = [], []
+    for a in leaves:
+        if _tileable(a):
+            r, c = _tile_grid(a.shape)
+            parts.append(a.reshape(r, sub, c, lanes).transpose(0, 2, 1, 3)
+                         .reshape(r * c, sub, lanes))
+        else:
+            ragged.append(a.ravel())
+    if ragged:
+        flat = jnp.concatenate(ragged)
+        n = -(-flat.size // (sub * lanes))
+        parts.append(jnp.pad(flat, (0, n * sub * lanes - flat.size))
+                     .reshape(n, sub, lanes))
+    return jnp.concatenate(parts)
+
+
+def _unpack_bucket(tiles, leaves) -> list:
+    """Inverse of :func:`_pack_bucket`: arrays shaped like ``leaves`` out of
+    ``tiles``, in the dtype of ``tiles``; the padding is dropped."""
+    sub, lanes = _TILE
+    out, off = [None] * len(leaves), 0
+    for i, like in enumerate(leaves):
+        if _tileable(like):
+            r, c = _tile_grid(like.shape)
+            out[i] = (tiles[off:off + r * c].reshape(r, c, sub, lanes)
+                      .transpose(0, 2, 1, 3).reshape(like.shape))
+            off += r * c
+    flat, off = tiles[off:].reshape(-1), 0
+    for i, like in enumerate(leaves):
+        if out[i] is None:
+            out[i] = flat[off:off + like.size].reshape(like.shape)
+            off += like.size
+    return out
+
+
 class GossipGrouping(NamedTuple):
     """How one ``neighbor_allreduce`` groups its leaves into permutes: a pure
     function of shapes, dtypes, readiness order and the plan's shift classes
@@ -103,6 +172,7 @@ class GossipGrouping(NamedTuple):
     leaves: int
     permutes: int
     packed_bytes: int
+    tiled_bytes: int  # of packed_bytes, what goes in as whole tiles
 
 
 def _dtype_groups(leaves) -> list:
@@ -150,7 +220,7 @@ def gossip_grouping(leaves, order, n_classes: int, *,
     either, with no shift class, or with a single leaf: per leaf."""
     n = len(leaves)
     if n_classes == 0 or n <= 1 or not (fuse or order is not None):
-        return GossipGrouping((), n, n * n_classes, 0)
+        return GossipGrouping((), n, n * n_classes, 0, 0)
     sizes = [math.prod(l.shape) for l in leaves]
     nbytes = [s * jnp.dtype(l.dtype).itemsize for s, l in zip(sizes, leaves)]
     groups = _dtype_groups(leaves)
@@ -163,9 +233,12 @@ def gossip_grouping(leaves, order, n_classes: int, *,
             big = max(range(len(groups)),
                       key=lambda j: sum(nbytes[i] for i in groups[j]))
             groups[big:big + 1] = _split_by_share(groups[big], sizes, 1 + spare)
-    packed = sum(nbytes[i] for g in groups if len(g) > 1 for i in g)
+    packed = [i for g in groups if len(g) > 1 for i in g]
+    tiled = [i for i in packed if _tileable(leaves[i])]
     return GossipGrouping(tuple(tuple(g) for g in groups), n,
-                          len(groups) * n_classes, packed)
+                          len(groups) * n_classes,
+                          sum(nbytes[i] for i in packed),
+                          sum(nbytes[i] for i in tiled))
 
 
 def neighbor_allreduce(
@@ -200,7 +273,7 @@ def neighbor_allreduce(
     scheduler keeps at most ``MAX_PERMUTES_OUTSTANDING`` in flight, so
     inside a train step all but that many run one after another behind the
     last gradient (PERF.md section 6, PR 27).  Two groupings pack leaves of
-    one dtype into flat buffers, one permute per class each, through one
+    one dtype into one buffer each, one permute per class each, through one
     pack/unpack routine (:func:`gossip_grouping`):
 
     - ``order``: a pytree like ``x`` of integers, the rank at which each
@@ -213,48 +286,77 @@ def neighbor_allreduce(
       ``operations.cc`` [U]); what the exact methods in
       :mod:`bluefog_tpu.algorithms` use for their small trees.
 
-    Both are exact: the weighted combine is linear and the per-edge weights
-    are leaf-independent, so every element sees the same operations in the
-    same order.  Output leaves are in their accumulation dtype either way.
+    Both do the per-leaf path's arithmetic: the per-edge weights are
+    leaf-independent and every leaf is combined by itself, ``sw * a + w1 * r1
+    + w2 * r2`` in that order, from what is unpacked out of each bucket that
+    arrives.  Called by itself on the CPU mesh that is bit for bit the
+    per-leaf path (``tests/test_ops.py``).  Inside a step the compiler decides:
+    where the weights fold to one constant the TPU compiler factors it out,
+    ``(a + r1 + r2) * c``, in the per-leaf path and for a bucket's vectors
+    but not for a leaf that leaves a bucket through a reshape, and XLA's CPU
+    fuses one multiply into an add by what else shares the kernel; either
+    parts the two in the last places of some leaves
+    (``chip_smoke.py --chips 4 --only buckets_vs_per_leaf``; PERF.md section
+    6, PR 30).  Output leaves are in their accumulation dtype either way.
+
+    **The bucket's layout.**  A bucket is ``[n, 8, 128]``: whole TPU tiles.
+    Gossip is element-wise and every rank runs this program, so a bucket may
+    hold its elements in any order that is the same on every rank, and it holds
+    them in the order the TPU keeps each leaf in memory.  A leaf of two or more
+    dimensions lives there as tiles of 8 rows by 128 columns of its last two
+    dimensions, so a leaf whose last dimension is whole lanes and whose
+    second-to-last is whole sublanes (read off its shape, nothing else) goes in
+    tile by tile and comes out the same way, and the compiler takes both as a
+    change of shape alone.  A row-major 1-D bucket costs such a leaf a pass
+    over memory each way (``ravel`` before, ``reshape`` after: in the
+    optimizer-and-gossip part of a ResNet-50 step on exp2(4), 92 relayout
+    passes of a megabyte or more, and 8 with this layout, all of them the
+    head's; ``tests/test_tpu_compile.py``, PERF.md section 6, PR 30).  The
+    other leaves (vectors, 64 or 1000 columns) follow in the same buffer,
+    raveled one after another and zero-padded together to whole tiles; the
+    padding travels and is never read back.  Because unpacking costs nothing,
+    nothing is combined at the bucket's size: a mixed bucket would be a second
+    buffer as large as the parameters, written once and sliced apart again for
+    consumers that are per leaf anyway.  On CPU and GPU, which keep arrays
+    row-major, the tile order is a real transpose each way: the library is
+    TPU-first, no measured path runs there, and one path stands.
     """
 
-    def nar(a):
-        wdt = average_dtype or _weight_dtype(a)
+    def nar(group):
+        """The leaves of ``group`` (one dtype) mixed, through one permute per
+        class: what is sent is packed, what arrives is unpacked, and every
+        leaf is combined by itself."""
+        wdt = average_dtype or _weight_dtype(group[0])
         idx = lax.axis_index(axis_name) if rank_index is None else rank_index
         if self_weight is None:
             sw = jnp.asarray(plan.self_weights, dtype=wdt)[idx]
         else:
             sw = jnp.asarray(self_weight, dtype=wdt)
-        acc = a.astype(wdt) * sw
+        accs = [a.astype(wdt) * sw for a in group]
         # permute in the NARROWER of storage/average dtype: bf16 params with
         # fp32 accumulation send 2 bytes/elem over ICI (the neighbor's exact
         # stored value either way), and an explicit narrow average_dtype
         # still shrinks the wire for wide params
-        wire = a if a.dtype.itemsize <= jnp.dtype(wdt).itemsize else a.astype(wdt)
+        narrow = group[0].dtype.itemsize <= jnp.dtype(wdt).itemsize
+        wire = group if narrow else [a.astype(wdt) for a in group]
+        one = len(group) == 1
+        sent = wire[0] if one else _pack_bucket(wire)
         for cls in plan.classes:
-            recvd = lax.ppermute(wire, axis_name, cls.perm).astype(wdt)
+            recvd = lax.ppermute(sent, axis_name, cls.perm)
+            recvd = [recvd] if one else _unpack_bucket(recvd, group)
             w = jnp.asarray(cls.recv_weights, dtype=wdt)[idx]
-            acc = acc + w * recvd
-        return acc
+            accs = [acc + w * r.astype(wdt) for acc, r in zip(accs, recvd)]
+        return accs
 
     leaves, treedef = jax.tree_util.tree_flatten(x)
     if order is not None:
         order = treedef.flatten_up_to(order)
     buckets = gossip_grouping(leaves, order, len(plan.classes),
                               fuse=fuse).buckets
-    if not buckets:
-        return jax.tree_util.tree_map(nar, x)
     out = [None] * len(leaves)
-    for idxs in buckets:
-        if len(idxs) == 1:
-            out[idxs[0]] = nar(leaves[idxs[0]])
-            continue
-        mixed = nar(jnp.concatenate([leaves[i].ravel() for i in idxs]))
-        off = 0
-        for i in idxs:
-            n = leaves[i].size
-            out[i] = mixed[off:off + n].reshape(leaves[i].shape)
-            off += n
+    for idxs in buckets or [(i,) for i in range(len(leaves))]:
+        for i, mixed in zip(idxs, nar([leaves[i] for i in idxs])):
+            out[i] = mixed
     return jax.tree_util.tree_unflatten(treedef, out)
 
 

@@ -145,6 +145,7 @@ def _note_gossip_grouping(params, order, plan):
     reg.gauge("gossip.buckets").set(len(g.buckets))
     reg.gauge("gossip.permutes").set(g.permutes)
     reg.gauge("gossip.packed_bytes").set(g.packed_bytes)
+    reg.gauge("gossip.tiled_bytes").set(g.tiled_bytes)
 
 
 def make_decentralized_train_step(

@@ -20,7 +20,11 @@ phase that fails raises: the traceback goes to stderr, the last line says
                                      and the block_until_ready probe
     python chip_smoke.py --chips 4   four chips, only what exists across
                                      chips: parity on exp2(4), placement,
-                                     ResNet-50 ATC vs allreduce, contraction
+                                     ResNet-50 ATC vs allreduce, contraction,
+                                     the optimizer's part with bucketed
+                                     gossip against per-leaf gossip
+    python chip_smoke.py --chips 4 --only buckets_vs_per_leaf
+                                     that phase alone
     python chip_smoke.py --rehearse  control-flow rehearsal at tiny sizes on
                                      whatever backend there is; never "ok"
 
@@ -39,10 +43,11 @@ import jax.monitoring
 import jax.numpy as jnp
 import numpy as np
 import optax
+from jax.sharding import PartitionSpec as P
 
 import bluefog_tpu as bf
 from bench import use_compile_cache
-from bluefog_tpu import models, native, topology_util
+from bluefog_tpu import models, native, ops_spmd, optim, topology_util
 from bluefog_tpu.core import basics
 from bluefog_tpu.kernels import make_flash_attention_fn
 from bluefog_tpu.kernels.flash_attention import _default_interpret
@@ -98,6 +103,17 @@ LOSS_ATOL = 5e-3
 # 3.3e-3 on the v5e (PERF.md section 6, PR 29).  A pass that is left out, counted twice or
 # scattered to the wrong tokens moves them by 0.2 or more.
 EXPERTS_L2_RTOL = 1.5e-2
+
+# the bucketed gossip against the per-leaf gossip, the widest gap of a leaf
+# over its largest value.  The two are the same sums written the same way;
+# what parts them is the compiler's (a common weight factored out on the TPU,
+# a multiply fused into an add on the CPU): a few roundings of 6e-8 an
+# element.  Not yet read on the chip as a gap (PERF.md section 7).
+BUCKETS_GAP_RTOL = 1e-6
+
+
+PHASES = ("ops_windows", "resnet_atc", "resnet_allreduce", "contraction",
+          "buckets_vs_per_leaf", "decoder", "experts_piled", "sync_probe")
 
 
 class _CompileClock:
@@ -215,6 +231,14 @@ def phase_ops_windows(n, elems, seed, clock):
 # ---------------------------------------------------------------------------
 
 
+def _resnet_model(cfg):
+    if cfg["model"] == "ResNet50":
+        return models.ResNet50(num_classes=cfg["classes"])
+    # rehearsal only
+    return models.ResNet18(
+        num_classes=cfg["classes"], num_filters=8, small_images=True)
+
+
 class _ResNetJob:
     """Seeded ResNet variables and one rank-major batch on ``ctx.mesh``."""
 
@@ -222,11 +246,7 @@ class _ResNetJob:
         self.cfg = cfg
         self.ctx = ctx = basics.context()
         n, b, img = ctx.size, cfg["batch"], cfg["img"]
-        if cfg["model"] == "ResNet50":
-            self.model = models.ResNet50(num_classes=cfg["classes"])
-        else:  # rehearsal only
-            self.model = models.ResNet18(
-                num_classes=cfg["classes"], num_filters=8, small_images=True)
+        self.model = _resnet_model(cfg)
         self.variables = jax.jit(
             lambda key, x: self.model.init(key, x, train=True)
         )(jax.random.PRNGKey(seed), jnp.ones((b, img, img, 3), jnp.float32))
@@ -361,6 +381,121 @@ def phase_contraction(job, clock):
     assert ratio <= predicted + 1e-3, (
         f"spread contracted by {ratio}, W allows at most {predicted}")
     assert atc_diff <= 1e-3 * scale, "ATC step is not local step then W"
+
+
+def phase_buckets_vs_per_leaf(ctx, cfg, seed, clock, steps=2):
+    """Four chips: what the ATC step does once it has its gradients, alone
+    (SGD with momentum, the gossip over exp2(4), ``p + (c - p)``), on seeded
+    parameters, gradients and momentum that differ on every rank, at the
+    shapes of the model's leaves and at exp2(4)'s own weights, which are
+    thirds and round.  Once with the leaves' order, as the step passes it
+    (two buckets, a permute per bucket and shift class, every leaf combined
+    out of the buckets that arrive), once with the order withheld (a permute
+    per leaf and class); ``steps`` times, each from what the last gave.
+
+    Both programs are written as ``sw * a + w1 * r1 + w2 * r2`` an element,
+    and no leaf may part from its twin by more than ``BUCKETS_GAP_RTOL`` of
+    its largest value: a wrong element, weight or order moves it by a tenth
+    and more.  **Not bit for bit**, and the phase says how many leaves do
+    differ: where the three weights fold to one constant (exp2's uniform
+    weights) the TPU compiler factors it out, ``(a + r1 + r2) * c``, wherever
+    it sees the three products together: in the per-leaf path, in a combine
+    at the bucket's size, and for the bucket's vectors, but not for a leaf
+    of two or more dimensions, whose product it moves in front of the
+    unpack's reshapes.  On four v5e chips 55 of 322 leaves differed in half
+    their elements (the first of them convolution kernels, 64 columns and
+    256; PERF.md section 6, PR 30); on one chip with self-permutes none.
+    XLA's CPU contracts one
+    multiply of an add into a fused multiply-add instead, by what else
+    shares the kernel, and parts a few leaves of a whole step in the last
+    place.
+
+    Not the whole step: the TPU compiler lays out the forward and backward
+    pass of two programs differently, so two steps that differ anywhere give
+    other gradients from the first step on (the first loss of the bucketed
+    and of the per-leaf ResNet-50 step read 6.970607 and 6.970569 on four
+    chips), whatever the gossip does."""
+    t0 = time.perf_counter()
+    n = ctx.size
+    tx = optim.adapt_then_combine_spmd(
+        optax.sgd(0.1, momentum=0.9),
+        optim.make_spmd_comm_fn(CommunicationType.neighbor_allreduce,
+                                plan=ctx.plan))
+    rng = np.random.default_rng(seed)
+    sharding = basics.rank_major_sharding(ctx)
+    img = cfg["img"]
+    shapes = jax.tree_util.tree_map(
+        lambda a: a.shape, jax.eval_shape(
+            lambda: _resnet_model(cfg).init(
+                jax.random.PRNGKey(0), jnp.ones((1, img, img, 3)), train=True)
+        )["params"])
+    is_shape = lambda x: isinstance(x, tuple)
+
+    def seeded(scale):
+        return jax.tree_util.tree_map(
+            lambda shape: jax.device_put(
+                scale * rng.standard_normal((n,) + shape, dtype=np.float32),
+                sharding), shapes, is_leaf=is_shape)
+
+    params, grads, trace = seeded(0.05), seeded(0.01), seeded(0.01)
+    # ready in reverse flatten order, about what a backward pass gives
+    count = len(jax.tree_util.tree_leaves(params))
+    ready = jax.tree_util.tree_unflatten(
+        jax.tree_util.tree_structure(params), range(count, 0, -1))
+    first = lambda t: jax.tree_util.tree_map(lambda a: a[0], t)
+    again = lambda t: jax.tree_util.tree_map(lambda a: a[None], t)
+
+    def run(**order):
+        def local(p, g, m):
+            p, g, m = first(p), first(g), first(m)
+            state = optax.tree_utils.tree_set(tx.init(p), trace=m)
+            updates, state = tx.update(g, state, p, **order)
+            return (again(optax.apply_updates(p, updates)),
+                    again(optax.tree_utils.tree_get(state, "trace")))
+
+        fn = jax.jit(jax.shard_map(
+            local, mesh=ctx.mesh, in_specs=P(basics.NODES_AXIS),
+            out_specs=P(basics.NODES_AXIS)))
+        permutes = fn.lower(params, grads, trace).as_text().count(
+            "collective_permute")
+        p, m = params, trace
+        for _ in range(steps):
+            p, m = fn(p, grads, m)
+        return permutes, [np.asarray(a)
+                          for a in jax.tree_util.tree_leaves((p, m))]
+
+    n_bucketed, bucketed = run(grad_order=ready)
+    n_per_leaf, per_leaf = run()
+    bits = lambda a: np.asarray(a).view(np.uint32)
+    moved = sum(int((bits(a) != bits(b)).sum()) for a, b in
+                zip(bucketed, jax.tree_util.tree_leaves((params, trace))))
+    differ = [(i, int((bits(a) != bits(b)).sum()), a.size)
+              for i, (a, b) in enumerate(zip(bucketed, per_leaf))
+              if not np.array_equal(bits(a), bits(b))]
+    # the widest gap as a share of its leaf's largest value: one unit of the
+    # last place is 6e-8 to 1.2e-7 of the value it belongs to
+    gap = max((float(np.abs(bucketed[i].astype(np.float64) - per_leaf[i]).max()
+                     / np.abs(per_leaf[i]).max())
+               for i, _, _ in differ), default=0.0)
+    elements = int(sum(a.size for a in bucketed))
+    _emit("buckets_vs_per_leaf", t0, clock, ranks=n, steps=steps,
+          self_weight=float(ctx.plan.self_weights[0]),
+          compared="every leaf of parameters and momentum, as bits, after "
+                   "SGD with momentum, gossip and p + (c - p)",
+          leaves=len(bucketed), elements=elements,
+          elements_moved_from_the_start=moved,
+          permutes_bucketed=n_bucketed, permutes_per_leaf=n_per_leaf,
+          leaves_that_differ=len(differ), first_that_differ=differ[:5],
+          largest_gap_over_leaf_max=gap, gap_limit=BUCKETS_GAP_RTOL)
+    classes = len(ctx.plan.classes)
+    buckets = (ops_spmd.MAX_PERMUTES_OUTSTANDING - 1) // classes
+    assert (n_bucketed, n_per_leaf) == (buckets * classes, count * classes), (
+        n_bucketed, n_per_leaf, count)
+    assert moved > 0.99 * elements, "the part left its inputs as they were"
+    assert gap <= BUCKETS_GAP_RTOL, (
+        f"a leaf of the bucketed gossip is {gap:.3g} of its largest value "
+        f"from the per-leaf gossip's; {len(differ)} differ: {differ[:5]}")
+    return len(differ), gap
 
 
 # ---------------------------------------------------------------------------
@@ -629,22 +764,34 @@ def run(args, device):
     _emit("init", t0, clock, ranks=n, compile_cache_dir=cache_dir,
           jax=jax.__version__, mesh_devices=[str(d) for d in ctx.devices])
 
-    phase_ops_windows(n, sizes["gossip_elems"], args.seed, clock)
-    t0 = time.perf_counter()
-    job = _ResNetJob(sizes["resnet"], args.seed)
-    jax.block_until_ready((job.variables, job.batch, job.labels))
-    _emit("resnet_setup", t0, clock, made="seeded variables and batch")
-    phase_resnet(job, "resnet_atc", CommunicationType.neighbor_allreduce,
-                 ctx.plan, clock)
-    phase_resnet(job, "resnet_allreduce", CommunicationType.allreduce, None,
-                 clock)
-    if n > 1:
-        phase_contraction(job, clock)
-    else:
+    want = lambda phase: args.only in (None, phase)
+    if want("ops_windows"):
+        phase_ops_windows(n, sizes["gossip_elems"], args.seed, clock)
+    if n > 1 and want("buckets_vs_per_leaf"):
+        # the cell's weights, which round
+        assert set(np.float32(ctx.plan.self_weights)) == {np.float32(1 / 3)}
+        phase_buckets_vs_per_leaf(ctx, sizes["resnet"], args.seed, clock)
+    if any(map(want, ("resnet_atc", "resnet_allreduce", "contraction"))):
+        t0 = time.perf_counter()
+        job = _ResNetJob(sizes["resnet"], args.seed)
+        jax.block_until_ready((job.variables, job.batch, job.labels))
+        _emit("resnet_setup", t0, clock, made="seeded variables and batch")
+        if want("resnet_atc"):
+            phase_resnet(job, "resnet_atc",
+                         CommunicationType.neighbor_allreduce, ctx.plan, clock)
+        if want("resnet_allreduce"):
+            phase_resnet(job, "resnet_allreduce", CommunicationType.allreduce,
+                         None, clock)
+        if n > 1 and want("contraction"):
+            phase_contraction(job, clock)
         del job
-        phase_decoder(sizes["decoder"], args.seed, on_tpu, clock)
-        phase_experts_piled(sizes["experts"], args.seed, clock)
-        phase_sync_probe(sizes["probe"], args.seed, clock)
+    if n == 1:
+        if want("decoder"):
+            phase_decoder(sizes["decoder"], args.seed, on_tpu, clock)
+        if want("experts_piled"):
+            phase_experts_piled(sizes["experts"], args.seed, clock)
+        if want("sync_probe"):
+            phase_sync_probe(sizes["probe"], args.seed, clock)
     bf.shutdown()
 
 
@@ -654,6 +801,8 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--rehearse", action="store_true",
                     help="tiny sizes on any backend; never prints ok: true")
+    ap.add_argument("--only", choices=PHASES,
+                    help="run this phase alone, with the set-up it needs")
     args = ap.parse_args()
 
     devs = jax.devices()

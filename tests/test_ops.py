@@ -109,34 +109,72 @@ def test_neighbor_allreduce_preserves_average():
     assert np.asarray(out).std(axis=0).max() < np.asarray(x).std(axis=0).max() * 0.2
 
 
+def _small_tree(rng):
+    tree = {
+        "w": jnp.asarray(rng.normal(size=(SIZE, 3, 4)), jnp.float32),
+        "b": jnp.asarray(rng.normal(size=(SIZE, 5)), jnp.float32),
+        "v": jnp.ones((SIZE, 1), jnp.float32),
+        "h": jnp.asarray(rng.normal(size=(SIZE, 2)), jnp.bfloat16),
+        "n": jnp.arange(SIZE, dtype=jnp.int32)[:, None] * jnp.ones(
+            (SIZE, 3), jnp.int32),
+    }
+    # ready in neither key order nor its reverse
+    return tree, {"w": 7, "b": 0, "v": 9, "h": 4, "n": 2}
+
+
+def _tiles_tree(rng):
+    """Leaves that are whole 8 x 128 tiles (they go into a bucket in tile
+    order), leaves that are not (raveled and padded into the same bucket:
+    ResNet-50's head, a 64-column and the first convolution, a vector) and
+    a bf16 leaf, which is a bucket of its own dtype."""
+    shapes = {"c3": (3, 3, 256, 256), "c1": (1, 1, 512, 2048),
+              "head": (2048, 1000), "c64": (3, 3, 64, 64), "c0": (7, 7, 3, 64),
+              "bn": (256,), "h1": (4, 16, 128), "h2": (5, 64)}
+    tree = {k: jnp.asarray(rng.normal(size=(SIZE,) + s),
+                           jnp.bfloat16 if k[0] == "h" else jnp.float32)
+            for k, s in shapes.items()}
+    return tree, {"c3": 3, "c1": 0, "head": 1, "c64": 6, "c0": 7, "bn": 5,
+                  "h1": 2, "h2": 4}
+
+
 # (id, topology, MAX_PERMUTES_OUTSTANDING or None for the module's own, fuse,
-#  order, buckets expected).  The tree below has three dtypes; exp2(8) has
-# three shift classes and ring(8) two, so B = (MAX - 1) // classes.
+#  order, buckets expected, tree).  The small tree has three dtypes, the
+# tiles tree two; exp2(8) has three shift classes and ring(8) two, so
+# B = (MAX - 1) // classes.
 _GROUPINGS = [
-    pytest.param(tu.ExponentialTwoGraph, None, True, None, 3, id="fuse"),
-    pytest.param(tu.ExponentialTwoGraph, None, False, "shuffled", 3,
+    pytest.param(tu.ExponentialTwoGraph, None, True, None, 3, _small_tree,
+                 id="fuse"),
+    pytest.param(tu.ExponentialTwoGraph, None, False, "shuffled", 3, _small_tree,
                  id="order-B1-one-bucket-per-dtype"),
-    pytest.param(tu.RingGraph, None, False, "shuffled", 3,
+    pytest.param(tu.RingGraph, None, False, "shuffled", 3, _small_tree,
                  id="order-B2-ring-fewer-than-dtypes"),
-    pytest.param(tu.ExponentialTwoGraph, 13, False, "shuffled", 4,
+    pytest.param(tu.ExponentialTwoGraph, 13, False, "shuffled", 4, _small_tree,
                  id="order-B4-one-split"),
-    pytest.param(tu.ExponentialTwoGraph, 16, False, "shuffled", 5,
+    pytest.param(tu.ExponentialTwoGraph, 16, False, "shuffled", 5, _small_tree,
                  id="order-B5-two-splits"),
     # ties fall back on flatten order (b, v, w): w holds two thirds of the
     # elements and comes last, so it is the tail, however small TAIL_SHARE is
-    pytest.param(tu.ExponentialTwoGraph, 13, False, "tied", 4,
+    pytest.param(tu.ExponentialTwoGraph, 13, False, "tied", 4, _small_tree,
                  id="order-B4-tied-ranks"),
+    pytest.param(tu.ExponentialTwoGraph, None, True, None, 2, _tiles_tree,
+                 id="fuse-tiles"),
+    pytest.param(tu.RingGraph, None, False, "shuffled", 2, _tiles_tree,
+                 id="order-B2-tiles"),
+    pytest.param(tu.ExponentialTwoGraph, 13, False, "shuffled", 4, _tiles_tree,
+                 id="order-B4-tiles-two-splits"),
 ]
 
 
-@pytest.mark.parametrize("graph,max_out,fuse,order,n_buckets", _GROUPINGS)
+@pytest.mark.parametrize("graph,max_out,fuse,order,n_buckets,make_tree",
+                         _GROUPINGS)
 def test_neighbor_allreduce_grouped_matches_per_leaf(
-        monkeypatch, graph, max_out, fuse, order, n_buckets):
+        monkeypatch, graph, max_out, fuse, order, n_buckets, make_tree):
     """Every grouping of the leaves into permutes (``fuse=True``, and an
     ``order`` at several bucket counts, shuffled or tied) must be bit-for-bit
     exact vs the per-leaf path on a mixed-shape, mixed-dtype pytree —
-    including an awkward scalar-shaped leaf (the push-sum weight case) and
-    an int leaf that accumulates in f32."""
+    including an awkward scalar-shaped leaf (the push-sum weight case), an
+    int leaf that accumulates in f32, and leaves that are packed as whole
+    tiles beside leaves that are padded to them."""
     import jax
     from jax.sharding import PartitionSpec as P
 
@@ -148,18 +186,10 @@ def test_neighbor_allreduce_grouped_matches_per_leaf(
     ctx = basics.context()
     if max_out is not None:
         monkeypatch.setattr(ops_spmd, "MAX_PERMUTES_OUTSTANDING", max_out)
-    rng = np.random.default_rng(3)
-    tree = {
-        "w": jnp.asarray(rng.normal(size=(SIZE, 3, 4)), jnp.float32),
-        "b": jnp.asarray(rng.normal(size=(SIZE, 5)), jnp.float32),
-        "v": jnp.ones((SIZE, 1), jnp.float32),
-        "h": jnp.asarray(rng.normal(size=(SIZE, 2)), jnp.bfloat16),
-        "n": jnp.arange(SIZE, dtype=jnp.int32)[:, None] * jnp.ones(
-            (SIZE, 3), jnp.int32),
-    }
-    # ready in neither key order nor its reverse; or all at once
-    ranks = {"shuffled": {"w": 7, "b": 0, "v": 9, "h": 4, "n": 2},
-             "tied": dict.fromkeys(tree, 0), None: None}[order]
+    tree, shuffled = make_tree(np.random.default_rng(3))
+    # ready in a shuffled order; or all at once
+    ranks = {"shuffled": shuffled, "tied": dict.fromkeys(tree, 0),
+             None: None}[order]
 
     def run(**grouping):
         spmd = lambda t: ops_spmd.neighbor_allreduce(

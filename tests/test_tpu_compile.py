@@ -14,16 +14,18 @@ single worker loads the library.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 import optax
 
 import bluefog_tpu as bf
-from bluefog_tpu import models, ops_spmd, topology_util
+from bluefog_tpu import models, ops_spmd, optim, topology_util
 from bluefog_tpu.common.hlo_inspect import entry_schedule, most_outstanding
 from bluefog_tpu.core import basics
 from bluefog_tpu.core.basics import NODES_AXIS
@@ -223,3 +225,131 @@ def test_per_leaf_step_parks_its_permutes_behind_the_backward_pass(
     assert most_outstanding(marks) == ops_spmd.MAX_PERMUTES_OUTSTANDING
     early = marks[:marks.rfind("C")].count("S")
     assert 0 < early <= ops_spmd.MAX_PERMUTES_OUTSTANDING
+
+
+def _flat_pack(leaves):
+    return jnp.concatenate([a.ravel() for a in leaves])
+
+
+def _flat_unpack(flat, leaves):
+    ends = np.cumsum([a.size for a in leaves])
+    return [flat[end - a.size:end].reshape(a.shape) for a, end in zip(leaves, ends)]
+
+
+def _gossip_part_on_2x2(topo, leaves):
+    """What the ATC step does once it has its gradients, alone: SGD with
+    momentum, the bucketed gossip over exp2(4), ``p + (c - p)``; parameters,
+    gradients and momentum are arguments.  ``leaves`` is a flat dict of
+    per-rank shapes, ready in reverse key order.  The compiled text."""
+    bf.init(devices=topo.devices)
+    try:
+        bf.set_topology(topology_util.ExponentialTwoGraph(4))
+        ctx = basics.context()
+        ranks = NamedSharding(ctx.mesh, P(NODES_AXIS))
+        tx = optim.adapt_then_combine_spmd(
+            optax.sgd(0.1, momentum=0.9),
+            optim.make_spmd_comm_fn(optim.CommunicationType.neighbor_allreduce,
+                                    plan=ctx.plan))
+        order = {k: len(leaves) - i for i, k in enumerate(leaves)}
+        first = lambda t: jax.tree_util.tree_map(lambda a: a[0], t)
+        again = lambda t: jax.tree_util.tree_map(lambda a: a[None], t)
+
+        def local(p, g, trace):
+            p, g, trace = first(p), first(g), first(trace)
+            state = optim.GossipState(
+                base=(optax.TraceState(trace=trace), optax.EmptyState()),
+                step=jnp.zeros((), jnp.int32))
+            updates, state = tx.update(g, state, p, grad_order=order)
+            return again(optax.apply_updates(p, updates)), again(state.base[0].trace)
+
+        tree = {k: jax.ShapeDtypeStruct((4,) + a.shape, a.dtype, sharding=ranks)
+                for k, a in leaves.items()}
+        fn = jax.jit(jax.shard_map(local, mesh=ctx.mesh, in_specs=P(NODES_AXIS),
+                                   out_specs=P(NODES_AXIS)),
+                     donate_argnums=(0, 2))
+        return fn.lower(tree, tree, tree).compile().as_text()
+    finally:
+        bf.shutdown()
+
+
+def _resnet50_leaves():
+    model = models.ResNet50(num_classes=1000)
+    v = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((1, 32, 32, 3))))
+    return {jax.tree_util.keystr(k): a for k, a in
+            jax.tree_util.tree_leaves_with_path(v["params"])}
+
+
+def _bf16_decoder_leaves():
+    # two layers' worth of a bf16 transformer's matrices and vectors
+    shapes = {"qkv": (1024, 3072), "out": (1024, 1024), "up": (1024, 4096),
+              "down": (4096, 1024), "norm": (1024,), "embed": (32000, 1024)}
+    return {f"{k}{i}": jax.ShapeDtypeStruct(s, jnp.bfloat16)
+            for i in range(2) for k, s in shapes.items()}
+
+
+_INSTRUCTION_RE = re.compile(
+    r"^\s+(?:ROOT\s+)?%([\w.\-]+) = (.*?) ([a-z][a-z\-]*)\(([^)]*)\)")
+_ARRAY_RE = re.compile(r"\b([a-z]+\d+)\[([\d,]*)\]")
+_BYTES = {"f32": 4, "bf16": 2, "s32": 4, "u32": 4}
+
+
+def _entry_instructions(text):
+    """(name, elements and bytes of its largest result, opcode, operand names)
+    of each instruction of the ENTRY computation, in program order."""
+    start = text.index("\nENTRY ")
+    for line in text[start + 1:text.index("\n}", start)].splitlines()[1:]:
+        name, result, opcode, operands = _INSTRUCTION_RE.match(line).groups()
+        n, nbytes = max(
+            (int(np.prod([int(d) for d in dims.split(",") if d])), _BYTES[dtype])
+            for dtype, dims in _ARRAY_RE.findall(result))
+        yield name, n, n * nbytes, opcode, re.findall(r"%([\w.\-]+)", operands)
+
+
+@pytest.mark.parametrize("make_leaves,pack", [
+    pytest.param(_resnet50_leaves, "tiles", id="resnet50-f32-tiles"),
+    pytest.param(_resnet50_leaves, "flat", id="resnet50-f32-flat"),
+    pytest.param(_bf16_decoder_leaves, "tiles", id="decoder-bf16-tiles"),
+])
+def test_gossip_buckets_pack_and_unpack_as_bitcasts(
+        topo, monkeypatch, make_leaves, pack):
+    """The optimizer-and-gossip part on real leaf shapes, where every ``copy``,
+    ``reshape`` or ``transpose`` instruction of the compiled program is a
+    relayout pass around a bucket (there is nothing else in it): a leaf that
+    is whole 8 x 128 tiles goes into the bucket that is sent and comes out of
+    each bucket that arrives with none over 1 MB; only leaves that are not
+    whole tiles pay (of ResNet-50's: the head [2048, 1000], 8.2 of the 9.0 MB
+    that are not).  2-byte leaves too: the compiler keeps them in 8-row
+    tiles.  The flat pack of PR 27 (``ravel`` and a 1-D bucket), put back by
+    hand, pays a pass each way for every large leaf: what the tile order is
+    for, and the first thing to look at if this fails.  Two buckets on two
+    shift classes: 4 permutes.
+
+    What the step's gain rests on besides (PERF.md section 6, PR 30): nothing
+    that reads a bucket a permute brought writes a buffer of the bucket's
+    size.  A combine at the bucket's size writes one, as large as the
+    parameters, and on the chip it cost more than the relayouts saved."""
+    leaves = make_leaves()
+    if pack == "flat":
+        monkeypatch.setattr(ops_spmd, "_pack_bucket", _flat_pack)
+        monkeypatch.setattr(ops_spmd, "_unpack_bucket", _flat_unpack)
+    text = _gossip_part_on_2x2(topo, leaves)
+    assert text.count(" collective-permute-start(") == 4
+    sizes = lambda keep: sorted(
+        int(np.prod(a.shape)) for a in leaves.values()
+        if keep(a) and a.size * a.dtype.itemsize > 1 << 20)
+    whole, ragged = sizes(ops_spmd._tileable), sizes(lambda a: not ops_spmd._tileable(a))
+    entry = list(_entry_instructions(text))
+    moved = sorted(n for _, n, nbytes, opcode, _ in entry
+                   if opcode in ("copy", "reshape", "transpose")
+                   and nbytes >= 1 << 20)
+    if pack == "tiles":
+        assert set(moved) <= set(ragged), moved
+        arrived = {name: n for name, n, _, opcode, _ in entry
+                   if opcode == "collective-permute-done"}
+        assert len(arrived) == 4
+        mixed = [(name, n) for name, n, _, _, operands in entry
+                 for o in operands if n >= arrived.get(o, n + 1)]
+        assert not mixed, mixed
+    else:
+        assert len(moved) >= len(whole)  # at least a pass per large leaf
