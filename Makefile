@@ -18,9 +18,6 @@
 #   make monitor         fleet-monitor gate: the monitor rule family
 #                        (seeded-bug alert completeness + clean-twin
 #                        false-alarm freedom + window coalescing)
-#   make trend           regression gate over the frozen BENCH_r*.json
-#                        corpus: exit nonzero when any gated headline of
-#                        the newest record regressed > 20%
 #
 # All targets force the CPU backend so they run on any host.
 
@@ -29,7 +26,7 @@ ENV     := JAX_PLATFORMS=cpu
 PYTEST  := $(ENV) $(PY) -m pytest tests/ -q -m 'not slow' \
            --continue-on-collection-errors -p no:cacheprovider
 
-.PHONY: verify analyze selftest changed test distrib loadgen monitor trend
+.PHONY: verify analyze selftest changed test distrib loadgen monitor
 
 verify: selftest analyze test
 
@@ -56,6 +53,3 @@ loadgen:
 
 monitor:
 	$(ENV) $(PY) -m bluefog_tpu.analysis --family monitor
-
-trend:
-	$(ENV) $(PY) bench.py --trend

@@ -1,26 +1,22 @@
-"""Gossip bandwidth benchmark — the second BASELINE.json tracked metric
-("win_put gossip bandwidth GB/s"; SURVEY.md §7 stage 6 names this file).
+"""Island gossip bandwidth — the true one-sided path of the frozen host planes,
+measured on a CPU.
 
-Measures the one-sided-emulation hot path: repeated ``win_put`` exchanges of
-a large tensor along the installed topology, reporting aggregate bytes moved
-across the mesh per second.  Bytes counted are payload bytes actually put on
-the wire: per exchange, every rank sends its payload once per out-edge
-(``lax.ppermute`` per shift class — the grouped-send/recv twin of the
-reference's per-neighbor ``MPI_Put`` [U], SURVEY.md §2.4).
+``--islands N``: N OS processes depositing through the native shared-memory
+mailbox (seqlock slots), reporting per-rank ``win_put`` bytes/s against the
+host's raw single-threaded memcpy for the same payload (``measure_islands``).
+``--protocol-probe``: the single-process self-edge protocol ceiling
+(``measure_island_protocol``; ``--sweep`` walks chunk size and pipeline depth).
+The ``measure_*_overhead``, ``measure_tcp_chunked`` and
+``measure_wire_compression`` functions are the gates each host plane's
+document names.
 
-A ``neighbor_allreduce`` phase runs for comparison (same wire pattern, no
-mailbox), so the window emulation's overhead over the raw collective is
-visible.
+The speed of gossip on the device is not measured here: that is
+``python -m chipbench`` (``collective_ms_per_step``, ``atc_over_allreduce``
+on the four-chip cell; ``BENCHMARK.json``, ``PERF_LEDGER.jsonl``).
 
-Run (CPU mesh): JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
-    python benchmarks/gossip_bandwidth.py --mb 4 --iters 5
-Run (TPU):      python benchmarks/gossip_bandwidth.py
-Islands mode (--islands N): measures the TRUE one-sided path instead —
-N OS processes depositing through the native shared-memory mailbox
-(seqlock slots), reporting aggregate win_put bytes/s across processes.
+Run: JAX_PLATFORMS=cpu python benchmarks/gossip_bandwidth.py --islands 2 --mb 16
 
-Prints ONE JSON line: {"metric", "value", "unit", "vs_baseline"} where
-vs_baseline is win_put bandwidth / neighbor_allreduce bandwidth.
+Prints ONE JSON line: {"metric", "value", "unit", "vs_baseline"}.
 """
 
 import argparse
@@ -35,15 +31,9 @@ import jax
 if os.environ.get("JAX_PLATFORMS"):
     jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
 
-import jax.numpy as jnp
-
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from bench import paired_slope, robust_min
-import bluefog_tpu as bf
 from bluefog_tpu import topology_util
-from bluefog_tpu.core import basics
-from bluefog_tpu.ops import device_sync as _sync  # proven host round-trip
 
 
 def _island_worker(rank, size, mb, iters, warmup, topo_name):
@@ -93,8 +83,8 @@ def _raw_copy_gbs(mb: float, iters: int = 10) -> float:
 def measure_islands(nprocs: int, mb: float, iters: int, warmup: int,
                     topology: str = "exp2") -> dict:
     """True one-sided win_put bandwidth: N OS processes depositing through
-    the native shm mailbox.  Returns the metric dict (bench.py reuses this
-    so BENCH_r{N}.json carries both BASELINE.json tracked metrics).
+    the native shm mailbox.  Returns the metric dict (the frozen
+    BENCH_r{N}.json records carry it).
 
     ``value`` is per-rank GB/s (the regime the README quotes; on a 1-core
     driver host aggregate-over-many-processes measures the OS scheduler,
@@ -754,8 +744,7 @@ def main():
     parser.add_argument("--warmup", type=int, default=3)
     parser.add_argument("--topology", default="exp2", choices=["exp2", "ring"])
     parser.add_argument("--islands", type=int, default=0, metavar="N",
-                        help="measure the island shm mailbox with N processes "
-                        "instead of the SPMD emulation")
+                        help="measure the island shm mailbox with N processes")
     parser.add_argument("--protocol-probe", action="store_true",
                         help="single-process self-edge protocol ceiling "
                         "(no second process, no scheduler confound)")
@@ -764,191 +753,10 @@ def main():
                         "pipeline depth around the defaults")
     args = parser.parse_args()
 
-    if args.islands or args.protocol_probe:
-        run_islands(args)
-        return
-
-    bf.init()
-    print(json.dumps(measure_spmd(args.mb, args.iters, args.warmup,
-                                  args.topology)))
-
-
-def _timed_per_call(fn, iters, warmup):
-    """Per-call time via the shared paired-slope estimator
-    (``bench.paired_slope``, repeats=2): the constant per-region cost —
-    fetch RTT AND pipeline fill — cancels in the region difference.  The
-    pre-r4 RTT-only subtraction left the fill share in, which at 256 MB
-    payloads (~16 ms/op true cost) inflated per-op time and
-    under-reported the wire bandwidth (r4 estimator note).  Returns (per_call_seconds, used_fallback)."""
-    out = fn()  # always at least one un-timed call to trigger compile
-    for _ in range(max(warmup - 1, 0)):
-        out = fn()
-    _sync(out)
-
-    def region(k):
-        o = None
-        t0 = time.perf_counter()
-        for _ in range(k):
-            o = fn()
-        _sync(o)
-        return time.perf_counter() - t0
-
-    def fallback_rt():
-        t0 = time.perf_counter()
-        for _ in range(3):
-            _sync(out)
-        return (time.perf_counter() - t0) / 3
-
-    # auto-size iters so the slope's delta (~iters/2 ops) is ~1 s: the
-    # two phases differ >5x in per-op cost (a self-edge ppermute+combine
-    # collapses to nearly an HBM copy while the mailbox path does real
-    # extra passes), and a fixed iters leaves the cheap phase's delta at
-    # the scale of ~100 ms host stalls.  Pilot mini-slope over
-    # 2-vs-8 ops estimates per-op.  TPU only: on the CPU test mesh each
-    # op fans out an 8-thread collective on a 1-core host — sizing up to
-    # hundreds of ops there trips the 40 s rendezvous timeout.
-    if jax.devices()[0].platform == "tpu":
-        est = (region(8) - region(2)) / 6
-        if est > 0:
-            # 2.0/est: the big region is ~2 s so the DELTA (iters/2 ops)
-            # is the targeted ~1 s, well clear of ~100 ms host stalls
-            iters = max(iters, min(int(2.0 / est), 1000))
-    ts, fb = [], 0
-    for _ in range(2):
-        t, f = paired_slope(region, iters, "gossip_bw", fallback_rt,
-                            repeats=2)
-        ts.append(max(t, 1e-9))
-        fb += int(f)
-    # two agreeing passes are enough; >3% disagreement means at least one
-    # caught a stall window, so buy a third pass — robust_min's 2nd-
-    # smallest guard then has a real quorum to arbitrate with instead of
-    # flagging an unresolvable 2-sample split
-    if abs(ts[0] - ts[1]) / max(ts) > 0.03:
-        t, f = paired_slope(region, iters, "gossip_bw", fallback_rt,
-                            repeats=2)
-        ts.append(max(t, 1e-9))
-        fb += int(f)
-    # robust_min, not min: a stall-deflated per-call would INFLATE the
-    # reported bandwidth (r4 advisor)
-    return robust_min(ts, "gossip_bw"), fb, ts
-
-
-def _loopback_plan():
-    """A hand-built 1-rank plan with one REAL self-edge ppermute.
-
-    ``compile_plan`` folds self-loops into self-weights (no transfer), so
-    on a single chip the compiled exp2/ring plans move no bytes.  This
-    plan keeps the (0, 0) edge as an actual ``lax.ppermute`` round: on one
-    device that is a device-local HBM copy through the full fused
-    win_put_update program — the honest single-chip measurement of the
-    window emulation's per-byte cost (the "wire" is the memory fabric).
-    """
-    from bluefog_tpu.core.plan import CommPlan, PermClass
-
-    cls = PermClass(
-        perm=((0, 0),),
-        recv_weights=(0.5,),
-        recv_mask=(1,),
-        send_mask=(1.0,),
-        slot_index=(0,),
-    )
-    return CommPlan(
-        size=1,
-        self_weights=(0.5,),
-        classes=(cls,),
-        in_degrees=(1,),
-        out_degrees=(1,),
-        in_neighbors=((0,),),
-        out_neighbors=((0,),),
-    )
-
-
-def measure_spmd(mb: float, iters: int, warmup: int,
-                 topology: str = "exp2") -> dict:
-    """SPMD win_put-emulation bandwidth on the live mesh (``bf.init()`` must
-    have run).  Returns the metric dict.
-
-    On a 1-rank mesh the compiled topologies have no edges, so this
-    installs the self-edge loopback plan (see ``_loopback_plan``) — the
-    ppermute becomes an on-device HBM copy and the number measures the
-    emulation's data path, not the scheduler.
-    """
-    n = bf.size()
-    topo = (topology_util.ExponentialTwoGraph(n) if topology == "exp2"
-            else topology_util.RingGraph(n))
-    bf.set_topology(topo)
-    ctx = basics.context()
-    label = topology
-    restore_key = None
-    if n == 1:
-        # inject the loopback plan for the current topology key so
-        # win_create and the ops below pick it up; restored in the finally
-        # below — a caller continuing after this measurement must get the
-        # real compiled plan back, not a plan that pays a full-payload
-        # copy per op
-        from bluefog_tpu.core.basics import _topo_key
-
-        restore_key = (_topo_key(topo), ())
-        restore_val = ctx._plan_cache.get(restore_key)
-        ctx._plan_cache[restore_key] = _loopback_plan()
-        label = "self-edge loopback"
-    try:
-        return _measure_spmd_inner(ctx, topo, n, label, mb, iters, warmup)
-    finally:
-        if restore_key is not None:
-            if restore_val is None:
-                ctx._plan_cache.pop(restore_key, None)
-            else:
-                ctx._plan_cache[restore_key] = restore_val
-
-
-def _measure_spmd_inner(ctx, topo, n, label, mb, iters, warmup):
-    plan = ctx.plan
-
-    elems = max(int(mb * 1e6 / 4), 1)
-    # pre-place with the mesh sharding: an unplaced input pays a full
-    # payload reshard on EVERY call (measured ~8 ms/call on CPU), which
-    # would measure the resharder, not the wire
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    from bluefog_tpu.core.basics import NODES_AXIS
-
-    x = jax.device_put(jnp.ones((n, elems), jnp.float32),
-                       NamedSharding(ctx.mesh, P(NODES_AXIS)))
-    payload_bytes = elems * 4
-    # one send per out-edge per exchange, summed over ranks
-    edges = sum(len(cls.perm) for cls in plan.classes)
-
-    # --- win_put phase (the metric; fused put+update = one dispatch) ---
-    bf.win_create(x, "gossip_bw")
-    t_put, fb_put, ts_put = _timed_per_call(
-        lambda: bf.win_put_update(x, "gossip_bw"), iters, warmup)
-    bf.win_free("gossip_bw")
-
-    # --- raw neighbor_allreduce phase (the comparison point) ---
-    t_nar, fb_nar, _ = _timed_per_call(
-        lambda: bf.neighbor_allreduce(x), iters, warmup)
-
-    gbs_put = edges * payload_bytes / t_put / 1e9
-    gbs_nar = edges * payload_bytes / t_nar / 1e9
-    return {
-        "metric": f"win_put gossip wire bandwidth ({label}, {n} rank(s), "
-                  f"{mb:g} MB payload)",
-        "value": round(gbs_put, 3),
-        "unit": "GB/s aggregate",
-        # the window path's bandwidth as a fraction of the raw collective's
-        "vs_baseline": round(gbs_put / gbs_nar, 4) if gbs_nar else 0.0,
-        "neighbor_allreduce_gbs": round(gbs_nar, 3),
-        # paired_slope's contract: flag phases that fell back to the
-        # fill-inflated RTT-subtraction estimator
-        "estimator_fallbacks": int(fb_put) + int(fb_nar),
-        "estimator": "paired-slope",
-        # per-headline uncertainty in the contract (r4 verdict #7):
-        # GB/s across the win_put passes, worst to best
-        "range": [round(edges * payload_bytes / max(ts_put) / 1e9, 3),
-                  round(edges * payload_bytes / min(ts_put) / 1e9, 3)],
-        "n_runs": len(ts_put),
-    }
+    if not (args.islands or args.protocol_probe):
+        parser.error("give --islands N or --protocol-probe: gossip on the "
+                     "device is measured by python -m chipbench")
+    run_islands(args)
 
 
 if __name__ == "__main__":

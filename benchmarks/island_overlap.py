@@ -164,7 +164,7 @@ def _worker_hidden(rank, size, rounds, mb, inner):
 
 
 def measure_overlap_hidden(nprocs=2, rounds=12, mb=16.0, inner=60):
-    """bench.py phase: ``overlap_hidden_pct`` headline (gate >= 90)."""
+    """``overlap_hidden_pct`` of the frozen BENCH_r*.json records (gate >= 90)."""
     from bluefog_tpu import islands
 
     prev = os.environ.get("BFTPU_TELEMETRY")

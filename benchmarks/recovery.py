@@ -53,7 +53,7 @@ def measure_recovery(nprocs: int = 4, victim: int = 1,
                      failure_timeout_s: float = _FAILURE_TIMEOUT_S) -> dict:
     """Kill one of ``nprocs`` gossiping island ranks; return the metric
     dict with ``value`` = median survivor kill-to-first-healed-round ms
-    (bench.py rides this in the headline's ``recovery_ms`` key)."""
+    (``recovery_ms`` in the frozen BENCH_r*.json records)."""
     import multiprocessing as mp
 
     from bluefog_tpu.native import shm_native
@@ -150,7 +150,7 @@ def _join_worker(job, q):
 def measure_join(nprocs: int = 4) -> dict:
     """Scale ``nprocs`` gossiping island ranks to ``nprocs + 1``: return
     the metric dict with ``value`` = rendezvous-to-first-gossip-round
-    latency of the joiner in ms (bench.py's ``join_ms`` headline).  Like
+    latency of the joiner in ms (the frozen records' ``join_ms``).  Like
     ``recovery_ms`` is dominated by the detector floor, this is
     dominated by the members' admission cadence (they probe the board
     once per gossip round) — the interesting part is the margin above
@@ -277,7 +277,7 @@ def measure_partition(nprocs: int = 4, victim: Optional[int] = None,
     machinery carrying its estimate, the majority heals the retired
     identity, and gossip re-converges.  Returns the metric dict with
     ``value`` = cut-to-first-gossip-round-as-readmitted-rank ms
-    (bench.py's ``partition_merge_ms`` headline).  Because the join
+    (the frozen records' ``partition_merge_ms``).  Because the join
     request NAMES the retired identity, the majority excises it at the
     grant instead of waiting out its heartbeats — so the merge beats
     the ``failure_timeout_ms`` detector floor that a crash-recovery
@@ -435,8 +435,8 @@ def measure_straggler(nprocs: int = 4, steps: int = 30,
     """One rank sleeps ``delay_s`` per round (gray failure: heartbeats
     keep flowing) while the others run synchronous gossip steps; return
     the metric dict with ``value`` = pooled healthy-rank step p99 in ms
-    with the adaptive control loop ON (bench.py's ``straggler_p99_ms``
-    headline), plus the adaptive-OFF p99 for the contrast.  ON is
+    with the adaptive control loop ON (the frozen records'
+    ``straggler_p99_ms``), plus the adaptive-OFF p99 for the contrast.  ON is
     bounded by the edge deadline (ABSORB) and then by the demotion that
     drops the straggler's edges; OFF eats the nap every round."""
     on_ms = _run_straggler_once(nprocs, steps, delay_s, adaptive_on=True)

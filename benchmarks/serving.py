@@ -4,7 +4,7 @@ The serving headline (docs/SERVING.md): a publisher commits versioned
 weight snapshots into the job's double-buffered seqlock'd region
 (``bluefog_tpu.serve.snapshot``) while a replica process subscribes and
 hot-swaps.  ``value`` is the median publish-complete to swap-complete
-wall time in ms (bench.py's ``publish_swap_ms`` headline) — dominated
+wall time in ms (``publish_swap_ms`` in the frozen records) — dominated
 by the replica's poll cadence by construction, so the interesting part
 is the margin above it (region read + crc + the reference flip).  The
 replica keeps calling ``serve_step`` between swaps, so a run with
@@ -56,8 +56,8 @@ def _replica_worker(job, n_versions, q):
 def measure_publish_swap(versions: int = 12, payload_kb: int = 64) -> dict:
     """Publish ``versions`` snapshots while one replica process
     subscribes; return the metric dict with ``value`` = median
-    publish-complete to hot-swap-complete ms (bench.py rides this in
-    the headline's ``publish_swap_ms`` key)."""
+    publish-complete to hot-swap-complete ms (``publish_swap_ms`` in the frozen
+    BENCH_r*.json records)."""
     import multiprocessing as mp
 
     from bluefog_tpu.native import shm_native
@@ -152,7 +152,7 @@ def measure_load(replica_counts=(4, 8), rate_hz: float = 200.0,
     (:mod:`bluefog_tpu.serve.loadgen`), so a swap stall shows up as
     queueing delay on every overdue request instead of silently
     vanishing (coordinated omission).  ``value`` is the churn-phase
-    p99 at the largest fleet (bench.py's
+    p99 at the largest fleet (the frozen records'
     ``serve_p99_during_publish_ms`` rides the per-fleet dict).
     """
     import threading
@@ -256,7 +256,7 @@ def measure_distrib(replicas=(4, 8, 16), versions: int = 8,
     K in ``replicas``.
 
     ``value`` is the median publish-complete to ALL-replicas-swapped
-    wall time in ms at the middle fleet size (bench.py's
+    wall time in ms at the middle fleet size (the frozen records'
     ``distrib_all_swap_ms``).  ``delta_ratio_bf16`` is the steady-state
     wire bytes a one-version-behind replica pulls divided by the raw
     f32 snapshot bytes — the < 0.6 acceptance gate, measured at the

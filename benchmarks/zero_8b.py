@@ -43,7 +43,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from bench import use_compile_cache
 import bluefog_tpu as bf
 from bluefog_tpu import topology_util
 from bluefog_tpu.core import basics
@@ -51,6 +50,7 @@ from bluefog_tpu.core.basics import LOCAL_AXIS, MACHINES_AXIS
 from bluefog_tpu.models.transformer import LlamaLM
 from bluefog_tpu.parallel import zero
 from bluefog_tpu.parallel.zero import make_fsdp_gossip_train_step
+from chipbench.compile_cache import use_compile_cache
 
 # Llama-3-8B shape (BASELINE config #5): GQA with 8 kv heads, 128k vocab
 CFG = dict(vocab=128256, hidden=4096, layers=32, heads=32, kv_heads=8,

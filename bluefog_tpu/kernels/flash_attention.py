@@ -18,7 +18,7 @@ speeds.**
 Two interchangeable forwards behind one ``impl`` switch ("auto" default =
 the Pallas kernel): a hand Pallas kernel and an online-softmax blockwise
 computation in plain XLA (``impl="xla"``).  Forward-only standing (r4
-continuation, benchmarks/attention_fwd_ab.py, scan-chain + slope
+continuation, a script since deleted, scan-chain + slope
 protocol): the Pallas forward is 4-6x FASTER than the XLA blockwise
 forward at 134M/1B/long-context dims (44-82 TF/s vs 9-18, builder
 readings of 2026-07 under a slope estimator that cancels the constant
@@ -105,7 +105,7 @@ _LANES = 128
 # output uses the full width; the backward packs BOTH scalars (lse, corr)
 # into one tile of this width — each gets _SCALAR_LANES/2 lanes — and
 # re-reads one such tile per (q-block, k-block) pair.  History (all
-# end-to-end interleaved benchmarks/llama.py A/Bs of 2026-07, where
+# end-to-end interleaved A/Bs of a script since deleted, 2026-07, where
 # microbenchmarks spread >100%; builder readings, not re-measured):
 # - r4, 512^2 blocks: separate 128-lane lse/corr arrays = ~1.8 GB of
 #   re-reads per 134M layer (r3 advisor finding); narrowing to 8 lanes
@@ -126,7 +126,7 @@ _ALIGNED_ENABLED = os.environ.get("BLUEFOG_FLASH_ALIGNED", "1") != "0"
 # unaffected.  Numerics: the folded multiplier is never a power of two, so
 # q rounds once in its storage dtype (<= 2^-9 relative on bf16 scores;
 # exact-ish on f32/CPU); all CPU-interpret numerics tests pass either way.
-# r4 end-to-end A/B (2 interleaved benchmarks/llama.py rounds, 134M,
+# r4 end-to-end A/B (2 interleaved rounds of a script since deleted, 134M,
 # 1024^2 blocks): off 92.3/93.0 vs on 92.5/87.6 tok/s — within noise to
 # negative; Mosaic's natural exp evidently already lowers to the cheap
 # path, so the saved multiply buys nothing on this chip.
@@ -559,7 +559,7 @@ def _blockwise_fwd_xla(q, k, v, q_start, k_start, *, scale, causal, block_k,
     Selectable via ``impl="xla"``.  At the r3-era 512^2 blocks it beat
     the hand kernel forward-only by ~25-35%; after the r4 aligned fast
     path + 1024^2 retune the Pallas forward is 4-6x FASTER
-    (benchmarks/attention_fwd_ab.py, slope protocol), and inside the custom-vjp's
+    (a script since deleted, slope protocol), and inside the custom-vjp's
     backward recompute this path measured 13x slower end-to-end on Llama
     training — so it is NOT the auto default on either lens.  Kept as
     the independent same-contract implementation (numerics cross-check,
@@ -1002,7 +1002,7 @@ def _fwd_dispatch(q, k, v, q_start, k_start, *, scale, causal, block_q,
     jit the unrolled per-block forward inside the custom-vjp recompute
     blows up the backward's schedule.  (Post-r4-retune the forward-only
     comparison reversed too — Pallas 4-6x faster,
-    benchmarks/attention_fwd_ab.py.)  Training throughput is the
+    by a script since deleted.)  Training throughput is the
     headline workload, so auto = Pallas; forward-heavy callers can still
     pass impl="xla"."""
     use_xla = impl == "xla"

@@ -255,7 +255,7 @@ def _bf16_matmul_f32_acc(x, kernel):
     Builder reading on the v5e (2026-07, not re-measured): even with this
     VJP the bf16 head is NEUTRAL at 1B and −3% at 134M vs the f32 head — XLA's
     default-precision f32 matmul already sustains 153–166 TF/s (~80% of
-    the bf16 rate, `benchmarks/peaks.py`), so the rate gain cannot pay
+    the bf16 rate, a script since deleted), so the rate gain cannot pay
     for the per-chunk operand casts.  f32 stays the default; the option
     exists for hardware where true-f32 matmul is actually slow.
     """

@@ -901,7 +901,8 @@ def statuspage_enabled() -> bool:
     """Whether the live-introspection plane (per-rank status pages + the
     mutex holder board) is on.  Default ON — the point of the plane is
     that a job is attachable *before* anyone knew it would misbehave;
-    ``BFTPU_STATUSPAGE=0`` opts out (bench.py gates the cost < 2%)."""
+    ``BFTPU_STATUSPAGE=0`` opts out (``benchmarks/gossip_bandwidth.py``'s
+    ``measure_statuspage_overhead`` gates the cost < 2%)."""
     return os.environ.get("BFTPU_STATUSPAGE", "1") not in ("0", "", "false")
 
 

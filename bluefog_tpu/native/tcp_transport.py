@@ -1044,7 +1044,7 @@ class _Peers:
             reg.counter("tcp.bytes_sent").add(wire_bytes)
             reg.counter("tcp.bytes_received").add(_HDR.size * (nchunks + 1))
             # raw vs wire payload volume: the measured compression ratio
-            # (bench.py wire_compression_ratio) is wire/raw
+            # (gossip_bandwidth.measure_wire_compression) is wire/raw
             reg.counter("tcp.raw_payload_bytes").add(arr.nbytes)
             reg.counter("tcp.wire_payload_bytes").add(wire_bytes)
             reg.histogram("tcp.rtt_s", op="write_chunked").observe(

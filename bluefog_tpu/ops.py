@@ -24,7 +24,6 @@ For code *inside* ``jit``/``shard_map`` (the idiomatic TPU path), use
 from __future__ import annotations
 
 import functools
-import os
 from typing import Any, Dict, Optional, Sequence, Union
 
 import jax
@@ -65,33 +64,14 @@ def device_sync(tree):
     """Block until every array leaf of ``tree`` is materialized on device,
     and return ``tree``.
 
-    ``jax.block_until_ready`` alone was NOT a trustworthy barrier on the
-    device path this was written against: it returned before the work was
-    done.  Completion is therefore *proven* by round-tripping to the host
-    one scalar DERIVED from every leaf — data dependency forces the fetch
-    to wait for the real computation.  The transfer is a single f32, so
-    where ``block_until_ready`` does block the extra cost is one host
-    round-trip (``chip_smoke.py`` times both on the chip it runs on).  Set
-    ``BLUEFOG_FETCH_SYNC=0`` to fall back to bare ``block_until_ready``.
+    ``jax.block_until_ready`` is the barrier.  It was once doubted on the
+    device path, and a scalar derived from every leaf was fetched to the
+    host as proof; on the v5e the block itself waits (PR 23: 512 chained
+    4096x4096 bf16 matmuls, dispatch returned in 0.23 ms, the block in
+    0.3662 s, the block plus the fetch in 0.3685 s), so the fetch is gone.
+    Leaves that are no ``jax.Array`` pass through untouched.
     """
     jax.block_until_ready(tree)
-    if os.environ.get("BLUEFOG_FETCH_SYNC", "1") != "0":
-        # multi-process: eager ops reject non-fully-addressable arrays, so
-        # probe this process's first shard instead — it lives on a local
-        # device whose execution stream ordered after the real computation
-        leaves = []
-        for l in jax.tree_util.tree_leaves(tree):
-            if not (isinstance(l, jax.Array) and l.size):
-                continue
-            if not l.is_fully_addressable:
-                shards = l.addressable_shards
-                if not shards:
-                    continue
-                l = shards[0].data
-            leaves.append(jnp.ravel(l)[:1].astype(jnp.float32))
-        if leaves:
-            probe = jnp.concatenate(leaves)
-            np.asarray(probe)  # the host round-trip that proves completion
     return tree
 
 
@@ -117,7 +97,7 @@ class Handle:
 
         MAY BLOCK on platforms whose arrays lack an async ``is_ready``
         query: there the only truthful
-        answer requires a ``device_sync`` round-trip, so a reference-style
+        answer requires a ``device_sync`` wait, so a reference-style
         "poll and do useful work meanwhile" loop degrades to a wait.  On
         standard jax.Array platforms it is a non-blocking probe.
         """

@@ -1,8 +1,7 @@
 """bluefog_tpu.telemetry — cross-rank metrics, counters, and event journal.
 
-The layer `timeline.py` (chrome-trace spans) and `profiling.py` (offline
-slope timing) do not provide: always-on, lock-light counters / gauges /
-fixed-bucket histograms plus a per-rank JSONL event journal, threaded
+What `timeline.py` (chrome-trace spans) does not provide: always-on,
+lock-light counters / gauges / fixed-bucket histograms plus a per-rank JSONL event journal, threaded
 through the gossip hot paths (islands win ops, shm mailbox, tcp
 transport) and the failure paths (resilience detector / healing /
 degraded steps).

@@ -75,7 +75,7 @@ def make_lm_loss_fns(model):
     ``[B, T, vocab]`` logits never materialize) and ``loss_fn`` is the
     identity; otherwise the model returns logits and ``loss_fn`` is the
     standard shifted cross-entropy.  One definition shared by
-    ``benchmarks/llama.py`` and ``examples/jax_llama_pretrain.py`` so the
+    ``chip_smoke.py`` and ``examples/jax_llama_pretrain.py`` so the
     chunked-loss contract cannot drift between them.
     """
     if getattr(model, "head_chunks", 0) > 1:

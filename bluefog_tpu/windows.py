@@ -370,7 +370,7 @@ class _FusionMeta:
     feature — a whole parameter tree rides ONE window, so each gossip round
     is one exchange instead of one per leaf (a builder reading of 27x on
     BERT-base, 2026-07, where each dispatch cost milliseconds; not
-    re-measured; `benchmarks/bert_pushsum.py`)."""
+    re-measured; the script that read it is gone)."""
 
     __slots__ = ("treedef", "shapes", "sizes")
 

@@ -16,8 +16,7 @@ phase that fails raises: the traceback goes to stderr, the last line says
                                      decoder step with the compiled Pallas
                                      flash kernel vs dense attention, the
                                      dropless expert layer under a pile-up
-                                     vs the benchmark's plain reference,
-                                     and the block_until_ready probe
+                                     vs the benchmark's plain reference
     python chip_smoke.py --chips 4   four chips, only what exists across
                                      chips: parity on exp2(4), placement,
                                      ResNet-50 ATC vs allreduce, contraction,
@@ -34,7 +33,6 @@ Times printed here are observations of a smoke run, not performance.
 import argparse
 import json
 import os
-import statistics
 import sys
 import time
 
@@ -46,7 +44,6 @@ import optax
 from jax.sharding import PartitionSpec as P
 
 import bluefog_tpu as bf
-from bench import use_compile_cache
 from bluefog_tpu import models, native, ops_spmd, optim, topology_util
 from bluefog_tpu.core import basics
 from bluefog_tpu.kernels import make_flash_attention_fn
@@ -58,6 +55,7 @@ from bluefog_tpu.training import (
     make_lm_loss_fns,
     replicate_for_mesh,
 )
+from chipbench.compile_cache import use_compile_cache
 
 
 # ---------------------------------------------------------------------------
@@ -67,7 +65,7 @@ from bluefog_tpu.training import (
 FULL = dict(
     gossip_elems=1 << 20,
     resnet=dict(model="ResNet50", classes=1000, img=224, batch=128),
-    # benchmarks/llama.py preset "small": hidden 768, 12 heads of 64
+    # a small decoder: hidden 768, 12 heads of 64
     decoder=dict(vocab=32000, hidden=768, layers=12, heads=12, dff=2048,
                  seq=2048, batch=8, head_chunks=8, logits_rows=2),
     # one layer of chipbench's `smallthinker-21b-a3b` at its cell's 2 x 8192
@@ -83,7 +81,6 @@ TINY = dict(
                  seq=128, batch=2, head_chunks=2, logits_rows=1),
     experts=dict(tokens=96, hidden=128, dff=8, experts=64, top_k=6, held=8,
                  rows=64),
-    probe=dict(dim=128, iters=8),
 )
 
 # flash-vs-dense agreement at bf16 compute on seeded weights.  The two paths
@@ -113,7 +110,7 @@ BUCKETS_GAP_RTOL = 1e-6
 
 
 PHASES = ("ops_windows", "resnet_atc", "resnet_allreduce", "contraction",
-          "buckets_vs_per_leaf", "decoder", "experts_piled", "sync_probe")
+          "buckets_vs_per_leaf", "decoder", "experts_piled")
 
 
 class _CompileClock:
@@ -689,52 +686,6 @@ def phase_experts_piled(cfg, seed, clock):
 
 
 # ---------------------------------------------------------------------------
-# phase: does block_until_ready block here?
-# ---------------------------------------------------------------------------
-
-
-def phase_sync_probe(cfg, seed, clock, repeats=3):
-    """Evidence for ROADMAP S2, not a pass/fail: a long dependent matmul
-    chain timed to (a) dispatch return, (b) ``jax.block_until_ready``,
-    (c) ``bf.device_sync`` (block + a scalar fetch)."""
-    t0 = time.perf_counter()
-    d, iters = cfg["dim"], cfg["iters"]
-    w = jax.random.normal(jax.random.PRNGKey(seed), (d, d), jnp.bfloat16) / (d ** 0.5)
-
-    @jax.jit
-    def chain(x):
-        return jax.lax.fori_loop(0, iters, lambda _, a: a @ w, x)
-
-    x = jnp.eye(d, dtype=jnp.bfloat16)
-    bf.device_sync(chain(x))  # compile + warm
-    dispatch, block, fetch_after_block, sync = [], [], [], []
-    for _ in range(repeats):
-        t = time.perf_counter()
-        y = chain(x)
-        t1 = time.perf_counter()
-        jax.block_until_ready(y)
-        t2 = time.perf_counter()
-        np.asarray(y[0, 0])
-        t3 = time.perf_counter()
-        dispatch.append(t1 - t)
-        block.append(t2 - t)
-        fetch_after_block.append(t3 - t2)
-        t = time.perf_counter()
-        bf.device_sync(chain(x))
-        sync.append(time.perf_counter() - t)
-    med = statistics.median
-    flops = 2.0 * d ** 3 * iters
-    _emit("block_until_ready_probe", t0, clock, matmul_dim=d, chain_length=iters,
-          flops=flops,
-          dispatch_return_seconds=med(dispatch),
-          block_until_ready_seconds=med(block),
-          scalar_fetch_after_block_seconds=med(fetch_after_block),
-          device_sync_seconds=med(sync),
-          implied_tflops_at_block_until_ready=flops / med(block) / 1e12,
-          block_until_ready_blocks=bool(med(block) >= 0.9 * med(sync)))
-
-
-# ---------------------------------------------------------------------------
 
 
 def _rebuild_native():
@@ -790,8 +741,6 @@ def run(args, device):
             phase_decoder(sizes["decoder"], args.seed, on_tpu, clock)
         if want("experts_piled"):
             phase_experts_piled(sizes["experts"], args.seed, clock)
-        if want("sync_probe"):
-            phase_sync_probe(sizes["probe"], args.seed, clock)
     bf.shutdown()
 
 

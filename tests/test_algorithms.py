@@ -55,6 +55,13 @@ def run(opt, A, c, iters=ITERS):
     for _ in range(iters):
         grads = {"w": grad_fn(params["w"], A, c)}
         params, state = opt.step(params, grads, state)
+        # one eager step in flight on the 8-device CPU mesh, never two: with
+        # steps queued behind one another and the host loaded, one of a
+        # step's eight participants gets no thread, the other seven wait out
+        # XLA's 40 s rendezvous limit and the process aborts (a wait every 25
+        # steps still lost 5 of 6 copies run beside 16 busy processes; a wait
+        # every step lost none of 18)
+        jax.block_until_ready(params)
     w = np.asarray(params["w"], np.float64)
     return w
 

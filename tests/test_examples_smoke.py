@@ -38,76 +38,47 @@ def test_example_runs_and_loss_finite(script, args):
     assert "done:" in proc.stdout
 
 
-@pytest.mark.parametrize("max_passes", [1, 4],
-                         ids=["degenerate-single-pass", "adaptive"])
-def test_bench_emits_strict_json(max_passes):
-    """bench.py's stdout contract: exactly ONE line of STRICT JSON with
-    the required keys.  max_passes=1 pins the degenerate single-pass path
-    (spread must print 0.0, never a non-RFC Infinity token — r4 review
-    finding); max_passes=4 exercises the adaptive loop + session-ceiling
-    emission."""
-    import json
+# what is left under benchmarks/ measures the frozen host planes; each
+# script with the entries its plane's document names (docs/BENCHMARKS.md)
+SURVIVING_BENCHMARKS = {
+    "zero_8b": ("execute_truncated", "main"),
+    "gossip_bandwidth": ("measure_islands", "measure_island_protocol",
+                         "measure_telemetry_overhead",
+                         "measure_tracing_overhead",
+                         "measure_statuspage_overhead",
+                         "measure_lab_probe_overhead",
+                         "measure_monitor_overhead", "measure_tcp_chunked",
+                         "measure_wire_compression"),
+    "recovery": ("measure_recovery", "measure_join", "measure_partition",
+                 "measure_straggler"),
+    "serving": ("measure_publish_swap", "measure_serve_rate", "measure_load",
+                "measure_distrib"),
+    "island_overlap": ("measure_overlap_hidden", "main"),
+}
 
-    env = dict(
-        os.environ,
-        JAX_PLATFORMS="cpu",
-        XLA_FLAGS="--xla_force_host_platform_device_count=8",
-        PYTHONPATH=REPO,
-        BENCH_STEPS="2",
-        BENCH_WARMUP="1",
-        BENCH_MAX_PASSES=str(max_passes),
-        # Small on purpose: bench.py keeps running optional budget-gated
-        # phases until the budget saturates, so this test costs ~budget
-        # seconds of wall clock.  Every key asserted below comes from the
-        # unconditional phases (headline + session ceiling), which ignore
-        # the budget — 75 s just stops the optional-phase accumulation.
-        BENCH_BUDGET_S="75",
+
+@pytest.mark.parametrize("script", sorted(SURVIVING_BENCHMARKS))
+def test_surviving_benchmark_script_imports_alone(script):
+    """Each script left under benchmarks/ imports with nothing but the
+    checkout on its path, no module ``bench`` anywhere on it, and exposes
+    the entries the documents send a reader to."""
+    code = (
+        "import importlib.util, sys\n"
+        "assert importlib.util.find_spec('bench') is None\n"
+        f"spec = importlib.util.spec_from_file_location({script!r}, "
+        f"{os.path.join(REPO, 'benchmarks', script + '.py')!r})\n"
+        "mod = importlib.util.module_from_spec(spec)\n"
+        "spec.loader.exec_module(mod)\n"
+        "assert 'bench' not in sys.modules\n"
+        f"missing = [n for n in {SURVIVING_BENCHMARKS[script]!r} "
+        "if not callable(getattr(mod, n, None))]\n"
+        "assert not missing, missing\n"
     )
-    proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py")],
-        env=env, capture_output=True, text=True, timeout=420, cwd=REPO,
-    )
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    lines = [l for l in proc.stdout.splitlines() if l.strip()]
-    assert len(lines) == 1, proc.stdout
-    rec = json.loads(lines[0])  # json.loads default REJECTS nothing...
-    # ...so re-check strictness explicitly: the RFC forbids Infinity/NaN
-    assert "Infinity" not in lines[0] and "NaN" not in lines[0], lines[0]
-    for key in ("metric", "value", "unit", "vs_baseline", "spread_pct",
-                "passes"):
-        assert key in rec, rec
-    assert rec["passes"] <= max_passes
-    if max_passes == 1:
-        assert rec["spread_pct"] == 0.0
-    else:
-        # the session-ceiling phase is try/except-guarded in bench.py, so
-        # a regression there would otherwise vanish silently
-        assert "session_ceiling_img_s" in rec, rec
-        assert "ratio_to_session_ceiling" in rec, rec
-
-
-def test_attention_fwd_ab_emits_json():
-    """benchmarks/attention_fwd_ab.py (the forward-only Pallas-vs-XLA
-    A/B that re-pinned the r3 'XLA wins fwd-only' claim) must keep
-    running off-TPU and emit its one-line JSON contract — the ratio is
-    meaningless on CPU, the contract is what's pinned."""
-    import json
-
     env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=REPO)
-    proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "benchmarks/attention_fwd_ab.py"),
-         "--batch", "1", "--heads", "1", "--seq", "128", "--head-dim", "64",
-         "--chain", "2", "--repeats", "1", "--group", "1"],
-        env=env, capture_output=True, text=True, timeout=420, cwd=REPO,
-    )
+    proc = subprocess.run([sys.executable, "-c", code], env=env,
+                          capture_output=True, text=True, timeout=240,
+                          cwd=REPO)
     assert proc.returncode == 0, proc.stderr[-2000:]
-    lines = [l for l in proc.stdout.splitlines() if l.strip()]
-    assert len(lines) == 1, proc.stdout
-    rec = json.loads(lines[0])
-    for key in ("metric", "value", "unit", "vs_baseline", "pallas_ms",
-                "xla_ms"):
-        assert key in rec, rec
-    assert rec["value"] > 0
 
 
 def test_async_islands_example():

@@ -18,8 +18,8 @@ under churn").
   send-re-anchor bugs are caught by the request-SLO and open-loop
   invariants;
 - bench: ``benchmarks/serving.py measure_load`` returns the strict
-  contract bench.py freezes, and the frozen ``BENCH_r10.json`` gates
-  hold;
+  contract the BENCH_r*.json records froze, and the ``BENCH_r10.json``
+  gates hold;
 - chaos e2e (slow): a publisher on a 1.5 s cadence + three loaded
   replica processes, one SIGKILLed mid-load and respawned — every
   replica's p99 stays finite and every SLO violation window in the
@@ -419,7 +419,7 @@ def test_measure_load_contract(shm_dir):
         assert set(out[key]) == {"2"}
         assert math.isfinite(out[key]["2"]) and out[key]["2"] > 0
     assert out["value"] == out["p99_publish_by_fleet_ms"]["2"]
-    json.dumps(out)   # the whole dict must be strict-JSON for bench.py
+    json.dumps(out)   # the whole dict must be strict JSON, as the records froze it
 
 
 def test_bench_r10_serve_load_gates_frozen():

@@ -44,7 +44,7 @@ GB = 1e9
 @pytest.fixture(autouse=True)
 def no_persistent_compile_cache():
     # an entry point's main() may have turned the persistent compilation
-    # cache on in this process (bench.use_compile_cache), and executables
+    # cache on in this process (use_compile_cache), and executables
     # deserialized from that cache report alias_size_in_bytes == 0 —
     # every aliasing assertion below would fail in-suite while passing
     # in isolation.  These contracts need a real compile.  Clearing the
@@ -100,7 +100,7 @@ def _compile_step(step_fn, *structs):
 
 
 def test_llama_134m_train_step_memory():
-    """The driver-benchmark 134M config (llama.py "small" preset shapes,
+    """The driver-benchmark 134M config (a small decoder's shapes,
     blockwise attention standing in for the Pallas kernel — same O(T)
     memory class; Pallas does not compile on CPU)."""
     from bluefog_tpu.kernels import make_flash_attention_fn

@@ -166,6 +166,16 @@ def _quadratic_loss_grads(params, targets):
     return {"w": params["w"] - targets}
 
 
+def _descend(opt, params, state, targets, steps=300):
+    for _ in range(steps):
+        grads = _quadratic_loss_grads(params, targets)
+        params, state = opt.step(params, grads, state)
+        # one eager step in flight on the CPU mesh, never two: see
+        # tests/test_algorithms.py::run
+        jax.block_until_ready(params)
+    return params
+
+
 _SCHED = optax.exponential_decay(0.3, 1, 0.985)  # decaying step: exact consensus
 
 
@@ -189,9 +199,7 @@ def test_decentralized_optimization_converges(opt_ctor):
     opt = opt_ctor()
     params = {"w": jnp.zeros((SIZE, 3))}
     state = opt.init(params)
-    for _ in range(300):
-        grads = _quadratic_loss_grads(params, targets)
-        params, state = opt.step(params, grads, state)
+    params = _descend(opt, params, state, targets)
     target_mean = np.asarray(targets).mean(axis=0)
     np.testing.assert_allclose(
         np.asarray(params["w"]), np.tile(target_mean, (SIZE, 1)), atol=5e-2
@@ -215,9 +223,7 @@ def test_adam_atc_reaches_consensus_and_descends():
         return 0.5 * float(jnp.sum((p["w"] - targets) ** 2))
 
     loss0 = global_loss(params)
-    for _ in range(300):
-        grads = _quadratic_loss_grads(params, targets)
-        params, state = opt.step(params, grads, state)
+    params = _descend(opt, params, state, targets)
     w = np.asarray(params["w"])
     assert w.std(axis=0).max() < 0.1  # consensus
     assert global_loss(params) < 0.6 * loss0  # descent

@@ -59,8 +59,8 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-# [B, T, H, D] of the decoder presets the benchmarks run (benchmarks/llama.py
-# "small", "1b", the long-context run) plus a short sequence and f32
+# [B, T, H, D] of three decoder presets ("small", "1b", a long-context run)
+# plus a short sequence and f32
 FLASH_SHAPES = [
     pytest.param((8, 2048, 12, 64), jnp.bfloat16, id="small-B8-T2048-H12-D64-bf16"),
     pytest.param((8, 2048, 14, 128), jnp.bfloat16, id="1b-B8-T2048-H14-D128-bf16"),
