@@ -18,6 +18,8 @@ place by chipbench/control.py and by the tests; a benchmark run never runs
 them.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -82,7 +84,7 @@ def local_step_fn(reference, sizes, opt_spec, lower_step=False):
     the stated precision (or one below), then the optax update."""
     tx = optimizers.make(opt_spec)
 
-    @jax.jit
+    @functools.partial(jax.jit, donate_argnums=(0, 2))
     def local_step(p, s, o, x, y):
         (loss, new_s), g = jax.value_and_grad(
             lambda p_: reference.loss_fn(p_, s, x, y, sizes, lower_step),
@@ -106,41 +108,46 @@ def reference_run(reference, sizes, opt_spec, M, seed, batches, *,
     local_step = local_step or local_step_fn(reference, sizes, opt_spec, lower_step)
 
     dev = jax.devices()[0]
-    ranks = [(params0, stats0, tx.init(params0)) for _ in range(n)]
+    paths = list(params0)
+    out = {"losses": np.zeros((STEPS, n)), "assoc_p": None,
+           "params0": {path: np.asarray(params0[path])[None] for path in paths}}
+    # the step donates its parameters and its optimizer state, so every rank
+    # starts from a tree of its own and the device holds 16 bytes a parameter
+    # (p, mu, nu, the gradient) beside the step's activations
+    starts = [jax.tree_util.tree_map(jnp.copy, params0) for _ in range(n - 1)]
+    ranks = [(p, stats0, tx.init(p)) for p in starts + [params0]]
     identity = n == 1 and not lower_payload
-    out = {"losses": np.zeros((STEPS, n)), "assoc_p": None}
+    grad_norms = []  # [ranks][leaves], of the first step
     for k in range(STEPS):
         x_all, y_all = batches[k]
-        locals_, grads = [], []
         for r, (p, s, o) in enumerate(ranks):
             x = jax.device_put(x_all[r], dev)
             y = jax.device_put(y_all[r], dev)
             p, s, o, loss, g = local_step(p, s, o, x, y)
             out["losses"][k, r] = float(loss)
+            if k == 0:
+                grad_norms.append([
+                    float(jnp.linalg.norm(g[path].astype(jnp.float32)))
+                    for path in paths])
+            del g  # 4 bytes a parameter that the next step needs
             ranks[r] = (p, s, o)
-            locals_.append(p)
-            grads.append(g)
         if k == 0:
-            out["grad_norms"] = {
-                path: np.array([float(jnp.linalg.norm(g[path].astype(jnp.float32)))
-                                for g in grads])
-                for path in params0}
+            out["grad_norms"] = dict(zip(paths, np.array(grad_norms).T))
             out["assoc_p"] = M @ np.ones(n)
-        del grads
         if identity and k > 0:
             continue  # (c x) / c is x: nothing to carry through the host
         mixed = {}
-        for path in params0:
-            stack = np.stack([np.asarray(p[path]) for p in locals_])
+        for path in paths:
+            stack = np.stack([np.asarray(p[path]) for p, _, _ in ranks])
             mixed[path] = mix(M, stack, lower_payload, payload_includes_self)[0] \
                 .astype(np.float32)
         if k == 0:
             out["params1"] = mixed
-        ranks = [({path: jax.device_put(mixed[path][r], dev) for path in params0},
-                  s, o) for r, (_, s, o) in enumerate(ranks)]
-    out["params0"] = {path: np.asarray(a)[None] for path, a in params0.items()}
+        for r, (p, _, _) in enumerate(ranks):
+            for path in paths:  # leaf by leaf: the old leaf goes as the new comes
+                p[path] = jax.device_put(mixed[path][r], dev)
     end = {path: np.stack([np.asarray(p[path]) for p, _, _ in ranks])
-           for path in params0}
+           for path in paths}
     out["delta_norms"] = _np_norms({p: end[p] - out["params0"][p] for p in end})
     return out
 

@@ -24,7 +24,7 @@ For layer l with input x [T, d]:
 6. RMSNorm, head over the vocabulary slice, next-token cross-entropy:
    position t against labels[t + 1], mean over the first T - 1 positions.
 
-It has to fit beside the 36 bytes a parameter that chipbench/check.py keeps
+It has to fit beside the 16 bytes a parameter that chipbench/check.py keeps
 on the device, so it is computed in blocks: one sequence at a time, one
 query head and one block of query rows at a time for the scores, a
 `jax.checkpoint` around each sequence, each layer and each block, the logits
@@ -215,6 +215,27 @@ def layer(x, p, b, window, sizes, lower, held_ids=None):
     x = x + _mm("tk,kd->td", att, p[(b, "o", "kernel")], lower)
     m = _rms_norm(x, p[(b, "ffn_norm", "scale")])
     return x + expert_terms(m, r, p, b, sizes, lower, held_ids)
+
+
+def held_rows(p, ids, sizes):
+    """ids [B, T] -> int[layers]: how many of the batch's T x top-6
+    assignments go, in each layer, to the experts held here, by equations 1 to
+    5 above.  At even routing that is B x T x 6 x held / 64 a layer; it is
+    what `python -m chipbench.routing` reads before and after a window."""
+    k = sizes["moe_num_active_primary_experts"]
+    windows = layer_windows(sizes)
+
+    def one(seq):
+        x, counts = p[("embed", "embedding")][seq], []
+        for i, window in enumerate(windows):
+            b = f"layer_{i}"
+            r = _mm("td,de->te", x, p[(b, "router")], False)
+            counts.append(jnp.sum(jax.lax.top_k(r, k)[1] < _held(sizes)))
+            if i + 1 < len(windows):
+                x = layer(x, p, b, window, sizes, False)
+        return jnp.stack(counts)
+
+    return jnp.sum(jax.lax.map(one, ids), axis=0)
 
 
 def _sequence_loss(p, ids, y, sizes, lower):

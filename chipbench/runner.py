@@ -171,7 +171,6 @@ class Session:
     def first_steps(self, job):
         """The first three steps of the object the window will time, captured
         for the comparison with the plain reference."""
-        start_params, _ = self.build_weights(self.key)  # the step donates its own
         got = {"losses": []}
         for k in range(check.STEPS):
             token, loss = job.step(k)
@@ -184,6 +183,9 @@ class Session:
                 p = job.assoc_p()
                 got["assoc_p"] = np.ones(self.chips) if p is None else p
         got["losses"] = np.stack(got["losses"])
+        # the start is built again, and only now: the step donates its own, and a
+        # copy kept beside the steps would be 4 bytes a parameter of their peak
+        start_params, _ = self.build_weights(self.key)
         got["delta_norms"] = check.delta_norms(job.params(), start_params)
         self.marks.append(("captures", time.perf_counter()))
         return got

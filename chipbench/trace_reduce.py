@@ -209,7 +209,8 @@ def reduce(trace, step_anchor, window_programs=()):
         for a, b in steps:
             comp = clip(_started_in(compute, a, b), a, b)
             cl = clip(_started_in(coll, a, b), a, b)
-            c_ms.append(1e3 * sum(e - s for s, e in comp))
+            # the union: a `while` op's interval holds its body's ops'
+            c_ms.append(1e3 * union_length(comp))
             k_ms.append(1e3 * union_length(cl))
             x_ms.append(1e3 * exposed_length(cl, comp))
             launched = _started_in(mods, a, b)

@@ -15,12 +15,12 @@ sys.path.insert(0, REPO)
 from chipbench import manifest  # noqa: E402
 
 
-def _chipbench(*args, devices=1):
+def _chipbench(*args, devices=1, timeout=120):
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}")
     env.pop("BENCH_RUN", None)
     return subprocess.run([sys.executable, "-m", "chipbench", *args], cwd=REPO,
-                          env=env, capture_output=True, text=True, timeout=120)
+                          env=env, capture_output=True, text=True, timeout=timeout)
 
 
 RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device", "checks"}
@@ -103,6 +103,25 @@ def test_every_number_compared_closes_stderr_and_the_result_line():
     assert cell.sizes()["per_rank_batch"] == 128
     assert cell.sizes(rehearse=True)["per_rank_batch"] == 4
     assert cell.mix["optimizer"]["name"] == "adamw"
+
+
+def test_the_decoder_cell_rehearses_correct_under_its_warm_up():
+    """The banded kernels in interpret mode, the dropless expert layer, the
+    chunked loss, AdamW at 1.5e-7, 3e-7 and 4.5e-7 in the three checked steps:
+    every number inside the limits that the constant rate was held to."""
+    name = "smallthinker-21b-a3b-atc-warmup-b2-s8k-1chip"
+    proc = _chipbench("--workload", name, "--seed", str(2**31 + 34), "--seconds",
+                      "1", "--trace", "0", "--rehearse", timeout=300)
+    result, earlier = _result(proc)
+    assert list(result)[-1] == "checks" and result["correct"] is True
+    assert result["failed"] == 0 and result["attempted"] > 3
+    limits = manifest.resolve(name).module("reference").LIMITS
+    checks = result["checks"]
+    assert {n: checks[n]["limit"] for n in limits} == limits
+    assert all(0 <= checks[n]["value"] <= limits[n] for n in limits)
+    # the parameters did move: a first update of nothing would compare zeros
+    assert checks["change1_rel_l2"]["value"] > 0
+    assert any('"collective_permute_in_lowered_step": false' in l for l in earlier)
 
 
 @pytest.mark.parametrize("args,message", [

@@ -63,6 +63,55 @@ def test_cells_pairs_and_chips(m):
         w["name"] for w in m["workloads"]}
 
 
+DECODER = "smallthinker-21b-a3b-atc-warmup-b2-s8k-1chip"
+CONSTANT_RATE = "smallthinker-21b-a3b-atc-b2-s8k-1chip"
+DECODER_METRICS = {
+    "train_step_host_ms_per_step", "attention_ms_per_step", "expert_ms_per_step",
+    "flash_fwd_window_roofline", "flash_bwd_dkv_window_roofline",
+    "flash_bwd_dq_window_roofline"}
+
+
+def test_the_warm_up_cell_is_the_constant_rate_cell_with_one_change(m):
+    cells = {w["name"]: w for w in m["workloads"]}
+    assert len(cells) == 6
+    assert cells[DECODER] == dict(
+        cells[CONSTANT_RATE], name=DECODER, traffic="atc-warmup-b2-s8k-1chip",
+        why=cells[DECODER]["why"])
+    # the constant-rate cell's mix with one change: the optimizer warms up
+    mix, old = manifest.resolve(DECODER).mix, manifest.resolve(CONSTANT_RATE).mix
+    assert {k: v for k, v in mix.items() if k != "describes"} == {
+        "job": "spmd_train_step", "communication_type": "neighbor_allreduce",
+        "mode": "atc", "topology": {"graph": "ExponentialTwoGraph", "kwargs": {}},
+        "mixing": "exp2", "pool": 4,
+        "optimizer": {"name": "adamw", "learning_rate": 0.0003, "weight_decay": 0.1,
+                      "warmup_steps": 2000},
+        "sizes": {"per_rank_batch": 2, "seq_len": 8192}}
+    assert dict(mix, optimizer=old["optimizer"], describes="") == dict(old, describes="")
+    # the configuration is as it was: no warm-up of its own, the same cut
+    config = manifest.resolve(DECODER).config
+    assert config["optimizer"] == old["optimizer"] == {
+        "name": "adamw", "learning_rate": 0.0003, "weight_decay": 0.1}
+    assert any("warm-up" in line for line in config["assumed"])
+
+
+@pytest.mark.parametrize("cell", [CONSTANT_RATE, DECODER])
+def test_both_decoder_cells_are_read_by_the_decoders_metrics(m, cell):
+    named = {p["name"] for p in m["per_layer"] if cell in p.get("workloads", [])}
+    assert named == DECODER_METRICS
+    resolved = {p["name"] for p in manifest.resolve(cell).per_layer}
+    assert DECODER_METRICS <= resolved
+
+
+def test_a_name_the_manifest_does_not_have_is_refused_and_never_stood_in_for():
+    """No alias in the harness: a cell is found by the name BENCHMARK.json
+    gives it, and any other name raises, a near miss too."""
+    assert not hasattr(manifest, "RENAMED")
+    for name in ("smallthinker-21b-a3b-atc-b2-s8k", DECODER + "-old",
+                 "smallthinker-21b-a3b"):
+        with pytest.raises(manifest.ManifestError, match="no workload named"):
+            manifest.resolve(name)
+
+
 def test_metrics_bounds_and_what_each_layer_metric_moves(m):
     e2e = {e["name"]: e for e in m["end_to_end"]}
     assert set(e2e) == {"train_samples_s_chip", "step_ms_p95", "setup_s"}

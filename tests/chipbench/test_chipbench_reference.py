@@ -19,7 +19,7 @@ import bluefog_tpu as bf
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 sys.path.insert(0, REPO)
 
-from chipbench import check, control, manifest, runner, seeded  # noqa: E402
+from chipbench import check, control, manifest, optimizers, runner, seeded  # noqa: E402
 
 
 def test_mixing_matrices_are_the_topologies_written_from_their_definitions():
@@ -130,6 +130,10 @@ def test_seeded_inputs_repeat_for_a_seed_and_differ_between_seeds():
     ("bert-base-pushsum-1chip", ["step", "payload"]),
     # exp2(1) has no edge: nothing travels, so there is no payload to round
     ("bert-base-atc-b128-1chip", ["step"]),
+    ("smallthinker-21b-a3b-atc-b2-s8k-1chip", ["step"]),
+    # the same; the learning rate warms up, so the three steps run at 1.5e-7 to
+    # 4.5e-7 and the limits have to hold at changes that small
+    ("smallthinker-21b-a3b-atc-warmup-b2-s8k-1chip", ["step"]),
 ])
 def test_sound_readings_pass_and_the_controls_fail(workload, controls):
     """chipbench.control at the rehearsal sizes, one CPU device: the program
@@ -151,6 +155,39 @@ def test_sound_readings_pass_and_the_controls_fail(workload, controls):
         assert failed("control_" + name), row["control_" + name]
 
 
+def test_the_references_step_donates_and_the_start_is_kept_on_the_host():
+    """The device holds p, mu, nu and the gradient, 16 bytes a parameter,
+    beside the step's activations: the step takes its parameters and its
+    optimizer state for its own, and `reference_run` returns the start as the
+    host's copy with every other capture."""
+    ses = runner.Session(manifest.resolve("bert-base-atc-b128-1chip"), rehearse=True)
+    try:
+        ses.load(5)
+        params, stats = ses._ref_weights(ses.key)
+        start = {p: np.array(a) for p, a in params.items()}
+        step = check.local_step_fn(ses.reference, ses.sizes, ses.opt_spec)
+        opt_state = optimizers.make(ses.opt_spec).init(params)
+        x, y = (a[0] for a in ses.batches[0])
+        new_p, _, new_o, loss, g = step(params, stats, opt_state, x, y)
+        given = jax.tree_util.tree_leaves((params, opt_state))
+        assert all(a.is_deleted() for a in given)
+        assert not any(a.is_deleted() for a in jax.tree_util.tree_leaves((new_p, new_o, g)))
+        ref = check.reference_run(
+            ses.reference, ses.sizes, ses.opt_spec, ses.M, ses.seed, ses.batches,
+            weights=ses._ref_weights(ses.key))
+    finally:
+        bf.shutdown()
+    assert set(ref) == {"losses", "assoc_p", "params0", "grad_norms", "params1",
+                        "delta_norms"}
+    assert ref["losses"][0, 0] == float(loss)
+    for p, a in start.items():
+        np.testing.assert_array_equal(ref["params0"][p][0], a)
+        np.testing.assert_array_equal(ref["params1"][p][0], np.asarray(new_p[p]))
+        assert ref["grad_norms"][p].shape == (1,) and ref["delta_norms"][p].shape == (1,)
+    assert np.isfinite(ref["losses"]).all() and ref["losses"].shape == (check.STEPS, 1)
+    assert min(float(v[0]) for v in ref["delta_norms"].values()) > 0
+
+
 class _Frozen:
     """A timed path broken underneath: the step runs and returns a loss, but
     the state it leaves behind is the state it was given (a copy of it: the
@@ -169,18 +206,48 @@ class _Frozen:
         return out
 
 
-@pytest.mark.parametrize("workload", ["bert-base-pushsum-1chip",
-                                      "bert-base-atc-b128-1chip"])
-def test_a_step_that_returns_its_state_unchanged_is_not_correct(workload, capsys):
+def _broken_run(workload, wrap_job, capsys):
     cell = manifest.resolve(workload)
     args = argparse.Namespace(workload=cell.name, seed=9, seconds=0.3,
                               trace=0, rehearse=True)
-    result = runner.run(args, time.perf_counter(), cell, wrap_job=_Frozen)
+    result = runner.run(args, time.perf_counter(), cell, wrap_job=wrap_job)
     out = capsys.readouterr().out
     assert result["correct"] is False
     assert result["failed"] == 0 and result["attempted"] > 0  # it ran; it is wrong
-    assert "NOT OK" in out and "check delta_norm_gap" in out
+    assert "NOT OK" in out
     assert set(result) == {"correct", "attempted", "failed", "metrics", "device",
                            "checks"}
-    assert result["checks"]["delta_norm_gap"]["value"] \
-        > result["checks"]["delta_norm_gap"]["limit"]
+    return result["checks"], out
+
+
+DECODER_CELLS = ["smallthinker-21b-a3b-atc-b2-s8k-1chip",
+                 "smallthinker-21b-a3b-atc-warmup-b2-s8k-1chip"]
+
+
+@pytest.mark.parametrize("workload", [
+    "bert-base-pushsum-1chip", "bert-base-atc-b128-1chip", *DECODER_CELLS])
+def test_a_step_that_returns_its_state_unchanged_is_not_correct(workload, capsys):
+    checks, out = _broken_run(workload, _Frozen, capsys)
+    assert "check delta_norm_gap" in out
+    assert checks["delta_norm_gap"]["value"] > checks["delta_norm_gap"]["limit"]
+
+
+def _half_batch(job):
+    """A timed path broken underneath: the second half of every batch is left
+    out and the mean taken over the rest (the first half stands in its place:
+    the same mean, the same shapes).  The reference keeps the whole batch."""
+    def halve(a):
+        half = a.shape[1] // 2
+        return jnp.concatenate([a[:, :half], a[:, :half]], axis=1)
+
+    job.spec.batches = [tuple(halve(a) for a in batch)
+                        for batch in job.spec.batches]
+    return job
+
+
+@pytest.mark.parametrize("workload", DECODER_CELLS)
+def test_half_of_the_batch_left_out_is_not_correct(workload, capsys):
+    checks, _ = _broken_run(workload, _half_batch, capsys)
+    over = [name for name, c in checks.items() if c["value"] > c["limit"]]
+    assert over and set(over) <= {"loss_gap", "grad_norm_gap", "delta_norm_gap",
+                                  "change1_rel_l2"}, checks

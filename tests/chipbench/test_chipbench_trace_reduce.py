@@ -93,6 +93,35 @@ def test_reduce_takes_the_median_step_of_the_slowest_device():
     assert len(r["breakdown"]["device_ops"]) <= 10
 
 
+@pytest.mark.parametrize("nested,compute_ms", [
+    ([], 7.0),
+    # a loop of 4 ms whose two body ops (1.5 ms each) run inside it, on top of
+    # the step's 6 ms fusion: the loop and its body are one stretch of the
+    # device's time, not 4 + 3 ms beside the 7
+    ([("%while.3 = (s32[], f32[8]) while()", 0.001, 0.005),
+      ("%fusion.7 = f32[8] fusion()", 0.001, 0.0025),
+      ("%fusion.8 = f32[8] fusion()", 0.003, 0.0045)], 7.0),
+    # a loop that runs where nothing else does adds its own length, once
+    ([("%while.3 = (s32[], f32[8]) while()", 0.0065, 0.0075),
+      ("%fusion.7 = f32[8] fusion()", 0.0065, 0.0075)], 8.0),
+    # and one that runs past the step's end is clipped to the step
+    ([("%while.3 = (s32[], f32[8]) while()", 0.009, 0.012),
+      ("%fusion.7 = f32[8] fusion()", 0.009, 0.0095)], 8.0),
+], ids=["flat", "loop-inside", "loop-alone", "loop-past-the-end"])
+def test_compute_is_the_union_of_a_loop_and_the_ops_inside_it(nested, compute_ms):
+    trace = _synthetic(devices=1, slow=None)
+    for k in range(6):
+        trace["devices"][0]["ops"] += [(n, k * 0.010 + s, k * 0.010 + e)
+                                       for n, s, e in nested]
+    r = tr.reduce(trace, r"^jit_local_step")
+    assert r["compute_ms_per_step"] == pytest.approx(compute_ms)
+    assert r["compute_ms_per_step"] <= 10.0  # never more than the step
+    # by name each op keeps its own time: a reader of one kernel is not cut
+    if nested:
+        assert r["ops_ms_per_step"][nested[0][0]] == pytest.approx(
+            1e3 * (min(nested[0][2], 0.010) - nested[0][1]))
+
+
 def test_reduce_counts_window_programs_and_launches_per_round():
     mods, ops = [], []
     for k in range(5):
