@@ -1,9 +1,11 @@
 """Flash attention: blockwise XLA forward/backward + a Pallas TPU kernel.
 
-**What the chip records hold (PR 29, `PERF.md` section 6; TPU v5e, one
-chip, a traced run of the cell `smallthinker-21b-a3b-atc-b2-s8k-1chip`:
-B2 S8192, 28 heads of 128, bfloat16, 1024 x 1024 blocks, device time a
-call, visible query-key pairs only counted as work):** the banded kernels
+**What the chip records hold (PR 29 and PR 34, `PERF.md` section 5; TPU
+v5e, one chip, a traced run of the cell
+`smallthinker-21b-a3b-atc-warmup-b2-s8k-1chip`, whose attention reads as
+the constant-rate cell's did: B2 S8192, 28 heads of 128, bfloat16, 1024 x
+1024 blocks, device time a call, visible query-key pairs only counted as
+work):** the banded kernels
 (``window=4096``, 30 of the 64 tiles visited): forward 7.42 ms (97 TF/s,
 49 % of the bf16 peak), dK/dV 10.87 ms (133 TF/s, 67 %), dQ 8.22 ms (132
 TF/s, 67 %); the whole-sequence causal kernels (``window=None``, 36 tiles):
@@ -204,7 +206,7 @@ def _block_spec(shape, index_map):
     return pl.BlockSpec(shape, index_map, memory_space=pltpu.VMEM)
 
 
-def _default_blocks(tq, tk, block_q, block_k):
+def _default_blocks(tq, tk, block_q, block_k, window=None):
     """Sequence-adaptive block defaults, measured on v5e fwd+bwd.
 
     History: 512x512 measured fastest at T=2048 in round 2 (12.4->9.8 ms
@@ -216,8 +218,14 @@ def _default_blocks(tq, tk, block_q, block_k):
     interleaved same-session) and +7% on Llama-1B (14.06k -> 15.03k,
     D=128).  2048x2048 fails to compile (a [2048, 2048] f32 score tile
     plus accumulators exceeds what Mosaic will carry).  So: 1024 whenever
-    the sequence admits it, 512 below."""
-    big = max(tq, tk) >= 2048
+    the sequence admits it, 512 below, and 512 under a band narrower than
+    1024 keys, whatever the sequence: a window layer of
+    the `laguna-xs.2` cell (64 query heads on 8 of 128, 8,192 tokens, 512
+    keys; forward and backward together, host clock over 20 calls, my chip
+    runs, PR 35) read 13.8 ms at 512x512 (2.0 pairs computed for one seen),
+    17.3 at 1024x1024 (3.9), 17.9 at 1024x512 (3.0; the forward alone 7.2
+    against 5.3), 15.9 at 256x512, 20.8 at 256x256 (1.5) and at 512x256."""
+    big = max(tq, tk) >= 2048 and (window is None or window >= 1024)
     if block_q is None:
         block_q = 1024 if big else 512
     if block_k is None:
@@ -473,6 +481,16 @@ def _aligned_or_none(tri_delta, causal, tq, tk, block_q, block_k):
     return None
 
 
+def _kv_head_of(q, k):
+    """Folded query head ``b`` (of ``[B * H, T, D]``) -> the folded key-value
+    head it reads (of ``[B * KV, T, D]``): ``b // (H / KV)``, since query head
+    ``h`` reads key-value head ``h // (H / KV)``.  The kernels' index maps
+    fetch the shared head where it lies; with equal head counts the map is
+    the identity and traces nothing."""
+    group = q.shape[0] // k.shape[0]
+    return (lambda b: b) if group == 1 else (lambda b: b // group)
+
+
 def _band_or_none(window, tri_delta, causal, tq, tk, block_q, block_k):
     """The banded grid needs what the aligned fast path needs, at delta 0:
     statically equal offsets and square shapes.  Otherwise a window is
@@ -491,14 +509,15 @@ def _flash_fwd(q, k, v, q_start, k_start, *, scale, causal, block_q, block_k,
     """
     bh, tq, d = q.shape
     tk = k.shape[1]
-    block_q, block_k = _default_blocks(tq, tk, block_q, block_k)
+    block_q, block_k = _default_blocks(tq, tk, block_q, block_k, window)
     block_q = _fit_block(tq, block_q)
     block_k = _fit_block(tk, block_k)
     num_q, num_k = tq // block_q, tk // block_k
+    kv_of = _kv_head_of(q, k)
 
     qs = jnp.asarray(q_start, jnp.int32).reshape(1, 1)
     ks = jnp.asarray(k_start, jnp.int32).reshape(1, 1)
-    kv_map = lambda b, i, j: (b, j, 0)
+    kv_map = lambda b, i, j: (kv_of(b), j, 0)
     kernel_kw, call_kw, aligned = {}, {}, None
     if window is None:
         aligned = _aligned_or_none(tri_delta, causal, tq, tk, block_q, block_k)
@@ -509,7 +528,7 @@ def _flash_fwd(q, k, v, q_start, k_start, *, scale, causal, block_q, block_k,
         if band is not None:
             num_k = band.k_steps  # the inner axis visits the band only
             kv_map = lambda b, i, j: (
-                b, jnp.minimum(band.k_lo(i) + j, band.k_hi(i)), 0)
+                kv_of(b), jnp.minimum(band.k_lo(i) + j, band.k_hi(i)), 0)
     kernel = functools.partial(
         _fwd_kernel,
         scale=scale,
@@ -637,7 +656,7 @@ def _bwd_dkv_kernel(qs_ref, ks_ref, q_ref, g_ref, aux_ref,
                     k_ref, v_ref, dk_ref, dv_ref, dk_acc, dv_acc,
                     *, scale: float, block_q: int, block_k: int,
                     causal: bool, num_q: int, aligned_delta, half: int,
-                    window=None, band=None):
+                    window=None, band=None, group: int = 1):
     """One (bh, jk, iq) program: fold q-block iq into dK/dV of k-block jk.
 
     Same recompute-from-lse trick as the XLA backward, but the
@@ -646,15 +665,23 @@ def _bwd_dkv_kernel(qs_ref, ks_ref, q_ref, g_ref, aux_ref,
     backward measured memory-bound (round-3 decomposition).
     ``aligned_delta``: see :func:`_fwd_kernel`.  ``aux_ref`` packs the two
     per-row scalars in one tile (lse in lanes [:half], corr in [half:]) —
-    one scalar DMA per grid step instead of two.
+    one scalar DMA per grid step instead of two.  ``group`` > 1: the grid is
+    (key-value head, jk, query head of the group, iq) and the accumulators
+    run over the group's query heads too, so dK/dV of a shared head leave
+    the kernel summed.
     """
     jk = pl.program_id(1)
-    iq = pl.program_id(2)
+    iq = pl.program_id(2 if group == 1 else 3)
     step = iq  # place on the inner grid axis
     if band is not None:
         iq = band.q_lo(jk) + step  # the q block this step holds
 
-    @pl.when(step == 0)
+    def at(inner, member):  # this step, of this member of the group
+        if group == 1:
+            return step == inner
+        return (step == inner) & (pl.program_id(2) == member)
+
+    @pl.when(at(0, 0))
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
@@ -722,7 +749,7 @@ def _bwd_dkv_kernel(qs_ref, ks_ref, q_ref, g_ref, aux_ref,
     else:
         _body(False)
 
-    @pl.when(step == num_q - 1)
+    @pl.when(at(num_q - 1, group - 1))
     def _finish():
         dk_ref[0] = (dk_acc[...] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
@@ -814,10 +841,11 @@ def _flash_bwd_pallas(q, k, v, lse, corr, q_start, k_start, g,
     """
     bh, tq, d = q.shape
     tk = k.shape[1]
-    block_q, block_k = _default_blocks(tq, tk, block_q, block_k)
+    block_q, block_k = _default_blocks(tq, tk, block_q, block_k, window)
     block_q = _fit_block(tq, block_q)
     block_k = _fit_block(tk, block_k)
     num_q, num_k = tq // block_q, tk // block_k
+    kv_of, group = _kv_head_of(q, k), bh // k.shape[0]
     # the inner grid axes and which block each of their steps holds: all of
     # them in order, or with a band only those the outer block can touch
     steps_q, steps_k = num_q, num_k
@@ -853,33 +881,36 @@ def _flash_bwd_pallas(q, k, v, lse, corr, q_start, k_start, g,
 
     def rowspec(index):  # q/g/aux blocks, selected by the q index
         return [
-            _block_spec((1, block_q, d), lambda b, x, y: (b, index(x, y), 0)),
-            _block_spec((1, block_q, d), lambda b, x, y: (b, index(x, y), 0)),
-            _block_spec((1, block_q, 2 * half),
-                        lambda b, x, y: (b, index(x, y), 0)),
+            _block_spec((1, block_q, d), index),
+            _block_spec((1, block_q, d), index),
+            _block_spec((1, block_q, 2 * half), index),
         ]
 
     def kvspec(index):  # k/v blocks, selected by the k index
-        return [
-            _block_spec((1, block_k, d), lambda b, x, y: (b, index(x, y), 0)),
-            _block_spec((1, block_k, d), lambda b, x, y: (b, index(x, y), 0)),
-        ]
+        return [_block_spec((1, block_k, d), index)] * 2
 
+    # the dK/dV grid: (head, k block, step), or where a group of query heads
+    # shares the head, (key-value head, k block, member of the group, step)
+    kernel_group = {}
+    if group == 1:
+        dkv_grid = (bh, num_k, steps_q)
+        dkv_rows = lambda b, j, i: (b, q_of(j, i), 0)
+    else:
+        dkv_grid = (bh // group, num_k, group, steps_q)
+        dkv_rows = lambda b, j, m, i: (b * group + m, q_of(j, i), 0)
+        kernel_group = dict(group=group)
+    dkv_keys = lambda b, j, *_: (b, j, 0)
     dk, dv = pl.pallas_call(
         functools.partial(
             _bwd_dkv_kernel, scale=scale, block_q=block_q, block_k=block_k,
             causal=causal, num_q=steps_q, aligned_delta=aligned, half=half,
-            **kernel_kw),
-        grid=(bh, num_k, steps_q),
-        in_specs=[smem, smem,
-                  *rowspec(q_of), *kvspec(lambda j, i: j)],
-        out_specs=[
-            _block_spec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-            _block_spec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-        ],
+            **kernel_kw, **kernel_group),
+        grid=dkv_grid,
+        in_specs=[smem, smem, *rowspec(dkv_rows), *kvspec(dkv_keys)],
+        out_specs=kvspec(dkv_keys),
         out_shape=[
-            _out_struct((bh, tk, d), k.dtype, (q, k, v, g)),
-            _out_struct((bh, tk, d), v.dtype, (q, k, v, g)),
+            _out_struct(k.shape, k.dtype, (q, k, v, g)),
+            _out_struct(v.shape, v.dtype, (q, k, v, g)),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
@@ -896,7 +927,8 @@ def _flash_bwd_pallas(q, k, v, lse, corr, q_start, k_start, g,
             **kernel_kw),
         grid=(bh, num_q, steps_k),
         in_specs=[smem, smem,
-                  *rowspec(lambda i, j: i), *kvspec(k_of)],
+                  *rowspec(lambda b, i, j: (b, i, 0)),
+                  *kvspec(lambda b, i, j: (kv_of(b), k_of(i, j), 0))],
         out_specs=[
             _block_spec((1, block_q, d), lambda b, i, j: (b, i, 0)),
         ],
@@ -990,6 +1022,13 @@ def _blockwise_bwd(q, k, v, o, lse, q_start, k_start, g, g_lse,
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
+def _repeat_heads(q, x):
+    """The XLA fall-back's way with shared key-value heads: each repeated
+    for its group of query heads (the Pallas kernels read them in place)."""
+    group = q.shape[0] // x.shape[0]
+    return x if group == 1 else jnp.repeat(x, group, axis=0)
+
+
 def _fwd_dispatch(q, k, v, q_start, k_start, *, scale, causal, block_q,
                   block_k, interpret, tri_delta, impl, window=None):
     """Choose the forward implementation (static): "pallas", "xla", or
@@ -1007,6 +1046,7 @@ def _fwd_dispatch(q, k, v, q_start, k_start, *, scale, causal, block_q,
     pass impl="xla"."""
     use_xla = impl == "xla"
     if use_xla:
+        k, v = _repeat_heads(q, k), _repeat_heads(q, v)
         return _blockwise_fwd_xla(
             q, k, v, q_start, k_start,
             scale=scale, causal=causal, block_k=block_k, tri_delta=tri_delta,
@@ -1047,11 +1087,14 @@ def _flash_core_bwd(scale, causal, block_q, block_k, interpret, tri_delta,
     g, g_lse = cts
     if impl == "xla":
         dq, dk, dv = _blockwise_bwd(
-            q, k, v, o, lse,
+            q, _repeat_heads(q, k), _repeat_heads(q, v), o, lse,
             q_start.astype(jnp.int32), k_start.astype(jnp.int32), g, g_lse,
             scale=scale, causal=causal, block_k=block_k, tri_delta=tri_delta,
             window=window,
         )
+        if dk.shape != k.shape:  # the fall-back repeated the shared heads
+            dk, dv = (x.reshape(k.shape[0], -1, *k.shape[1:]).sum(1).astype(k.dtype)
+                      for x in (dk, dv))
     else:
         # Pallas backward (default): probability/score tiles stay in VMEM.
         # The XLA blockwise backward materialized them per k-block in HBM
@@ -1106,6 +1149,13 @@ def flash_attention_with_lse(
     key ``j`` iff ``0 <= i - j < window`` on global positions.  With static
     equal offsets and square shapes the Pallas kernels' inner grid axis
     visits only the blocks the band touches; ``None`` is today's lowering.
+
+    ``k`` and ``v`` may have fewer heads than ``q`` (``[B, T, KV, D]``, KV
+    dividing H): query head ``h`` reads head ``h // (H / KV)`` where it
+    lies, through the kernels' index maps, and dK/dV come back ``[B, T, KV,
+    D]``, summed over each group inside the dK/dV kernel.  Nothing is
+    repeated, kept for the backward pass or summed afterwards; equal head
+    counts lower to what they always did.
     """
     if impl not in ("auto", "xla", "pallas"):
         raise ValueError(f"impl must be auto/xla/pallas, got {impl!r}")
@@ -1116,9 +1166,13 @@ def flash_attention_with_lse(
         interpret = _default_interpret()
     b, tq, h, d = q.shape
     scale = 1.0 / math.sqrt(d)
+    if k.shape[2] != v.shape[2] or h % k.shape[2]:
+        raise ValueError(
+            f"{h} query heads on {k.shape[2]} key and {v.shape[2]} value heads: "
+            "keys and values share a head count that divides the queries'")
 
     def fold(x):  # [B, T, H, D] -> [B*H, T, D]
-        return x.transpose(0, 2, 1, 3).reshape(b * h, x.shape[1], d)
+        return x.transpose(0, 2, 1, 3).reshape(b * x.shape[2], x.shape[1], d)
 
     # static offsets with a small key-ahead delta + square shapes unlock
     # the triangular fast paths (delta 0 = aligned; delta 1 = the striped

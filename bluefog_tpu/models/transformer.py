@@ -15,17 +15,20 @@ from __future__ import annotations
 
 import math
 from functools import partial
-from typing import Any, Callable, Optional, Tuple
+from typing import Any, Callable, NamedTuple, Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
 __all__ = [
     "BertEncoder",
     "LlamaLM",
     "MixedAttentionMoELM",
+    "Rotary",
+    "rotary_frequencies",
     "dense_attention",
     "chunked_softmax_cross_entropy",
 ]
@@ -111,19 +114,61 @@ class BertEncoder(nn.Module):
 # --------------------------------------------------------------------------
 
 
-def _rotary(x, positions, base=10000.0):
+class Rotary(NamedTuple):
+    """A rotary's inverse frequencies (twice as many dimensions of a head are
+    rotated, the rest pass untouched) and the factor on its cos and sin."""
+
+    inv_freq: Tuple[float, ...]
+    factor: float = 1.0
+
+
+def rotary_frequencies(dims: int, base: float, *, factor: float = 1.0,
+                       original_max: int = 0, beta_fast: float = 32.0,
+                       beta_slow: float = 1.0) -> Rotary:
+    """The rotary over ``dims`` dimensions of a head at ``base``: ``dims / 2``
+    inverse frequencies ``f_i = base ** (-2 i / dims)``.  With ``factor`` > 1
+    they are YaRN's (Peng et al., arXiv:2309.00071): ``f_i`` kept where
+    dimension ``i`` turns ``beta_fast`` times or more in ``original_max``
+    positions, ``f_i / factor`` where it turns ``beta_slow`` times or fewer,
+    blended linearly in ``i`` between the two (the ramp's ends rounded
+    outwards to whole dimensions), and cos and sin are multiplied by ``0.1 ln
+    factor + 1``.  Worked out in float64 from the numbers, once."""
+    i = np.arange(dims // 2, dtype=np.float64)
+    freq = base ** (-2.0 * i / dims)
+    if factor == 1.0:
+        return Rotary(tuple(freq.tolist()))
+
+    def turns(n):  # the dimension that turns n times in original_max positions
+        return dims * math.log(original_max / (n * 2 * math.pi)) / (2 * math.log(base))
+
+    low = max(math.floor(turns(beta_fast)), 0)
+    high = min(math.ceil(turns(beta_slow)), dims - 1)
+    ramp = np.clip((i - low) / max(high - low, 1e-3), 0.0, 1.0)
+    freq = freq * (1.0 - ramp) + freq / factor * ramp
+    return Rotary(tuple(freq.tolist()), 0.1 * math.log(factor) + 1.0)
+
+
+def _rotary(x, positions, base=10000.0, rotary: Optional[Rotary] = None):
     """Rotary position embedding (half-split convention); x: [B, T, H, D],
-    positions: [T]."""
-    d = x.shape[-1]
-    half = d // 2
-    freqs = 1.0 / (base ** (jnp.arange(half, dtype=jnp.float32) / half))
+    positions: [T].  With ``rotary`` its frequencies and factor take the
+    place of ``base``'s: the first ``2 len(inv_freq)`` dimensions are rotated,
+    half-split among themselves, and the others are returned as they came."""
+    if rotary is None:
+        half = x.shape[-1] // 2
+        freqs = 1.0 / (base ** (jnp.arange(half, dtype=jnp.float32) / half))
+    else:
+        half = len(rotary.inv_freq)
+        freqs = jnp.asarray(rotary.inv_freq, jnp.float32)
     angles = positions[:, None].astype(jnp.float32) * freqs[None, :]  # [T, half]
     cos = jnp.cos(angles)[None, :, None, :]
     sin = jnp.sin(angles)[None, :, None, :]
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate(
-        [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
-    ).astype(x.dtype)
+    if rotary is not None and rotary.factor != 1.0:
+        cos, sin = cos * rotary.factor, sin * rotary.factor
+    x1, x2 = x[..., :half], x[..., half:2 * half]
+    parts = [x1 * cos - x2 * sin, x2 * cos + x1 * sin]
+    if 2 * half < x.shape[-1]:
+        parts.append(x[..., 2 * half:])
+    return jnp.concatenate(parts, axis=-1).astype(x.dtype)
 
 
 class RMSNorm(nn.Module):
@@ -639,6 +684,88 @@ class _MixedBlock(nn.Module):
         return x + y.reshape(B, T, d)
 
 
+class _GatedMLP(nn.Module):
+    """``(silu(m wg) * (m wu)) wd`` as two products: gate and up in one."""
+
+    dff: int
+    dtype: Any
+
+    @nn.compact
+    def __call__(self, m):
+        d, init = m.shape[-1], nn.initializers.normal(0.02)
+        wg = self.param("wg", init, (d, self.dff), jnp.float32)
+        wu = self.param("wu", init, (d, self.dff), jnp.float32)
+        wd = self.param("wd", init, (self.dff, d), jnp.float32)
+        gu = m @ jnp.concatenate([wg, wu], axis=1).astype(self.dtype)
+        h = jax.nn.silu(gu[..., :self.dff]) * gu[..., self.dff:]
+        return h @ wd.astype(self.dtype)
+
+
+class _GatedBlock(nn.Module):
+    """One layer of the decoder whose layers differ by more than their window
+    (poolside's Laguna): a head count and a rotary of the layer's own, key and
+    value heads handed to the kernels as they are (the kernels read the shared
+    head in place), every head's output times a sigmoid gate of the normed
+    input, and after the attention either a dense gated MLP (``dense_dff``) or
+    this share's part of the top-k expert layer, routed on the normed stream
+    the experts read, beside a shared expert that every share computes."""
+
+    num_heads: int
+    num_kv_heads: int
+    head_dim: int
+    window: Optional[int]
+    rotary: Rotary
+    dense_dff: Optional[int]
+    num_experts: int
+    top_k: int
+    experts_held: Tuple[int, ...]
+    expert_dff: int
+    shared_dff: int
+    routed_scale: float
+    dtype: Any
+    attention_fn: Callable  # (q, k, v, window=) -> out, causal
+
+    @nn.compact
+    def __call__(self, x, positions):
+        from bluefog_tpu.parallel.expert import held_topk_experts, route_topk
+
+        B, T, d = x.shape
+        hd, H, kvh = self.head_dim, self.num_heads, self.num_kv_heads
+        init = nn.initializers.normal(0.02)
+        dense = partial(nn.DenseGeneral, use_bias=False, dtype=self.dtype)
+        h = RMSNorm(dtype=self.dtype, name="attn_norm")(x)
+        q = _rotary(dense((H, hd), name="q")(h), positions, rotary=self.rotary)
+        k = _rotary(dense((kvh, hd), name="k")(h), positions, rotary=self.rotary)
+        v = dense((kvh, hd), name="v")(h)
+        scope = "attention_global" if self.window is None else "attention_window"
+        with jax.named_scope(scope):
+            att = self.attention_fn(q, k, v, window=self.window)
+        with jax.named_scope("attention_gate"):
+            gate = jax.nn.sigmoid(dense(H, name="gate")(h).astype(jnp.float32))
+            att = (att * gate[..., None]).astype(self.dtype)
+        x = x + dense(d, name="o")(att.reshape(B, T, H * hd))
+
+        m = RMSNorm(dtype=self.dtype, name="ffn_norm")(x)
+        if self.dense_dff is not None:
+            with jax.named_scope("mlp_dense"):
+                return x + _GatedMLP(self.dense_dff, self.dtype, name="mlp")(m)
+        router = self.param("router", init, (d, self.num_experts), jnp.float32)
+        rows = m.reshape(B * T, d)
+        experts, weights = route_topk(rows, router, self.top_k, self.routed_scale)
+        n_held, f = len(self.experts_held), self.expert_dff
+        stacks = {
+            "wg": self.param("wg", init, (n_held, d, f), jnp.float32),
+            "wu": self.param("wu", init, (n_held, d, f), jnp.float32),
+            "wd": self.param("wd", init, (n_held, f, d), jnp.float32),
+        }
+        y = held_topk_experts(rows, experts, weights, stacks, self.experts_held,
+                              self.num_experts, activation=jax.nn.silu)
+        with jax.named_scope("moe_shared"):
+            y = y.reshape(B, T, d) + _GatedMLP(
+                self.shared_dff, self.dtype, name="shared")(m)
+        return x + y
+
+
 class MixedAttentionMoELM(nn.Module):
     """Decoder-only LM whose layers differ by kind and whose feed-forward
     is a top-k expert layer cut to the experts this chip holds.
@@ -652,6 +779,16 @@ class MixedAttentionMoELM(nn.Module):
     weights live here, and what the others would add is left out (see
     :func:`bluefog_tpu.parallel.expert.held_topk_experts`).  RMSNorm
     (eps 1e-6), no bias anywhere, embedding and head untied.
+
+    With ``layer_heads`` (one query-head count a layer) the layers are of
+    the gated kind (:class:`_GatedBlock`): ``layer_rotary`` gives each layer's
+    :class:`Rotary` (:func:`rotary_frequencies`: how much of a head is
+    rotated, at which frequencies, global layers too), ``layer_dense_dff`` an
+    int where the layer's feed-forward is a dense gated MLP of that width and
+    ``None`` where it is the expert layer, ``shared_dff`` the width of the
+    shared expert beside it and ``routed_scale`` what the renormalised top-k
+    weights are multiplied by; ``num_heads`` and ``rope_base`` are then not
+    read.
 
     With ``labels`` it returns the shifted next-token loss through
     :func:`chunked_softmax_cross_entropy` (``head_chunks`` chunks), so
@@ -673,23 +810,51 @@ class MixedAttentionMoELM(nn.Module):
     head_chunks: int = 1
     dtype: Any = jnp.bfloat16
     attention_fn: Optional[Callable] = None  # None: the flash kernels
+    layer_heads: Optional[Tuple[int, ...]] = None
+    layer_rotary: Tuple[Rotary, ...] = ()
+    layer_dense_dff: Tuple[Optional[int], ...] = ()
+    shared_dff: int = 0
+    routed_scale: float = 1.0
 
     @nn.compact
     def __call__(self, input_ids, positions=None, labels=None):
         from bluefog_tpu.kernels.flash_attention import flash_attention
         from bluefog_tpu.telemetry import registry as _telemetry
 
-        if self.num_heads % self.num_kv_heads:
-            raise ValueError(
-                f"num_heads {self.num_heads} not divisible by "
-                f"num_kv_heads {self.num_kv_heads}")
         windows = tuple(self.layer_windows)
+        heads = self.layer_heads or (self.num_heads,) * len(windows)
+        if any(h % self.num_kv_heads for h in heads):
+            raise ValueError(
+                f"num_heads {sorted(set(heads))} not divisible by "
+                f"num_kv_heads {self.num_kv_heads}")
+        if self.layer_heads is not None and not (
+                len(heads) == len(self.layer_rotary) == len(self.layer_dense_dff)
+                == len(windows)):
+            raise ValueError(
+                f"{len(windows)} layers, but {len(heads)} head counts, "
+                f"{len(self.layer_rotary)} rotaries and "
+                f"{len(self.layer_dense_dff)} feed-forward kinds")
         reg = _telemetry.get_registry()
         if reg.enabled:
             banded = [w for w in windows if w is not None]
             reg.gauge("attention.window").set(max(banded, default=0))
             reg.gauge("attention.layers_window").set(len(banded))
             reg.gauge("attention.layers_global").set(len(windows) - len(banded))
+        if reg.enabled and self.layer_heads is not None:
+            def of_kind(values, is_window):  # the kind's one value, 0 without one
+                return max((v for v, w in zip(values, windows)
+                            if (w is not None) == is_window), default=0)
+
+            rotated = [2 * len(r.inv_freq) for r in self.layer_rotary]
+            reg.gauge("attention.heads_window").set(of_kind(heads, True))
+            reg.gauge("attention.heads_global").set(of_kind(heads, False))
+            reg.gauge("attention.kv_heads").set(self.num_kv_heads)
+            reg.gauge("attention.rotary_dims_window").set(of_kind(rotated, True))
+            reg.gauge("attention.rotary_dims_global").set(of_kind(rotated, False))
+            reg.gauge("moe.shared_width").set(self.shared_dff)
+            reg.gauge("moe.routed_scale").set(self.routed_scale)
+            reg.gauge("moe.dense_layers").set(
+                sum(f is not None for f in self.layer_dense_dff))
         T = input_ids.shape[1]
         if positions is None:
             positions = jnp.arange(T)
@@ -698,11 +863,20 @@ class MixedAttentionMoELM(nn.Module):
                      embedding_init=nn.initializers.normal(0.02),
                      name="embed")(input_ids)
         for i, window in enumerate(windows):
-            x = _MixedBlock(
-                self.num_heads, self.num_kv_heads, self.head_dim, window,
-                self.rope_base, self.num_experts, self.top_k,
-                tuple(self.experts_held), self.expert_dff, self.dtype,
-                attention_fn, name=f"layer_{i}")(x, positions)
+            if self.layer_heads is None:
+                block = _MixedBlock(
+                    self.num_heads, self.num_kv_heads, self.head_dim, window,
+                    self.rope_base, self.num_experts, self.top_k,
+                    tuple(self.experts_held), self.expert_dff, self.dtype,
+                    attention_fn, name=f"layer_{i}")
+            else:
+                block = _GatedBlock(
+                    heads[i], self.num_kv_heads, self.head_dim, window,
+                    self.layer_rotary[i], self.layer_dense_dff[i],
+                    self.num_experts, self.top_k, tuple(self.experts_held),
+                    self.expert_dff, self.shared_dff, self.routed_scale,
+                    self.dtype, attention_fn, name=f"layer_{i}")
+            x = block(x, positions)
         x = RMSNorm(dtype=jnp.float32, name="final_norm")(x)
         kernel = _HeadKernel(self.vocab_size, name="head")(self.hidden_size)
         if labels is None:

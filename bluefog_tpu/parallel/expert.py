@@ -135,20 +135,21 @@ def switch_moe(
 # --------------------------------------------------------------------------
 
 
-def route_topk(x, router, top_k: int):
+def route_topk(x, router, top_k: int, scale: float = 1.0):
     """Top-k routing over all the experts the router knows.
 
     ``x [T, d]`` is what the router reads; ``router [d, E]``.  Logits, top-k
     and the softmax over the k chosen logits run in float32 (softmax over
-    all E, top-k, renormalised, is the same number).  Returns ``(experts
-    [T, k] int32, weights [T, k] float32)``; the weights carry the router's
-    gradient."""
+    all E, top-k, renormalised, is the same number), times ``scale`` where a
+    model scales its routed experts.  Returns ``(experts [T, k] int32,
+    weights [T, k] float32)``; the weights carry the router's gradient."""
     with jax.named_scope("moe_route"):
         logits = jnp.einsum("td,de->te", x.astype(jnp.float32),
                             router.astype(jnp.float32),
                             precision=lax.Precision.HIGHEST)
         top, experts = lax.top_k(logits, top_k)
-        return experts, jax.nn.softmax(top, axis=-1)
+        weights = jax.nn.softmax(top, axis=-1)
+        return experts, weights if scale == 1.0 else weights * scale
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -221,7 +222,7 @@ def _pass(i, rows, k, order, starts, ends):
     return first, tok, valid, sizes
 
 
-def _pass_rows_out(xs, w_rows, wg, wu, wd, sizes, valid, dtype):
+def _pass_rows_out(xs, w_rows, wg, wu, wd, sizes, valid, dtype, activation):
     """The rows of one pass through their experts, weighted: ``[rows, d]``
     float32.  Rows past the last group belong to no expert: the grouped
     product leaves them undefined, so they are cut off on both sides and
@@ -229,7 +230,7 @@ def _pass_rows_out(xs, w_rows, wg, wu, wd, sizes, valid, dtype):
     xs = jnp.where(valid[:, None], xs, 0)
     hg = _grouped_matmul(xs, wg, sizes, dtype)
     hu = _grouped_matmul(xs, wu, sizes, dtype)
-    y = _grouped_matmul((jax.nn.relu(hg) * hu).astype(dtype), wd, sizes, dtype)
+    y = _grouped_matmul((activation(hg) * hu).astype(dtype), wd, sizes, dtype)
     return jnp.where(valid[:, None], y, 0.0) * jnp.where(valid, w_rows, 0.0)[:, None]
 
 
@@ -247,8 +248,8 @@ def _add_rows(acc, tok, rows):
     return acc.at[tok].add(rows.astype(acc.dtype).reshape((-1,) + acc.shape[1:]))
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(8, 9))
-def _held_passes(m, w_sorted, wg, wu, wd, order, starts, ends, k, rows):
+@partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10))
+def _held_passes(m, w_sorted, wg, wu, wd, order, starts, ends, k, rows, activation):
     """``out[t] = sum over t's assignments a to experts held of w[a]
     E(m[t])``, float32, computed in as many passes of ``rows`` sorted rows as
     there are assignments (a loop whose length is the load's, which reverse
@@ -256,14 +257,17 @@ def _held_passes(m, w_sorted, wg, wu, wd, order, starts, ends, k, rows):
     passes and lets ``jax.vjp`` differentiate each).  ``w_sorted`` are the
     weights in sorted order.  Its residuals are its inputs: every pass is
     recomputed in the backward pass."""
-    return _held_passes_fwd(m, w_sorted, wg, wu, wd, order, starts, ends, k, rows)[0]
+    return _held_passes_fwd(m, w_sorted, wg, wu, wd, order, starts, ends, k, rows,
+                            activation)[0]
 
 
-def _held_passes_fwd(m, w_sorted, wg, wu, wd, order, starts, ends, k, rows):
+def _held_passes_fwd(m, w_sorted, wg, wu, wd, order, starts, ends, k, rows,
+                     activation):
     def body(i, out):
         first, tok, valid, sizes = _pass(i, rows, k, order, starts, ends)
         w_rows = lax.dynamic_slice_in_dim(w_sorted, first, rows)
-        y = _pass_rows_out(m[tok], w_rows, wg, wu, wd, sizes, valid, m.dtype)
+        y = _pass_rows_out(m[tok], w_rows, wg, wu, wd, sizes, valid, m.dtype,
+                           activation)
         return _add_rows(out, tok, y)
 
     out = lax.fori_loop(0, _passes(ends, rows), body,
@@ -271,7 +275,7 @@ def _held_passes_fwd(m, w_sorted, wg, wu, wd, order, starts, ends, k, rows):
     return out.reshape(m.shape), (m, w_sorted, wg, wu, wd, order, starts, ends)
 
 
-def _held_passes_bwd(k, rows, res, g):
+def _held_passes_bwd(k, rows, activation, res, g):
     m, w_sorted, wg, wu, wd, order, starts, ends = res
 
     def body(i, acc):
@@ -279,7 +283,7 @@ def _held_passes_bwd(k, rows, res, g):
         first, tok, valid, sizes = _pass(i, rows, k, order, starts, ends)
         _, vjp = jax.vjp(
             lambda xs, w_rows, wg, wu, wd: _pass_rows_out(
-                xs, w_rows, wg, wu, wd, sizes, valid, m.dtype),
+                xs, w_rows, wg, wu, wd, sizes, valid, m.dtype, activation),
             m[tok], lax.dynamic_slice_in_dim(w_sorted, first, rows), wg, wu, wd)
         dxs, dw_rows, g1, g2, g3 = vjp(g[tok])
         return (_add_rows(dm, tok, dxs),
@@ -299,14 +303,15 @@ _held_passes.defvjp(_held_passes_fwd, _held_passes_bwd)
 
 
 def held_topk_experts(m, experts, weights, params, held, num_experts: int,
-                      *, rows: Optional[int] = None):
+                      *, rows: Optional[int] = None, activation=jax.nn.relu):
     """This share's part of a top-k expert layer, with no token dropped.
 
     ``m [T, d]``: what the experts read.  ``experts`` / ``weights``:
     :func:`route_topk`'s result, over all ``num_experts`` experts.  ``held``:
     the global ids of the experts this share holds, in the order of the
     leading axis of ``params``: ``wg``, ``wu`` ``[H, d, f]`` and ``wd``
-    ``[H, f, d]``, gated ReLU: ``E_e(m) = (relu(m wg) * (m wu)) wd``.
+    ``[H, f, d]``, a gated product: ``E_e(m) = (activation(m wg) * (m wu))
+    wd``, ``activation`` ReLU unless given.
 
     Returns ``[T, d]``: for every token the sum, over those of its top-k
     experts that are held here, of ``w_e E_e(m)``; the other shares'
@@ -356,5 +361,5 @@ def held_topk_experts(m, experts, weights, params, held, num_experts: int,
             order = jnp.concatenate([order, jnp.zeros((most - A,), order.dtype)])
         out = _held_passes(m, weights.reshape(A)[order], params["wg"],
                            params["wu"], params["wd"], order, ends - sizes, ends,
-                           k, rows)
+                           k, rows, activation)
         return out.astype(m.dtype)

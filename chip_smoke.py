@@ -16,7 +16,10 @@ phase that fails raises: the traceback goes to stderr, the last line says
                                      decoder step with the compiled Pallas
                                      flash kernel vs dense attention, the
                                      dropless expert layer under a pile-up
-                                     vs the benchmark's plain reference
+                                     vs the benchmark's plain reference, the
+                                     flash kernels reading 8 shared key-value
+                                     heads for 64 query heads vs the heads
+                                     repeated
     python chip_smoke.py --chips 4   four chips, only what exists across
                                      chips: parity on exp2(4), placement,
                                      ResNet-50 ATC vs allreduce, contraction,
@@ -72,6 +75,10 @@ FULL = dict(
     # tokens; rows None: the pass the program chooses (16,384 sorted rows)
     experts=dict(tokens=16384, hidden=2560, dff=768, experts=64, top_k=6,
                  held=8, rows=None),
+    # a window layer of chipbench's `laguna-xs.2` at its cell's 8192 tokens,
+    # and the same heads over the whole sequence; None: the kernels' own blocks
+    shared_heads=dict(seq=8192, heads=64, kv_heads=8, head_dim=128, window=512,
+                      block=None),
     probe=dict(dim=4096, iters=512),
 )
 TINY = dict(
@@ -81,6 +88,8 @@ TINY = dict(
                  seq=128, batch=2, head_chunks=2, logits_rows=1),
     experts=dict(tokens=96, hidden=128, dff=8, experts=64, top_k=6, held=8,
                  rows=64),
+    shared_heads=dict(seq=128, heads=6, kv_heads=2, head_dim=16, window=40,
+                      block=16),
 )
 
 # flash-vs-dense agreement at bf16 compute on seeded weights.  The two paths
@@ -101,6 +110,16 @@ LOSS_ATOL = 5e-3
 # scattered to the wrong tokens moves them by 0.2 or more.
 EXPERTS_L2_RTOL = 1.5e-2
 
+# the flash kernels handed 8 key-value heads for 64 query heads against the
+# same kernels handed the heads repeated: the output and dQ are the same
+# arithmetic on the same blocks (equal to the bit on the v5e and on the CPU);
+# dK and dV are summed over the group in float32 inside the kernel and rounded
+# to bfloat16 once, where the repeated call rounds each head's and XLA sums the
+# eight: a few bfloat16 ulps of 0.4-0.8 % over the whole tensor, the kernels'
+# standing tolerance against dense attention being 3e-2 (LOGITS_L2_RTOL).  A
+# wrong head, a group left out of the sum or a block offset moves them by O(1).
+SHARED_HEADS_L2_RTOL = 1e-2
+
 # the bucketed gossip against the per-leaf gossip, the widest gap of a leaf
 # over its largest value.  The two are the same sums written the same way;
 # what parts them is the compiler's (a common weight factored out on the TPU,
@@ -110,7 +129,7 @@ BUCKETS_GAP_RTOL = 1e-6
 
 
 PHASES = ("ops_windows", "resnet_atc", "resnet_allreduce", "contraction",
-          "buckets_vs_per_leaf", "decoder", "experts_piled")
+          "buckets_vs_per_leaf", "decoder", "experts_piled", "shared_heads")
 
 
 class _CompileClock:
@@ -686,6 +705,60 @@ def phase_experts_piled(cfg, seed, clock):
 
 
 # ---------------------------------------------------------------------------
+# phase: shared key-value heads read in place vs the heads repeated
+# ---------------------------------------------------------------------------
+
+
+def phase_shared_heads(cfg, seed, on_tpu, clock):
+    """The banded and the whole-sequence flash kernels (forward, dK/dV, dQ)
+    with fewer key-value heads than query heads, at the sizes of a window
+    layer of the benchmark's `laguna-xs.2` cell: the index maps fetch head
+    ``h // group`` where it lies and the dK/dV kernel sums over the group.
+    Against the same kernels handed ``jnp.repeat``-ed heads, whose dK and dV
+    are summed back afterwards: values and all three gradients."""
+    from bluefog_tpu.kernels.flash_attention import flash_attention
+
+    t0 = time.perf_counter()
+    T, H, KV, D = cfg["seq"], cfg["heads"], cfg["kv_heads"], cfg["head_dim"]
+    keys = jax.random.split(jax.random.PRNGKey(seed), 4)
+    q, g = (jax.random.normal(k, (1, T, H, D), jnp.bfloat16) for k in keys[:2])
+    k, v = (jax.random.normal(k, (1, T, KV, D), jnp.bfloat16) for k in keys[2:])
+
+    def run(window, repeat):
+        def loss(q, k, v):
+            if repeat:
+                k, v = (jnp.repeat(a, H // KV, axis=2) for a in (k, v))
+            out = flash_attention(
+                q, k, v, causal=True, window=window, impl="pallas",
+                interpret=not on_tpu, block_q=cfg["block"], block_k=cfg["block"])
+            return jnp.sum((out * g).astype(jnp.float32)), out
+
+        (_, out), grads = jax.jit(jax.value_and_grad(
+            loss, (0, 1, 2), has_aux=True))(q, k, v)
+        return dict(zip(("out", "dq", "dk", "dv"), (out,) + grads))
+
+    rel = {}
+    for name, window in (("window", cfg["window"]), ("global", None)):
+        got, want = run(window, False), run(window, True)
+        assert got["dk"].shape == k.shape and got["dq"].shape == q.shape
+        rel[name] = {
+            n: float(jnp.linalg.norm((got[n] - want[n]).astype(jnp.float32))
+                     / jnp.linalg.norm(want[n].astype(jnp.float32)))
+            for n in got}
+    _emit("shared_heads_vs_repeated", t0, clock, seq=T, heads=H, kv_heads=KV,
+          head_dim=D, window=cfg["window"], interpret=not on_tpu,
+          compared="output, dQ, dK, dV of flash_attention handed the shared "
+                   "heads against the same call handed them repeated, "
+                   "bfloat16: relative L2",
+          rel_l2=rel, rel_l2_tol=SHARED_HEADS_L2_RTOL)
+    for name, gaps in rel.items():
+        for n, gap in gaps.items():
+            assert gap <= SHARED_HEADS_L2_RTOL, (
+                f"{name} {n}: {gap} from the repeated call in relative L2")
+    return rel
+
+
+# ---------------------------------------------------------------------------
 
 
 def _rebuild_native():
@@ -741,6 +814,8 @@ def run(args, device):
             phase_decoder(sizes["decoder"], args.seed, on_tpu, clock)
         if want("experts_piled"):
             phase_experts_piled(sizes["experts"], args.seed, clock)
+        if want("shared_heads"):
+            phase_shared_heads(sizes["shared_heads"], args.seed, on_tpu, clock)
     bf.shutdown()
 
 

@@ -28,7 +28,7 @@ sys.path.insert(0, REPO)
 
 from chipbench import manifest, seeded  # noqa: E402
 
-CELL = "smallthinker-21b-a3b-atc-b2-s8k-1chip"
+CELL = "smallthinker-21b-a3b-atc-warmup-b2-s8k-1chip"
 
 
 @pytest.fixture(scope="module")
@@ -143,8 +143,9 @@ def test_no_width_differs_from_the_source_and_the_cut_is_stated(cell):
     assert "eight" in cfg["deployment"] and "one period" in cfg["deployment"]
     mix = cell.mix
     assert mix["sizes"] == {"per_rank_batch": 2, "seq_len": 8192}
-    assert mix["optimizer"] == cfg["optimizer"] == {
+    assert cfg["optimizer"] == {
         "name": "adamw", "learning_rate": 3e-4, "weight_decay": 0.1}
+    assert mix["optimizer"] == dict(cfg["optimizer"], warmup_steps=2000)
     entry = next(c for c in manifest.load_manifest()["configs"]
                  if c["name"] == cell.config_name)
     assert entry["source"] == cfg["source"] and entry["source"].endswith("config.json")

@@ -117,6 +117,34 @@ def test_banded_flash_kernels_compile_for_v5e(one_chip, shape, window):
         assert name in text  # the names the benchmark's readers look up
 
 
+# the laguna-xs.2 cell's two kinds of layer (B1 S8192, 8 key-value heads of
+# 128): 64 query heads in a 512-key band at the blocks its program passes and
+# at the kernels' own, 48 over the whole sequence
+@pytest.mark.parametrize("heads,window,blocks", [
+    pytest.param(64, 512, (512, 512), id="H64-KV8-W512-512x512"),
+    pytest.param(64, 512, (None, None), id="H64-KV8-W512-default"),
+    pytest.param(48, None, (1024, 1024), id="H48-KV8-global"),
+])
+def test_flash_kernels_read_shared_heads_in_place_for_v5e(one_chip, heads, window,
+                                                          blocks):
+    """Key and value heads fewer than the query heads: the index maps divide
+    the head's program id by the group, and the dK/dV grid gains the group as
+    an axis of its own over which the accumulators run."""
+    q = jax.ShapeDtypeStruct((1, 8192, heads, 128), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 8192, 8, 128), jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(
+            q, k, v, causal=True, interpret=False, impl="pallas", window=window,
+            block_q=blocks[0], block_k=blocks[1]).astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, kv, kv).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 3
+    assert [tuple(o.shape) for o in compiled.out_info] == [
+        q.shape, kv.shape, kv.shape]  # dK and dV leave summed over the group
+
+
 def test_grouped_expert_products_compile_to_xlas_kernel_for_v5e(one_chip):
     """`held_topk_experts` at the benchmark's sizes: the three grouped
     products of a pass and their transposes are XLA's own grouped-matmul
