@@ -1,38 +1,28 @@
 """Flash attention: blockwise XLA forward/backward + a Pallas TPU kernel.
 
-**What the chip records hold (PR 29 and PR 34, `PERF.md` section 5; TPU
-v5e, one chip, a traced run of the cell
-`smallthinker-21b-a3b-atc-warmup-b2-s8k-1chip`, whose attention reads as
-the constant-rate cell's did: B2 S8192, 28 heads of 128, bfloat16, 1024 x
-1024 blocks, device time a call, visible query-key pairs only counted as
-work):** the banded kernels
-(``window=4096``, 30 of the 64 tiles visited): forward 7.42 ms (97 TF/s,
-49 % of the bf16 peak), dK/dV 10.87 ms (133 TF/s, 67 %), dQ 8.22 ms (132
-TF/s, 67 %); the whole-sequence causal kernels (``window=None``, 36 tiles):
-forward 9.17 ms (105 TF/s), dK/dV 13.93 ms (138 TF/s), dQ 10.81 ms (134
-TF/s).  **Every other speed in this file (the "builder readings of
-2026-07": forward and training numbers, tok/s, the block and lane A/Bs) is
-older than the chip records, was taken on older code with another
-estimator at invented widths (D=64, 12-14 heads), and has not been
-re-measured: read them as the history of the choices, not as today's
-speeds.**
+**What the chip records hold (my chip runs, PR 36, `PERF.md` sections 5
+and 6; TPU v5e, one chip, a traced run of the cell
+`smallthinker-21b-a3b-atc-warmup-b2-s8k-1chip`: B2 S8192, 28 heads of 128,
+bfloat16, 1024 x 1024 blocks, device time a call, visible query-key pairs
+only counted as work):** the banded kernels (``window=4096``, 30 of the 64
+tiles visited): forward 7.42 ms (97 TF/s, 49 % of the bf16 peak), dK/dV
+10.46 ms (138 TF/s, 70 %), dQ 7.61 ms (142 TF/s, 72 %); the whole-sequence
+causal kernels (``window=None``, 36 tiles): forward 9.10 ms (106 TF/s),
+dK/dV 13.76 ms (140 TF/s), dQ 10.49 ms (138 TF/s).  Before the backward
+kernels walked their cut tiles in sub-tiles (PR 29 and PR 34): 10.87 and
+8.22, 13.93 and 10.81 ms.  **No other speed is stated in this file as
+today's**: where a choice rests on a 2026-07 reading whose record is
+deleted, the comment says so and says "not measured".
 
 Two interchangeable forwards behind one ``impl`` switch ("auto" default =
 the Pallas kernel): a hand Pallas kernel and an online-softmax blockwise
-computation in plain XLA (``impl="xla"``).  Forward-only standing (r4
-continuation, a script since deleted, scan-chain + slope
-protocol): the Pallas forward is 4-6x FASTER than the XLA blockwise
-forward at 134M/1B/long-context dims (44-82 TF/s vs 9-18, builder
-readings of 2026-07 under a slope estimator that cancels the constant
-per-dispatch cost; not re-measured since).
-(The r3-era header claimed the
-reverse — XLA ahead 25-35% — measured at 512^2 blocks before the aligned
-fast path and packed scalar tiles; the r4 kernel work flipped it, closing
-the r3 verdict's "largest known recoverable perf item".)  END-TO-END the
-margin is larger still: training with ``impl="xla"`` measured 13x slower
-(Llama-134M S=2048: 4.8k vs 63.0k tok/s/chip) — the unrolled blockwise
-forward inside the custom-vjp recompute wrecks the backward schedule
-under jit — so auto stays Pallas on both lenses.
+computation in plain XLA (``impl="xla"``).  The Pallas kernels against the
+XLA path, forward alone or in training: not measured (no cell runs
+``impl="xla"``; the 2026-07 readings that chose "auto" — the Pallas forward
+several times faster, training with the XLA forward an order of magnitude
+slower because the unrolled blockwise forward inside the custom-vjp
+recompute wrecks the backward schedule under jit — went with their
+records).
 Both share the custom-VJP blockwise backward and produce identical
 (o, lse) contracts; interpret mode always runs the Pallas logic so CPU
 tests exercise the kernel.
@@ -59,10 +49,9 @@ saved logsumexp (the flash trick — no O(T²) residuals).  The default is
 a PAIR OF PALLAS KERNELS (dK/dV accumulated over q blocks, dQ over k
 blocks, probability tiles live only in VMEM): the earlier XLA
 ``fori_loop`` backward materialized `[BH, T, block_k]` f32 tiles in HBM
-per k-block and measured memory-bound — 12.6 ms/block vs ~1 ms
-causal-matmul ideal at 134M/S=2048, 79% of block time (round-3
-decomposition); switching to the Pallas backward measured **+15%
-end-to-end** on Llama-134M training (72.1k → 83.2k tok/s) and +6% at 1B.
+per k-block and was memory-bound (a 2026-07 reading whose record is
+deleted; the Pallas backward against it: not measured by any cell, the
+decoder cells time the Pallas backward alone).
 The XLA backward remains behind ``impl="xla"``.  The lse output is
 itself differentiable (its cotangent folds into the dS term), which is
 what lets ring attention's logsumexp *merge* train end-to-end.
@@ -79,6 +68,21 @@ traces what it always traced (the interpret-mode lowering is the parent's
 byte for byte; the compiled kernel's serialized form carries source line
 numbers, which moved).
 
+*Cut tiles in sub-tiles* (PR 36): with static offsets and blocks of 1024,
+the two backward kernels do not compute whole and mask a tile that the
+band's edge or the causal diagonal cuts.  One rolled ``lax.fori_loop`` walks
+its four square sub-tiles of 512 (:func:`_sub_edge`, :func:`_walk_cut_tile`:
+slices of the blocks already in VMEM, ``pl.ds`` at a multiple of the edge):
+a sub-tile that sees nothing is skipped, one that sees everything runs the
+unmasked body, the rest the masked one, three of four on the diagonal and on
+the band's lower edge.  The blocks, the grid and what is fetched are as they
+were.  Rolled, not unrolled: a kernel carries two bodies of a sub-tile in
+place of one of a tile and less generated code than before, where sixteen
+unrolled bodies a kernel multiplied what every run traces, lowers and loads
+(PR 32's 7.7 s of `setup_s`).  The forward kernel, blocks under 1024 and the
+dynamic-offset kernels lower to what they lowered to; :func:`_sub_edge` says
+what the chip read.
+
 On non-TPU platforms the same kernel runs in Pallas interpret mode (tests
 exercise the real kernel logic on the CPU mesh).
 """
@@ -88,7 +92,7 @@ from __future__ import annotations
 import functools
 import math
 import os
-from typing import Callable, Optional, Tuple
+from typing import Callable, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -97,6 +101,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from bluefog_tpu.parallel._util import vma_full
+from bluefog_tpu.telemetry import registry as _telemetry
 
 __all__ = ["flash_attention", "flash_attention_with_lse", "make_flash_attention_fn"]
 
@@ -106,19 +111,14 @@ _LANES = 128
 # Total lane width of the per-row-scalar tiles.  The forward's lse
 # output uses the full width; the backward packs BOTH scalars (lse, corr)
 # into one tile of this width — each gets _SCALAR_LANES/2 lanes — and
-# re-reads one such tile per (q-block, k-block) pair.  History (all
-# end-to-end interleaved A/Bs of a script since deleted, 2026-07, where
-# microbenchmarks spread >100%; builder readings, not re-measured):
-# - r4, 512^2 blocks: separate 128-lane lse/corr arrays = ~1.8 GB of
-#   re-reads per 134M layer (r3 advisor finding); narrowing to 8 lanes
-#   measured 3-4% SLOWER (the narrow 512x8 f32 DMA cost more than the
-#   fat reads, which fwd+bwd overlap hid); packing both scalars into one
-#   128-lane tile (half the bytes, one DMA) measured +1% and shipped.
-# - r4 continuation, 1024^2 blocks (the retuned default): the lane
-#   conclusion FLIPPED — 8 lanes is +5.1% at 134M (97.7k vs 93.0k tok/s,
-#   reproduced 97.8k/97.7k) and +0.9% at 1B (15.60k vs 15.46k): a
-#   1024-row scalar tile amortizes the narrow-DMA overhead that the
-#   512-row tile could not, and 16x fewer scalar bytes win.  8 ships.
+# re-reads one such tile per (q-block, k-block) pair.  History of the
+# choice (2026-07, records deleted; the lane width against another: not
+# measured by any cell, the decoder cells run at 8):
+# - 512^2 blocks: 8 lanes lost to one packed 128-lane tile (the narrow
+#   512x8 f32 DMA cost more than the fat reads, which fwd+bwd overlap hid).
+# - 1024^2 blocks (the default since): the conclusion flipped; a 1024-row
+#   scalar tile amortizes the narrow DMA that the 512-row tile could not,
+#   and 16x fewer scalar bytes win.  8 ships.
 _SCALAR_LANES = int(os.environ.get("BLUEFOG_FLASH_SCALAR_LANES", "8"))
 _ALIGNED_ENABLED = os.environ.get("BLUEFOG_FLASH_ALIGNED", "1") != "0"
 # Experiment knob (MEASURED NULL, default off): run the kernels' softmax
@@ -128,10 +128,9 @@ _ALIGNED_ENABLED = os.environ.get("BLUEFOG_FLASH_ALIGNED", "1") != "0"
 # unaffected.  Numerics: the folded multiplier is never a power of two, so
 # q rounds once in its storage dtype (<= 2^-9 relative on bf16 scores;
 # exact-ish on f32/CPU); all CPU-interpret numerics tests pass either way.
-# r4 end-to-end A/B (2 interleaved rounds of a script since deleted, 134M,
-# 1024^2 blocks): off 92.3/93.0 vs on 92.5/87.6 tok/s — within noise to
-# negative; Mosaic's natural exp evidently already lowers to the cheap
-# path, so the saved multiply buys nothing on this chip.
+# On against off: not measured by any cell (a 2026-07 A/B, its record
+# deleted, read within noise to negative: Mosaic's natural exp evidently
+# already lowers to the cheap path).
 _EXP2_ENABLED = os.environ.get("BLUEFOG_FLASH_EXP2", "0") != "0"
 # Experiment knob: backward-only block override ("BQxBK", e.g. "512x1024").
 # The bwd kernels carry more live VMEM tiles than the forward (p, dp, ds
@@ -152,6 +151,7 @@ if os.environ.get("BLUEFOG_FLASH_BWD_BLOCKS"):
 _LOG2E = math.log2(math.e)
 _LN2 = math.log(2.0)
 _MAX_UNROLL = 64  # triangular fast paths unroll at most this many k blocks
+_SUB_EDGE = 512  # see _sub_edge
 
 
 def _kexp(x):
@@ -207,30 +207,61 @@ def _block_spec(shape, index_map):
 
 
 def _default_blocks(tq, tk, block_q, block_k, window=None):
-    """Sequence-adaptive block defaults, measured on v5e fwd+bwd.
+    """Sequence-adaptive block defaults: 1024 x 1024 whenever the sequence
+    admits it, 512 below, and 512 under a band narrower than 1024 keys,
+    whatever the sequence.
 
-    History: 512x512 measured fastest at T=2048 in round 2 (12.4->9.8 ms
-    vs 256x256) and 1024x1024 won only at T>=8192 (30.1 vs 41.1 ms) — but
-    that tuning predates the aligned fast path (interior causal tiles now
-    run ZERO mask VPU work), which shifts the balance toward bigger tiles:
-    re-measured END-TO-END in round 4 with the aligned path, 1024x1024 at
-    T=2048 is +14% on Llama-134M training (81.8k -> 93.2k tok/s, D=64,
-    interleaved same-session) and +7% on Llama-1B (14.06k -> 15.03k,
-    D=128).  2048x2048 fails to compile (a [2048, 2048] f32 score tile
-    plus accumulators exceeds what Mosaic will carry).  So: 1024 whenever
-    the sequence admits it, 512 below, and 512 under a band narrower than
-    1024 keys, whatever the sequence: a window layer of
-    the `laguna-xs.2` cell (64 query heads on 8 of 128, 8,192 tokens, 512
-    keys; forward and backward together, host clock over 20 calls, my chip
-    runs, PR 35) read 13.8 ms at 512x512 (2.0 pairs computed for one seen),
-    17.3 at 1024x1024 (3.9), 17.9 at 1024x512 (3.0; the forward alone 7.2
-    against 5.3), 15.9 at 256x512, 20.8 at 256x256 (1.5) and at 512x256."""
+    What chose them.  1024 over 512 from 2,048 tokens: at 8,192 tokens the
+    whole-sequence kernels (48 query heads on 8 of 128; forward and backward
+    together, host clock over 20 calls, my chip runs, PR 35) read 29.5 ms at
+    1024x1024 against 44.2 at 512x512; at 2,048 tokens: not measured by any
+    cell (the 2026-07 readings that moved the default there, on invented
+    widths, went with their records).  2048x2048 fails to compile (a
+    [2048, 2048] f32 score tile plus accumulators exceeds what Mosaic will
+    carry).  512 under a narrow band: a window layer of the `laguna-xs.2`
+    cell (64 query heads on 8 of 128, 8,192 tokens, 512 keys; same clock,
+    PR 35) read 13.8 ms at 512x512 (2.0 pairs computed for one seen), 17.3
+    at 1024x1024 (3.9), 17.9 at 1024x512 (3.0; the forward alone 7.2
+    against 5.3), 15.9 at 256x512, 20.8 at 256x256 (1.5) and at 512x256.
+    Those were whole tiles; the backward kernels have since walked the
+    tiles a mask cuts in sub-tiles (:func:`_sub_edge` says what the edge
+    was chosen from)."""
     big = max(tq, tk) >= 2048 and (window is None or window >= 1024)
     if block_q is None:
         block_q = 1024 if big else 512
     if block_k is None:
         block_k = 1024 if big else 512
     return block_q, block_k
+
+
+def _sub_edge(block_q, block_k):
+    """The edge of the square sub-tiles in which the static-offset backward
+    kernels walk a tile that the mask cuts (a band's edge, the causal
+    diagonal), or None where they compute it whole and mask: 512 under blocks
+    of 1024, nothing under smaller ones.
+
+    Chosen from a sweep on the chip (`chip_smoke.py --only subtiles`, my chip
+    runs, PR 36; TPU v5e, 8,192 tokens, heads of 128, host clock over 20
+    calls, ms a call whole / 512 / 256 / 128).  A SmallThinker window layer
+    (2 x 28 heads, window 4096, 1024 x 1024): dK/dV 11.37 / 10.95 / 14.52 /
+    18.25, dQ 8.71 / 8.10 / 9.02 / 13.70, the forward 8.87 / 9.28 / 12.82 /
+    17.81.  Its whole-sequence layer: dK/dV 14.90 / 14.73 / 17.18 / 19.64, dQ
+    11.22 / 10.90 / 11.56 / 14.64, the forward 9.54 / 11.01 / 13.29 / 16.57.
+    A Laguna window layer (64 on 8 heads, window 512, 512 x 512): dK/dV 4.49
+    / - / 7.28 / 9.83, dQ 3.25 / - / 3.83 / 7.40, the forward 5.19 / - / 7.95
+    / 11.65.  So the backward kernels take 512, and **the forward kernel
+    walks nothing**: a rolled loop's body is scheduled alone, and a sub-tile
+    of the forward (its row maxima, its accumulator rescaled once a
+    sub-tile) ran 2.4 to 4 times slower a pair than the same pairs inside
+    the whole tile's body, which no skipped quarter pays for.  256 computes
+    10 sub-tiles of 16 where 512 computes 3 of 4, and loses more to that than
+    it skips.  Mosaic unrolls a loop whole or not at all
+    (``fori_loop(unroll=)`` takes 1 or the trip count), and whole is sixteen
+    bodies a kernel (PR 32's form: 22.6 MB of code, 7.7 s of every run's
+    set-up)."""
+    if min(block_q, block_k) < 2 * _SUB_EDGE or block_q % _SUB_EDGE or block_k % _SUB_EDGE:
+        return None
+    return _SUB_EDGE
 
 
 def _fit_block(t, b):
@@ -354,10 +385,95 @@ class _Band:
         interior = (c0 + self.bk - 1 <= r0) & (r0 + self.bq - 1 - c0 < self.w)
         return runs, interior, r0 - c0
 
-    def when(self, iq, jk, body):
+    def when(self, iq, jk, body, cut=None):
+        """``body(False)`` on an interior tile; on a tile the band cuts
+        ``cut()``, by default the masked body over the whole tile."""
         runs, interior, _ = self.tile(iq, jk)
         pl.when(runs & interior)(lambda: body(False))
-        pl.when(runs & jnp.logical_not(interior))(lambda: body(True))
+        pl.when(runs & jnp.logical_not(interior))(cut or (lambda: body(True)))
+
+    def pairs(self, sub):
+        """(visible, computed) query-key pairs of one head over the whole
+        sequence: what the mask lets through, and what the kernels compute
+        for it when a cut tile is walked in sub-tiles of edge ``sub`` (falsy:
+        computed whole)."""
+        visible = sum(min(r + 1, self.w) for r in range(self.num_q * self.bq))
+        computed = 0
+        for i in range(self.num_q):
+            for j in range(self.k_lo(i), self.k_hi(i) + 1):
+                _, interior, offset = self.tile(i, j)
+                if interior or not sub:
+                    computed += self.bq * self.bk
+                    continue
+                computed += sub * sub * sum(
+                    _seen_and_full(offset + (a - b) * sub, sub, 0, self.w)[0]
+                    for a in range(self.bq // sub) for b in range(self.bk // sub))
+        return visible, computed
+
+
+def _seen_and_full(offset, edge, lo, hi):
+    """Whether a square of ``edge``, its first row ``offset`` positions past
+    its first column, sees anything of the mask ``lo <= qpos - kpos < hi``
+    (``hi`` None: no upper edge), and whether it sees all of it.  Python
+    ints or traced scalars."""
+    d_min, d_max = offset - (edge - 1), offset + (edge - 1)
+    seen, full = d_max >= lo, d_min >= lo
+    if hi is not None:
+        seen, full = seen & (d_min < hi), full & (d_max < hi)
+    return seen, full
+
+
+class _Part(NamedTuple):
+    """One sub-tile of a cut tile: where its rows and columns lie in the
+    blocks held in VMEM, and its first row's position less its first
+    column's."""
+    rows: object
+    cols: object
+    offset: object
+
+
+def _walk_cut_tile(block_q, block_k, sub, offset, lo, hi, body):
+    """A tile that the mask cuts, as square sub-tiles of edge ``sub`` in one
+    rolled loop: ``body(masked, part)`` once for each sub-tile that sees
+    anything (``lo <= qpos - kpos < hi`` somewhere in it; ``hi`` None: no
+    upper edge), unmasked where it sees everything.  A sub-tile that sees
+    nothing is skipped.  Two traced bodies, whatever the count of sub-tiles."""
+    nk = block_k // sub
+
+    def step(t, carry):
+        a, b = lax.div(t, nk), lax.rem(t, nk)
+        part = _Part(pl.ds(pl.multiple_of(a * sub, sub), sub),
+                     pl.ds(pl.multiple_of(b * sub, sub), sub),
+                     offset + (a - b) * sub)
+        seen, full = _seen_and_full(part.offset, sub, lo, hi)
+        pl.when(full)(lambda: body(False, part))
+        pl.when(seen & jnp.logical_not(full))(lambda: body(True, part))
+        return carry
+
+    lax.fori_loop(0, (block_q // sub) * nk, step, 0)
+
+
+def _span(part, block_q, block_k, sub):
+    """(rows, cols, bq, bk) of what one traced body computes: the whole
+    tile (``part`` None) or one sub-tile of it."""
+    if part is None:
+        return slice(None), slice(None), block_q, block_k
+    return part.rows, part.cols, sub, sub
+
+
+def _cut_tile(body, block_q, block_k, sub, band, aligned_delta, iq, jk):
+    """What a static-offset kernel runs on a tile its mask cuts: the masked
+    body over the whole tile, or with an edge ``sub`` the walk in sub-tiles
+    (of the diagonal tile those that are cut lie on the diagonal themselves,
+    so the bodies' ``_aligned_mask`` holds for them as it stands).  The
+    dynamic-offset paths never call it."""
+    if not sub:
+        return lambda: body(True)
+    if band is not None:
+        return lambda: _walk_cut_tile(
+            block_q, block_k, sub, band.tile(iq, jk)[2], 0, band.w, body)
+    return lambda: _walk_cut_tile(
+        block_q, block_k, sub, 0, aligned_delta, None, body)
 
 
 def _fwd_kernel(qs_ref, ks_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
@@ -481,6 +597,18 @@ def _aligned_or_none(tri_delta, causal, tq, tk, block_q, block_k):
     return None
 
 
+def _gauge_pairs(kind, band, sub):
+    """`attention.pairs_visible_<kind>` and `.pairs_computed_<kind>` at trace
+    time, a head and a sequence: what the mask lets through and what each
+    backward kernel computes for it (the forward computes its tiles whole,
+    ``band.pairs(0)``).  XProf and tests read them."""
+    reg = _telemetry.get_registry()
+    if reg.enabled:
+        visible, computed = band.pairs(sub)
+        reg.gauge(f"attention.pairs_visible_{kind}").set(visible)
+        reg.gauge(f"attention.pairs_computed_{kind}").set(computed)
+
+
 def _kv_head_of(q, k):
     """Folded query head ``b`` (of ``[B * H, T, D]``) -> the folded key-value
     head it reads (of ``[B * KV, T, D]``): ``b // (H / KV)``, since query head
@@ -575,14 +703,10 @@ def _blockwise_fwd_xla(q, k, v, q_start, k_start, *, scale, causal, block_k,
     """Online-softmax blockwise forward in plain XLA; same math and
     (o, lse) contract as the Pallas kernel.
 
-    Selectable via ``impl="xla"``.  At the r3-era 512^2 blocks it beat
-    the hand kernel forward-only by ~25-35%; after the r4 aligned fast
-    path + 1024^2 retune the Pallas forward is 4-6x FASTER
-    (a script since deleted, slope protocol), and inside the custom-vjp's
-    backward recompute this path measured 13x slower end-to-end on Llama
-    training — so it is NOT the auto default on either lens.  Kept as
-    the independent same-contract implementation (numerics cross-check,
-    non-Mosaic fallback).
+    Selectable via ``impl="xla"``; not the auto default (its speed
+    against the Pallas kernel: not measured, see the module docstring).
+    Kept as the independent same-contract implementation (numerics
+    cross-check, non-Mosaic fallback).
     """
     bh, tq, d = q.shape
     tk = k.shape[1]
@@ -656,13 +780,12 @@ def _bwd_dkv_kernel(qs_ref, ks_ref, q_ref, g_ref, aux_ref,
                     k_ref, v_ref, dk_ref, dv_ref, dk_acc, dv_acc,
                     *, scale: float, block_q: int, block_k: int,
                     causal: bool, num_q: int, aligned_delta, half: int,
-                    window=None, band=None, group: int = 1):
+                    window=None, band=None, group: int = 1, sub=None):
     """One (bh, jk, iq) program: fold q-block iq into dK/dV of k-block jk.
 
     Same recompute-from-lse trick as the XLA backward, but the
     [block_q, block_k] probability/score tiles live and die in VMEM —
-    the XLA path materializes them per k-block in HBM, which is why the
-    backward measured memory-bound (round-3 decomposition).
+    the XLA path materializes them per k-block in HBM.
     ``aligned_delta``: see :func:`_fwd_kernel`.  ``aux_ref`` packs the two
     per-row scalars in one tile (lse in lanes [:half], corr in [half:]) —
     one scalar DMA per grid step instead of two.  ``group`` > 1: the grid is
@@ -686,24 +809,26 @@ def _bwd_dkv_kernel(qs_ref, ks_ref, q_ref, g_ref, aux_ref,
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    def _body(masked):
-        q = q_ref[0]  # [block_q, D]
-        g = g_ref[0]  # [block_q, D]
-        k = k_ref[0]  # [block_k, D]
-        v = v_ref[0]  # [block_k, D]
-        lse = _lse_in_score_space(aux_ref[0][:, :1])  # [block_q, 1]
-        corr = aux_ref[0][:, half:half + 1]
+    def _body(masked, part=None):
+        rows, cols, bq, bk = _span(part, block_q, block_k, sub)
+        q = q_ref[0, rows]  # [bq, D]
+        g = g_ref[0, rows]  # [bq, D]
+        k = k_ref[0, cols]  # [bk, D]
+        v = v_ref[0, cols]  # [bk, D]
+        lse = _lse_in_score_space(aux_ref[0, rows][:, :1])  # [bq, 1]
+        corr = aux_ref[0, rows][:, half:half + 1]
         qk, scale_scores = _score_operand(q, q_ref.dtype, scale)
         s = jax.lax.dot_general(
             qk, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-        )  # [block_q, block_k] fp32
+        )  # [bq, bk] fp32
         if scale_scores:
             s = s * scale
         if masked:
             if band is not None:
-                s = _band_mask(s, block_q, block_k, band.tile(iq, jk)[2],
-                               band.w)
+                s = _band_mask(
+                    s, bq, bk,
+                    band.tile(iq, jk)[2] if part is None else part.offset, band.w)
             elif aligned_delta is None:
                 qpos = qs_ref[0, 0] + iq * block_q + lax.broadcasted_iota(
                     jnp.int32, (block_q, block_k), 0)
@@ -711,14 +836,14 @@ def _bwd_dkv_kernel(qs_ref, ks_ref, q_ref, g_ref, aux_ref,
                     jnp.int32, (block_q, block_k), 1)
                 s = jnp.where(_visible(qpos, kpos, window), s, _NEG_INF)
             else:
-                s = _aligned_mask(s, block_q, block_k, aligned_delta)
+                s = _aligned_mask(s, bq, bk, aligned_delta)
             # masked entries (and whole sentinel-lse rows) exp to exactly 0
             p = _kexp(jnp.where(s > _MASK_THRESH, s - lse, _NEG_INF))
         else:
             # interior tile: nothing is masked and (aligned path) no
             # sentinel-lse row can appear here — plain recompute
             p = _kexp(s - lse)
-        dv_acc[...] += jax.lax.dot_general(
+        dv_acc[cols] += jax.lax.dot_general(
             p.astype(g.dtype), g, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
@@ -730,16 +855,17 @@ def _bwd_dkv_kernel(qs_ref, ks_ref, q_ref, g_ref, aux_ref,
         # once at _finish (a [block_k, D] pass instead of a
         # [block_q, block_k] pass per tile — exact, any scale)
         ds = (p * (dp + corr)).astype(q.dtype)
-        dk_acc[...] += jax.lax.dot_general(
+        dk_acc[cols] += jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
 
+    cut = _cut_tile(_body, block_q, block_k, sub, band, aligned_delta, iq, jk)
     if band is not None:
-        band.when(iq, jk, _body)
+        band.when(iq, jk, _body, cut)
     elif causal and aligned_delta is not None:
         pl.when(iq > jk)(lambda: _body(False))
-        pl.when(iq == jk)(lambda: _body(True))
+        pl.when(iq == jk)(cut)
     elif causal:
         # skip q blocks entirely above the diagonal (they reach no k row)
         last_q = qs_ref[0, 0] + (iq + 1) * block_q - 1
@@ -759,7 +885,7 @@ def _bwd_dq_kernel(qs_ref, ks_ref, q_ref, g_ref, aux_ref,
                    k_ref, v_ref, dq_ref, dq_acc,
                    *, scale: float, block_q: int, block_k: int,
                    causal: bool, num_k: int, aligned_delta, half: int,
-                   window=None, band=None):
+                   window=None, band=None, sub=None):
     """One (bh, iq, jk) program: fold k-block jk into dQ of q-block iq.
     ``aligned_delta``: see :func:`_fwd_kernel`; ``aux_ref``/``half``: see
     :func:`_bwd_dkv_kernel`."""
@@ -773,13 +899,14 @@ def _bwd_dq_kernel(qs_ref, ks_ref, q_ref, g_ref, aux_ref,
     def _init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    def _body(masked):
-        q = q_ref[0]
-        g = g_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        lse = _lse_in_score_space(aux_ref[0][:, :1])
-        corr = aux_ref[0][:, half:half + 1]
+    def _body(masked, part=None):
+        rows, cols, bq, bk = _span(part, block_q, block_k, sub)
+        q = q_ref[0, rows]
+        g = g_ref[0, rows]
+        k = k_ref[0, cols]
+        v = v_ref[0, cols]
+        lse = _lse_in_score_space(aux_ref[0, rows][:, :1])
+        corr = aux_ref[0, rows][:, half:half + 1]
         qk, scale_scores = _score_operand(q, q_ref.dtype, scale)
         s = jax.lax.dot_general(
             qk, k, (((1,), (1,)), ((), ())),
@@ -789,8 +916,9 @@ def _bwd_dq_kernel(qs_ref, ks_ref, q_ref, g_ref, aux_ref,
             s = s * scale
         if masked:
             if band is not None:
-                s = _band_mask(s, block_q, block_k, band.tile(iq, jk)[2],
-                               band.w)
+                s = _band_mask(
+                    s, bq, bk,
+                    band.tile(iq, jk)[2] if part is None else part.offset, band.w)
             elif aligned_delta is None:
                 qpos = qs_ref[0, 0] + iq * block_q + lax.broadcasted_iota(
                     jnp.int32, (block_q, block_k), 0)
@@ -798,7 +926,7 @@ def _bwd_dq_kernel(qs_ref, ks_ref, q_ref, g_ref, aux_ref,
                     jnp.int32, (block_q, block_k), 1)
                 s = jnp.where(_visible(qpos, kpos, window), s, _NEG_INF)
             else:
-                s = _aligned_mask(s, block_q, block_k, aligned_delta)
+                s = _aligned_mask(s, bq, bk, aligned_delta)
             p = _kexp(jnp.where(s > _MASK_THRESH, s - lse, _NEG_INF))
         else:
             p = _kexp(s - lse)
@@ -808,16 +936,17 @@ def _bwd_dq_kernel(qs_ref, ks_ref, q_ref, g_ref, aux_ref,
         )
         # unscaled ds; scale applied once to the accumulator at _finish
         ds = (p * (dp + corr)).astype(q.dtype)
-        dq_acc[...] += jax.lax.dot_general(
+        dq_acc[rows] += jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
 
+    cut = _cut_tile(_body, block_q, block_k, sub, band, aligned_delta, iq, jk)
     if band is not None:
-        band.when(iq, jk, _body)
+        band.when(iq, jk, _body, cut)
     elif causal and aligned_delta is not None:
         pl.when(jk < iq)(lambda: _body(False))
-        pl.when(jk == iq)(lambda: _body(True))
+        pl.when(jk == iq)(cut)
     elif causal:
         first_k = ks_ref[0, 0] + jk * block_k
         last_q = qs_ref[0, 0] + (iq + 1) * block_q - 1
@@ -833,11 +962,13 @@ def _bwd_dq_kernel(qs_ref, ks_ref, q_ref, g_ref, aux_ref,
 
 def _flash_bwd_pallas(q, k, v, lse, corr, q_start, k_start, g,
                       *, scale, causal, block_q, block_k, interpret,
-                      tri_delta=None, window=None):
+                      tri_delta=None, window=None, sub=None):
     """dQ/dK/dV via two Pallas kernels; all [BH, T, D].
 
     ``corr`` is ``g_lse − rowsum(o·g)`` per q row (f32, [BH, Tq]) — the
-    dS correction term, precomputed once in XLA.
+    dS correction term, precomputed once in XLA.  ``sub``: the edge of a cut
+    tile's sub-tiles, for tests and the edge's sweep (None:
+    :func:`_sub_edge`'s; 0: cut tiles computed whole).
     """
     bh, tq, d = q.shape
     tk = k.shape[1]
@@ -846,6 +977,8 @@ def _flash_bwd_pallas(q, k, v, lse, corr, q_start, k_start, g,
     block_k = _fit_block(tk, block_k)
     num_q, num_k = tq // block_q, tk // block_k
     kv_of, group = _kv_head_of(q, k), bh // k.shape[0]
+    if sub is None:
+        sub = _sub_edge(block_q, block_k)
     # the inner grid axes and which block each of their steps holds: all of
     # them in order, or with a band only those the outer block can touch
     steps_q, steps_k = num_q, num_k
@@ -854,12 +987,15 @@ def _flash_bwd_pallas(q, k, v, lse, corr, q_start, k_start, g,
     kernel_kw, dkv_kw, dq_kw, aligned = {}, {}, {}, None
     if window is None:
         aligned = _aligned_or_none(tri_delta, causal, tq, tk, block_q, block_k)
+        if aligned == 0:
+            _gauge_pairs("global", _Band(block_q, block_k, tq, num_q, num_k), sub)
     else:
         band = _band_or_none(window, tri_delta, causal, tq, tk, block_q, block_k)
         kernel_kw = dict(window=window, band=band)
         dkv_kw = dict(name="flash_bwd_dkv_window")  # the device trace's names
         dq_kw = dict(name="flash_bwd_dq_window")
         if band is not None:
+            _gauge_pairs("window", band, sub)
             steps_q, steps_k = band.q_steps, band.k_steps
             q_of = lambda j, i: jnp.minimum(band.q_lo(j) + i, band.q_hi(j))
             k_of = lambda i, j: jnp.minimum(band.k_lo(i) + j, band.k_hi(i))
@@ -870,8 +1006,7 @@ def _flash_bwd_pallas(q, k, v, lse, corr, q_start, k_start, g,
     # lanes [:half], corr in [half:]): the packed tile is the SAME width
     # as ONE of the old separate lse/corr tiles, so each (q-block,
     # k-block) grid step reads half the scalar bytes in one DMA instead
-    # of two (the separate 128-lane arrays measured ~1.8 GB of re-reads
-    # per 134M layer, r3 advisor finding)
+    # of two
     half = max(_SCALAR_LANES // 2, 1)
     aux = jnp.concatenate(
         [jnp.broadcast_to(lse[..., None], (bh, tq, half)),
@@ -904,7 +1039,7 @@ def _flash_bwd_pallas(q, k, v, lse, corr, q_start, k_start, g,
         functools.partial(
             _bwd_dkv_kernel, scale=scale, block_q=block_q, block_k=block_k,
             causal=causal, num_q=steps_q, aligned_delta=aligned, half=half,
-            **kernel_kw, **kernel_group),
+            sub=sub, **kernel_kw, **kernel_group),
         grid=dkv_grid,
         in_specs=[smem, smem, *rowspec(dkv_rows), *kvspec(dkv_keys)],
         out_specs=kvspec(dkv_keys),
@@ -924,7 +1059,7 @@ def _flash_bwd_pallas(q, k, v, lse, corr, q_start, k_start, g,
         functools.partial(
             _bwd_dq_kernel, scale=scale, block_q=block_q, block_k=block_k,
             causal=causal, num_k=steps_k, aligned_delta=aligned, half=half,
-            **kernel_kw),
+            sub=sub, **kernel_kw),
         grid=(bh, num_q, steps_k),
         in_specs=[smem, smem,
                   *rowspec(lambda b, i, j: (b, i, 0)),
@@ -1034,16 +1169,11 @@ def _fwd_dispatch(q, k, v, q_start, k_start, *, scale, causal, block_q,
     """Choose the forward implementation (static): "pallas", "xla", or
     "auto" (= Pallas kernel; "xla" remains selectable).
 
-    Auto history: at the r3-era 512^2 blocks the XLA blockwise forward
-    won a forward-only microbenchmark by ~25-35% and auto briefly
-    pointed at it — but END-TO-END TRAINING with it measured 13x slower
-    on the Llama-134M S=2048 benchmark (4.8k vs 63.0k tok/s/chip): under
-    jit the unrolled per-block forward inside the custom-vjp recompute
-    blows up the backward's schedule.  (Post-r4-retune the forward-only
-    comparison reversed too — Pallas 4-6x faster,
-    by a script since deleted.)  Training throughput is the
-    headline workload, so auto = Pallas; forward-heavy callers can still
-    pass impl="xla"."""
+    Auto = Pallas: under jit the XLA path's unrolled per-block forward
+    inside the custom-vjp recompute blows up the backward's schedule (a
+    2026-07 reading; the two against each other: not measured by any
+    cell, see the module docstring).  Callers can still pass
+    impl="xla"."""
     use_xla = impl == "xla"
     if use_xla:
         k, v = _repeat_heads(q, k), _repeat_heads(q, v)
@@ -1096,12 +1226,8 @@ def _flash_core_bwd(scale, causal, block_q, block_k, interpret, tri_delta,
             dk, dv = (x.reshape(k.shape[0], -1, *k.shape[1:]).sum(1).astype(k.dtype)
                       for x in (dk, dv))
     else:
-        # Pallas backward (default): probability/score tiles stay in VMEM.
-        # The XLA blockwise backward materialized them per k-block in HBM
-        # and measured memory-bound — 12.6 ms/block vs ~1 ms causal-matmul
-        # ideal at 134M/S=2048, 79% of block time (round-3
-        # decomposition); the "Mosaic backward deprioritized" round-1 note
-        # is superseded by that measurement.
+        # Pallas backward (default): probability/score tiles stay in VMEM;
+        # the XLA blockwise backward materializes them per k-block in HBM.
         delta = jnp.sum(o.astype(jnp.float32) * g.astype(jnp.float32),
                         axis=-1)  # [BH, Tq]
         corr = g_lse.astype(jnp.float32) - delta
@@ -1140,9 +1266,8 @@ def flash_attention_with_lse(
     calls this with the rotating key-block offset.  Rows with no visible
     keys return out=0, lse≈-1e30, which merge correctly.
 
-    ``impl``: "auto" (default = the Pallas kernel — see module docstring
-    for the measured 13x training-throughput gap vs "xla"), "xla", or
-    "pallas".  ``block_q`` only affects the Pallas kernel; the XLA path
+    ``impl``: "auto" (default = the Pallas kernel, see the module
+    docstring), "xla", or "pallas".  ``block_q`` only affects the Pallas kernel; the XLA path
     blocks on ``block_k`` alone.
 
     ``window`` (static int, causal only): a causal band, query ``i`` sees

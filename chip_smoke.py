@@ -19,7 +19,8 @@ phase that fails raises: the traceback goes to stderr, the last line says
                                      vs the benchmark's plain reference, the
                                      flash kernels reading 8 shared key-value
                                      heads for 64 query heads vs the heads
-                                     repeated
+                                     repeated, the backward kernels' cut tiles
+                                     walked in sub-tiles vs computed whole
     python chip_smoke.py --chips 4   four chips, only what exists across
                                      chips: parity on exp2(4), placement,
                                      ResNet-50 ATC vs allreduce, contraction,
@@ -79,6 +80,15 @@ FULL = dict(
     # and the same heads over the whole sequence; None: the kernels' own blocks
     shared_heads=dict(seq=8192, heads=64, kv_heads=8, head_dim=128, window=512,
                       block=None),
+    # the three attention shapes of the decoder cells (heads on key-value
+    # heads, window, block; None: the kernels' own) and the edges swept
+    subtiles=dict(seq=8192, head_dim=128, edges=(128, 256, 512), calls=20, shapes=(
+        ("smallthinker_window", 2, 28, 4, 4096, None),
+        ("laguna_window", 1, 64, 8, 512, None),
+        ("laguna_global", 1, 48, 8, None, None),
+        # as the SmallThinker cell runs them: `_MixedBlock` repeats its heads
+        ("smallthinker_window_repeated", 2, 28, 28, 4096, None),
+        ("smallthinker_global_repeated", 2, 28, 28, None, None))),
     probe=dict(dim=4096, iters=512),
 )
 TINY = dict(
@@ -90,6 +100,8 @@ TINY = dict(
                  rows=64),
     shared_heads=dict(seq=128, heads=6, kv_heads=2, head_dim=16, window=40,
                       block=16),
+    subtiles=dict(seq=128, head_dim=16, edges=(8, 16), calls=2, shapes=(
+        ("window", 2, 4, 2, 40, 32), ("global", 1, 4, 2, None, 32))),
 )
 
 # flash-vs-dense agreement at bf16 compute on seeded weights.  The two paths
@@ -128,8 +140,15 @@ SHARED_HEADS_L2_RTOL = 1e-2
 BUCKETS_GAP_RTOL = 1e-6
 
 
+# the static-offset backward kernels walking a cut tile in sub-tiles against the
+# same kernels computing it whole and masking: every visible pair is computed
+# once in both, the sums inside a tile taken in another order.  What PR 35
+# read between two programs of one arithmetic on the v5e (2.3e-3 to 2.6e-3).
+SUBTILES_L2_RTOL = 4e-3
+
 PHASES = ("ops_windows", "resnet_atc", "resnet_allreduce", "contraction",
-          "buckets_vs_per_leaf", "decoder", "experts_piled", "shared_heads")
+          "buckets_vs_per_leaf", "decoder", "experts_piled", "shared_heads",
+          "subtiles")
 
 
 class _CompileClock:
@@ -162,6 +181,13 @@ def _emit(phase, t0, clock, **fields):
 def _max_abs_diff(a, b):
     return float(np.max(np.abs(np.asarray(a, np.float64)
                                - np.asarray(b, np.float64))))
+
+
+def _rel_l2(got, want):
+    """Relative L2 of each named array of ``got`` against ``want``'s."""
+    return {n: float(jnp.linalg.norm((got[n] - want[n]).astype(jnp.float32))
+                     / jnp.linalg.norm(want[n].astype(jnp.float32)))
+            for n in got}
 
 
 def _exp2_mixing_matrix(n):
@@ -741,10 +767,7 @@ def phase_shared_heads(cfg, seed, on_tpu, clock):
     for name, window in (("window", cfg["window"]), ("global", None)):
         got, want = run(window, False), run(window, True)
         assert got["dk"].shape == k.shape and got["dq"].shape == q.shape
-        rel[name] = {
-            n: float(jnp.linalg.norm((got[n] - want[n]).astype(jnp.float32))
-                     / jnp.linalg.norm(want[n].astype(jnp.float32)))
-            for n in got}
+        rel[name] = _rel_l2(got, want)
     _emit("shared_heads_vs_repeated", t0, clock, seq=T, heads=H, kv_heads=KV,
           head_dim=D, window=cfg["window"], interpret=not on_tpu,
           compared="output, dQ, dK, dV of flash_attention handed the shared "
@@ -756,6 +779,71 @@ def phase_shared_heads(cfg, seed, on_tpu, clock):
             assert gap <= SHARED_HEADS_L2_RTOL, (
                 f"{name} {n}: {gap} from the repeated call in relative L2")
     return rel
+
+
+# ---------------------------------------------------------------------------
+# phase: cut tiles walked in sub-tiles vs computed whole, and the edge's sweep
+# ---------------------------------------------------------------------------
+
+
+def phase_subtiles(cfg, seed, on_tpu, clock):
+    """The backward kernels' builder at the decoder cells' attention shapes,
+    a cut tile walked in sub-tiles of each swept edge against the tile
+    computed whole (``sub=0``): dQ, dK and dV in relative L2, and the host
+    clock over ``calls`` calls of each kernel, which is what `_sub_edge`'s
+    edge is chosen from.  The bring-up proof of the sub-tiled kernels; no
+    cell runs it."""
+    from bluefog_tpu.kernels.flash_attention import (
+        _default_blocks, _flash_bwd_pallas, _flash_fwd, _sub_edge)
+
+    T, D = cfg["seq"], cfg["head_dim"]
+    for name, B, H, KV, window, block in cfg["shapes"]:
+        t0 = time.perf_counter()
+        keys = jax.random.split(jax.random.PRNGKey(seed), 4)
+        q, g = (jax.random.normal(k, (B * H, T, D), jnp.bfloat16) for k in keys[:2])
+        k, v = (jax.random.normal(k, (B * KV, T, D), jnp.bfloat16) for k in keys[2:])
+        kw = dict(scale=D ** -0.5, causal=True, block_q=block, block_k=block,
+                  interpret=not on_tpu, tri_delta=0, window=window)
+        out, lse = jax.jit(lambda q, k, v: _flash_fwd(q, k, v, 0, 0, **kw))(q, k, v)
+        corr = -jnp.sum(out.astype(jnp.float32) * g.astype(jnp.float32), -1)
+        args = (q, k, v, lse, corr, g)
+
+        def run(sub):
+            def bwd(*a):
+                return _flash_bwd_pallas(*a[:5], 0, 0, a[5], sub=sub, **kw)
+
+            got = dict(zip(("dq", "dk", "dv"), jax.jit(bwd)(*args)))
+            ms = {}
+            # a kernel whose outputs are dropped is not in the compiled program
+            for kind, fn in (("dkv", jax.jit(lambda *a: bwd(*a)[1:])),
+                             ("dq", jax.jit(lambda *a: bwd(*a)[0]))):
+                jax.block_until_ready((got, fn(*args)))
+                t = time.perf_counter()
+                for _ in range(cfg["calls"]):
+                    last = fn(*args)
+                jax.block_until_ready(last)
+                ms[kind] = round((time.perf_counter() - t) / cfg["calls"] * 1e3, 3)
+            return got, ms
+
+        blocks = _default_blocks(T, T, block, block, window)
+        whole, ms = run(0)
+        sweep, rel = {"whole": ms}, {}
+        for edge in (e for e in cfg["edges"] if e < min(blocks)):
+            got, sweep[str(edge)] = run(edge)
+            rel[str(edge)] = _rel_l2(got, whole)
+        _emit("subtiles_vs_whole_tiles", t0, clock, shape=name, batch=B, seq=T,
+              heads=H, kv_heads=KV, head_dim=D, window=window, blocks=blocks,
+              interpret=not on_tpu, the_kernels_own_edge=_sub_edge(*blocks),
+              compared="dQ, dK, dV of the backward kernels' builder, a cut tile "
+                       "walked in sub-tiles of each edge against the tile "
+                       "computed whole, bfloat16: relative L2; ms a call of each "
+                       "kernel, host clock",
+              ms_per_call=sweep, rel_l2=rel, rel_l2_tol=SUBTILES_L2_RTOL)
+        for edge, gaps in rel.items():
+            for n, gap in gaps.items():
+                assert gap <= SUBTILES_L2_RTOL, (
+                    f"{name} edge {edge} {n}: {gap} from the whole-tile kernels "
+                    "in relative L2")
 
 
 # ---------------------------------------------------------------------------
@@ -816,6 +904,8 @@ def run(args, device):
             phase_experts_piled(sizes["experts"], args.seed, clock)
         if want("shared_heads"):
             phase_shared_heads(sizes["shared_heads"], args.seed, on_tpu, clock)
+        if want("subtiles"):
+            phase_subtiles(sizes["subtiles"], args.seed, on_tpu, clock)
     bf.shutdown()
 
 

@@ -2,7 +2,10 @@
 kernel and both backward kernels (Pallas, interpret mode at tiny blocks) and
 in the XLA fall-back against the masked dense attention, at a window smaller
 than, equal to and larger than the sequence; ``window=None`` against the call
-that never heard of a window, bit for bit."""
+that never heard of a window, bit for bit.  And the tiles that a band's edge
+or the causal diagonal cuts, walked in sub-tiles by the backward kernels (PR
+36): their builder through its internal edge at blocks the CPU can interpret,
+the text a small block lowers to, and the gauges that count the pairs."""
 
 import hashlib
 import math
@@ -12,8 +15,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from bluefog_tpu import telemetry
 from bluefog_tpu.kernels.flash_attention import (
-    flash_attention, flash_attention_with_lse, make_flash_attention_fn)
+    _Band, _flash_bwd_pallas, _flash_fwd, _sub_edge, flash_attention,
+    flash_attention_with_lse, make_flash_attention_fn)
 
 B, T, H, D = 1, 64, 2, 16
 
@@ -55,8 +60,6 @@ def test_band_forward_and_both_backward_kernels_match_masked_dense(
 
 
 def test_the_band_grid_visits_only_the_blocks_the_band_touches():
-    from bluefog_tpu.kernels.flash_attention import _Band
-
     band = _Band(1024, 1024, 4096, 8, 8)  # the benchmark's window layer
     assert (band.k_steps, band.q_steps) == (5, 5)
     tiles = sum(band.k_hi(i) - band.k_lo(i) + 1 for i in range(8))
@@ -109,3 +112,132 @@ def test_a_window_needs_a_causal_mask():
         flash_attention(x, x, x, causal=False, window=4)
     with pytest.raises(ValueError, match="window"):
         flash_attention(x, x, x, window=0)
+
+
+# ---- cut tiles walked in sub-tiles (PR 36) --------------------------------
+
+
+def _folded(x):  # [B, T, H, D] -> [B * H, T, D], as the builders take them
+    return x.transpose(0, 2, 1, 3).reshape(-1, x.shape[1], x.shape[3])
+
+
+# None: the causal diagonal alone; 16: a block's own width
+@pytest.mark.parametrize("group", [1, 2])
+@pytest.mark.parametrize("edge", [8, 4], ids=["2x2", "4x4"])
+@pytest.mark.parametrize("window", [None] + WINDOWS + [16])
+def test_sub_tiled_backward_kernels_match_masked_dense(qkvg, window, edge, group):
+    """16 x 16 blocks whose cut tiles the backward kernels walk in sub-tiles
+    of ``edge``: dK/dV (summed over a group of query heads that share a
+    key-value head) and dQ against the masked dense attention, after the
+    forward, which computes its tiles whole."""
+    q, k, v, g = qkvg
+    k, v = k[:, :, :H // group], v[:, :, :H // group]
+    kw = dict(scale=1 / math.sqrt(D), causal=True, block_q=16, block_k=16,
+              interpret=True, tri_delta=0, window=window)
+    out, lse = _flash_fwd(_folded(q), _folded(k), _folded(v), 0, 0, **kw)
+    corr = -jnp.sum(out * _folded(g), axis=-1)  # the lse has no cotangent
+    got = (out,) + _flash_bwd_pallas(
+        _folded(q), _folded(k), _folded(v), lse, corr, 0, 0, _folded(g),
+        sub=edge, **kw)
+
+    def dense(q, k, v):
+        k, v = (jnp.repeat(x, group, axis=2) for x in (k, v))
+        return masked_dense(q, k, v, window)
+
+    want = (dense(q, k, v),) + jax.grad(
+        lambda *a: jnp.sum(dense(*a) * g), (0, 1, 2))(q, k, v)
+    assert got[2].shape == _folded(k).shape  # dK left summed over the group
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, _folded(b), atol=5e-6)
+
+
+@pytest.mark.parametrize("edge", [8, 4], ids=["2x2", "4x4"])
+def test_sub_tiles_on_the_striped_rings_strict_diagonal_match_the_whole_tile(
+        qkvg, edge):
+    """Keys one position ahead (``tri_delta=1``, a striped ring's hop): the
+    diagonal tile's first row sees none of its keys, the sentinel's case."""
+    q, k, v, g = (_folded(x) for x in qkvg)
+    kw = dict(scale=1 / math.sqrt(D), causal=True, block_q=16, block_k=16,
+              interpret=True, tri_delta=1)
+
+    out, lse = _flash_fwd(q, k, v, 0, 1, **kw)
+    assert float(jnp.abs(out[:, 0]).max()) == 0.0  # row 0 saw nothing
+    corr = -jnp.sum(out * g, axis=-1)
+    got, want = (_flash_bwd_pallas(q, k, v, lse, corr, 0, 1, g, sub=sub, **kw)
+                 for sub in (edge, 0))
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, atol=5e-6)
+
+
+def test_an_edge_is_chosen_from_the_blocks_and_from_nothing_else():
+    assert _sub_edge(1024, 1024) == _sub_edge(2048, 1024) == 512
+    assert _sub_edge(512, 512) is None and _sub_edge(1024, 512) is None
+    assert _sub_edge(1280, 1280) is None  # 512 does not divide it
+
+
+def _grad_text(fn, *args):
+    return hashlib.sha256(jax.jit(jax.grad(fn, (0, 1, 2))).lower(*args)
+                          .as_text().encode()).hexdigest()
+
+
+# sha256 of the lowered gradient on the parent's tree (84e0f02), from the
+# calls below run there: blocks under 1024 with static offsets, banded and
+# whole-sequence, and 1024 x 1024 blocks with traced offsets (a ring's hop)
+@pytest.mark.parametrize("block,window,hop,want", [
+    pytest.param(16, 24, False, "72438abc76060147131576d183377b259e13b0d6ab857b9565528fb5f5983d50", id="band-16"),
+    pytest.param(512, 700, False, "d300a4043d784787eb50f9f8862651ceb67611534fb216be5d7ba8ee3daf75e9", id="band-512"),
+    pytest.param(512, None, False, "094d9c87d32a4f7101a59ef06fd54ff7afe13b1b467f40dfe7a2307c48527756", id="causal-512"),
+    pytest.param(1024, 1500, True, "096c72d215eb85a6bc5b39d1b9116d99ee39a62e22c233d7e762de2aa10da6c4", id="hop-1024"),
+])
+def test_small_blocks_and_traced_offsets_lower_to_the_parents_text(
+        block, window, hop, want):
+    T = 64 if block == 16 else 2048
+    q = jax.ShapeDtypeStruct((1, T, 4, 16), jnp.float32)
+    kv = jax.ShapeDtypeStruct((1, T, 2, 16), jnp.float32)
+    kw = dict(window=window, block_q=block, block_k=block, interpret=True)
+    if not hop:
+        assert _grad_text(lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, causal=True, **kw)), q, kv, kv) == want
+        return
+    at = jax.ShapeDtypeStruct((), jnp.int32)
+
+    def hop(q, k, v, qs, ks):  # the name is in the text
+        o, lse = flash_attention_with_lse(q, k, v, q_start=qs, k_start=ks, **kw)
+        return jnp.sum(o) + jnp.sum(lse)
+
+    assert _grad_text(hop, kv, kv, kv, at, at) == want
+
+
+# a head and a sequence of 8,192 at the decoder cells' attention shapes:
+# (window, block) -> pairs visible, computed in whole tiles, by a backward kernel
+PAIRS = {
+    "smallthinker-window": (4096, None, 25_167_872, 31_457_280, 28_311_552),
+    "laguna-window": (512, 512, 4_063_488, 8_126_464, 8_126_464),
+    "global": (None, None, 33_558_528, 37_748_736, 35_651_584),
+}
+
+
+@pytest.mark.parametrize("name", sorted(PAIRS))
+def test_gauges_count_the_pairs_seen_and_the_pairs_computed(name, monkeypatch,
+                                                            tmp_path):
+    window, block, visible, whole, computed = PAIRS[name]
+    kind = "global" if window is None else "window"
+    edge = block or 1024
+    band = _Band(edge, edge, window or 8192, 8192 // edge, 8192 // edge)
+    assert band.pairs(0) == (visible, whole)
+    assert band.pairs(_sub_edge(edge, edge)) == (visible, computed)
+    if block is None:  # what sub-tiles of 256 would leave, were they worth walking
+        assert band.pairs(256)[1] == {"window": 26_738_688, "global": 34_603_008}[kind]
+    monkeypatch.setenv("BFTPU_TELEMETRY", str(tmp_path))
+    telemetry.reset()
+    try:
+        x = jax.ShapeDtypeStruct((1, 8192, 1, 128), jnp.bfloat16)
+        jax.eval_shape(jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, causal=True, window=window, block_q=block, block_k=block,
+            interpret=True).astype(jnp.float32)), (0, 1, 2)), x, x, x)
+        gauges = {g["name"]: g["value"] for g in
+                  telemetry.get_registry().snapshot()["gauges"]}
+    finally:
+        telemetry.reset()
+    assert gauges == {f"attention.pairs_visible_{kind}": visible,
+                      f"attention.pairs_computed_{kind}": computed}

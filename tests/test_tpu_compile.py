@@ -94,16 +94,23 @@ def test_flash_backward_compiles_for_v5e(one_chip, shape, dtype):
 
 
 # the benchmark's window layer (smallthinker-21b-a3b: B2 S8192, 28 heads of
-# 128, window 4096), a window shorter than a block and one past the sequence
-@pytest.mark.parametrize("shape,window", [
-    pytest.param((2, 8192, 28, 128), 4096, id="B2-T8192-H28-D128-W4096"),
-    pytest.param((2, 2048, 4, 128), 256, id="B2-T2048-H4-D128-W256"),
-    pytest.param((2, 2048, 4, 128), 4096, id="B2-T2048-H4-D128-W4096"),
+# 128, window 4096), a window shorter than a block and one past the sequence.
+# The first carries a budget: the bytes of generated code the three kernels
+# had while a cut tile was one masked body of 1024 x 1024 (PR 35's tree).  With
+# the two backward kernels' rolled loop over sub-tiles of 512 they carry
+# 2,743,808; sixteen unrolled bodies a kernel read 22.6 MB, and what is traced,
+# compiled and loaded is paid in every run's set-up (PR 32: 7.7 s).
+@pytest.mark.parametrize("shape,window,code_budget", [
+    pytest.param((2, 8192, 28, 128), 4096, 3_202_560, id="B2-T8192-H28-D128-W4096"),
+    pytest.param((2, 2048, 4, 128), 256, None, id="B2-T2048-H4-D128-W256"),
+    pytest.param((2, 2048, 4, 128), 4096, None, id="B2-T2048-H4-D128-W4096"),
 ])
-def test_banded_flash_kernels_compile_for_v5e(one_chip, shape, window):
+def test_banded_flash_kernels_compile_for_v5e(one_chip, shape, window, code_budget):
     """Forward, dK/dV and dQ with a causal band: the inner grid axis and the
     block index maps are the band's (clamped `lax.div` arithmetic on program
-    ids), which interpret mode cannot refuse and Mosaic can."""
+    ids), and the backward kernels walk a cut tile of 1024 in sub-tiles by a
+    rolled loop (`pl.ds` at a traced multiple of the edge), which interpret
+    mode cannot refuse and Mosaic can."""
     x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
 
     def loss(q, k, v):
@@ -111,10 +118,13 @@ def test_banded_flash_kernels_compile_for_v5e(one_chip, shape, window):
             q, k, v, causal=True, interpret=False, impl="pallas",
             window=window).astype(jnp.float32))
 
-    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(x, x, x).compile().as_text()
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(x, x, x).compile()
+    text = compiled.as_text()
     assert text.count("tpu_custom_call") == 3
     for name in ("flash_fwd_window", "flash_bwd_dkv_window", "flash_bwd_dq_window"):
         assert name in text  # the names the benchmark's readers look up
+    if code_budget is not None:
+        assert compiled.memory_analysis().generated_code_size_in_bytes <= code_budget
 
 
 # the laguna-xs.2 cell's two kinds of layer (B1 S8192, 8 key-value heads of
