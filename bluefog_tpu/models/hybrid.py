@@ -1,0 +1,232 @@
+"""A decoder whose layers mix their tokens by a state-space scan or by
+attention, a kind a layer: the hybrid of IBM's Granite 4.0-H (`model_type`
+granitemoehybrid), whose state-space layer is Mamba-2 (Dao and Gu,
+arXiv:2405.21060).
+
+Every layer is ``h + r Mixer(RMSNorm(h))`` then ``h + r MLP(RMSNorm(h))``
+with one residual multiplier ``r`` and the dense gated MLP of
+:class:`bluefog_tpu.models.transformer._GatedMLP`.  An ``"attention"`` layer
+is grouped-query causal attention over the whole sequence with **no position
+signal**, its scores scaled by ``attention_multiplier`` in place of
+``1 / sqrt(head_dim)``; a ``"mamba"`` layer is :class:`Mamba2Mixer`.  The
+input is ``embedding_multiplier`` times the embedding, the logits are the
+final norm's output times the **same** tensor over ``logits_scaling``; with
+``labels`` the model returns the chunked next-token loss
+(:func:`bluefog_tpu.models.transformer.chunked_softmax_cross_entropy`), so
+:func:`bluefog_tpu.training.make_lm_loss_fns`' identity loss serves it.
+"""
+
+from __future__ import annotations
+
+import math
+from functools import partial
+from typing import Any, Callable, Optional, Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from bluefog_tpu.models.transformer import (
+    RMSNorm,
+    _GatedMLP,
+    _head_matmul,
+    _HeadKernel,
+    chunked_softmax_cross_entropy,
+)
+
+__all__ = ["HybridMambaLM", "Mamba2Mixer", "causal_conv"]
+
+
+def causal_conv(x, kernel, bias):
+    """Depth-wise causal convolution: ``out[:, t] = bias + sum_k kernel[k] *
+    x[:, t - (W - 1) + k]``, zeros before the sequence.  x ``[B, T, C]``,
+    kernel ``[W, C]``; float32 out.  ``W`` shifted multiply-adds, which XLA
+    fuses into one pass."""
+    w, t = kernel.shape[0], x.shape[1]
+    padded = jnp.pad(x.astype(jnp.float32), ((0, 0), (w - 1, 0), (0, 0)))
+    return bias + sum(kernel[k] * padded[:, k:k + t] for k in range(w))
+
+
+class Mamba2Mixer(nn.Module):
+    """``[z, xBC, dt] = u W_in``; ``xBC`` through a causal depth-wise
+    convolution and SiLU; ``[x, B, C] = xBC``; the scan of
+    :func:`bluefog_tpu.kernels.ssd.ssd_scan` with step sizes ``softplus(dt +
+    dt_bias)``; ``w * RMSNorm(y * silu(z))`` over all channels; ``W_out``.
+    No bias but the convolution's.  Step sizes, the decay rates and the two
+    norms in float32, the products in ``dtype``."""
+
+    num_heads: int
+    head_dim: int
+    state_size: int
+    groups: int = 1
+    conv_width: int = 4
+    chunk: int = 256
+    eps: float = 1e-5
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, u):
+        from bluefog_tpu.kernels.ssd import ssd_scan
+
+        B, T, d = u.shape
+        h, p, n, g = self.num_heads, self.head_dim, self.state_size, self.groups
+        inner, conv = h * p, h * p + 2 * g * n
+        init = nn.initializers.normal(0.02)
+        dense = partial(nn.Dense, use_bias=False, dtype=self.dtype, kernel_init=init)
+        with jax.named_scope("ssm_in_proj"):
+            zxbcdt = dense(inner + conv + h, name="in_proj")(u)
+        z, xbc, dt = (zxbcdt[..., :inner], zxbcdt[..., inner:inner + conv],
+                      zxbcdt[..., inner + conv:])
+        with jax.named_scope("ssm_conv"):
+            xbc = jax.nn.silu(causal_conv(
+                xbc, self.param("conv_kernel", init, (self.conv_width, conv), jnp.float32),
+                self.param("conv_bias", nn.initializers.zeros_init(), (conv,),
+                           jnp.float32))).astype(self.dtype)
+        x, bm, cm = (xbc[..., :inner], xbc[..., inner:inner + g * n],
+                     xbc[..., inner + g * n:])
+        ones = nn.initializers.ones_init()
+        dt_bias = self.param("dt_bias", ones, (h,), jnp.float32)
+        a_log = self.param("A_log", ones, (h,), jnp.float32)
+        skip = self.param("D", ones, (h,), jnp.float32)
+        with jax.named_scope("ssm_scan"):
+            y = ssd_scan(x.reshape(B, T, h, p),
+                         jax.nn.softplus(dt.astype(jnp.float32) + dt_bias), a_log,
+                         bm.reshape(B, T, g, n), cm.reshape(B, T, g, n), skip,
+                         chunk=self.chunk)
+        with jax.named_scope("ssm_gate_norm"):
+            gated = y.reshape(B, T, inner).astype(jnp.float32) * jax.nn.silu(
+                z.astype(jnp.float32))
+            gated = RMSNorm(dtype=self.dtype, eps=self.eps, name="norm")(gated)
+        with jax.named_scope("ssm_out_proj"):
+            return dense(d, name="out_proj")(gated)
+
+
+class _AttentionMixer(nn.Module):
+    """Grouped-query causal attention over the whole sequence, no position
+    signal; the kernels read the shared key-value heads in place.  They scale
+    scores by ``1 / sqrt(head_dim)``, so ``q`` carries the rest of ``scale``
+    (exact in bfloat16 where that is a power of two, as Granite's 1/64 on
+    heads of 64)."""
+
+    num_heads: int
+    num_kv_heads: int
+    head_dim: int
+    scale: float
+    dtype: Any
+    attention_fn: Callable  # (q, k, v) -> out, causal
+
+    @nn.compact
+    def __call__(self, u):
+        B, T, d = u.shape
+        H, kvh, hd = self.num_heads, self.num_kv_heads, self.head_dim
+        dense = partial(nn.DenseGeneral, use_bias=False, dtype=self.dtype)
+        q = dense((H, hd), name="q")(u) * (self.scale * math.sqrt(hd))
+        k, v = dense((kvh, hd), name="k")(u), dense((kvh, hd), name="v")(u)
+        with jax.named_scope("attention_global"):
+            att = self.attention_fn(q, k, v)
+        return dense(d, name="o")(att.reshape(B, T, H * hd))
+
+
+class _HybridBlock(nn.Module):
+    mixer: Callable[[], nn.Module]
+    dff: int
+    residual_multiplier: float
+    eps: float
+    dtype: Any
+
+    @nn.compact
+    def __call__(self, h):
+        norm = partial(RMSNorm, dtype=self.dtype, eps=self.eps)
+        r = self.residual_multiplier
+        h = h + (r * self.mixer()(norm(name="mixer_norm")(h))).astype(h.dtype)
+        with jax.named_scope("mlp_dense"):
+            return h + (r * _GatedMLP(self.dff, self.dtype, name="mlp")(
+                norm(name="mlp_norm")(h))).astype(h.dtype)
+
+
+class HybridMambaLM(nn.Module):
+    """The decoder of the module's docstring.  ``layer_kinds`` names each
+    layer's mixer, ``"mamba"`` or ``"attention"``.  ``remat`` recomputes each
+    block in the backward pass (``nn.remat``: a block's input is all that is
+    kept of it).  ``tie_embeddings=False`` gives the head a tensor of its own
+    (``head/kernel``)."""
+
+    vocab_size: int
+    hidden_size: int
+    layer_kinds: Tuple[str, ...]
+    dff: int
+    num_heads: int
+    num_kv_heads: int
+    head_dim: int
+    ssm_heads: int
+    ssm_head_dim: int
+    ssm_state: int
+    ssm_groups: int = 1
+    conv_width: int = 4
+    chunk: int = 256
+    embedding_multiplier: float = 1.0
+    attention_multiplier: Optional[float] = None  # None: 1 / sqrt(head_dim)
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    eps: float = 1e-5
+    tie_embeddings: bool = True
+    remat: bool = True
+    head_chunks: int = 1
+    dtype: Any = jnp.bfloat16
+    attention_fn: Optional[Callable] = None  # None: the flash kernels
+
+    @nn.compact
+    def __call__(self, input_ids, labels=None):
+        from bluefog_tpu.kernels.flash_attention import flash_attention
+        from bluefog_tpu.telemetry import registry as _telemetry
+
+        kinds = tuple(self.layer_kinds)
+        if set(kinds) - {"mamba", "attention"}:
+            raise ValueError(f"layer kinds {sorted(set(kinds))}: 'mamba' or 'attention'")
+        if self.num_heads % self.num_kv_heads:
+            raise ValueError(f"num_heads {self.num_heads} not divisible by "
+                             f"num_kv_heads {self.num_kv_heads}")
+        scale = (self.head_dim ** -0.5 if self.attention_multiplier is None
+                 else self.attention_multiplier)
+        reg = _telemetry.get_registry()
+        if reg.enabled:
+            for name, value in (
+                    ("ssm.layers", kinds.count("mamba")), ("ssm.heads", self.ssm_heads),
+                    ("ssm.head_dim", self.ssm_head_dim), ("ssm.state", self.ssm_state),
+                    ("ssm.groups", self.ssm_groups), ("ssm.chunk", self.chunk),
+                    ("ssm.conv_width", self.conv_width),
+                    ("attention.layers_global", kinds.count("attention")),
+                    ("attention.heads_global", self.num_heads),
+                    ("attention.kv_heads", self.num_kv_heads),
+                    ("attention.scale", scale),
+                    ("lm.tied_head", int(self.tie_embeddings)),
+                    ("lm.remat_blocks", len(kinds) if self.remat else 0)):
+                reg.gauge(name).set(value)
+        mixers = {
+            "mamba": partial(
+                Mamba2Mixer, self.ssm_heads, self.ssm_head_dim, self.ssm_state,
+                self.ssm_groups, self.conv_width, self.chunk, self.eps, self.dtype,
+                name="mixer"),
+            "attention": partial(
+                _AttentionMixer, self.num_heads, self.num_kv_heads, self.head_dim,
+                scale, self.dtype,
+                self.attention_fn or partial(flash_attention, causal=True),
+                name="mixer"),
+        }
+        embed = nn.Embed(self.vocab_size, self.hidden_size, dtype=self.dtype,
+                         embedding_init=nn.initializers.normal(0.02), name="embed")
+        h = (jnp.take(embed.embedding, input_ids, axis=0)
+             * self.embedding_multiplier).astype(self.dtype)
+        block_cls = nn.remat(_HybridBlock) if self.remat else _HybridBlock
+        for i, kind in enumerate(kinds):
+            h = block_cls(mixers[kind], self.dff, self.residual_multiplier, self.eps,
+                          self.dtype, name=f"layer_{i}")(h)
+        h = RMSNorm(dtype=jnp.float32, eps=self.eps, name="final_norm")(h)
+        h = h / self.logits_scaling
+        if self.tie_embeddings:
+            kernel = embed.embedding.T  # one tensor: its gradient sums both uses
+        else:
+            kernel = _HeadKernel(self.vocab_size, name="head")(self.hidden_size)
+        if labels is None:
+            return _head_matmul(h, kernel, jnp.float32)
+        return chunked_softmax_cross_entropy(h, kernel, labels, max(self.head_chunks, 1))

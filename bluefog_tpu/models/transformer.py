@@ -173,12 +173,13 @@ def _rotary(x, positions, base=10000.0, rotary: Optional[Rotary] = None):
 
 class RMSNorm(nn.Module):
     dtype: Any = jnp.float32
+    eps: float = 1e-6
 
     @nn.compact
     def __call__(self, x):
         scale = self.param("scale", nn.initializers.ones_init(), (x.shape[-1],))
         var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
-        return (x.astype(jnp.float32) * jax.lax.rsqrt(var + 1e-6) * scale).astype(
+        return (x.astype(jnp.float32) * jax.lax.rsqrt(var + self.eps) * scale).astype(
             self.dtype
         )
 

@@ -20,7 +20,10 @@ phase that fails raises: the traceback goes to stderr, the last line says
                                      flash kernels reading 8 shared key-value
                                      heads for 64 query heads vs the heads
                                      repeated, the backward kernels' cut tiles
-                                     walked in sub-tiles vs computed whole
+                                     walked in sub-tiles vs computed whole, the
+                                     chunked scan's kernels vs the recurrence
+                                     taken token by token and the flash
+                                     kernels at heads of 64 vs dense attention
     python chip_smoke.py --chips 4   four chips, only what exists across
                                      chips: parity on exp2(4), placement,
                                      ResNet-50 ATC vs allreduce, contraction,
@@ -89,6 +92,10 @@ FULL = dict(
         # as the SmallThinker cell runs them: `_MixedBlock` repeats its heads
         ("smallthinker_window_repeated", 2, 28, 28, 4096, None),
         ("smallthinker_global_repeated", 2, 28, 28, None, None))),
+    # a state-space layer's scan and the attention layer of chipbench's
+    # `granite-4.0-h-micro` at its cell's 8192 tokens
+    ssd=dict(seq=8192, heads=64, head_dim=64, state=128, chunks=(128, 256),
+             calls=20, att_heads=32, att_kv_heads=8, att_head_dim=64),
     probe=dict(dim=4096, iters=512),
 )
 TINY = dict(
@@ -102,6 +109,8 @@ TINY = dict(
                       block=16),
     subtiles=dict(seq=128, head_dim=16, edges=(8, 16), calls=2, shapes=(
         ("window", 2, 4, 2, 40, 32), ("global", 1, 4, 2, None, 32))),
+    ssd=dict(seq=64, heads=4, head_dim=16, state=32, chunks=(8, 16), calls=2,
+             att_heads=4, att_kv_heads=2, att_head_dim=16),
 )
 
 # flash-vs-dense agreement at bf16 compute on seeded weights.  The two paths
@@ -146,9 +155,18 @@ BUCKETS_GAP_RTOL = 1e-6
 # read between two programs of one arithmetic on the v5e (2.3e-3 to 2.6e-3).
 SUBTILES_L2_RTOL = 4e-3
 
+# the chunked scan's kernels (bfloat16 operands, float32 accumulators, step
+# sizes and state in float32) against the recurrence taken one token after
+# another in float32 at `highest`, on the same bfloat16 inputs: relative L2 of
+# the output and of the six gradients.  What is left is the kernels' rounding
+# of the decay-weighted scores and of the carried state to bfloat16 before each
+# product (2^-9 an operand); a chunk's state dropped, a decay applied twice or
+# a head reading another group moves them by 0.1 or more.
+SSD_L2_RTOL = 2e-2
+
 PHASES = ("ops_windows", "resnet_atc", "resnet_allreduce", "contraction",
           "buckets_vs_per_leaf", "decoder", "experts_piled", "shared_heads",
-          "subtiles")
+          "subtiles", "ssd")
 
 
 class _CompileClock:
@@ -847,6 +865,117 @@ def phase_subtiles(cfg, seed, on_tpu, clock):
 
 
 # ---------------------------------------------------------------------------
+# phase: the chunked scan's kernels vs the token recurrence; flash at heads of 64
+# ---------------------------------------------------------------------------
+
+
+def phase_ssd(cfg, seed, on_tpu, clock):
+    """`ssd_scan` (forward and backward Pallas kernels) at the sizes of a
+    state-space layer of the benchmark's `granite-4.0-h-micro` cell, one
+    group, against the recurrence of chipbench's plain reference taken token
+    by token: the output and all six gradients in relative L2, and the host
+    clock over ``calls`` calls of the forward alone and of forward and
+    backward, for each chunk swept.  Then the whole-sequence flash kernels at
+    that cell's attention shape (heads of 64, no cell had run them there)
+    against dense attention, a key-value head's group at a time."""
+    from bluefog_tpu.kernels.flash_attention import flash_attention
+    from bluefog_tpu.kernels.ssd import ssd_scan
+    from bluefog_tpu.models.transformer import dense_attention
+    from chipbench import manifest
+
+    reference = manifest.load_module(os.path.join(
+        manifest.REPO, "chipbench", "reference", "granite-4.0-h-micro.py"))
+    t0 = time.perf_counter()
+    T, H, P, N = cfg["seq"], cfg["heads"], cfg["head_dim"], cfg["state"]
+    keys = jax.random.split(jax.random.PRNGKey(seed), 7)
+    x, g = (jax.random.normal(k, (1, T, H, P), jnp.bfloat16) for k in keys[:2])
+    bm, cm = (0.5 * jax.random.normal(k, (1, T, 1, N), jnp.bfloat16) for k in keys[2:4])
+    # step sizes and rates spread as the configuration's seeded weights spread them
+    dt = jax.nn.softplus(jax.random.normal(keys[4], (1, T, H)) + jnp.log(jnp.expm1(
+        jnp.exp(jax.random.uniform(keys[5], (H,), minval=np.log(1e-3), maxval=np.log(0.1))))))
+    a_log = jnp.log(jax.random.uniform(keys[6], (H,), minval=1.0, maxval=16.0))
+    skip = jnp.ones((H,), jnp.float32)
+    args = (x, dt, a_log, bm, cm, skip)
+    names = ("x", "dt", "A_log", "B", "C", "D")
+
+    def values(fn):
+        def loss(*a):
+            y = fn(*a)
+            return jnp.sum(y.astype(jnp.float32) * g.astype(jnp.float32)), y
+        (_, y), grads = jax.jit(jax.value_and_grad(
+            loss, tuple(range(6)), has_aux=True))(*args)
+        return dict(zip(("y",) + tuple("d" + n for n in names), (y,) + grads))
+
+    def recurrence(x, dt, a_log, bm, cm, skip):
+        f32 = lambda a: a[0].astype(jnp.float32)
+        with jax.default_matmul_precision("highest"):
+            return reference.ssm_scan(f32(x), dt[0], a_log, f32(bm), f32(cm), skip)[None]
+
+    want = values(recurrence)
+    rel, ms = {}, {}
+    for chunk in cfg["chunks"]:
+        scan = lambda *a: ssd_scan(*a, chunk=chunk, interpret=not on_tpu)
+        rel[str(chunk)] = _rel_l2(values(scan), want)
+        fwd = jax.jit(scan)
+        both = jax.jit(jax.grad(lambda *a: jnp.sum(
+            scan(*a).astype(jnp.float32) * g.astype(jnp.float32)), tuple(range(6))))
+        ms[str(chunk)] = {}
+        for kind, fn in (("fwd", fwd), ("fwd_bwd", both)):
+            jax.block_until_ready(fn(*args))
+            t = time.perf_counter()
+            for _ in range(cfg["calls"]):
+                last = fn(*args)
+            jax.block_until_ready(last)
+            ms[str(chunk)][kind] = round((time.perf_counter() - t) / cfg["calls"] * 1e3, 3)
+    _emit("ssd_vs_recurrence", t0, clock, seq=T, heads=H, head_dim=P, state=N,
+          groups=1, interpret=not on_tpu,
+          compared="y and the six gradients of ssd_scan (bfloat16 x, B, C) against "
+                   "the float32 recurrence taken token by token: relative L2; ms a "
+                   "call, host clock, by chunk",
+          rel_l2=rel, ms_per_call=ms, rel_l2_tol=SSD_L2_RTOL)
+    for chunk, gaps in rel.items():
+        for n, gap in gaps.items():
+            assert gap <= SSD_L2_RTOL, (
+                f"chunk {chunk} {n}: {gap} from the recurrence in relative L2")
+
+    t0 = time.perf_counter()
+    HQ, KV, D = cfg["att_heads"], cfg["att_kv_heads"], cfg["att_head_dim"]
+    keys = jax.random.split(jax.random.PRNGKey(seed + 1), 4)
+    q, go = (jax.random.normal(k, (1, T, HQ, D), jnp.bfloat16) for k in keys[:2])
+    k_, v_ = (jax.random.normal(k, (1, T, KV, D), jnp.bfloat16) for k in keys[2:])
+
+    def dense(q, k, v):  # a key-value head with its query heads at a time
+        group = HQ // KV
+        qg = q.reshape(1, T, KV, group, D).transpose(2, 0, 1, 3, 4)
+        one = jax.checkpoint(lambda a: dense_attention(
+            a[0], jnp.repeat(a[1], group, 2), jnp.repeat(a[2], group, 2),
+            causal=True, dtype=jnp.bfloat16))
+        out = jax.lax.map(one, (qg, k.transpose(2, 0, 1, 3)[:, :, :, None],
+                                v.transpose(2, 0, 1, 3)[:, :, :, None]))
+        return out.transpose(1, 2, 0, 3, 4).reshape(q.shape)
+
+    def att(fn):
+        def loss(q, k, v):
+            out = fn(q, k, v)
+            return jnp.sum((out * go).astype(jnp.float32)), out
+        (_, out), grads = jax.jit(jax.value_and_grad(
+            loss, (0, 1, 2), has_aux=True))(q, k_, v_)
+        return dict(zip(("out", "dq", "dk", "dv"), (out,) + grads))
+
+    block = None if T >= 2048 else T // 4
+    rel = _rel_l2(att(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, impl="pallas", interpret=not on_tpu,
+        block_q=block, block_k=block)), att(dense))
+    _emit("flash_heads_of_64_vs_dense", t0, clock, seq=T, heads=HQ, kv_heads=KV,
+          head_dim=D, interpret=not on_tpu,
+          compared="output, dQ, dK, dV of the whole-sequence flash kernels "
+                   "against dense attention, bfloat16: relative L2",
+          rel_l2=rel, rel_l2_tol=LOGITS_L2_RTOL)
+    for n, gap in rel.items():
+        assert gap <= LOGITS_L2_RTOL, f"{n}: {gap} from dense attention in relative L2"
+
+
+# ---------------------------------------------------------------------------
 
 
 def _rebuild_native():
@@ -906,6 +1035,8 @@ def run(args, device):
             phase_shared_heads(sizes["shared_heads"], args.seed, on_tpu, clock)
         if want("subtiles"):
             phase_subtiles(sizes["subtiles"], args.seed, on_tpu, clock)
+        if want("ssd"):
+            phase_ssd(sizes["ssd"], args.seed, on_tpu, clock)
     bf.shutdown()
 
 

@@ -449,7 +449,7 @@ def test_no_width_differs_from_the_source_and_the_cut_is_stated(cell):
     assert entry["reduced"] == cfg["reduced"]
     assert [w["name"] for w in bench["workloads"] if w["chips"] == 4] \
         == ["resnet50-atc-exp2-4chip"]
-    assert len(bench["workloads"]) == 7
+    assert len(bench["workloads"]) >= 7
     named = {p["name"] for p in bench["per_layer"] if CELL in p.get("workloads", [])}
     assert named == {
         "train_step_host_ms_per_step", "attention_ms_per_step", "expert_ms_per_step",

@@ -155,6 +155,44 @@ def test_flash_kernels_read_shared_heads_in_place_for_v5e(one_chip, heads, windo
         q.shape, kv.shape, kv.shape]  # dK and dV leave summed over the group
 
 
+# a state-space layer of the granite-4.0-h-micro cell (B1 S8192, 64 heads of 64
+# on one group, state 128) at the configuration's chunk and at half of it.  The
+# first carries a budget: the bytes of generated code the scan's program had at
+# its first compile (2,742,272: two kernels and the XLA around them), since
+# what a Pallas kernel carries is traced and lowered in every run's set-up
+# (PR 32).
+@pytest.mark.parametrize("chunk,code_budget", [
+    pytest.param(256, 3_000_000, id="B1-T8192-H64-P64-N128-chunk256"),
+    pytest.param(128, None, id="B1-T8192-H64-P64-N128-chunk128"),
+])
+def test_scan_kernels_compile_for_v5e(one_chip, chunk, code_budget):
+    """The chunked scan, forward and backward: two heads of 64 a step in one
+    128-lane block of x, a head's state found in the scratch by its program
+    id, per-token scalars down the rows and along the lanes, transposed
+    products, which interpret mode cannot refuse and Mosaic can.  No matrix of
+    chunk x chunk leaves a kernel for HBM."""
+    from bluefog_tpu.kernels.ssd import ssd_scan
+
+    T, H, P, N = 8192, 64, 64, 128
+    spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    args = (spec((1, T, H, P), jnp.bfloat16), spec((1, T, H), jnp.float32),
+            spec((H,), jnp.float32), spec((1, T, 1, N), jnp.bfloat16),
+            spec((1, T, 1, N), jnp.bfloat16), spec((H,), jnp.float32))
+
+    def loss(*a):
+        return jnp.sum(ssd_scan(*a, chunk=chunk, interpret=False).astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=tuple(range(6)))).lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2
+    for name in ("ssd_chunk_fwd", "ssd_chunk_bwd"):
+        assert name in text  # the names the benchmark's readers look up
+    assert not re.search(rf"f32\[[0-9,]*{chunk},{chunk}\]", text)
+    assert [tuple(o.shape) for o in compiled.out_info] == [a.shape for a in args]
+    if code_budget is not None:
+        assert compiled.memory_analysis().generated_code_size_in_bytes <= code_budget
+
+
 def test_grouped_expert_products_compile_to_xlas_kernel_for_v5e(one_chip):
     """`held_topk_experts` at the benchmark's sizes: the three grouped
     products of a pass and their transposes are XLA's own grouped-matmul
