@@ -97,6 +97,7 @@ from typing import Callable, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -1208,6 +1209,10 @@ def _flash_core_fwd(q, k, v, q_start, k_start, scale, causal, block_q,
         scale=scale, causal=causal, block_q=block_q, block_k=block_k,
         interpret=interpret, tri_delta=tri_delta, impl=impl, window=window,
     )
+    # named for a recomputed block's policy: with the output alone kept the
+    # forward kernel runs again for the logsumexp; outside a checkpoint a
+    # name lowers to nothing
+    o, lse = checkpoint_name(o, "attn_out"), checkpoint_name(lse, "attn_lse")
     return (o, lse), (q, k, v, o, lse, q_start, k_start)
 
 

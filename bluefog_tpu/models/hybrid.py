@@ -25,6 +25,7 @@ from typing import Any, Callable, Optional, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from bluefog_tpu.models.transformer import (
     RMSNorm,
@@ -35,6 +36,20 @@ from bluefog_tpu.models.transformer import (
 )
 
 __all__ = ["HybridMambaLM", "Mamba2Mixer", "causal_conv"]
+
+# What the backward pass of a recomputed block is handed beside the block's
+# input, each a `checkpoint_name` where the value is made: the flash forward's
+# output and logsumexp (`kernels.flash_attention._flash_core_fwd`; with both
+# its kernel does not run again), a mixer's output and the MLP's gate-and-up
+# product (`models.transformer._GatedMLP`).  Made again: the norms, the
+# state-space input projection, the convolution and its SiLU, the softplus,
+# the scan's forward, the gate and its norm, `silu(gate) * up` and the
+# attention layer's q, k and v.  In the order a larger share drops them from
+# the tail: bytes kept over the recomputation spared rise along it.  The next
+# name, "ssm_in_proj", is on its value too and is not kept: with it the
+# granite-4.0-h-micro cell's compiled step asks for 15.13 GB, with these for
+# 13.81 (PERF.md section 6, PR 40: the table, and both read on the chip).
+REMAT_KEEPS = ("attn_out", "attn_lse", "mixer_out", "mlp_gate_up")
 
 
 def causal_conv(x, kernel, bias):
@@ -74,7 +89,8 @@ class Mamba2Mixer(nn.Module):
         init = nn.initializers.normal(0.02)
         dense = partial(nn.Dense, use_bias=False, dtype=self.dtype, kernel_init=init)
         with jax.named_scope("ssm_in_proj"):
-            zxbcdt = dense(inner + conv + h, name="in_proj")(u)
+            zxbcdt = checkpoint_name(dense(inner + conv + h, name="in_proj")(u),
+                                     "ssm_in_proj")
         z, xbc, dt = (zxbcdt[..., :inner], zxbcdt[..., inner:inner + conv],
                       zxbcdt[..., inner + conv:])
         with jax.named_scope("ssm_conv"):
@@ -138,7 +154,8 @@ class _HybridBlock(nn.Module):
     def __call__(self, h):
         norm = partial(RMSNorm, dtype=self.dtype, eps=self.eps)
         r = self.residual_multiplier
-        h = h + (r * self.mixer()(norm(name="mixer_norm")(h))).astype(h.dtype)
+        mixed = checkpoint_name(self.mixer()(norm(name="mixer_norm")(h)), "mixer_out")
+        h = h + (r * mixed).astype(h.dtype)
         with jax.named_scope("mlp_dense"):
             return h + (r * _GatedMLP(self.dff, self.dtype, name="mlp")(
                 norm(name="mlp_norm")(h))).astype(h.dtype)
@@ -147,8 +164,9 @@ class _HybridBlock(nn.Module):
 class HybridMambaLM(nn.Module):
     """The decoder of the module's docstring.  ``layer_kinds`` names each
     layer's mixer, ``"mamba"`` or ``"attention"``.  ``remat`` recomputes each
-    block in the backward pass (``nn.remat``: a block's input is all that is
-    kept of it).  ``tie_embeddings=False`` gives the head a tensor of its own
+    block in the backward pass (``nn.remat``: a block's input and the values
+    ``REMAT_KEEPS`` names are all that is kept of it).
+    ``tie_embeddings=False`` gives the head a tensor of its own
     (``head/kernel``)."""
 
     vocab_size: int
@@ -188,19 +206,33 @@ class HybridMambaLM(nn.Module):
                              f"num_kv_heads {self.num_kv_heads}")
         scale = (self.head_dim ** -0.5 if self.attention_multiplier is None
                  else self.attention_multiplier)
+        keeps = REMAT_KEEPS if self.remat else ()
         reg = _telemetry.get_registry()
         if reg.enabled:
+            tokens, width = input_ids.size, jnp.dtype(self.dtype).itemsize
+            n_att, n_ssm = kinds.count("attention"), kinds.count("mamba")
+            kept = {  # bytes a step under each name, from the shapes where it is made
+                "attn_out": n_att * tokens * self.num_heads * self.head_dim * width,
+                "attn_lse": n_att * tokens * self.num_heads * 4,
+                "mixer_out": len(kinds) * tokens * self.hidden_size * width,
+                "mlp_gate_up": len(kinds) * tokens * 2 * self.dff * width,
+                "ssm_in_proj": n_ssm * tokens * width * (
+                    2 * self.ssm_heads * self.ssm_head_dim
+                    + 2 * self.ssm_groups * self.ssm_state + self.ssm_heads),
+            }
             for name, value in (
-                    ("ssm.layers", kinds.count("mamba")), ("ssm.heads", self.ssm_heads),
+                    ("ssm.layers", n_ssm), ("ssm.heads", self.ssm_heads),
                     ("ssm.head_dim", self.ssm_head_dim), ("ssm.state", self.ssm_state),
                     ("ssm.groups", self.ssm_groups), ("ssm.chunk", self.chunk),
                     ("ssm.conv_width", self.conv_width),
-                    ("attention.layers_global", kinds.count("attention")),
+                    ("attention.layers_global", n_att),
                     ("attention.heads_global", self.num_heads),
                     ("attention.kv_heads", self.num_kv_heads),
                     ("attention.scale", scale),
                     ("lm.tied_head", int(self.tie_embeddings)),
-                    ("lm.remat_blocks", len(kinds) if self.remat else 0)):
+                    ("lm.remat_blocks", len(kinds) if self.remat else 0),
+                    ("lm.remat_kept_names", len(keeps)),
+                    ("lm.remat_kept_mb", sum(kept[k] for k in keeps) / 1e6)):
                 reg.gauge(name).set(value)
         mixers = {
             "mamba": partial(
@@ -217,7 +249,10 @@ class HybridMambaLM(nn.Module):
                          embedding_init=nn.initializers.normal(0.02), name="embed")
         h = (jnp.take(embed.embedding, input_ids, axis=0)
              * self.embedding_multiplier).astype(self.dtype)
-        block_cls = nn.remat(_HybridBlock) if self.remat else _HybridBlock
+        block_cls = _HybridBlock
+        if self.remat:
+            block_cls = nn.remat(_HybridBlock, policy=jax.checkpoint_policies
+                                 .save_only_these_names(*keeps))
         for i, kind in enumerate(kinds):
             h = block_cls(mixers[kind], self.dff, self.residual_multiplier, self.eps,
                           self.dtype, name=f"layer_{i}")(h)

@@ -220,8 +220,9 @@ class _DecoderBlock(nn.Module):
             att = self.attention_fn(q, k, v)
         else:
             att = dense_attention(q, k, v, causal=True, dtype=self.dtype)
-        # named for remat_policy="attn" (save these ~B*T*d bf16 outputs,
-        # recompute everything else — see _remat_block)
+        # named for remat_policy="attn" (see _remat_block); the flash
+        # kernels name their own output and logsumexp, an `attention_fn`
+        # that is not theirs has this name alone
         att = checkpoint_name(att, "attn_out")
         att = att.reshape(att.shape[:2] + (self.num_heads * hd,))
         x = x + nn.Dense(d, use_bias=False, dtype=self.dtype)(att)
@@ -240,8 +241,8 @@ def _remat_block(policy_name):
     outputs: recompute shrinks to elementwise/norm passes at the cost of
     O(layers·B·T·dff) saved activations); "dots_no_batch" =
     ``checkpoint_dots_with_no_batch_dims``, the PaLM-style middle ground;
-    "attn" = save only the named attention outputs (cheapest; measured
-    slower than full remat on the benched v5e — see the dict comment).
+    "attn" = save only the flash kernels' named output and logsumexp
+    (cheapest; see the dict comment).
     """
     if not policy_name:
         return nn.remat(_DecoderBlock)
@@ -249,14 +250,16 @@ def _remat_block(policy_name):
         "dots": jax.checkpoint_policies.checkpoint_dots,
         "dots_no_batch":
             jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims,
-        # save ONLY the named attention outputs (~layers*B*T*d bf16 —
-        # 0.7 GB at the 1b preset).  Hypothesis was sparing the backward
-        # the flash-forward recompute; MEASURED 6.8% SLOWER than full
-        # remat at 1b same-window (14.0k -> 13.1k tok/s, r4): the flash
-        # custom-vjp regenerates its residuals regardless, so the saved
-        # output only displaces fusion.  Kept as a knob for hardware
-        # where attention recompute dominates differently.
-        "attn": jax.checkpoint_policies.save_only_these_names("attn_out"),
+        # save ONLY what the flash forward hands its backward pass, named
+        # in `kernels.flash_attention._flash_core_fwd` (~layers*B*T*d bf16
+        # and a float32 a head a token).  Both, because with the output
+        # alone saved the forward kernel runs again for the logsumexp: four
+        # kernel calls a layer in the gradient's jaxpr, three with both
+        # (tests/test_granite_hybrid.py; on the chip: PERF.md, PR 40, the
+        # Granite cell, which keeps them under its own policy).  Speed
+        # under this preset: not measured by any cell.
+        "attn": jax.checkpoint_policies.save_only_these_names(
+            "attn_out", "attn_lse"),
     }
     return nn.remat(_DecoderBlock, policy=policies[policy_name])
 
@@ -697,7 +700,8 @@ class _GatedMLP(nn.Module):
         wg = self.param("wg", init, (d, self.dff), jnp.float32)
         wu = self.param("wu", init, (d, self.dff), jnp.float32)
         wd = self.param("wd", init, (self.dff, d), jnp.float32)
-        gu = m @ jnp.concatenate([wg, wu], axis=1).astype(self.dtype)
+        gu = checkpoint_name(
+            m @ jnp.concatenate([wg, wu], axis=1).astype(self.dtype), "mlp_gate_up")
         h = jax.nn.silu(gu[..., :self.dff]) * gu[..., self.dff:]
         return h @ wd.astype(self.dtype)
 

@@ -182,12 +182,17 @@ def _grad_text(fn, *args):
 
 # sha256 of the lowered gradient on the parent's tree (84e0f02), from the
 # calls below run there: blocks under 1024 with static offsets, banded and
-# whole-sequence, and 1024 x 1024 blocks with traced offsets (a ring's hop)
+# whole-sequence, and 1024 x 1024 blocks with traced offsets (a ring's hop).
+# Taken again at PR 40, whose names on the forward rule's output and logsumexp
+# lower to nothing but move the number MLIR's symbol table gives two private
+# functions (`@_where_72` -> `_73`, `@floor_divide_76` -> `_77`): with
+# `@name_<n>` written `@name_N` the texts of 6f0fa38 and of PR 40 are equal,
+# character for character, in all four
 @pytest.mark.parametrize("block,window,hop,want", [
-    pytest.param(16, 24, False, "72438abc76060147131576d183377b259e13b0d6ab857b9565528fb5f5983d50", id="band-16"),
-    pytest.param(512, 700, False, "d300a4043d784787eb50f9f8862651ceb67611534fb216be5d7ba8ee3daf75e9", id="band-512"),
-    pytest.param(512, None, False, "094d9c87d32a4f7101a59ef06fd54ff7afe13b1b467f40dfe7a2307c48527756", id="causal-512"),
-    pytest.param(1024, 1500, True, "096c72d215eb85a6bc5b39d1b9116d99ee39a62e22c233d7e762de2aa10da6c4", id="hop-1024"),
+    pytest.param(16, 24, False, "290eaa2d17f7eb64ce4fe4b549d41210499a8ae08ca4739d19e923b84185e899", id="band-16"),
+    pytest.param(512, 700, False, "e53a1d44e14a65ea4106fba2bcbfc45f5121b8cb304cc076612af0a5233e163d", id="band-512"),
+    pytest.param(512, None, False, "8e36c7755df547ba504f14c1e08b0d09d9ef2ce36428e7fe42f8f9280010f39b", id="causal-512"),
+    pytest.param(1024, 1500, True, "b82d4abd9168c3aa5050350cd5ebc1b79bdf8023a0e74dc5736c4f0810dbefb9", id="hop-1024"),
 ])
 def test_small_blocks_and_traced_offsets_lower_to_the_parents_text(
         block, window, hop, want):

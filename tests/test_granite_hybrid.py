@@ -9,6 +9,7 @@ to matter; recomputation changing nothing; the gauges; the configuration file
 against its published source; the FLOP count against a hand count; the new
 readers; the cell's rehearsal through `python -m chipbench` and its controls."""
 
+import collections
 import json
 import os
 import subprocess
@@ -237,6 +238,76 @@ def test_recomputing_the_blocks_changes_no_gradient(cell, seeded_case):
                                    err_msg="/".join(path))
 
 
+ALL_FIVE_NAMES = ("attn_out", "attn_lse", "mixer_out", "mlp_gate_up", "ssm_in_proj")
+
+
+def _count_calls(jaxpr, counts):
+    """Every equation of a jaxpr and of the jaxprs inside it by its primitive,
+    a Pallas kernel by its name (the whole-sequence flash kernels have none)
+    and not by what interpret mode would run in its place."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            counts[f"pallas:{eqn.params['name']}"] += 1
+            continue
+        counts[eqn.primitive.name] += 1
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else (value,):
+                if hasattr(sub, "eqns") or hasattr(sub, "jaxpr"):
+                    _count_calls(getattr(sub, "jaxpr", sub), counts)
+    return counts
+
+
+@pytest.fixture(scope="module")
+def gradient_calls(cell, ref):
+    """`counts(model)`: the kernel calls and products in the jaxpr of the
+    gradient of a two-layer model's loss, one layer of each kind."""
+    sizes = dict(cell.sizes(rehearse=True), num_hidden_layers=2,
+                 layer_types=["mamba", "attention"])
+    params = {p: jnp.zeros(s, jnp.float32)
+              for p, s in ref.param_shapes(sizes)[0].items()}
+    ids = jnp.zeros((1, sizes["seq_len"]), jnp.int32)
+
+    def counts(**changed):
+        model = cell.module("program").build(sizes)["model"].clone(**changed)
+        apply_fn = make_lm_loss_fns(model)[0]
+        grad = jax.grad(lambda p: apply_fn({"params": seeded.nest(p)}, ids, labels=ids))
+        found = _count_calls(jax.make_jaxpr(grad)(params).jaxpr, collections.Counter())
+        return {"flash": found["pallas:None"], "scan_fwd": found["pallas:ssd_chunk_fwd"],
+                "scan_bwd": found["pallas:ssd_chunk_bwd"],
+                "products": found["dot_general"]}
+
+    return counts
+
+
+# what a recomputed block's backward pass makes again, by the names it is
+# handed: the tuple as shipped, the tuple cut from its tail name by name (the
+# order a larger share drops them in), and nothing named (a bare `nn.remat`)
+@pytest.mark.parametrize("names,flash_calls,products_again", [
+    pytest.param(None, 3, 4, id="as-shipped"),
+    pytest.param(5, 3, 3, id="all-five"),
+    pytest.param(4, 3, 4, id="without-ssm_in_proj"),
+    pytest.param(3, 3, 6, id="without-mlp_gate_up"),
+    pytest.param(2, 3, 8, id="without-mixer_out"),
+    pytest.param(1, 4, 8, id="attn_out-alone"),
+    pytest.param(0, 4, 8, id="nothing-kept")])
+def test_a_recomputed_block_makes_again_only_what_it_is_not_handed(
+        gradient_calls, monkeypatch, names, flash_calls, products_again):
+    """With the flash forward's output and logsumexp both kept its kernel runs
+    three times in the gradient (forward, dK/dV, dQ) and not four; with the
+    output alone it runs again for the logsumexp.  Of a block's products all
+    five names leave the attention layer's q, k and v to be made again (3);
+    `in_proj` (1: the shipped tuple), the two layers' gate-and-up (2) and
+    `out_proj` and `o` (2) join them as their names go.  The scan's forward
+    runs twice whatever is kept (PERF.md section 7)."""
+    if names is not None:
+        monkeypatch.setattr(hybrid, "REMAT_KEEPS", ALL_FIVE_NAMES[:names])
+    plain, again = gradient_calls(remat=False), gradient_calls()
+    assert plain["flash"] == 3 and plain["scan_fwd"] == plain["scan_bwd"] == 1
+    assert again["flash"] == flash_calls
+    assert (again["scan_fwd"], again["scan_bwd"]) == (2, 1)
+    assert again["products"] - plain["products"] == products_again
+
+
 def test_three_steps_of_adamw_as_the_cells_correct_compares_them(cell, ref):
     """The float32 program's first three steps under the mix's optimizer
     against `check.reference_run`, every number the cell's LIMITS name."""
@@ -282,7 +353,10 @@ WANTED_GAUGES = {
     "ssm.groups": 1, "ssm.chunk": 16, "ssm.conv_width": 4,
     "attention.layers_global": 1, "attention.heads_global": 4,
     "attention.kv_heads": 2, "attention.scale": 0.015625, "lm.tied_head": 1,
-    "lm.remat_blocks": 3}
+    "lm.remat_blocks": 3,
+    # bfloat16 of 32 tokens: an attention layer's [4, 32, 16] and float32
+    # [4, 32], three layers' [32, 64] and [32, 192]
+    "lm.remat_kept_names": 4, "lm.remat_kept_mb": (4096 + 512 + 12288 + 36864) / 1e6}
 
 
 def test_the_model_sets_its_gauges(cell, monkeypatch, tmp_path):

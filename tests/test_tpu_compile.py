@@ -13,6 +13,7 @@ different collections.  All TPU compiles live in this one file so that a
 single worker loads the library.
 """
 
+import importlib
 import os
 import re
 
@@ -191,6 +192,49 @@ def test_scan_kernels_compile_for_v5e(one_chip, chunk, code_budget):
     assert [tuple(o.shape) for o in compiled.out_info] == [a.shape for a in args]
     if code_budget is not None:
         assert compiled.memory_analysis().generated_code_size_in_bytes <= code_budget
+
+
+# blocks of the granite-4.0-h-micro cell (B1 S8192, hidden 2048, MLP 8192; 64
+# scan heads of 64 with a state of 128; 32 query heads on 8 of 64) recomputed
+# under the model's own policy: one of each kind, and two state-space blocks,
+# where the first one's kept values stand while the second's backward pass
+# runs.  The budgets are the temporaries' bytes read at PR 40 (851,702,272,
+# 803,047,936 and 1,090,421,760) plus 5 %.  One block alone hardly feels the
+# tuple; the pair read 1,231,030,784 with "ssm_in_proj" kept too and
+# 1,288,187,392 without "mlp_gate_up" (a block's working set grows as it is
+# handed less): a change to `hybrid.REMAT_KEEPS`, or to what a name is put on,
+# shows here in bytes before it shows on the chip as an out-of-memory.
+@pytest.mark.parametrize("kinds,kernel_calls,temp_budget", [
+    pytest.param(("mamba",), 3, 894_300_000, id="state-space-block"),
+    pytest.param(("attention",), 3, 843_200_000, id="attention-block"),
+    pytest.param(("mamba", "mamba"), 6, 1_144_900_000, id="two-state-space-blocks"),
+])
+def test_recomputed_granite_blocks_keep_what_their_policy_names_for_v5e(
+        one_chip, monkeypatch, kinds, kernel_calls, temp_budget):
+    """Three kernel calls in a block's gradient: the flash forward, dK/dV and
+    dQ, the forward not run again because its output and logsumexp are both
+    kept; the scan's forward, its forward again and its backward, because the
+    scan's own residuals are not among the names."""
+    from bluefog_tpu.models.hybrid import HybridMambaLM
+
+    # the model calls its kernels with their defaults, which ask the backend:
+    # the CPU here.  Steered in the test, not through an option of the program
+    for module in ("flash_attention", "ssd"):
+        monkeypatch.setattr(importlib.import_module(f"bluefog_tpu.kernels.{module}"),
+                            "_default_interpret", lambda: False)
+    model = HybridMambaLM(
+        vocab_size=256, hidden_size=2048, layer_kinds=kinds, dff=8192,
+        num_heads=32, num_kv_heads=8, head_dim=64, ssm_heads=64, ssm_head_dim=64,
+        ssm_state=128, attention_multiplier=1 / 64, residual_multiplier=0.22)
+    ids = jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=one_chip)
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0), ids))
+    compiled = jax.jit(jax.grad(
+        lambda p, i: model.apply(p, i, labels=i))).lower(params, ids).compile()
+    assert compiled.as_text().count("tpu_custom_call") == kernel_calls
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= temp_budget, temp
 
 
 def test_grouped_expert_products_compile_to_xlas_kernel_for_v5e(one_chip):
