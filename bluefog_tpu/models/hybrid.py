@@ -88,18 +88,20 @@ class Mamba2Mixer(nn.Module):
         inner, conv = h * p, h * p + 2 * g * n
         init = nn.initializers.normal(0.02)
         dense = partial(nn.Dense, use_bias=False, dtype=self.dtype, kernel_init=init)
+        # each scope holds the slices of what it made: a slice outside would
+        # be the mixer's by its path and no scope's
         with jax.named_scope("ssm_in_proj"):
             zxbcdt = checkpoint_name(dense(inner + conv + h, name="in_proj")(u),
                                      "ssm_in_proj")
-        z, xbc, dt = (zxbcdt[..., :inner], zxbcdt[..., inner:inner + conv],
-                      zxbcdt[..., inner + conv:])
+            z, xbc, dt = (zxbcdt[..., :inner], zxbcdt[..., inner:inner + conv],
+                          zxbcdt[..., inner + conv:])
         with jax.named_scope("ssm_conv"):
             xbc = jax.nn.silu(causal_conv(
                 xbc, self.param("conv_kernel", init, (self.conv_width, conv), jnp.float32),
                 self.param("conv_bias", nn.initializers.zeros_init(), (conv,),
                            jnp.float32))).astype(self.dtype)
-        x, bm, cm = (xbc[..., :inner], xbc[..., inner:inner + g * n],
-                     xbc[..., inner + g * n:])
+            x, bm, cm = (xbc[..., :inner], xbc[..., inner:inner + g * n],
+                         xbc[..., inner + g * n:])
         ones = nn.initializers.ones_init()
         dt_bias = self.param("dt_bias", ones, (h,), jnp.float32)
         a_log = self.param("A_log", ones, (h,), jnp.float32)

@@ -148,6 +148,7 @@ def rotary_frequencies(dims: int, base: float, *, factor: float = 1.0,
     return Rotary(tuple(freq.tolist()), 0.1 * math.log(factor) + 1.0)
 
 
+@jax.named_scope("attention_rotary")
 def _rotary(x, positions, base=10000.0, rotary: Optional[Rotary] = None):
     """Rotary position embedding (half-split convention); x: [B, T, H, D],
     positions: [T].  With ``rotary`` its frequencies and factor take the
@@ -363,6 +364,7 @@ def _head_matmul(x, kernel, dtype):
     )
 
 
+@jax.named_scope("lm_head_loss")
 def chunked_softmax_cross_entropy(hidden, kernel, labels, num_chunks,
                                   dtype=jnp.float32, onehot_targets=False,
                                   kernel_constraint=None):

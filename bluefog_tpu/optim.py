@@ -155,7 +155,9 @@ def adapt_then_combine_spmd(
         kw = {} if grad_order is None else {"order": grad_order}
         with jax.named_scope("gossip_combine"):
             combined = maybe_comm(adapted, state.step, **kw)
-        out = jax.tree_util.tree_map(lambda c, p: (c - p).astype(p.dtype), combined, params)
+        with jax.named_scope("optimizer_update"):
+            out = jax.tree_util.tree_map(
+                lambda c, p: (c - p).astype(p.dtype), combined, params)
         return out, GossipState(base=base_state, step=state.step + 1)
 
     return optax.GradientTransformationExtraArgs(init, update)
@@ -180,9 +182,10 @@ def adapt_with_combine_spmd(
             updates, base_state = base.update(grads, state.base, params)
         with jax.named_scope("gossip_combine"):
             combined = maybe_comm(params, state.step)
-        out = jax.tree_util.tree_map(
-            lambda c, u, p: (c + u - p).astype(p.dtype), combined, updates, params
-        )
+        with jax.named_scope("optimizer_update"):
+            out = jax.tree_util.tree_map(
+                lambda c, u, p: (c + u - p).astype(p.dtype), combined, updates, params
+            )
         return out, GossipState(base=base_state, step=state.step + 1)
 
     return optax.GradientTransformation(init, update)

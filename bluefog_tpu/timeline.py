@@ -22,6 +22,11 @@ Otherwise a span costs one ``is_enabled()`` check and reads no clock.
 
 ``timeline_start_activity`` / ``timeline_end_activity`` mirror the
 reference's custom-span toggles [U].
+
+Beside the host's spans the recorder keeps what the program knows of its own
+compiled step: :func:`step_scopes` says, for every instruction the device
+executes, which ``jax.named_scope`` and module it was traced in.  A device
+trace names its ops by those instructions, so the two join by name.
 """
 
 from __future__ import annotations
@@ -31,11 +36,13 @@ import collections
 import itertools
 import json
 import os
+import re
 import signal
 import threading
 import time
-from typing import List, NamedTuple, Optional, Union
+from typing import Dict, List, NamedTuple, Optional, Tuple, Union
 
+import jax
 import jax.profiler
 
 from bluefog_tpu.common.logging_util import logger
@@ -46,6 +53,9 @@ __all__ = [
     "timeline_context",
     "spans",
     "Span",
+    "step_scopes",
+    "StepProgram",
+    "ScopedOp",
     "TimelineWriter",
 ]
 
@@ -301,3 +311,128 @@ class timeline_context:
             w.record(self.name, self._start * 1e6 - w._t0 / 1e3,
                      (end - self._start) * 1e6,
                      tid=threading.get_ident() & 0x7FFFFFFF)
+
+
+# -- the compiled step's scopes -------------------------------------------------
+
+
+class ScopedOp(NamedTuple):
+    """One instruction of a compiled step that the device executes.  ``name``
+    is the instruction's (``fusion.14``: a device trace names the op
+    ``%fusion.14 = ...``); ``path`` the ``op_name`` the compiler kept for it,
+    ``jit(local_step)/forward_backward/.../layer_0/mlp_dense/up/dot_general``
+    (a fusion has one path, that of the op it was built around; empty where
+    the compiler kept none); ``within`` the ``while``, ``conditional`` or
+    ``call`` instruction whose computation holds it, None in the entry
+    computation.  ``backward``: traced by the gradient's transposition;
+    ``recomputed``: in a ``jax.checkpoint`` / ``nn.remat`` block's second
+    forward pass."""
+
+    name: str
+    path: str
+    within: Optional[str]
+    backward: bool
+    recomputed: bool
+
+
+class StepProgram(NamedTuple):
+    """A step program's module name (``jit_local_step``: a device trace names
+    each execution ``jit_local_step(<hash>)``) and its instructions."""
+
+    module: str
+    ops: Tuple[ScopedOp, ...]
+
+
+# how this JAX writes the two passes into an op's path
+BACKWARD_MARK = "transpose("
+RECOMPUTED_MARK = "rematted_computation"
+STEP_PROGRAMS = 8  # programs kept: a process that builds steps forever must not grow
+
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([^\s(]+) \(.*\{$")
+_INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%?([^\s=]+) = (.*)$")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_CALLED = re.compile(
+    r"\b(?:body|condition|to_apply|true_computation|false_computation)=%?([^\s,)}]+)")
+_BRANCHES = re.compile(r"\bbranch_computations=\{([^}]*)\}")
+# `<type> <opcode>(<operands>)`: in a type, tuple or not, no word is followed
+# by a bracket after a space (a layout's `T(8,128)` follows a colon or a bracket)
+_OPCODE = re.compile(r" ([a-z][a-z-]*)\(")
+_HOLDS_OTHERS = ("while", "conditional", "call")
+
+_step_programs = collections.deque(maxlen=STEP_PROGRAMS)  # [jitted, avals, record]
+
+
+def _register_step_program(jitted, args) -> None:
+    """Called by the train step when it builds the program of a new state
+    structure, with the arguments of that first call.  Keeps the function and
+    the arguments' shapes, types and shardings; compiles and reads nothing.
+    A call that is itself being traced (``jax.jit(step_fn)``) runs no program
+    of its own and registers none."""
+    leaves = jax.tree_util.tree_leaves(args)
+    if any(isinstance(a, jax.core.Tracer) for a in leaves):
+        return
+    # an array that was never placed goes wherever the program wants it: its
+    # sharding says where it happens to be, and would pin it there
+    avals = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype,
+            sharding=a.sharding if getattr(a, "committed", False) else None), args)
+    _step_programs.append([jitted, avals, None])
+
+
+def _parse_step_program(text: str) -> StepProgram:
+    """The record of a compiled module's text: the entry computation's
+    instructions and, below an instruction that holds others, its
+    computations', to any depth.  A fusion's inside is not walked (the
+    device runs a fusion as one op), nor a reduction's or a sort's scalar
+    function."""
+    module = text.split(None, 2)[1].rstrip(",") if text.startswith("HloModule") else ""
+    computations: Dict[str, List[Tuple[str, str]]] = {}
+    entry = current = None
+    for line in text.splitlines():
+        if current is None:
+            m = _COMPUTATION.match(line)
+            if m:
+                current = computations.setdefault(m.group(1), [])
+                if line.startswith("ENTRY"):
+                    entry = m.group(1)
+        elif line.startswith("}"):
+            current = None
+        else:
+            m = _INSTRUCTION.match(line)
+            if m:
+                current.append(m.groups())
+    ops: List[ScopedOp] = []
+
+    def walk(computation, within):
+        for name, rest in computations.get(computation, ()):
+            found = _OP_NAME.search(rest)
+            path = found.group(1) if found else ""
+            ops.append(ScopedOp(name, path, within, BACKWARD_MARK in path,
+                                RECOMPUTED_MARK in path))
+            opcode = _OPCODE.search(" " + rest)
+            if opcode and opcode.group(1) in _HOLDS_OTHERS:
+                called = _CALLED.findall(rest)
+                for group in _BRANCHES.findall(rest):
+                    called += [c.strip().lstrip("%") for c in group.split(",")]
+                for c in called:
+                    walk(c, name)
+
+    if entry is not None:
+        walk(entry, None)
+    return StepProgram(module, tuple(ops))
+
+
+def step_scopes() -> List[StepProgram]:
+    """For each step program this process has built (the newest
+    ``STEP_PROGRAMS``), where every instruction of its compiled module came
+    from.  A record is made when it is first read and kept: the registered
+    function is lowered and compiled for the registered shapes (the
+    executable of the step that ran, from JAX's caches, where the call's
+    arguments were laid out as the first call's), and the module's text is
+    read.  Nothing is done inside a step."""
+    for entry in _step_programs:
+        if entry[2] is None:
+            jitted, avals, _ = entry
+            entry[2] = _parse_step_program(jitted.lower(*avals).compile().as_text())
+    return [entry[2] for entry in _step_programs]

@@ -36,7 +36,7 @@ from bluefog_tpu.optim import (
     make_spmd_comm_fn,
 )
 from bluefog_tpu.telemetry import registry as _telemetry
-from bluefog_tpu.timeline import timeline_context
+from bluefog_tpu.timeline import _register_step_program, timeline_context
 
 __all__ = [
     "apply_accepts_labels",
@@ -258,7 +258,8 @@ def make_decentralized_train_step(
             updates, new_os = tx.update(grads, os_, p, grad_order=order)
         if communication_type == CommunicationType.neighbor_allreduce:
             _note_gossip_grouping(p, order, plan)
-        new_p = optax.apply_updates(p, updates)
+        with jax.named_scope("optimizer_update"):
+            new_p = optax.apply_updates(p, updates)
         if logits.ndim >= 2:
             acc = jnp.mean((jnp.argmax(logits, -1) == y).astype(jnp.float32))
         else:
@@ -268,13 +269,18 @@ def make_decentralized_train_step(
             acc = jnp.full_like(loss, jnp.nan)
         # re-attach the rank-major axis
         expand = lambda t: jax.tree_util.tree_map(lambda a: a[None], t)
-        new_os_out = jax.tree_util.tree_map(
-            lambda new, old: new[None] if old.ndim >= 1 and old.shape[0] == 1 else new,
-            new_os,
-            opt_state,
-        )
+        # the compiler fuses a leaf's update with what follows it and names
+        # the fusion after its last op: the new state's axis goes back on
+        # inside the scope, or the optimizer's fusions carry no scope at all
+        with jax.named_scope("optimizer_update"):
+            new_os_out = jax.tree_util.tree_map(
+                lambda new, old: new[None] if old.ndim >= 1 and old.shape[0] == 1 else new,
+                new_os,
+                opt_state,
+            )
+            new_p = expand(new_p)
         return (
-            expand(new_p),
+            new_p,
             expand(new_bs),
             new_os_out,
             expand(loss),
@@ -360,6 +366,9 @@ def make_decentralized_train_step(
                     ),
                     donate_argnums=(0, 1, 2) if donate else (),
                 )
+                # what timeline.step_scopes() is read from, noted here once
+                _register_step_program(
+                    compiled[key], (params, batch_stats, opt_state, batch, labels))
             reg = _telemetry.get_registry()
             if reg.enabled:
                 # one host call may run several fused sub-steps

@@ -430,7 +430,11 @@ def test_no_width_differs_from_the_source_and_the_cut_is_stated(cell):
     assert named == {
         "train_step_host_ms_per_step", "attention_ms_per_step",
         "attention_global_ms_per_step", "ssm_scan_ms_per_step",
-        "ssd_chunk_fwd_roofline", "ssd_chunk_bwd_roofline"}
+        "ssd_chunk_fwd_roofline", "ssd_chunk_bwd_roofline",
+        # PR 41: the step's split by scope
+        "optimizer_ms_per_step", "head_loss_ms_per_step", "mlp_ms_per_step",
+        "attention_proj_ms_per_step", "ssm_mixer_ms_per_step",
+        "recompute_ms_per_step", "unscoped_ms_per_step"}
 
 
 PUBLISHED = {

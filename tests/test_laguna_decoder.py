@@ -455,7 +455,11 @@ def test_no_width_differs_from_the_source_and_the_cut_is_stated(cell):
         "train_step_host_ms_per_step", "attention_ms_per_step", "expert_ms_per_step",
         "flash_fwd_window_roofline", "flash_bwd_dkv_window_roofline",
         "flash_bwd_dq_window_roofline", "attention_window_ms_per_step",
-        "attention_global_ms_per_step"}
+        "attention_global_ms_per_step",
+        # PR 41: the step's split by scope
+        "optimizer_ms_per_step", "head_loss_ms_per_step", "mlp_ms_per_step",
+        "attention_proj_ms_per_step", "expert_dispatch_ms_per_step",
+        "unscoped_ms_per_step"}
 
 
 # ---- the FLOP count and the readers -----------------------------------------
