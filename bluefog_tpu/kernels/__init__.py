@@ -5,6 +5,15 @@ combines in ``bluefog/common/nccl_controller.cc`` [U], fused MPI combine
 loops in ``mpi_controller.cc`` [U]); the TPU-native analogue is Pallas —
 kernels compiled straight to Mosaic for the MXU/VPU, fused with XLA around
 them.
+
+``flash_attention``: causal attention, whole-sequence and banded, with the
+shared key-value heads read in place.  ``ssd``: the chunked state-space scan
+of a Mamba-2 layer (:func:`bluefog_tpu.kernels.ssd.ssd_scan`).
+``causal_conv``: that layer's depth-wise causal convolution with its bias and
+SiLU as one pass each way
+(:func:`bluefog_tpu.kernels.causal_conv.causal_conv_silu`).  Each has a
+``custom_vjp`` whose backward pass is a kernel too, and runs in Pallas'
+interpret mode off the TPU.
 """
 
 from bluefog_tpu.kernels.flash_attention import (

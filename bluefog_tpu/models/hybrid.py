@@ -35,7 +35,7 @@ from bluefog_tpu.models.transformer import (
     chunked_softmax_cross_entropy,
 )
 
-__all__ = ["HybridMambaLM", "Mamba2Mixer", "causal_conv"]
+__all__ = ["HybridMambaLM", "Mamba2Mixer", "causal_conv", "conv_silu"]
 
 # What the backward pass of a recomputed block is handed beside the block's
 # input, each a `checkpoint_name` where the value is made: the flash forward's
@@ -55,16 +55,52 @@ REMAT_KEEPS = ("attn_out", "attn_lse", "mixer_out", "mlp_gate_up")
 def causal_conv(x, kernel, bias):
     """Depth-wise causal convolution: ``out[:, t] = bias + sum_k kernel[k] *
     x[:, t - (W - 1) + k]``, zeros before the sequence.  x ``[B, T, C]``,
-    kernel ``[W, C]``; float32 out.  ``W`` shifted multiply-adds, which XLA
-    fuses into one pass."""
+    kernel ``[W, C]``; float32 out.  The definition, and the path of the
+    shapes that :mod:`bluefog_tpu.kernels.causal_conv` does not tile: ``W``
+    shifted multiply-adds over a padded float32 copy, which the TPU's compiler
+    does not make one pass of (every slice starts off the 8-row tile: 1.36 ms
+    a layer forward at 8,192 x 4,352 where the bytes ask for 0.17; PERF.md
+    section 6, PR 42)."""
     w, t = kernel.shape[0], x.shape[1]
     padded = jnp.pad(x.astype(jnp.float32), ((0, 0), (w - 1, 0), (0, 0)))
     return bias + sum(kernel[k] * padded[:, k:k + t] for k in range(w))
 
 
+def conv_kernels_take(tokens, inner, group_states, width):
+    """Whether a state-space layer's convolution goes through the kernels of
+    :mod:`bluefog_tpu.kernels.causal_conv` or through :func:`causal_conv`: by
+    the shapes alone (the scan's ``inner`` channels and its ``2 *
+    group_states`` of B and C each in whole 128-lane blocks, the tokens in
+    whole 8-row tiles)."""
+    from bluefog_tpu.kernels.causal_conv import tiles
+
+    return (tiles(tokens, inner, width, inner)
+            and tiles(tokens, 2 * group_states, width, 2 * inner))
+
+
+def conv_silu(zxbcdt, taps, bias, inner):
+    """``silu(causal_conv(xBC))`` of a Mamba-2 layer in ``zxbcdt``'s type,
+    ``xBC`` the channels ``inner .. inner + C`` of the input projection's
+    output ``zxbcdt``: the scan's ``x`` (``inner`` channels) and its ``[B,
+    C]``.  Where the kernels take the shapes, two calls, a channel's
+    convolution being its own: each reads its channels where the product left
+    them and writes what the scan reads, so that no slice of ``[T, C]`` is
+    made before or after."""
+    from bluefog_tpu.kernels.causal_conv import causal_conv_silu
+
+    width, conv = taps.shape
+    if conv_kernels_take(zxbcdt.shape[1], inner, (conv - inner) // 2, width):
+        return (causal_conv_silu(zxbcdt, taps[:, :inner], bias[:inner], offset=inner),
+                causal_conv_silu(zxbcdt, taps[:, inner:], bias[inner:],
+                                 offset=2 * inner))
+    xbc = jax.nn.silu(causal_conv(
+        zxbcdt[..., inner:inner + conv], taps, bias)).astype(zxbcdt.dtype)
+    return xbc[..., :inner], xbc[..., inner:]
+
+
 class Mamba2Mixer(nn.Module):
     """``[z, xBC, dt] = u W_in``; ``xBC`` through a causal depth-wise
-    convolution and SiLU; ``[x, B, C] = xBC``; the scan of
+    convolution and SiLU (:func:`conv_silu`); ``[x, B, C] = xBC``; the scan of
     :func:`bluefog_tpu.kernels.ssd.ssd_scan` with step sizes ``softplus(dt +
     dt_bias)``; ``w * RMSNorm(y * silu(z))`` over all channels; ``W_out``.
     No bias but the convolution's.  Step sizes, the decay rates and the two
@@ -93,15 +129,14 @@ class Mamba2Mixer(nn.Module):
         with jax.named_scope("ssm_in_proj"):
             zxbcdt = checkpoint_name(dense(inner + conv + h, name="in_proj")(u),
                                      "ssm_in_proj")
-            z, xbc, dt = (zxbcdt[..., :inner], zxbcdt[..., inner:inner + conv],
-                          zxbcdt[..., inner + conv:])
+            z, dt = zxbcdt[..., :inner], zxbcdt[..., inner + conv:]
         with jax.named_scope("ssm_conv"):
-            xbc = jax.nn.silu(causal_conv(
-                xbc, self.param("conv_kernel", init, (self.conv_width, conv), jnp.float32),
+            x, bc = conv_silu(
+                zxbcdt,
+                self.param("conv_kernel", init, (self.conv_width, conv), jnp.float32),
                 self.param("conv_bias", nn.initializers.zeros_init(), (conv,),
-                           jnp.float32))).astype(self.dtype)
-            x, bm, cm = (xbc[..., :inner], xbc[..., inner:inner + g * n],
-                         xbc[..., inner + g * n:])
+                           jnp.float32), inner)
+            bm, cm = bc[..., :g * n], bc[..., g * n:]
         ones = nn.initializers.ones_init()
         dt_bias = self.param("dt_bias", ones, (h,), jnp.float32)
         a_log = self.param("A_log", ones, (h,), jnp.float32)
@@ -227,6 +262,9 @@ class HybridMambaLM(nn.Module):
                     ("ssm.head_dim", self.ssm_head_dim), ("ssm.state", self.ssm_state),
                     ("ssm.groups", self.ssm_groups), ("ssm.chunk", self.chunk),
                     ("ssm.conv_width", self.conv_width),
+                    ("ssm.conv_kernel_layers", n_ssm * conv_kernels_take(
+                        input_ids.shape[1], self.ssm_heads * self.ssm_head_dim,
+                        self.ssm_groups * self.ssm_state, self.conv_width)),
                     ("attention.layers_global", n_att),
                     ("attention.heads_global", self.num_heads),
                     ("attention.kv_heads", self.num_kv_heads),

@@ -23,7 +23,9 @@ phase that fails raises: the traceback goes to stderr, the last line says
                                      walked in sub-tiles vs computed whole, the
                                      chunked scan's kernels vs the recurrence
                                      taken token by token and the flash
-                                     kernels at heads of 64 vs dense attention
+                                     kernels at heads of 64 vs dense attention,
+                                     the causal convolution's kernels vs the
+                                     reference's expression in float32
     python chip_smoke.py --chips 4   four chips, only what exists across
                                      chips: parity on exp2(4), placement,
                                      ResNet-50 ATC vs allreduce, contraction,
@@ -96,6 +98,9 @@ FULL = dict(
     # `granite-4.0-h-micro` at its cell's 8192 tokens
     ssd=dict(seq=8192, heads=64, head_dim=64, state=128, chunks=(128, 256),
              calls=20, att_heads=32, att_kv_heads=8, att_head_dim=64),
+    # a state-space layer's convolution of that cell: the 8,512-wide product
+    # of its input projection, 4,096 channels of x and 128 each of B and C
+    conv=dict(seq=8192, inner=4096, states=128, heads=64, width=4, calls=20),
     probe=dict(dim=4096, iters=512),
 )
 TINY = dict(
@@ -111,6 +116,7 @@ TINY = dict(
         ("window", 2, 4, 2, 40, 32), ("global", 1, 4, 2, None, 32))),
     ssd=dict(seq=64, heads=4, head_dim=16, state=32, chunks=(8, 16), calls=2,
              att_heads=4, att_kv_heads=2, att_head_dim=16),
+    conv=dict(seq=64, inner=128, states=64, heads=8, width=4, calls=2),
 )
 
 # flash-vs-dense agreement at bf16 compute on seeded weights.  The two paths
@@ -164,9 +170,16 @@ SUBTILES_L2_RTOL = 4e-3
 # a head reading another group moves them by 0.1 or more.
 SSD_L2_RTOL = 2e-2
 
+# the convolution's kernels (`kernels/causal_conv.py`: bfloat16 in and out,
+# float32 between) against the plain reference's expression and a SiLU in
+# float32 on the same bfloat16 input: what is left is the one rounding of the
+# output and of dx to bfloat16 (2^-9 an element); a tap off by a row or a block
+# that does not see its neighbour moves them by 0.1 or more.
+CONV_L2_RTOL = 2e-2
+
 PHASES = ("ops_windows", "resnet_atc", "resnet_allreduce", "contraction",
           "buckets_vs_per_leaf", "decoder", "experts_piled", "shared_heads",
-          "subtiles", "ssd")
+          "subtiles", "ssd", "conv")
 
 
 class _CompileClock:
@@ -206,6 +219,16 @@ def _rel_l2(got, want):
     return {n: float(jnp.linalg.norm((got[n] - want[n]).astype(jnp.float32))
                      / jnp.linalg.norm(want[n].astype(jnp.float32)))
             for n in got}
+
+
+def _ms_a_call(fn, args, calls):
+    """Host clock over ``calls`` calls of a compiled ``fn``, ms a call."""
+    jax.block_until_ready(fn(*args))
+    t = time.perf_counter()
+    for _ in range(calls):
+        last = fn(*args)
+    jax.block_until_ready(last)
+    return round((time.perf_counter() - t) / calls * 1e3, 3)
 
 
 def _exp2_mixing_matrix(n):
@@ -919,14 +942,8 @@ def phase_ssd(cfg, seed, on_tpu, clock):
         fwd = jax.jit(scan)
         both = jax.jit(jax.grad(lambda *a: jnp.sum(
             scan(*a).astype(jnp.float32) * g.astype(jnp.float32)), tuple(range(6))))
-        ms[str(chunk)] = {}
-        for kind, fn in (("fwd", fwd), ("fwd_bwd", both)):
-            jax.block_until_ready(fn(*args))
-            t = time.perf_counter()
-            for _ in range(cfg["calls"]):
-                last = fn(*args)
-            jax.block_until_ready(last)
-            ms[str(chunk)][kind] = round((time.perf_counter() - t) / cfg["calls"] * 1e3, 3)
+        ms[str(chunk)] = {kind: _ms_a_call(fn, args, cfg["calls"])
+                          for kind, fn in (("fwd", fwd), ("fwd_bwd", both))}
     _emit("ssd_vs_recurrence", t0, clock, seq=T, heads=H, head_dim=P, state=N,
           groups=1, interpret=not on_tpu,
           compared="y and the six gradients of ssd_scan (bfloat16 x, B, C) against "
@@ -973,6 +990,102 @@ def phase_ssd(cfg, seed, on_tpu, clock):
           rel_l2=rel, rel_l2_tol=LOGITS_L2_RTOL)
     for n, gap in rel.items():
         assert gap <= LOGITS_L2_RTOL, f"{n}: {gap} from dense attention in relative L2"
+
+
+# ---------------------------------------------------------------------------
+# phase: the causal convolution's kernels vs the expression, and a plain candidate
+# ---------------------------------------------------------------------------
+
+
+def _rolled_conv(x, kernel, bias):
+    """`hybrid.causal_conv` with no pad and no slice off the tile: the input
+    widened, rolled along the tokens a tap at a time, the rows that came
+    round masked.  The plain candidate the kernels are timed against."""
+    w = kernel.shape[0]
+    wide = x.astype(jnp.float32)
+    row = jax.lax.broadcasted_iota(jnp.int32, (1, x.shape[1], 1), 1)
+    taps = (wide if k == w - 1 else
+            jnp.where(row >= w - 1 - k, jnp.roll(wide, w - 1 - k, axis=1), 0.0)
+            for k in range(w))
+    return bias + sum(kernel[k] * rows for k, rows in enumerate(taps))
+
+
+def phase_conv(cfg, seed, on_tpu, clock):
+    """`hybrid.conv_silu` (the causal convolution, its bias and SiLU through
+    the forward and backward Pallas kernels) at the sizes of a state-space
+    layer of the benchmark's `granite-4.0-h-micro` cell, on the input
+    projection's whole output, against the plain reference's `causal_conv` and
+    a SiLU in float32: the output and the three gradients in relative L2, and
+    the host clock over ``calls`` calls of the forward alone and of forward and
+    backward, for the kernels, for `hybrid.causal_conv`'s expression and for
+    the same sum written with rolls."""
+    from bluefog_tpu.models import hybrid
+    from chipbench import manifest
+
+    reference = manifest.load_module(os.path.join(
+        manifest.REPO, "chipbench", "reference", "granite-4.0-h-micro.py"))
+    t0 = time.perf_counter()
+    T, inner, states, W = cfg["seq"], cfg["inner"], cfg["states"], cfg["width"]
+    conv = inner + 2 * states
+    keys = jax.random.split(jax.random.PRNGKey(seed), 4)
+    # [z, xBC, dt], its columns rounded up to whole 128-lane blocks: an argument
+    # of 8,512 columns is laid out tokens-minor, and every path would be timed
+    # with a copy that a product inside a step does not need
+    wide = -(-(inner + conv + cfg["heads"]) // 128) * 128
+    zxbcdt = jax.random.normal(keys[0], (1, T, wide), jnp.bfloat16)
+    g = jax.random.normal(keys[1], (1, T, conv), jnp.bfloat16)
+    taps = 0.5 * jax.random.normal(keys[2], (W, conv))
+    bias = 0.5 * jax.random.normal(keys[3], (conv,))
+    args = (zxbcdt, taps, bias)
+    assert hybrid.conv_kernels_take(T, inner, states, W)
+
+    def expression(conv_fn):  # as `hybrid.conv_silu` where the kernels do not tile
+        def fn(zxbcdt, taps, bias):
+            xbc = jax.nn.silu(conv_fn(
+                zxbcdt[..., inner:inner + conv], taps, bias)).astype(zxbcdt.dtype)
+            return xbc[..., :inner], xbc[..., inner:]
+        return fn
+
+    def in_float32(zxbcdt, taps, bias):
+        with jax.default_matmul_precision("highest"):
+            xbc = jax.nn.silu(reference.causal_conv(
+                zxbcdt[0, :, inner:inner + conv].astype(jnp.float32), taps, bias))[None]
+        return xbc[..., :inner], xbc[..., inner:]
+
+    paths = {"kernels": lambda *a: hybrid.conv_silu(*a, inner),
+             "expression": expression(hybrid.causal_conv),
+             "rolled": expression(_rolled_conv)}
+
+    def loss(fn):  # x and [B, C] each against its part of g, as the scan takes them
+        def of(*a):
+            x, bc = fn(*a)
+            return (jnp.sum(x.astype(jnp.float32) * g[..., :inner].astype(jnp.float32))
+                    + jnp.sum(bc.astype(jnp.float32) * g[..., inner:].astype(jnp.float32)))
+        return of
+
+    def values(fn):
+        y, grads = jax.jit(lambda *a: (fn(*a), jax.grad(loss(fn), (0, 1, 2))(*a)))(*args)
+        return dict(zip(("y", "dx", "dkernel", "dbias"),
+                        (jnp.concatenate(y, axis=-1),
+                         grads[0][..., inner:inner + conv]) + grads[1:]))
+
+    want = values(in_float32)
+    rel, ms = {}, {}
+    for name, fn in paths.items():
+        rel[name] = _rel_l2(values(fn), want)
+        ms[name] = {kind: _ms_a_call(jax.jit(timed), args, cfg["calls"]) for kind, timed in (
+            ("fwd", fn), ("fwd_bwd", jax.grad(loss(fn), (0, 1, 2))))}
+    _emit("conv_vs_expression", t0, clock, seq=T, channels=conv, of=zxbcdt.shape[-1],
+          width=W, interpret=not on_tpu,
+          compared="silu(causal_conv) and its gradients in the input, the taps and "
+                   "the bias (bfloat16 in and out) against the reference's "
+                   "expression in float32: relative L2; ms a call, host clock; the "
+                   "kernels, hybrid.causal_conv's expression, the same with rolls",
+          rel_l2=rel, ms_per_call=ms, rel_l2_tol=CONV_L2_RTOL)
+    for name, gaps in rel.items():
+        for n, gap in gaps.items():
+            assert gap <= CONV_L2_RTOL, (
+                f"{name} {n}: {gap} from the float32 expression in relative L2")
 
 
 # ---------------------------------------------------------------------------
@@ -1037,6 +1150,8 @@ def run(args, device):
             phase_subtiles(sizes["subtiles"], args.seed, on_tpu, clock)
         if want("ssd"):
             phase_ssd(sizes["ssd"], args.seed, on_tpu, clock)
+        if want("conv"):
+            phase_conv(sizes["conv"], args.seed, on_tpu, clock)
     bf.shutdown()
 
 

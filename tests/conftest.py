@@ -30,3 +30,15 @@ def devices():
     devs = jax.devices()
     assert len(devs) == 8, f"expected 8 virtual CPU devices, got {devs}"
     return devs
+
+
+def pytest_configure(config):
+    """Build `bluefog_tpu.native` once, in the process that has no xdist
+    `workerinput` (the controller, or the only process), before any worker
+    imports `tests/test_native.py`: its module-level `skipif(get_lib() is
+    None)` builds on first use, and six workers racing one `make` in one
+    directory load a half-written library and skip the file's 18 tests."""
+    if not hasattr(config, "workerinput"):
+        from bluefog_tpu import native
+
+        native.build()

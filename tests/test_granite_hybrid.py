@@ -112,6 +112,16 @@ def test_the_scan_in_bfloat16_stays_within_its_smoke_tolerance():
     assert "ssd" in chip_smoke.PHASES
 
 
+def test_the_convolutions_kernels_stay_within_their_smoke_tolerance():
+    """What `chip_smoke.py --only conv` holds the compiled kernels to at the
+    cell's shapes, here its rehearsal: the kernels, the expression and the
+    rolled candidate against the reference's float32 expression."""
+    import chip_smoke
+
+    chip_smoke.phase_conv(chip_smoke.TINY["conv"], 0, False, chip_smoke._CompileClock())
+    assert chip_smoke.PHASES[-2:] == ("ssd", "conv")
+
+
 def test_b_and_c_of_a_group_count_that_does_not_divide_the_heads_are_refused():
     (x, dt, a_log, bm, cm, skip), _ = _scan_inputs(0, 16, heads=4, groups=3)
     with pytest.raises(ValueError, match="divides"):
@@ -150,15 +160,18 @@ def _loss_and_grads(model, params, x, y):
         lambda p: apply_fn({"params": seeded.nest(p)}, x, labels=y)))(params)
 
 
-@pytest.fixture(scope="module")
-def seeded_case(cell, ref):
-    sizes = cell.sizes(rehearse=True)
+def _seeded_case(ref, sizes):
     params = seeded.make_weights(ref, sizes, seed=11)[0]
     (x, y), = seeded.make_batches(ref, sizes, 11, ranks=1, pool=1)
     x, y = x[0], y[0]
     (loss, _), grads = jax.jit(jax.value_and_grad(
         lambda p: ref.loss_fn(p, {}, x, y, sizes), has_aux=True))(params)
     return sizes, params, x, y, float(loss), grads
+
+
+@pytest.fixture(scope="module")
+def seeded_case(cell, ref):
+    return _seeded_case(ref, cell.sizes(rehearse=True))
 
 
 def _worst_gap(got, want):
@@ -181,6 +194,20 @@ def test_loss_and_gradients_match_the_plain_reference(cell, ref, seeded_case):
     lp, gp = _loss_and_grads(_float32_model(cell, sizes), params, x, y)
     assert abs(float(lp) - loss) < 1e-5
     assert set(gp) == set(grads) == set(ref.param_shapes(sizes)[0])
+    gap, where = _worst_gap(gp, grads)
+    assert gap < 1e-4, (where, gap)
+
+
+def test_the_reference_is_matched_through_the_convolutions_kernels_too(cell, ref):
+    """The same comparison with a state of 64 for 32: B and C are then one
+    128-lane block, and both state-space layers' convolutions go through the
+    kernels of `kernels/causal_conv.py` (the rehearsal's own shapes send them
+    down the expression).  The reference has a convolution of its own."""
+    sizes, params, x, y, loss, grads = _seeded_case(
+        ref, dict(cell.sizes(rehearse=True), mamba_d_state=64))
+    assert hybrid.conv_kernels_take(x.shape[1], 128, 64, 4)
+    lp, gp = _loss_and_grads(_float32_model(cell, sizes), params, x, y)
+    assert abs(float(lp) - loss) < 1e-5
     gap, where = _worst_gap(gp, grads)
     assert gap < 1e-4, (where, gap)
 
@@ -359,18 +386,31 @@ WANTED_GAUGES = {
     "lm.remat_kept_names": 4, "lm.remat_kept_mb": (4096 + 512 + 12288 + 36864) / 1e6}
 
 
-def test_the_model_sets_its_gauges(cell, monkeypatch, tmp_path):
+# the rehearsal's convolution is 128 + 2 x 32 channels wide: B and C are no
+# whole 128-lane block and every layer takes the expression; with a state of 64
+# they are one, and with 12 tokens for 32 the tokens are no whole 8-row tiles
+@pytest.mark.parametrize("changed,tokens,conv_kernel_layers", [
+    pytest.param({}, 32, 0, id="as-rehearsed"),
+    pytest.param({"ssm_state": 64}, 32, 2, id="shapes-that-tile"),
+    pytest.param({"ssm_state": 64}, 12, 0, id="tokens-that-do-not")])
+def test_the_model_sets_its_gauges(cell, monkeypatch, tmp_path, changed, tokens,
+                                   conv_kernel_layers):
     monkeypatch.setenv("BFTPU_TELEMETRY", str(tmp_path))
     telemetry.reset()
     try:
         model = cell.module("program").build(cell.sizes(rehearse=True))["model"]
-        jax.eval_shape(lambda i: model.init(jax.random.PRNGKey(0), i),
-                       jax.ShapeDtypeStruct((1, 32), jnp.int32))
+        jax.eval_shape(lambda i: model.clone(**changed).init(jax.random.PRNGKey(0), i),
+                       jax.ShapeDtypeStruct((1, tokens), jnp.int32))
         gauges = {g["name"]: g["value"] for g in
                   telemetry.get_registry().snapshot()["gauges"]}
     finally:
         telemetry.reset()
-    assert {k: v for k, v in gauges.items() if k in WANTED_GAUGES} == WANTED_GAUGES
+    wanted = {**WANTED_GAUGES, "ssm.conv_kernel_layers": conv_kernel_layers,
+              "ssm.state": changed.get("ssm_state", 32),
+              # bfloat16 of the tokens: an attention layer's [4, T, 16] and
+              # float32 [4, T], three layers' [T, 64] and [T, 192]
+              "lm.remat_kept_mb": tokens * (128 + 16 + 384 + 1152) / 1e6}
+    assert {k: v for k, v in gauges.items() if k in wanted} == wanted
 
 
 def test_a_mixer_kind_the_decoder_does_not_have_is_refused(cell):

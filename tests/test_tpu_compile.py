@@ -194,32 +194,74 @@ def test_scan_kernels_compile_for_v5e(one_chip, chunk, code_budget):
         assert compiled.memory_analysis().generated_code_size_in_bytes <= code_budget
 
 
+# a state-space layer's convolution of the granite-4.0-h-micro cell (B1 S8192):
+# the product of the input projection, of which the 4,352 channels from 4,096
+# on are convolved.  Its 8,512 columns are rounded up to whole 128-lane blocks
+# here, 8,576: as an argument of a program, and not a product inside it, an
+# array of 8,512 is laid out tokens-minor and copied into the kernels' layout.
+# The budget: the bytes of generated code read at the first compile (462,336:
+# the two kernels and the sum of the taps' rows) plus 10 %.
+def test_causal_conv_kernels_compile_for_v5e(one_chip):
+    """The convolution's two kernels: sublane rolls of a float32 stack, a
+    scratch carried along the tokens, a 16-row halo block of bfloat16 and an
+    output block of sums that every step of a channel block revisits, which
+    interpret mode cannot refuse and Mosaic can.  The input is read inside the
+    wider array and nothing of [T, C] in float32 is written."""
+    from bluefog_tpu.kernels import causal_conv
+    from bluefog_tpu.models import hybrid
+
+    T, inner, conv = 8192, 4096, 4352
+    spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    args = (spec((1, T, inner + conv + 128), jnp.bfloat16), spec((4, conv), jnp.float32),
+            spec((conv,), jnp.float32))
+    assert hybrid.conv_kernels_take(T, inner, (conv - inner) // 2, 4)
+
+    def loss(x, taps, bias):  # the output too: its gradient alone needs no forward
+        y = causal_conv.causal_conv_silu(x, taps, bias, offset=inner, interpret=False)
+        return jnp.sum(y.astype(jnp.float32)), y
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2), has_aux=True)).lower(
+        *args).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2
+    for name in ("causal_conv_fwd", "causal_conv_bwd"):
+        assert name in text  # the names the device trace shows
+    assert "f32[1,8192,4352]" not in text and "f32[1,8195,4352]" not in text
+    assert [tuple(o.shape) for o in jax.tree_util.tree_leaves(compiled.out_info)] == [
+        a.shape for a in args] + [(1, T, conv)]
+    assert compiled.memory_analysis().generated_code_size_in_bytes <= 508_500
+
+
 # blocks of the granite-4.0-h-micro cell (B1 S8192, hidden 2048, MLP 8192; 64
 # scan heads of 64 with a state of 128; 32 query heads on 8 of 64) recomputed
 # under the model's own policy: one of each kind, and two state-space blocks,
 # where the first one's kept values stand while the second's backward pass
-# runs.  The budgets are the temporaries' bytes read at PR 40 (851,702,272,
-# 803,047,936 and 1,090,421,760) plus 5 %.  One block alone hardly feels the
-# tuple; the pair read 1,231,030,784 with "ssm_in_proj" kept too and
+# runs.  The budgets are the temporaries' bytes read at PR 42 (574,669,312,
+# 803,047,936 and 880,642,048) plus 5 %; at PR 40, with the convolution as
+# XLA's expression over a padded float32 copy, the state-space ones read
+# 851,702,272 and 1,090,421,760.  One block alone hardly feels the tuple; the
+# pair read 1,231,030,784 at PR 40 with "ssm_in_proj" kept too and
 # 1,288,187,392 without "mlp_gate_up" (a block's working set grows as it is
 # handed less): a change to `hybrid.REMAT_KEEPS`, or to what a name is put on,
 # shows here in bytes before it shows on the chip as an out-of-memory.
 @pytest.mark.parametrize("kinds,kernel_calls,temp_budget", [
-    pytest.param(("mamba",), 3, 894_300_000, id="state-space-block"),
+    pytest.param(("mamba",), 9, 603_400_000, id="state-space-block"),
     pytest.param(("attention",), 3, 843_200_000, id="attention-block"),
-    pytest.param(("mamba", "mamba"), 6, 1_144_900_000, id="two-state-space-blocks"),
+    pytest.param(("mamba", "mamba"), 18, 924_600_000, id="two-state-space-blocks"),
 ])
 def test_recomputed_granite_blocks_keep_what_their_policy_names_for_v5e(
         one_chip, monkeypatch, kinds, kernel_calls, temp_budget):
-    """Three kernel calls in a block's gradient: the flash forward, dK/dV and
-    dQ, the forward not run again because its output and logsumexp are both
-    kept; the scan's forward, its forward again and its backward, because the
-    scan's own residuals are not among the names."""
+    """Three kernel calls in an attention block's gradient: the flash forward,
+    dK/dV and dQ, the forward not run again because its output and logsumexp
+    are both kept.  Nine in a state-space block's: the scan's forward, its
+    forward again and its backward, because the scan's own residuals are not
+    among the names, and the same three of the convolution, twice: x's
+    channels, and B's with C's (`hybrid.conv_silu`)."""
     from bluefog_tpu.models.hybrid import HybridMambaLM
 
     # the model calls its kernels with their defaults, which ask the backend:
     # the CPU here.  Steered in the test, not through an option of the program
-    for module in ("flash_attention", "ssd"):
+    for module in ("flash_attention", "ssd", "causal_conv"):
         monkeypatch.setattr(importlib.import_module(f"bluefog_tpu.kernels.{module}"),
                             "_default_interpret", lambda: False)
     model = HybridMambaLM(
