@@ -7,7 +7,10 @@ kernels compiled straight to Mosaic for the MXU/VPU, fused with XLA around
 them.
 
 ``flash_attention``: causal attention, whole-sequence and banded, with the
-shared key-value heads read in place.  ``ssd``: the chunked state-space scan
+shared key-value heads read in place and a value head of another size than the
+query-key head's.  ``kda``: the chunked delta rule with a decay a channel of a
+Kimi Delta Attention layer (:func:`bluefog_tpu.kernels.kda.kda_chunked`).
+``ssd``: the chunked state-space scan
 of a Mamba-2 layer (:func:`bluefog_tpu.kernels.ssd.ssd_scan`).
 ``causal_conv``: that layer's depth-wise causal convolution with its bias and
 SiLU as one pass each way

@@ -637,7 +637,7 @@ def _flash_fwd(q, k, v, q_start, k_start, *, scale, causal, block_q, block_k,
     Returns (o [BH, Tq, D], lse [BH, Tq]).
     """
     bh, tq, d = q.shape
-    tk = k.shape[1]
+    tk, vd = k.shape[1], v.shape[2]  # values may be of another head size
     block_q, block_k = _default_blocks(tq, tk, block_q, block_k, window)
     block_q = _fit_block(tq, block_q)
     block_k = _fit_block(tk, block_k)
@@ -678,18 +678,18 @@ def _flash_fwd(q, k, v, q_start, k_start, *, scale, causal, block_q, block_k,
             smem,
             _block_spec((1, block_q, d), lambda b, i, j: (b, i, 0)),
             _block_spec((1, block_k, d), kv_map),
-            _block_spec((1, block_k, d), kv_map),
+            _block_spec((1, block_k, vd), kv_map),
         ],
         out_specs=[
-            _block_spec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+            _block_spec((1, block_q, vd), lambda b, i, j: (b, i, 0)),
             _block_spec((1, block_q, _SCALAR_LANES), lambda b, i, j: (b, i, 0)),
         ],
         out_shape=[
-            _out_struct((bh, tq, d), q.dtype, (q, k, v)),
+            _out_struct((bh, tq, vd), q.dtype, (q, k, v)),
             _out_struct((bh, tq, _SCALAR_LANES), jnp.float32, (q, k, v)),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, vd), jnp.float32),
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, _LANES), jnp.float32),
         ],
@@ -720,7 +720,7 @@ def _blockwise_fwd_xla(q, k, v, q_start, k_start, *, scale, causal, block_k,
     # is the fall-back; the Pallas kernels skip what the band leaves out)
     if window is None and _use_triangular(causal, tri_delta, tq, tk, num_k):
         # triangular unroll: k block j touches only q rows >= j*block_k
-        o = vma_full(q, q.shape, jnp.float32)
+        o = vma_full(q, (bh, tq, v.shape[2]), jnp.float32)
         m = vma_full(q, (bh, tq, 1), jnp.float32, _NEG_INF)
         l = vma_full(q, (bh, tq, 1), jnp.float32)
         for j in range(num_k):
@@ -767,7 +767,7 @@ def _blockwise_fwd_xla(q, k, v, q_start, k_start, *, scale, causal, block_k,
         o, m, l = lax.fori_loop(
             0, num_k,
             body,
-            (vma_full(q, q.shape, jnp.float32),
+            (vma_full(q, (bh, tq, v.shape[2]), jnp.float32),
              vma_full(q, (bh, tq, 1), jnp.float32, _NEG_INF),
              vma_full(q, (bh, tq, 1), jnp.float32)),
         )
@@ -972,7 +972,7 @@ def _flash_bwd_pallas(q, k, v, lse, corr, q_start, k_start, g,
     :func:`_sub_edge`'s; 0: cut tiles computed whole).
     """
     bh, tq, d = q.shape
-    tk = k.shape[1]
+    tk, vd = k.shape[1], v.shape[2]  # values may be of another head size
     block_q, block_k = _default_blocks(tq, tk, block_q, block_k, window)
     block_q = _fit_block(tq, block_q)
     block_k = _fit_block(tk, block_k)
@@ -1018,12 +1018,13 @@ def _flash_bwd_pallas(q, k, v, lse, corr, q_start, k_start, g,
     def rowspec(index):  # q/g/aux blocks, selected by the q index
         return [
             _block_spec((1, block_q, d), index),
-            _block_spec((1, block_q, d), index),
+            _block_spec((1, block_q, vd), index),
             _block_spec((1, block_q, 2 * half), index),
         ]
 
     def kvspec(index):  # k/v blocks, selected by the k index
-        return [_block_spec((1, block_k, d), index)] * 2
+        return [_block_spec((1, block_k, d), index),
+                _block_spec((1, block_k, vd), index)]
 
     # the dK/dV grid: (head, k block, step), or where a group of query heads
     # shares the head, (key-value head, k block, member of the group, step)
@@ -1050,7 +1051,7 @@ def _flash_bwd_pallas(q, k, v, lse, corr, q_start, k_start, g,
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, vd), jnp.float32),
         ],
         interpret=interpret,
         **dkv_kw,
@@ -1286,6 +1287,11 @@ def flash_attention_with_lse(
     D]``, summed over each group inside the dK/dV kernel.  Nothing is
     repeated, kept for the backward pass or summed afterwards; equal head
     counts lower to what they always did.
+
+    ``v`` may be of another head size than ``q`` and ``k`` (a latent-attention
+    layer's 128 beside 128 + 64 rotary): the scores are scaled by the
+    query-key size, the output has the values'.  One size lowers to what it
+    always did.
     """
     if impl not in ("auto", "xla", "pallas"):
         raise ValueError(f"impl must be auto/xla/pallas, got {impl!r}")
@@ -1300,9 +1306,12 @@ def flash_attention_with_lse(
         raise ValueError(
             f"{h} query heads on {k.shape[2]} key and {v.shape[2]} value heads: "
             "keys and values share a head count that divides the queries'")
+    if k.shape[3] != d:
+        raise ValueError(f"query heads of {d} on key heads of {k.shape[3]}: "
+                         "queries and keys share a head size (the values' is their own)")
 
     def fold(x):  # [B, T, H, D] -> [B*H, T, D]
-        return x.transpose(0, 2, 1, 3).reshape(b * x.shape[2], x.shape[1], d)
+        return x.transpose(0, 2, 1, 3).reshape(b * x.shape[2], x.shape[1], x.shape[3])
 
     # static offsets with a small key-ahead delta + square shapes unlock
     # the triangular fast paths (delta 0 = aligned; delta 1 = the striped
@@ -1316,7 +1325,7 @@ def flash_attention_with_lse(
         jnp.asarray(q_start, jnp.float32), jnp.asarray(k_start, jnp.float32),
         scale, causal, block_q, block_k, interpret, tri_delta, impl, window,
     )
-    o = o.reshape(b, h, tq, d).transpose(0, 2, 1, 3)
+    o = o.reshape(b, h, tq, v.shape[3]).transpose(0, 2, 1, 3)
     return o, lse.reshape(b, h, tq)
 
 

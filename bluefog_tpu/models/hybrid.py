@@ -1,7 +1,15 @@
-"""A decoder whose layers mix their tokens by a state-space scan or by
-attention, a kind a layer: the hybrid of IBM's Granite 4.0-H (`model_type`
-granitemoehybrid), whose state-space layer is Mamba-2 (Dao and Gu,
-arXiv:2405.21060).
+"""Decoders whose layers mix their tokens by a recurrence or by attention, a
+kind a layer.  :class:`HybridMambaLM`: the hybrid of IBM's Granite 4.0-H
+(`model_type` granitemoehybrid), whose state-space layer is Mamba-2 (Dao and
+Gu, arXiv:2405.21060).  :class:`DeltaLatentMoELM`: inclusionAI's Ling 3.0
+flash, whose linear layer is Kimi Delta Attention (:class:`KDAMixer`,
+arXiv:2510.26692), whose attention layer is DeepSeek-V2's latent attention
+(:class:`LatentAttentionMixer`, arXiv:2405.04434) and whose feed-forward part
+is, after a leading dense layer, DeepSeek-V3's expert layer.  Both are stacks
+of :class:`_HybridBlock`, which takes its mixer and its feed-forward part as
+it is handed them.
+
+Of :class:`HybridMambaLM`:
 
 Every layer is ``h + r Mixer(RMSNorm(h))`` then ``h + r MLP(RMSNorm(h))``
 with one residual multiplier ``r`` and the dense gated MLP of
@@ -29,13 +37,18 @@ from jax.ad_checkpoint import checkpoint_name
 
 from bluefog_tpu.models.transformer import (
     RMSNorm,
+    Rotary,
     _GatedMLP,
     _head_matmul,
     _HeadKernel,
+    _rotary,
     chunked_softmax_cross_entropy,
+    expert_feed_forward,
+    rotary_frequencies,
 )
 
-__all__ = ["HybridMambaLM", "Mamba2Mixer", "causal_conv", "conv_silu"]
+__all__ = ["DeltaLatentMoELM", "HybridMambaLM", "KDAMixer", "LatentAttentionMixer",
+           "Mamba2Mixer", "causal_conv", "conv_silu"]
 
 # What the backward pass of a recomputed block is handed beside the block's
 # input, each a `checkpoint_name` where the value is made: the flash forward's
@@ -50,6 +63,15 @@ __all__ = ["HybridMambaLM", "Mamba2Mixer", "causal_conv", "conv_silu"]
 # granite-4.0-h-micro cell's compiled step asks for 15.13 GB, with these for
 # 13.81 (PERF.md section 6, PR 40: the table, and both read on the chip).
 REMAT_KEEPS = ("attn_out", "attn_lse", "mixer_out", "mlp_gate_up")
+# What :class:`DeltaLatentMoELM` keeps: the latent-attention layer's flash
+# residuals and the delta rule's output, 67 MB a layer at 8,192 tokens x 32
+# heads of 128.  `kda_chunked` walks its heads a group at a time, each group
+# under a checkpoint of its own so that one group's intermediates are alive
+# and not a layer's (3.57 GB a layer at once, compiled for the v5e; PERF.md
+# section 6, PR 43); with its output kept, the recomputed block does not run
+# the group's forward a second time before the group's backward runs it a
+# third.
+DELTA_KEEPS = ("attn_out", "attn_lse", "kda_out")
 
 
 def causal_conv(x, kernel, bias):
@@ -180,9 +202,30 @@ class _AttentionMixer(nn.Module):
         return dense(d, name="o")(att.reshape(B, T, H * hd))
 
 
+def dense_ffn(dff, dtype):
+    """A block's feed-forward part: the dense gated MLP, leaves under
+    ``mlp``."""
+    def ffn(block, normed):
+        return _GatedMLP(dff, dtype, name="mlp")(normed)
+    return "mlp_dense", ffn
+
+
+def expert_ffn(*args, **routing):
+    """A block's feed-forward part: this share of an expert layer beside the
+    shared expert (:func:`bluefog_tpu.models.transformer.expert_feed_forward`,
+    whose arguments after the stream these are), the leaves the block's own."""
+    def ffn(block, normed):
+        return expert_feed_forward(block, normed, *args, **routing)
+    return "moe_layer", ffn
+
+
 class _HybridBlock(nn.Module):
+    """``h + r Mixer(norm(h))``, then ``h + r FFN(norm(h))``: ``mixer`` makes
+    the mixer's module, ``ffn`` is ``(scope, (block, normed) -> out)`` of
+    :func:`dense_ffn` or :func:`expert_ffn`."""
+
     mixer: Callable[[], nn.Module]
-    dff: int
+    ffn: Tuple[str, Callable]
     residual_multiplier: float
     eps: float
     dtype: Any
@@ -193,9 +236,24 @@ class _HybridBlock(nn.Module):
         r = self.residual_multiplier
         mixed = checkpoint_name(self.mixer()(norm(name="mixer_norm")(h)), "mixer_out")
         h = h + (r * mixed).astype(h.dtype)
-        with jax.named_scope("mlp_dense"):
-            return h + (r * _GatedMLP(self.dff, self.dtype, name="mlp")(
-                norm(name="mlp_norm")(h))).astype(h.dtype)
+        scope, ffn = self.ffn
+        with jax.named_scope(scope):
+            return h + (r * ffn(self, norm(name="mlp_norm")(h))).astype(h.dtype)
+
+
+def _remat(keeps):
+    return nn.remat(_HybridBlock, policy=jax.checkpoint_policies
+                    .save_only_these_names(*keeps))
+
+
+def _head(h, embed, head_of, labels, head_chunks):
+    """Logits of the normed stream ``h``, or with ``labels`` the chunked
+    next-token loss: through the embedding's own tensor (its gradient sums
+    both uses), or where ``head_of`` makes one, through the head's."""
+    kernel = embed.embedding.T if head_of is None else head_of()
+    if labels is None:
+        return _head_matmul(h, kernel, jnp.float32)
+    return chunked_softmax_cross_entropy(h, kernel, labels, max(head_chunks, 1))
 
 
 class HybridMambaLM(nn.Module):
@@ -289,19 +347,252 @@ class HybridMambaLM(nn.Module):
                          embedding_init=nn.initializers.normal(0.02), name="embed")
         h = (jnp.take(embed.embedding, input_ids, axis=0)
              * self.embedding_multiplier).astype(self.dtype)
-        block_cls = _HybridBlock
-        if self.remat:
-            block_cls = nn.remat(_HybridBlock, policy=jax.checkpoint_policies
-                                 .save_only_these_names(*keeps))
+        block_cls = _remat(keeps) if self.remat else _HybridBlock
+        ffn = dense_ffn(self.dff, self.dtype)
         for i, kind in enumerate(kinds):
-            h = block_cls(mixers[kind], self.dff, self.residual_multiplier, self.eps,
+            h = block_cls(mixers[kind], ffn, self.residual_multiplier, self.eps,
                           self.dtype, name=f"layer_{i}")(h)
         h = RMSNorm(dtype=jnp.float32, eps=self.eps, name="final_norm")(h)
         h = h / self.logits_scaling
-        if self.tie_embeddings:
-            kernel = embed.embedding.T  # one tensor: its gradient sums both uses
-        else:
-            kernel = _HeadKernel(self.vocab_size, name="head")(self.hidden_size)
-        if labels is None:
-            return _head_matmul(h, kernel, jnp.float32)
-        return chunked_softmax_cross_entropy(h, kernel, labels, max(self.head_chunks, 1))
+        untied = lambda: _HeadKernel(self.vocab_size, name="head")(self.hidden_size)
+        return _head(h, embed, None if self.tie_embeddings else untied, labels,
+                     self.head_chunks)
+
+
+def kda_conv_kernels_take(tokens, inner, width):
+    """Whether a delta-rule layer's three convolutions (q, k and v, ``3 *
+    inner`` channels of one product) go through the kernels of
+    :mod:`bluefog_tpu.kernels.causal_conv` or through :func:`causal_conv`: by
+    the shapes alone, as :func:`conv_kernels_take`."""
+    from bluefog_tpu.kernels.causal_conv import tiles
+
+    return tiles(tokens, 3 * inner, width)
+
+
+class KDAMixer(nn.Module):
+    """Kimi Delta Attention (arXiv:2510.26692 section 3).  ``[q, k, v] = u
+    W_qkv`` through a causal depth-wise convolution and SiLU, no bias (the
+    kernels of :mod:`bluefog_tpu.kernels.causal_conv` where they take the
+    shapes); a head at a time ``q / |q| / sqrt(K)`` and ``k / |k|``; the
+    log-decay a channel ``lower_bound * sigmoid(exp(A_log[head]) * (u W_f +
+    dt_bias))``, in ``(lower_bound, 0)``; the step ``sigmoid(u W_b)`` a head;
+    the delta rule of :func:`bluefog_tpu.kernels.kda.kda_chunked`; each head's
+    output through an RMS norm with one learned weight a channel, times
+    ``sigmoid(u W_g)``; ``W_o``.  No position signal: the decay carries it.
+    The norms, the decay, the step, the gate and the state in float32, the
+    products in ``dtype``."""
+
+    num_heads: int
+    head_dim: int
+    conv_width: int = 4
+    chunk: int = 64
+    lower_bound: float = -5.0
+    eps: float = 1e-6
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, u):
+        from bluefog_tpu.kernels.causal_conv import causal_conv_silu
+        from bluefog_tpu.kernels.kda import kda_chunked
+        from bluefog_tpu.parallel._util import vma_full
+
+        B, T, d = u.shape
+        H, hd = self.num_heads, self.head_dim
+        inner = H * hd
+        init = nn.initializers.normal(0.02)
+        dense = partial(nn.Dense, use_bias=False, dtype=self.dtype, kernel_init=init)
+        with jax.named_scope("kda_in_proj"):
+            qkv = dense(3 * inner, name="kda_qkv")(u)
+        with jax.named_scope("kda_conv"):
+            taps = self.param("conv_kernel", init, (self.conv_width, 3 * inner),
+                              jnp.float32)
+            if kda_conv_kernels_take(T, inner, self.conv_width):
+                # no bias: zeros that vary over the mesh as the taps do
+                qkv = causal_conv_silu(qkv, taps, vma_full(taps, (3 * inner,), jnp.float32))
+            else:
+                qkv = jax.nn.silu(causal_conv(qkv, taps, 0.0)).astype(self.dtype)
+            q, k, v = (qkv[..., i * inner:(i + 1) * inner].reshape(B, T, H, hd)
+                       for i in range(3))
+        with jax.named_scope("kda_gates"):
+            a_log = self.param("A_log", nn.initializers.zeros_init(), (H,), jnp.float32)
+            dt_bias = self.param("dt_bias", nn.initializers.zeros_init(), (inner,),
+                                 jnp.float32)
+            f = dense(inner, name="kda_f")(u).astype(jnp.float32) + dt_bias
+            g = self.lower_bound * jax.nn.sigmoid(
+                jnp.exp(a_log)[:, None] * f.reshape(B, T, H, hd))
+            beta = jax.nn.sigmoid(dense(H, name="kda_b")(u).astype(jnp.float32))
+        with jax.named_scope("kda_chunk"):
+            def unit(x):  # a head's vector over its length, float32
+                x = x.astype(jnp.float32)
+                return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
+
+            o = kda_chunked((unit(q) * hd ** -0.5).astype(self.dtype),
+                            unit(k).astype(self.dtype), v, g, beta, chunk=self.chunk)
+            o = checkpoint_name(o, "kda_out")
+        with jax.named_scope("kda_gate_norm"):
+            gate = jax.nn.sigmoid(dense(inner, name="kda_g")(u).astype(jnp.float32))
+            o = RMSNorm(dtype=jnp.float32, eps=self.eps, name="kda_norm")(o)
+            o = (o.reshape(B, T, inner) * gate).astype(self.dtype)
+        with jax.named_scope("kda_out_proj"):
+            return dense(d, name="kda_o")(o)
+
+
+class LatentAttentionMixer(nn.Module):
+    """DeepSeek-V2's latent attention (arXiv:2405.04434 section 2.1) with no
+    query compression, in its plain (not absorbed) form: ``q = u W_q`` in
+    heads of ``nope + rope``; ``[c, k_r] = u W_kva``, ``c`` of ``kv_rank``
+    through an RMS norm, ``k_r`` **one** rotary key head of ``rope`` that every
+    head reads; ``[k_n, v] = c W_kvb`` in heads of ``nope + v_dim``; rotary on
+    the queries' last ``rope`` channels and on ``k_r``; causal softmax of ``(q_n
+    . k_n + q_r . k_r) / sqrt(nope + rope)`` over the whole sequence through
+    the flash kernels, which take the values' head size beside the query-key
+    one; every head's output times a sigmoid gate of the normed input; ``W_o``."""
+
+    num_heads: int
+    kv_rank: int
+    nope: int
+    rope: int
+    v_dim: int
+    rotary: Rotary
+    eps: float
+    dtype: Any
+    attention_fn: Callable  # (q, k, v) -> out, causal
+
+    @nn.compact
+    def __call__(self, u):
+        B, T, d = u.shape
+        H = self.num_heads
+        dense = partial(nn.DenseGeneral, use_bias=False, dtype=self.dtype)
+        positions = jnp.arange(T)
+        q = dense((H, self.nope + self.rope), name="mla_q")(u)
+        down = dense(self.kv_rank + self.rope, name="mla_kv_down")(u)
+        with jax.named_scope("mla_kv_norm"):
+            c = RMSNorm(dtype=self.dtype, eps=self.eps, name="mla_kv_norm")(
+                down[..., :self.kv_rank])
+        kv = dense((H, self.nope + self.v_dim), name="mla_kv_up")(c)
+        q = jnp.concatenate([q[..., :self.nope], _rotary(
+            q[..., self.nope:], positions, rotary=self.rotary)], axis=-1)
+        k_r = _rotary(down[..., None, self.kv_rank:], positions, rotary=self.rotary)
+        with jax.named_scope("mla_kv_up"):  # the one rotary head beside every head's own
+            k = jnp.concatenate([kv[..., :self.nope], jnp.broadcast_to(
+                k_r, (B, T, H, self.rope))], axis=-1)
+        with jax.named_scope("attention_global"):
+            att = self.attention_fn(q, k, kv[..., self.nope:])
+        with jax.named_scope("attention_gate"):
+            gate = jax.nn.sigmoid(dense(H, name="gate")(u).astype(jnp.float32))
+            att = (att * gate[..., None]).astype(self.dtype)
+        return dense(d, name="o")(att.reshape(B, T, H * self.v_dim))
+
+
+class DeltaLatentMoELM(nn.Module):
+    """The decoder of Ling 3.0 flash: ``layer_kinds`` names each layer's
+    mixer, ``"kda"`` (:class:`KDAMixer`) or ``"mla"``
+    (:class:`LatentAttentionMixer`), ``layer_dense`` says where the
+    feed-forward part is the dense gated MLP of ``dff`` and where this share
+    of the expert layer: sigmoid scores over ``num_experts``, the choice on
+    the score plus a bias that takes no gradient, ``top_k`` among the
+    ``groups_kept`` best of ``groups`` groups, weights renormalised times
+    ``routed_scale``, the ``experts_held`` computed dropless beside a shared
+    expert of ``shared_dff``.  Pre-norm residual blocks, embedding and head
+    untied, every block recomputed in the backward pass but for
+    :data:`DELTA_KEEPS`.  With ``labels`` the chunked next-token loss."""
+
+    vocab_size: int
+    hidden_size: int
+    layer_kinds: Tuple[str, ...]
+    layer_dense: Tuple[bool, ...]
+    dff: int
+    num_heads: int
+    head_dim: int
+    kv_rank: int
+    qk_nope: int
+    qk_rope: int
+    v_dim: int
+    rope_theta: float
+    num_experts: int
+    top_k: int
+    experts_held: Tuple[int, ...]
+    expert_dff: int
+    shared_dff: int
+    routed_scale: float
+    groups: int
+    groups_kept: int
+    conv_width: int = 4
+    chunk: int = 64
+    lower_bound: float = -5.0
+    eps: float = 1e-6
+    remat: bool = True
+    head_chunks: int = 1
+    dtype: Any = jnp.bfloat16
+    attention_fn: Optional[Callable] = None  # None: the flash kernels
+
+    @nn.compact
+    def __call__(self, input_ids, labels=None):
+        from bluefog_tpu.kernels.flash_attention import flash_attention
+        from bluefog_tpu.telemetry import registry as _telemetry
+
+        kinds, is_dense = tuple(self.layer_kinds), tuple(self.layer_dense)
+        if set(kinds) - {"kda", "mla"} or len(is_dense) != len(kinds):
+            raise ValueError(f"layer kinds {sorted(set(kinds))}: 'kda' or 'mla', and "
+                             f"{len(is_dense)} feed-forward kinds for {len(kinds)}")
+        keeps = DELTA_KEEPS if self.remat else ()
+        reg = _telemetry.get_registry()
+        if reg.enabled:
+            tokens, width = input_ids.size, jnp.dtype(self.dtype).itemsize
+            n_kda, n_mla = kinds.count("kda"), kinds.count("mla")
+            inner = self.num_heads * self.head_dim
+            kept = {
+                "attn_out": n_mla * tokens * self.num_heads * self.v_dim * width,
+                "attn_lse": n_mla * tokens * self.num_heads * 4,
+                "kda_out": n_kda * tokens * inner * width,
+            }
+            for name, value in (
+                    ("kda.layers", n_kda), ("kda.heads", self.num_heads),
+                    ("kda.head_dim", self.head_dim), ("kda.chunk", self.chunk),
+                    ("kda.lower_bound", self.lower_bound),
+                    ("kda.kernel_layers", n_kda * kda_conv_kernels_take(
+                        input_ids.shape[1], inner, self.conv_width)),
+                    ("mla.layers", n_mla), ("mla.kv_rank", self.kv_rank),
+                    ("mla.qk_dims", self.qk_nope + self.qk_rope),
+                    ("mla.v_dims", self.v_dim),
+                    ("attention.layers_global", n_mla),
+                    ("attention.heads_global", self.num_heads),
+                    ("moe.score", 1),  # 1: sigmoid scores (0: a softmax's)
+                    ("moe.groups", self.groups), ("moe.groups_kept", self.groups_kept),
+                    ("moe.shared_width", self.shared_dff),
+                    ("moe.routed_scale", self.routed_scale),
+                    ("moe.dense_layers", sum(is_dense)),
+                    ("lm.tied_head", 0),
+                    ("lm.remat_blocks", len(kinds) if self.remat else 0),
+                    ("lm.remat_kept_names", len(keeps)),
+                    ("lm.remat_kept_mb", sum(kept[k] for k in keeps) / 1e6)):
+                reg.gauge(name).set(value)
+        mixers = {
+            "kda": partial(KDAMixer, self.num_heads, self.head_dim, self.conv_width,
+                           self.chunk, self.lower_bound, self.eps, self.dtype,
+                           name="mixer"),
+            "mla": partial(LatentAttentionMixer, self.num_heads, self.kv_rank,
+                           self.qk_nope, self.qk_rope, self.v_dim,
+                           rotary_frequencies(self.qk_rope, self.rope_theta), self.eps,
+                           self.dtype,
+                           self.attention_fn or partial(flash_attention, causal=True),
+                           name="mixer"),
+        }
+        ffns = {
+            True: dense_ffn(self.dff, self.dtype),
+            False: expert_ffn(
+                self.num_experts, self.top_k, tuple(self.experts_held), self.expert_dff,
+                self.shared_dff, self.routed_scale, self.dtype, score="sigmoid",
+                bias=True, groups=self.groups, groups_kept=self.groups_kept),
+        }
+        embed = nn.Embed(self.vocab_size, self.hidden_size, dtype=self.dtype,
+                         embedding_init=nn.initializers.normal(0.02), name="embed")
+        h = jnp.take(embed.embedding, input_ids, axis=0).astype(self.dtype)
+        block_cls = _remat(keeps) if self.remat else _HybridBlock
+        for i, kind in enumerate(kinds):
+            h = block_cls(mixers[kind], ffns[is_dense[i]], 1.0, self.eps, self.dtype,
+                          name=f"layer_{i}")(h)
+        h = RMSNorm(dtype=jnp.float32, eps=self.eps, name="final_norm")(h)
+        return _head(h, embed,
+                     lambda: _HeadKernel(self.vocab_size, name="head")(self.hidden_size),
+                     labels, self.head_chunks)

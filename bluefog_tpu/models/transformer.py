@@ -708,6 +708,36 @@ class _GatedMLP(nn.Module):
         return h @ wd.astype(self.dtype)
 
 
+def expert_feed_forward(block, m, num_experts, top_k, experts_held, expert_dff,
+                        shared_dff, routed_scale, dtype, **routing):
+    """This share's part of a top-k expert layer beside a shared expert that
+    every share computes, on the normed stream ``m [B, T, d]``; the leaves
+    (``router``, the held experts' ``wg`` / ``wu`` / ``wd`` stacks, ``shared``)
+    are ``block``'s, the module whose ``__call__`` this runs in.  ``routing``:
+    :func:`bluefog_tpu.parallel.expert.route_topk`'s keywords; with ``bias``
+    true the choice's bias is the leaf ``router_bias``."""
+    from bluefog_tpu.parallel.expert import held_topk_experts, route_topk
+
+    B, T, d = m.shape
+    init = nn.initializers.normal(0.02)
+    router = block.param("router", init, (d, num_experts), jnp.float32)
+    if routing.pop("bias", False):
+        routing["bias"] = block.param("router_bias", nn.initializers.zeros_init(),
+                                      (num_experts,), jnp.float32)
+    rows = m.reshape(B * T, d)
+    experts, weights = route_topk(rows, router, top_k, routed_scale, **routing)
+    n_held, f = len(experts_held), expert_dff
+    stacks = {
+        "wg": block.param("wg", init, (n_held, d, f), jnp.float32),
+        "wu": block.param("wu", init, (n_held, d, f), jnp.float32),
+        "wd": block.param("wd", init, (n_held, f, d), jnp.float32),
+    }
+    y = held_topk_experts(rows, experts, weights, stacks, experts_held,
+                          num_experts, activation=jax.nn.silu)
+    with jax.named_scope("moe_shared"):
+        return y.reshape(B, T, d) + _GatedMLP(shared_dff, dtype, name="shared")(m)
+
+
 class _GatedBlock(nn.Module):
     """One layer of the decoder whose layers differ by more than their window
     (poolside's Laguna): a head count and a rotary of the layer's own, key and
@@ -734,11 +764,8 @@ class _GatedBlock(nn.Module):
 
     @nn.compact
     def __call__(self, x, positions):
-        from bluefog_tpu.parallel.expert import held_topk_experts, route_topk
-
         B, T, d = x.shape
         hd, H, kvh = self.head_dim, self.num_heads, self.num_kv_heads
-        init = nn.initializers.normal(0.02)
         dense = partial(nn.DenseGeneral, use_bias=False, dtype=self.dtype)
         h = RMSNorm(dtype=self.dtype, name="attn_norm")(x)
         q = _rotary(dense((H, hd), name="q")(h), positions, rotary=self.rotary)
@@ -756,21 +783,9 @@ class _GatedBlock(nn.Module):
         if self.dense_dff is not None:
             with jax.named_scope("mlp_dense"):
                 return x + _GatedMLP(self.dense_dff, self.dtype, name="mlp")(m)
-        router = self.param("router", init, (d, self.num_experts), jnp.float32)
-        rows = m.reshape(B * T, d)
-        experts, weights = route_topk(rows, router, self.top_k, self.routed_scale)
-        n_held, f = len(self.experts_held), self.expert_dff
-        stacks = {
-            "wg": self.param("wg", init, (n_held, d, f), jnp.float32),
-            "wu": self.param("wu", init, (n_held, d, f), jnp.float32),
-            "wd": self.param("wd", init, (n_held, f, d), jnp.float32),
-        }
-        y = held_topk_experts(rows, experts, weights, stacks, self.experts_held,
-                              self.num_experts, activation=jax.nn.silu)
-        with jax.named_scope("moe_shared"):
-            y = y.reshape(B, T, d) + _GatedMLP(
-                self.shared_dff, self.dtype, name="shared")(m)
-        return x + y
+        return x + expert_feed_forward(
+            self, m, self.num_experts, self.top_k, self.experts_held,
+            self.expert_dff, self.shared_dff, self.routed_scale, self.dtype)
 
 
 class MixedAttentionMoELM(nn.Module):

@@ -135,21 +135,50 @@ def switch_moe(
 # --------------------------------------------------------------------------
 
 
-def route_topk(x, router, top_k: int, scale: float = 1.0):
+def route_topk(x, router, top_k: int, scale: float = 1.0, *, score: str = "softmax",
+               bias=None, groups: int = 1, groups_kept: Optional[int] = None):
     """Top-k routing over all the experts the router knows.
 
     ``x [T, d]`` is what the router reads; ``router [d, E]``.  Logits, top-k
     and the softmax over the k chosen logits run in float32 (softmax over
     all E, top-k, renormalised, is the same number), times ``scale`` where a
     model scales its routed experts.  Returns ``(experts [T, k] int32,
-    weights [T, k] float32)``; the weights carry the router's gradient."""
+    weights [T, k] float32)``; the weights carry the router's gradient.
+
+    With any of the keywords, DeepSeek-V3's router (arXiv:2412.19437 section
+    2.1.2): ``score`` ``"sigmoid"`` scores every expert ``s = sigmoid(logit)``
+    by itself (``"softmax"``: over all the experts); the choice is made on ``s
+    + bias`` (``bias [E]``, which takes no gradient: a balancing update's to
+    move, not the loss's); with ``groups`` > 1 the experts stand in that many
+    equal groups, a group scores the sum of its two largest ``s + bias``, the
+    ``groups_kept`` best groups stay and the ``top_k`` largest ``s + bias``
+    among their experts are chosen; the weights are the chosen ``s`` **without
+    the bias** over their sum, times ``scale``."""
     with jax.named_scope("moe_route"):
         logits = jnp.einsum("td,de->te", x.astype(jnp.float32),
                             router.astype(jnp.float32),
                             precision=lax.Precision.HIGHEST)
-        top, experts = lax.top_k(logits, top_k)
-        weights = jax.nn.softmax(top, axis=-1)
-        return experts, weights if scale == 1.0 else weights * scale
+        if score == "softmax" and bias is None and groups == 1:
+            top, experts = lax.top_k(logits, top_k)
+            weights = jax.nn.softmax(top, axis=-1)
+            return experts, weights if scale == 1.0 else weights * scale
+        if score not in ("softmax", "sigmoid"):
+            raise ValueError(f"score {score!r}: 'softmax' or 'sigmoid'")
+        s = jax.nn.sigmoid(logits) if score == "sigmoid" else jax.nn.softmax(logits, -1)
+        choice = lax.stop_gradient(s if bias is None else s + bias.astype(jnp.float32))
+        if groups > 1:
+            t, e = choice.shape
+            if e % groups or not 0 < (groups_kept or groups) <= groups:
+                raise ValueError(f"{e} experts in {groups} groups, {groups_kept} kept")
+            by_group = choice.reshape(t, groups, e // groups)
+            best = jnp.sum(lax.top_k(by_group, 2)[0], axis=-1)           # [T, groups]
+            kept = lax.top_k(best, groups_kept or groups)[1]             # [T, kept]
+            stays = jnp.zeros((t, groups), bool).at[
+                jnp.arange(t)[:, None], kept].set(True)
+            choice = jnp.where(stays[:, :, None], by_group, -jnp.inf).reshape(t, e)
+        experts = lax.top_k(choice, top_k)[1]
+        chosen = jnp.take_along_axis(s, experts, axis=-1)
+        return experts, chosen * (scale / jnp.sum(chosen, axis=-1, keepdims=True))
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(3,))

@@ -101,6 +101,11 @@ FULL = dict(
     # a state-space layer's convolution of that cell: the 8,512-wide product
     # of its input projection, 4,096 channels of x and 128 each of B and C
     conv=dict(seq=8192, inner=4096, states=128, heads=64, width=4, calls=20),
+    # one linear layer's delta rule and one latent-attention layer's kernel
+    # call of the `ling-3.0-flash-vl` cell: 1 x 8192 x 32 heads of 128, chunks
+    # of 64; 128 + 64 rotary query-key channels beside values of 128
+    kda=dict(seq=8192, heads=32, head_dim=128, chunk=64, lower=-5.0, calls=10),
+    mla=dict(seq=8192, heads=32, nope=128, rope=64, v_dim=128, calls=10),
     probe=dict(dim=4096, iters=512),
 )
 TINY = dict(
@@ -117,6 +122,8 @@ TINY = dict(
     ssd=dict(seq=64, heads=4, head_dim=16, state=32, chunks=(8, 16), calls=2,
              att_heads=4, att_kv_heads=2, att_head_dim=16),
     conv=dict(seq=64, inner=128, states=64, heads=8, width=4, calls=2),
+    kda=dict(seq=128, heads=4, head_dim=16, chunk=32, lower=-5.0, calls=2),
+    mla=dict(seq=128, heads=4, nope=16, rope=8, v_dim=16, calls=2),
 )
 
 # flash-vs-dense agreement at bf16 compute on seeded weights.  The two paths
@@ -177,9 +184,18 @@ SSD_L2_RTOL = 2e-2
 # that does not see its neighbour moves them by 0.1 or more.
 CONV_L2_RTOL = 2e-2
 
+# the chunked delta rule (`kernels/kda.py`: bfloat16 q, k and v, the pairs, the
+# triangle and the carried state in float32, the kernels' products on bfloat16
+# operands) against the recurrence taken one token after another in float32 on
+# the same bfloat16 inputs: relative L2 of the output and of the five
+# gradients.  What is left is the rounding of W, of q exp G, of U and of the
+# state to bfloat16 before each product; a chunk's state dropped, a decay
+# applied twice or a sub-block's reference misplaced moves them by 0.1 or more.
+KDA_L2_RTOL = 2e-2
+
 PHASES = ("ops_windows", "resnet_atc", "resnet_allreduce", "contraction",
-          "buckets_vs_per_leaf", "decoder", "experts_piled", "shared_heads",
-          "subtiles", "ssd", "conv")
+          "buckets_vs_per_leaf", "decoder", "experts_piled", "kda_vs_recurrence",
+          "mla_two_head_sizes", "shared_heads", "subtiles", "ssd", "conv")
 
 
 class _CompileClock:
@@ -1091,6 +1107,116 @@ def phase_conv(cfg, seed, on_tpu, clock):
 # ---------------------------------------------------------------------------
 
 
+def phase_kda(cfg, seed, on_tpu, clock):
+    """`kda_chunked` (the stateless stage in XLA, the forward and backward
+    Pallas kernels) at the sizes of a linear layer of the benchmark's
+    `ling-3.0-flash-vl` cell against the recurrence of chipbench's plain
+    reference taken token by token, eight heads at a time: the output and all
+    five gradients in relative L2, and the host clock over ``calls`` calls of
+    the forward alone and of forward and backward.  The decays are drawn over
+    their whole range, a quarter of the channels within 1e-2 of the bound."""
+    from bluefog_tpu.kernels.kda import kda_chunked
+    from chipbench import manifest
+
+    reference = manifest.load_module(os.path.join(
+        manifest.REPO, "chipbench", "reference", "ling-3.0-flash-vl.py"))
+    t0 = time.perf_counter()
+    T, H, K = cfg["seq"], cfg["heads"], cfg["head_dim"]
+    keys = jax.random.split(jax.random.PRNGKey(seed), 7)
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+    q = (unit(jax.random.normal(keys[0], (1, T, H, K))) * K ** -0.5).astype(jnp.bfloat16)
+    k = unit(jax.random.normal(keys[1], (1, T, H, K))).astype(jnp.bfloat16)
+    v, go = (jax.random.normal(r, (1, T, H, K), jnp.bfloat16) for r in keys[2:4])
+    shift = jnp.where(jax.random.uniform(keys[4], (K,)) < 0.25, 9.0, -4.0)
+    g = cfg["lower"] * jax.nn.sigmoid(shift + jax.random.normal(keys[5], (1, T, H, K)))
+    beta = jax.nn.sigmoid(jax.random.normal(keys[6], (1, T, H)))
+    args, names = (q, k, v, g, beta), ("q", "k", "v", "g", "beta")
+
+    def values(fn):
+        def loss(*a):
+            o = fn(*a)
+            return jnp.sum(o.astype(jnp.float32) * go.astype(jnp.float32)), o
+        (_, o), grads = jax.jit(jax.value_and_grad(
+            loss, tuple(range(5)), has_aux=True))(*args)
+        return dict(zip(("o",) + tuple("d" + n for n in names), (o,) + grads))
+
+    def recurrence(q, k, v, g, beta):
+        f32 = lambda a: a[0].astype(jnp.float32)
+        groups = max(1, H // 8)
+        split = lambda a: jnp.moveaxis(
+            f32(a).reshape((T, groups, H // groups) + a.shape[3:]), 1, 0)
+        one = jax.checkpoint(lambda a: reference.kda_scan(*a))
+        o = jax.lax.map(one, tuple(map(split, (q, k, v, g, beta))))
+        return jnp.moveaxis(o, 0, 1).reshape(1, T, H, K)
+
+    chunked = lambda *a: kda_chunked(*a, chunk=cfg["chunk"], interpret=not on_tpu)
+    rel = _rel_l2(values(chunked), values(recurrence))
+    both = jax.jit(jax.grad(lambda *a: jnp.sum(
+        chunked(*a).astype(jnp.float32) * go.astype(jnp.float32)), tuple(range(5))))
+    ms = {kind: _ms_a_call(fn, args, cfg["calls"])
+          for kind, fn in (("fwd", jax.jit(chunked)), ("fwd_bwd", both))}
+    _emit("kda_vs_recurrence", t0, clock, seq=T, heads=H, head_dim=K,
+          chunk=cfg["chunk"], interpret=not on_tpu,
+          compared="o and the five gradients of kda_chunked (bfloat16 q, k, v) "
+                   "against the float32 recurrence taken token by token: relative "
+                   "L2; ms a call, host clock",
+          rel_l2=rel, ms_per_call=ms, rel_l2_tol=KDA_L2_RTOL)
+    for n, gap in rel.items():
+        assert gap <= KDA_L2_RTOL, f"{n}: {gap} from the recurrence in relative L2"
+
+
+def phase_mla(cfg, seed, on_tpu, clock):
+    """The whole-sequence flash kernels handed a query-key head of ``nope +
+    rope`` beside a value head of ``v_dim`` (a latent-attention layer of the
+    `ling-3.0-flash-vl` cell: 192 beside 128, the rotary key channels one head
+    laid beside every head's own) against dense softmax in float32 blocks, a
+    head and 2,048 query rows at a time: the output and the three gradients in
+    relative L2, and ms a call."""
+    from bluefog_tpu.kernels.flash_attention import flash_attention
+    from chipbench import manifest
+
+    reference = manifest.load_module(os.path.join(
+        manifest.REPO, "chipbench", "reference", "ling-3.0-flash-vl.py"))
+    t0 = time.perf_counter()
+    T, H, dq, dv = cfg["seq"], cfg["heads"], cfg["nope"] + cfg["rope"], cfg["v_dim"]
+    keys = jax.random.split(jax.random.PRNGKey(seed), 5)
+    q = jax.random.normal(keys[0], (1, T, H, dq), jnp.bfloat16)
+    k_n = jax.random.normal(keys[1], (1, T, H, cfg["nope"]), jnp.bfloat16)
+    k_r = jax.random.normal(keys[2], (1, T, 1, cfg["rope"]), jnp.bfloat16)
+    k = jnp.concatenate([k_n, jnp.broadcast_to(k_r, (1, T, H, cfg["rope"]))], axis=-1)
+    v, go = (jax.random.normal(r, (1, T, H, dv), jnp.bfloat16) for r in keys[3:])
+    block = None if T >= 4096 else 32
+
+    def values(fn):
+        def loss(*a):
+            o = fn(*a)
+            return jnp.sum(o.astype(jnp.float32) * go.astype(jnp.float32)), o
+        (_, o), grads = jax.jit(jax.value_and_grad(loss, (0, 1, 2), has_aux=True))(q, k, v)
+        return dict(zip(("o", "dq", "dk", "dv"), (o,) + grads))
+
+    def dense(q, k, v):  # a head at a time
+        heads_first = lambda a: jnp.moveaxis(a[0].astype(jnp.float32), 1, 0)
+        one = jax.checkpoint(lambda a: reference._attention(*a, dq ** -0.5, False))
+        with jax.default_matmul_precision("highest"):
+            o = jax.lax.map(one, tuple(map(heads_first, (q, k, v))))
+        return jnp.moveaxis(o, 0, 1)[None]
+
+    fast = lambda *a: flash_attention(*a, causal=True, block_q=block, block_k=block,
+                                      interpret=not on_tpu)
+    rel = _rel_l2(values(fast), values(dense))
+    both = jax.jit(jax.grad(lambda *a: jnp.sum(
+        fast(*a).astype(jnp.float32) * go.astype(jnp.float32)), (0, 1, 2)))
+    ms = {kind: _ms_a_call(fn, (q, k, v), cfg["calls"])
+          for kind, fn in (("fwd", jax.jit(fast)), ("fwd_bwd", both))}
+    _emit("mla_two_head_sizes", t0, clock, seq=T, heads=H, qk_dims=dq, v_dims=dv,
+          interpret=not on_tpu,
+          compared="o, dq, dk, dv of the flash kernels at two head sizes (bfloat16) "
+                   "against dense float32 softmax: relative L2; ms a call, host clock",
+          rel_l2=rel, ms_per_call=ms, rel_l2_tol=LOGITS_L2_RTOL)
+    for n, gap in rel.items():
+        assert gap <= LOGITS_L2_RTOL, f"{n}: {gap} from dense softmax in relative L2"
+
+
 def _rebuild_native():
     """The plan compiler on this path loads the native library when it is
     there; the chip tool copies the disk, so build it from the committed
@@ -1144,6 +1270,10 @@ def run(args, device):
             phase_decoder(sizes["decoder"], args.seed, on_tpu, clock)
         if want("experts_piled"):
             phase_experts_piled(sizes["experts"], args.seed, clock)
+        if want("kda_vs_recurrence"):
+            phase_kda(sizes["kda"], args.seed, on_tpu, clock)
+        if want("mla_two_head_sizes"):
+            phase_mla(sizes["mla"], args.seed, on_tpu, clock)
         if want("shared_heads"):
             phase_shared_heads(sizes["shared_heads"], args.seed, on_tpu, clock)
         if want("subtiles"):

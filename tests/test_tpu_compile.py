@@ -279,6 +279,58 @@ def test_recomputed_granite_blocks_keep_what_their_policy_names_for_v5e(
     assert temp <= temp_budget, temp
 
 
+# a linear layer's delta rule of the ling-3.0-flash-vl cell (B1 S8192, 32 heads
+# of 128, chunks of 64), four heads a group as the mixer calls it and all at
+# once.  The budgets are the temporaries' bytes read at PR 43 (1,041,389,568 and
+# 3,572,845,056) plus 5 %: a group of four wants under a third of what the
+# layer's stateless stage wants at once.
+@pytest.mark.parametrize("at_once,temp_budget", [
+    pytest.param(4, 1_093_500_000, id="B1-T8192-H32-K128-four-heads-a-group"),
+    pytest.param(32, 3_751_500_000, id="B1-T8192-H32-K128-the-layer-at-once")])
+def test_delta_rule_kernels_compile_for_v5e(one_chip, at_once, temp_budget):
+    """The chunked delta rule, forward and backward: the state carried
+    transposed in VMEM scratch, a [1, 128] decay broadcast down its rows,
+    transposed products on bfloat16 operands, which interpret mode cannot
+    refuse and Mosaic can; the stateless stage's float32 pairs and its scanned
+    substitution beside them."""
+    from bluefog_tpu.kernels.kda import kda_chunked
+
+    T, H, K = 8192, 32, 128
+    spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    args = (spec((1, T, H, K), jnp.bfloat16),) * 3 + (
+        spec((1, T, H, K), jnp.float32), spec((1, T, H), jnp.float32))
+
+    def loss(*a):
+        return jnp.sum(kda_chunked(*a, chunk=64, heads_at_once=at_once,
+                                   interpret=False).astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=tuple(range(5)))).lower(*args).compile()
+    text = compiled.as_text()
+    for name in ("kda_chunk_fwd", "kda_chunk_bwd"):
+        assert name in text  # the names the benchmark's readers look up
+    assert [tuple(o.shape) for o in compiled.out_info] == [a.shape for a in args]
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= temp_budget, temp
+
+
+def test_flash_kernels_take_a_wider_query_key_head_for_v5e(one_chip):
+    """The latent-attention layer's call of the ling-3.0-flash-vl cell: 32
+    heads, queries and keys of 128 + 64 rotary, values of 128, 8,192 tokens.
+    A block of 192 lanes is one and a half tiles, which interpret mode cannot
+    refuse and Mosaic can."""
+    T, H = 8192, 32
+    spec = lambda d: jax.ShapeDtypeStruct((1, T, H, d), jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=True, interpret=False)
+                       .astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        spec(192), spec(192), spec(128)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 3
+    assert [tuple(o.shape)[-1] for o in compiled.out_info] == [192, 192, 128]
+
+
 def test_grouped_expert_products_compile_to_xlas_kernel_for_v5e(one_chip):
     """`held_topk_experts` at the benchmark's sizes: the three grouped
     products of a pass and their transposes are XLA's own grouped-matmul
