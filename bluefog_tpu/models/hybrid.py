@@ -67,8 +67,9 @@ REMAT_KEEPS = ("attn_out", "attn_lse", "mixer_out", "mlp_gate_up")
 # residuals and the delta rule's output, 67 MB a layer at 8,192 tokens x 32
 # heads of 128.  `kda_chunked` walks its heads a group at a time, each group
 # under a checkpoint of its own so that one group's intermediates are alive
-# and not a layer's (3.57 GB a layer at once, compiled for the v5e; PERF.md
-# section 6, PR 43); with its output kept, the recomputed block does not run
+# and not a layer's (1.28 GB a layer at once, compiled for the v5e, 3.57 while
+# the chunk's stateless stage was an XLA expression; PERF.md section 6, PR 44
+# and PR 43); with its output kept, the recomputed block does not run
 # the group's forward a second time before the group's backward runs it a
 # third.
 DELTA_KEEPS = ("attn_out", "attn_lse", "kda_out")
@@ -552,6 +553,9 @@ class DeltaLatentMoELM(nn.Module):
                     ("kda.lower_bound", self.lower_bound),
                     ("kda.kernel_layers", n_kda * kda_conv_kernels_take(
                         input_ids.shape[1], inner, self.conv_width)),
+                    # whose chunks' stateless stage the kernels take: one path,
+                    # every shape (`kernels/kda.py`)
+                    ("kda.intra_kernel_layers", n_kda),
                     ("mla.layers", n_mla), ("mla.kv_rank", self.kv_rank),
                     ("mla.qk_dims", self.qk_nope + self.qk_rope),
                     ("mla.v_dims", self.v_dim),

@@ -1108,8 +1108,11 @@ def phase_conv(cfg, seed, on_tpu, clock):
 
 
 def phase_kda(cfg, seed, on_tpu, clock):
-    """`kda_chunked` (the stateless stage in XLA, the forward and backward
-    Pallas kernels) at the sizes of a linear layer of the benchmark's
+    """`kda_chunked` (the chunk's stateless stage through `kda_intra_fwd` /
+    `kda_intra_bwd`, 3.75 and 7.0 us a grid step of four heads and a chunk,
+    the walk through `kda_chunk_fwd` / `kda_chunk_bwd`: four Pallas kernels;
+    8.02 ms a forward call and 20.91 with backward, my chip runs, PR 44) at
+    the sizes of a linear layer of the benchmark's
     `ling-3.0-flash-vl` cell against the recurrence of chipbench's plain
     reference taken token by token, eight heads at a time: the output and all
     five gradients in relative L2, and the host clock over ``calls`` calls of

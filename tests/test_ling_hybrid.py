@@ -3,7 +3,9 @@
 `ling-3.0-flash-vl`) at a small size on the CPU: the chunked delta rule
 (`kernels/kda.py`, its kernels in interpret mode) against the recurrence taken
 token by token, values and all five gradients, with decays at the bound and
-near none; latent attention through the flash kernels' two head sizes against
+near none; the chunk's stateless stage, its forward kernel's six outputs and
+its backward kernel's five gradients, against the XLA expression it was
+(`tests/kda_oracle.py`); latent attention through the flash kernels' two head sizes against
 dense softmax from the compressed form; the router against a written-out
 loop; the shares of an expert layer adding up to the uncut layer; the model's
 loss and every gradient against the configuration's plain reference, and three
@@ -13,6 +15,7 @@ configuration file against its published source; the FLOP count against a hand
 count; the new readers; the cell's rehearsal through `python -m chipbench` and
 its controls."""
 
+import functools
 import json
 import os
 import subprocess
@@ -25,7 +28,10 @@ import numpy as np
 import optax
 import pytest
 
+import kda_oracle
+
 import bluefog_tpu as bf
+from bluefog_tpu.kernels import kda
 from bluefog_tpu.kernels.flash_attention import flash_attention
 from bluefog_tpu.kernels.kda import kda_chunked
 from bluefog_tpu.models import hybrid
@@ -130,6 +136,70 @@ def test_a_chunk_that_is_not_sub_blocks_doubled_is_refused(chunk):
     args, _ = _delta_inputs(5, 48, "spread")
     with pytest.raises(ValueError, match="power of two"):
         kda_chunked(*args, chunk=chunk)
+
+
+# ---- the chunk's stateless stage against the expression it was ------------------
+
+
+STAGE_OUTPUTS = ("q exp G", "P", "W", "U0", "k exp(G[last] - G)", "exp G[last]")
+
+
+@functools.lru_cache(maxsize=None)
+def _stage_case(chunk, gate, on_grid=True):
+    """Two chunks of four heads through `kda._intra` (both kernels, the four
+    heads in one grid step) and through the oracle under `jax.vjp`, the same random
+    cotangents of all six outputs into both.  `on_grid`: the log-decay rounded
+    to a multiple of 2^-12, so that a running sum of 128 of them is exact in
+    float32 in whatever order it is taken and what is left between the two is
+    the stage's own arithmetic."""
+    args, _ = _delta_inputs(chunk, 2 * chunk, gate)
+    if on_grid:
+        args = args[:3] + (jnp.round(args[3] * 4096) / 4096,) + args[4:]
+    flat = lambda a: a.reshape(a.shape[:2] + (-1,))  # the heads side by side
+    got, pull = jax.vjp(lambda q, k, v, g, beta: kda._intra(
+        flat(q), flat(k), flat(v), flat(g), beta, chunk, True), *args)
+    want, pull_oracle = jax.vjp(lambda *a: kda_oracle.intra(
+        *(kda_oracle.by_chunk(x, chunk) for x in a), jnp.float32), *args)
+    cotangents = tuple(jax.random.normal(jax.random.PRNGKey(n), w.shape)
+                       for n, w in enumerate(want))
+    return got, want, pull(cotangents), pull_oracle(cotangents)
+
+
+def _gaps(got, want):
+    """Largest difference of each array over the oracle's largest entry."""
+    return [float(jnp.max(jnp.abs(a - b))) / max(float(jnp.max(jnp.abs(b))), 1e-30)
+            for a, b in zip(got, want)]
+
+
+@pytest.mark.parametrize("gate", ["bound", "none", "spread"])
+@pytest.mark.parametrize("chunk", [16, 32, 64, 128])
+def test_the_stage_forward_kernel_is_the_expression(chunk, gate):
+    got, want, _, _ = _stage_case(chunk, gate)
+    assert [a.shape for a in got] == [w.shape for w in want]
+    for name, gap in zip(STAGE_OUTPUTS, _gaps(got, want)):
+        assert gap <= 2e-6, (name, gap)
+
+
+@pytest.mark.parametrize("gate", ["bound", "none", "spread"])
+@pytest.mark.parametrize("chunk", [16, 32, 64, 128])
+def test_the_stage_backward_kernel_is_the_expressions_vjp(chunk, gate):
+    """The adjoint written out against JAX's transpose of the expression:
+    2e-5 of each gradient's largest entry, 1e-4 on the decay's at the bound."""
+    _, _, got, want = _stage_case(chunk, gate)
+    assert [a.shape for a in got] == [w.shape for w in want]
+    for name, gap in zip(NAMES[1:], _gaps(got, want)):
+        assert gap <= (1e-4 if (name, gate) == ("dg", "bound") else 2e-5), (name, gap)
+
+
+@pytest.mark.parametrize("chunk", [16, 32, 64, 128])
+def test_the_stages_running_sum_is_cumsums_to_float32_rounding(chunk):
+    """Off the grid the kernels' running sum (a product with a triangle of
+    ones at `HIGHEST`) and `jnp.cumsum` round a sum that reaches 5 x chunk each
+    in its own way: an ulp of 320 is 3e-5, and that is what `exp(G[last] - G)` and
+    the gradients then differ by.  4e-7 a token of the chunk, twice what was
+    read (3.8e-6 at 16, 7.6e-6 at 32, 7.5e-6 at 64, 3.0e-5 at 128)."""
+    got, want, dgot, dwant = _stage_case(chunk, "spread", on_grid=False)
+    assert max(_gaps(got, want) + _gaps(dgot, dwant)) <= 4e-7 * chunk
 
 
 # ---- two head sizes in one attention call -------------------------------------
@@ -506,7 +576,8 @@ def test_three_steps_of_adamw_as_the_cells_correct_compares_them(cell, ref):
 
 WANTED_GAUGES = {
     "kda.layers": 3, "kda.heads": 4, "kda.head_dim": 16, "kda.chunk": 32,
-    "kda.lower_bound": -5, "kda.kernel_layers": 0, "mla.layers": 1, "mla.kv_rank": 32,
+    "kda.lower_bound": -5, "kda.kernel_layers": 0, "kda.intra_kernel_layers": 3,
+    "mla.layers": 1, "mla.kv_rank": 32,
     "mla.qk_dims": 24, "mla.v_dims": 16, "attention.layers_global": 1,
     "attention.heads_global": 4, "moe.score": 1, "moe.groups": 4,
     "moe.groups_kept": 2, "moe.shared_width": 32, "moe.routed_scale": 2.5,
