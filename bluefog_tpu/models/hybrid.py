@@ -5,9 +5,11 @@ Gu, arXiv:2405.21060).  :class:`DeltaLatentMoELM`: inclusionAI's Ling 3.0
 flash, whose linear layer is Kimi Delta Attention (:class:`KDAMixer`,
 arXiv:2510.26692), whose attention layer is DeepSeek-V2's latent attention
 (:class:`LatentAttentionMixer`, arXiv:2405.04434) and whose feed-forward part
-is, after a leading dense layer, DeepSeek-V3's expert layer.  Both are stacks
-of :class:`_HybridBlock`, which takes its mixer and its feed-forward part as
-it is handed them.
+is, after a leading dense layer, DeepSeek-V3's expert layer; with latent
+attention in every layer, no gate on its heads and the rotary over interleaved
+pairs, the same class is a DeepSeek-V3-style decoder (kakaocorp's kanana-2).
+Both are stacks of :class:`_HybridBlock`, which takes its mixer and its
+feed-forward part as it is handed them.
 
 Of :class:`HybridMambaLM`:
 
@@ -447,7 +449,10 @@ class LatentAttentionMixer(nn.Module):
     the queries' last ``rope`` channels and on ``k_r``; causal softmax of ``(q_n
     . k_n + q_r . k_r) / sqrt(nope + rope)`` over the whole sequence through
     the flash kernels, which take the values' head size beside the query-key
-    one; every head's output times a sigmoid gate of the normed input; ``W_o``."""
+    one; with ``head_gate`` (Ling's) every head's output times a sigmoid gate
+    of the normed input, without it (DeepSeek-V2's own) no ``gate`` leaf;
+    ``W_o``.  ``rotary_interleaved``: the rotary pairs channel ``2i`` with
+    ``2i + 1`` (:func:`bluefog_tpu.models.transformer._rotary`)."""
 
     num_heads: int
     kv_rank: int
@@ -458,6 +463,8 @@ class LatentAttentionMixer(nn.Module):
     eps: float
     dtype: Any
     attention_fn: Callable  # (q, k, v) -> out, causal
+    head_gate: bool = True
+    rotary_interleaved: bool = False
 
     @nn.compact
     def __call__(self, u):
@@ -471,32 +478,36 @@ class LatentAttentionMixer(nn.Module):
             c = RMSNorm(dtype=self.dtype, eps=self.eps, name="mla_kv_norm")(
                 down[..., :self.kv_rank])
         kv = dense((H, self.nope + self.v_dim), name="mla_kv_up")(c)
-        q = jnp.concatenate([q[..., :self.nope], _rotary(
-            q[..., self.nope:], positions, rotary=self.rotary)], axis=-1)
-        k_r = _rotary(down[..., None, self.kv_rank:], positions, rotary=self.rotary)
+        turn = partial(_rotary, positions=positions, rotary=self.rotary,
+                       interleaved=self.rotary_interleaved)
+        q = jnp.concatenate([q[..., :self.nope], turn(q[..., self.nope:])], axis=-1)
+        k_r = turn(down[..., None, self.kv_rank:])
         with jax.named_scope("mla_kv_up"):  # the one rotary head beside every head's own
             k = jnp.concatenate([kv[..., :self.nope], jnp.broadcast_to(
                 k_r, (B, T, H, self.rope))], axis=-1)
         with jax.named_scope("attention_global"):
             att = self.attention_fn(q, k, kv[..., self.nope:])
-        with jax.named_scope("attention_gate"):
-            gate = jax.nn.sigmoid(dense(H, name="gate")(u).astype(jnp.float32))
-            att = (att * gate[..., None]).astype(self.dtype)
+        if self.head_gate:
+            with jax.named_scope("attention_gate"):
+                gate = jax.nn.sigmoid(dense(H, name="gate")(u).astype(jnp.float32))
+                att = (att * gate[..., None]).astype(self.dtype)
         return dense(d, name="o")(att.reshape(B, T, H * self.v_dim))
 
 
 class DeltaLatentMoELM(nn.Module):
-    """The decoder of Ling 3.0 flash: ``layer_kinds`` names each layer's
-    mixer, ``"kda"`` (:class:`KDAMixer`) or ``"mla"``
-    (:class:`LatentAttentionMixer`), ``layer_dense`` says where the
+    """The decoder of Ling 3.0 flash, and with ``"mla"`` in every layer a
+    DeepSeek-V3-style one: ``layer_kinds`` names each layer's mixer,
+    ``"kda"`` (:class:`KDAMixer`, heads of ``head_dim``) or ``"mla"``
+    (:class:`LatentAttentionMixer`, with ``head_gate`` and
+    ``rotary_interleaved`` as it takes them), ``layer_dense`` says where the
     feed-forward part is the dense gated MLP of ``dff`` and where this share
     of the expert layer: sigmoid scores over ``num_experts``, the choice on
     the score plus a bias that takes no gradient, ``top_k`` among the
-    ``groups_kept`` best of ``groups`` groups, weights renormalised times
-    ``routed_scale``, the ``experts_held`` computed dropless beside a shared
-    expert of ``shared_dff``.  Pre-norm residual blocks, embedding and head
-    untied, every block recomputed in the backward pass but for
-    :data:`DELTA_KEEPS`.  With ``labels`` the chunked next-token loss."""
+    ``groups_kept`` best of ``groups`` groups (one group: among all), weights
+    renormalised times ``routed_scale``, the ``experts_held`` computed
+    dropless beside a shared expert of ``shared_dff``.  Pre-norm residual
+    blocks, embedding and head untied, every block recomputed in the backward
+    pass but for :data:`DELTA_KEEPS`.  With ``labels`` the chunked next-token loss."""
 
     vocab_size: int
     hidden_size: int
@@ -504,7 +515,6 @@ class DeltaLatentMoELM(nn.Module):
     layer_dense: Tuple[bool, ...]
     dff: int
     num_heads: int
-    head_dim: int
     kv_rank: int
     qk_nope: int
     qk_rope: int
@@ -518,6 +528,9 @@ class DeltaLatentMoELM(nn.Module):
     routed_scale: float
     groups: int
     groups_kept: int
+    head_dim: Optional[int] = None  # a delta-rule head's channels: "kda" layers only
+    head_gate: bool = True
+    rotary_interleaved: bool = False
     conv_width: int = 4
     chunk: int = 64
     lower_bound: float = -5.0
@@ -536,29 +549,34 @@ class DeltaLatentMoELM(nn.Module):
         if set(kinds) - {"kda", "mla"} or len(is_dense) != len(kinds):
             raise ValueError(f"layer kinds {sorted(set(kinds))}: 'kda' or 'mla', and "
                              f"{len(is_dense)} feed-forward kinds for {len(kinds)}")
+        n_kda, n_mla = kinds.count("kda"), kinds.count("mla")
+        if n_kda and not self.head_dim:
+            raise ValueError(f"{n_kda} 'kda' layers and no head_dim")
         keeps = DELTA_KEEPS if self.remat else ()
         reg = _telemetry.get_registry()
         if reg.enabled:
             tokens, width = input_ids.size, jnp.dtype(self.dtype).itemsize
-            n_kda, n_mla = kinds.count("kda"), kinds.count("mla")
-            inner = self.num_heads * self.head_dim
+            inner = self.num_heads * (self.head_dim or 0)
             kept = {
                 "attn_out": n_mla * tokens * self.num_heads * self.v_dim * width,
                 "attn_lse": n_mla * tokens * self.num_heads * 4,
                 "kda_out": n_kda * tokens * inner * width,
             }
-            for name, value in (
-                    ("kda.layers", n_kda), ("kda.heads", self.num_heads),
-                    ("kda.head_dim", self.head_dim), ("kda.chunk", self.chunk),
-                    ("kda.lower_bound", self.lower_bound),
-                    ("kda.kernel_layers", n_kda * kda_conv_kernels_take(
-                        input_ids.shape[1], inner, self.conv_width)),
-                    # whose chunks' stateless stage the kernels take: one path,
-                    # every shape (`kernels/kda.py`)
-                    ("kda.intra_kernel_layers", n_kda),
+            delta = (
+                ("kda.layers", n_kda), ("kda.heads", self.num_heads),
+                ("kda.head_dim", self.head_dim), ("kda.chunk", self.chunk),
+                ("kda.lower_bound", self.lower_bound),
+                ("kda.kernel_layers", n_kda * kda_conv_kernels_take(
+                    input_ids.shape[1], inner, self.conv_width)),
+                # whose chunks' stateless stage the kernels take: one path,
+                # every shape (`kernels/kda.py`)
+                ("kda.intra_kernel_layers", n_kda)) if n_kda else ()
+            for name, value in delta + (
                     ("mla.layers", n_mla), ("mla.kv_rank", self.kv_rank),
                     ("mla.qk_dims", self.qk_nope + self.qk_rope),
                     ("mla.v_dims", self.v_dim),
+                    ("mla.head_gate", int(self.head_gate)),
+                    ("mla.rotary_interleaved", int(self.rotary_interleaved)),
                     ("attention.layers_global", n_mla),
                     ("attention.heads_global", self.num_heads),
                     ("moe.score", 1),  # 1: sigmoid scores (0: a softmax's)
@@ -580,7 +598,7 @@ class DeltaLatentMoELM(nn.Module):
                            rotary_frequencies(self.qk_rope, self.rope_theta), self.eps,
                            self.dtype,
                            self.attention_fn or partial(flash_attention, causal=True),
-                           name="mixer"),
+                           self.head_gate, self.rotary_interleaved, name="mixer"),
         }
         ffns = {
             True: dense_ffn(self.dff, self.dtype),

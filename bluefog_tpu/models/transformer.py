@@ -149,17 +149,31 @@ def rotary_frequencies(dims: int, base: float, *, factor: float = 1.0,
 
 
 @jax.named_scope("attention_rotary")
-def _rotary(x, positions, base=10000.0, rotary: Optional[Rotary] = None):
+def _rotary(x, positions, base=10000.0, rotary: Optional[Rotary] = None,
+            interleaved: bool = False):
     """Rotary position embedding (half-split convention); x: [B, T, H, D],
     positions: [T].  With ``rotary`` its frequencies and factor take the
     place of ``base``'s: the first ``2 len(inv_freq)`` dimensions are rotated,
-    half-split among themselves, and the others are returned as they came."""
+    half-split among themselves, and the others are returned as they came.
+
+    ``interleaved``: the checkpoint pairs channel ``2i`` with ``2i + 1``
+    (DeepSeek-V3's ``rope_interleave``).  The rotated channels are put evens
+    first, odds after, once, and rotated half-split, and they **stay in that
+    order** (as the published implementation leaves them): a query and its key
+    are both handed through here, and their product sums over the channels in
+    whatever order both have.  Rotating in place would shuffle the lanes a
+    second time to put the pairs back, on the way in and on the cotangent's
+    way out, for the same scores."""
     if rotary is None:
         half = x.shape[-1] // 2
         freqs = 1.0 / (base ** (jnp.arange(half, dtype=jnp.float32) / half))
     else:
         half = len(rotary.inv_freq)
         freqs = jnp.asarray(rotary.inv_freq, jnp.float32)
+    if interleaved:
+        rotated, rest = x[..., :2 * half], x[..., 2 * half:]
+        evens_first = jnp.swapaxes(rotated.reshape(x.shape[:-1] + (half, 2)), -1, -2)
+        x = jnp.concatenate([evens_first.reshape(rotated.shape), rest], axis=-1)
     angles = positions[:, None].astype(jnp.float32) * freqs[None, :]  # [T, half]
     cos = jnp.cos(angles)[None, :, None, :]
     sin = jnp.sin(angles)[None, :, None, :]

@@ -25,7 +25,10 @@ phase that fails raises: the traceback goes to stderr, the last line says
                                      taken token by token and the flash
                                      kernels at heads of 64 vs dense attention,
                                      the causal convolution's kernels vs the
-                                     reference's expression in float32
+                                     reference's expression in float32, a
+                                     latent-attention mixer with no gate and
+                                     interleaved rotary pairs vs its dense
+                                     float32 oracle
     python chip_smoke.py --chips 4   four chips, only what exists across
                                      chips: parity on exp2(4), placement,
                                      ResNet-50 ATC vs allreduce, contraction,
@@ -40,6 +43,7 @@ Times printed here are observations of a smoke run, not performance.
 """
 
 import argparse
+import functools
 import json
 import os
 import sys
@@ -106,6 +110,10 @@ FULL = dict(
     # of 64; 128 + 64 rotary query-key channels beside values of 128
     kda=dict(seq=8192, heads=32, head_dim=128, chunk=64, lower=-5.0, calls=10),
     mla=dict(seq=8192, heads=32, nope=128, rope=64, v_dim=128, calls=10),
+    # a layer's whole mixer of the `kanana-2-30b-a3b` cell: hidden 2048, a
+    # latent of 512, no head gate, the rotary over interleaved pairs
+    mla_mixer=dict(seq=8192, hidden=2048, heads=32, rank=512, nope=128, rope=64,
+                   v_dim=128, theta=1e6, calls=10),
     probe=dict(dim=4096, iters=512),
 )
 TINY = dict(
@@ -124,6 +132,8 @@ TINY = dict(
     conv=dict(seq=64, inner=128, states=64, heads=8, width=4, calls=2),
     kda=dict(seq=128, heads=4, head_dim=16, chunk=32, lower=-5.0, calls=2),
     mla=dict(seq=128, heads=4, nope=16, rope=8, v_dim=16, calls=2),
+    mla_mixer=dict(seq=128, hidden=64, heads=4, rank=32, nope=16, rope=8, v_dim=16,
+                   theta=1e6, calls=2),
 )
 
 # flash-vs-dense agreement at bf16 compute on seeded weights.  The two paths
@@ -195,7 +205,8 @@ KDA_L2_RTOL = 2e-2
 
 PHASES = ("ops_windows", "resnet_atc", "resnet_allreduce", "contraction",
           "buckets_vs_per_leaf", "decoder", "experts_piled", "kda_vs_recurrence",
-          "mla_two_head_sizes", "shared_heads", "subtiles", "ssd", "conv")
+          "mla_two_head_sizes", "mla_mixer_no_gate", "shared_heads", "subtiles", "ssd",
+          "conv")
 
 
 class _CompileClock:
@@ -1220,6 +1231,72 @@ def phase_mla(cfg, seed, on_tpu, clock):
         assert gap <= LOGITS_L2_RTOL, f"{n}: {gap} from dense softmax in relative L2"
 
 
+def phase_mla_mixer(cfg, seed, on_tpu, clock):
+    """A latent-attention layer's whole mixer as the `kanana-2-30b-a3b` cell
+    calls it (`hybrid.LatentAttentionMixer` with no head gate and the rotary
+    over interleaved pairs put evens first, bfloat16 products, the flash
+    kernels at 192 beside 128) against the dense oracle of chipbench's plain
+    reference (float32, the pairs turned in place, a head and 2,048 query rows
+    at a time): the output and the gradients of the input and of the five
+    leaves in relative L2, and ms a call."""
+    from bluefog_tpu.kernels.flash_attention import flash_attention
+    from bluefog_tpu.models.hybrid import LatentAttentionMixer
+    from bluefog_tpu.models.transformer import rotary_frequencies
+    from chipbench import manifest, seeded
+
+    reference = manifest.load_module(os.path.join(
+        manifest.REPO, "chipbench", "reference", "kanana-2-30b-a3b.py"))
+    t0 = time.perf_counter()
+    sizes = dict(hidden_size=cfg["hidden"], num_attention_heads=cfg["heads"],
+                 kv_lora_rank=cfg["rank"], qk_nope_head_dim=cfg["nope"],
+                 qk_rope_head_dim=cfg["rope"], v_head_dim=cfg["v_dim"],
+                 rope_theta=cfg["theta"], rms_norm_eps=1e-6)
+    block = None if cfg["seq"] >= 4096 else 32
+    mixer = LatentAttentionMixer(
+        cfg["heads"], cfg["rank"], cfg["nope"], cfg["rope"], cfg["v_dim"],
+        rotary_frequencies(cfg["rope"], cfg["theta"]), 1e-6, jnp.bfloat16,
+        functools.partial(flash_attention, causal=True, block_q=block, block_k=block,
+                          interpret=not on_tpu),
+        head_gate=False, rotary_interleaved=True)
+    keys = jax.random.split(jax.random.PRNGKey(seed), 3)
+    u = jax.random.normal(keys[0], (1, cfg["seq"], cfg["hidden"]), jnp.float32)
+    go = jax.random.normal(keys[1], u.shape, jnp.float32)
+    leaves = {path: (jnp.ones(a.shape) if path[-1] == "scale" else 0.02 * jax.random.normal(
+        jax.random.fold_in(keys[2], i), a.shape)) for i, (path, a) in enumerate(sorted(
+            seeded.flatten(jax.eval_shape(mixer.init, keys[2], u)["params"]).items()))}
+    assert ("gate", "kernel") not in leaves and len(leaves) == 5
+
+    def fast(p, u_):
+        return mixer.apply({"params": seeded.nest(p)}, u_.astype(jnp.bfloat16))
+
+    def dense(p, u_):
+        full = {("mixer",) + path: a for path, a in p.items()}
+        with jax.default_matmul_precision("highest"):
+            return reference.latent_attention(u_[0], full, ("mixer",), sizes, False)[None]
+
+    def values(fn):
+        def loss(p, u_):
+            o = fn(p, u_)
+            return jnp.sum(o.astype(jnp.float32) * go), o
+        (_, o), (dp, du) = jax.jit(jax.value_and_grad(loss, (0, 1), has_aux=True))(leaves, u)
+        return {"o": o, "du": du, **{"d_" + "/".join(path): a for path, a in dp.items()}}
+
+    rel = _rel_l2(values(fast), values(dense))
+    both = jax.jit(jax.grad(lambda p, u_: jnp.sum(fast(p, u_).astype(jnp.float32) * go),
+                            (0, 1)))
+    ms = {kind: _ms_a_call(fn, (leaves, u), cfg["calls"])
+          for kind, fn in (("fwd", jax.jit(fast)), ("fwd_bwd", both))}
+    _emit("mla_mixer_no_gate", t0, clock, seq=cfg["seq"], hidden=cfg["hidden"],
+          heads=cfg["heads"], qk_dims=cfg["nope"] + cfg["rope"], v_dims=cfg["v_dim"],
+          interpret=not on_tpu,
+          compared="o, du and the five leaves' gradients of the latent-attention mixer "
+                   "(no gate, interleaved pairs, bfloat16) against the dense float32 "
+                   "oracle that turns the pairs in place: relative L2; ms a call, host clock",
+          rel_l2=rel, ms_per_call=ms, rel_l2_tol=LOGITS_L2_RTOL)
+    for n, gap in rel.items():
+        assert gap <= LOGITS_L2_RTOL, f"{n}: {gap} from the dense oracle in relative L2"
+
+
 def _rebuild_native():
     """The plan compiler on this path loads the native library when it is
     there; the chip tool copies the disk, so build it from the committed
@@ -1277,6 +1354,8 @@ def run(args, device):
             phase_kda(sizes["kda"], args.seed, on_tpu, clock)
         if want("mla_two_head_sizes"):
             phase_mla(sizes["mla"], args.seed, on_tpu, clock)
+        if want("mla_mixer_no_gate"):
+            phase_mla_mixer(sizes["mla_mixer"], args.seed, on_tpu, clock)
         if want("shared_heads"):
             phase_shared_heads(sizes["shared_heads"], args.seed, on_tpu, clock)
         if want("subtiles"):

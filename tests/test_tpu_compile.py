@@ -334,6 +334,60 @@ def test_flash_kernels_take_a_wider_query_key_head_for_v5e(one_chip):
     assert [tuple(o.shape)[-1] for o in compiled.out_info] == [192, 192, 128]
 
 
+# the kanana-2-30b-a3b cell's step at the timed size (B1 S8192, six layers of
+# latent attention at its published widths, 687.5 M parameters) and the step of
+# its plain reference, which chipbench/check.py runs on the same chip after the
+# window: loss and gradient, then AdamW, parameters and optimizer state donated.
+# Bytes: arguments + results - aliased + temporaries, read at PR 45 as
+# 13,280,411,648 (8.25 GB of weights and moments standing, 5.03 GB of
+# temporaries of which 2.75 GB are the gradient) and 12,661,027,328 (the
+# reference hands its gradient back: 11.0 GB of results), plus 3 %.  The chip
+# has 16.909 GB; the floor for a cell is a quarter of it.
+@pytest.mark.parametrize("which,kernel_calls,budget", [
+    pytest.param("program", 18, 13_680_000_000, id="program-B1-T8192-six-MLA-layers"),
+    pytest.param("reference", 0, 13_041_000_000, id="reference-float32-in-pieces")])
+def test_the_latent_attention_decoders_step_fits_a_v5e(one_chip, monkeypatch, which,
+                                                       kernel_calls, budget):
+    """Eighteen flash kernel calls in the program's step: forward, dK/dV and
+    dQ of six layers at a query-key head of 192 beside a value head of 128,
+    the forward not run again because its output and logsumexp are kept."""
+    import sys
+    sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    from chipbench import manifest, optimizers, seeded
+
+    monkeypatch.setattr(importlib.import_module("bluefog_tpu.kernels.flash_attention"),
+                        "_default_interpret", lambda: False)
+    cell = manifest.resolve("kanana-2-30b-a3b-atc-warmup-b1-s8k-1chip")
+    sizes, ref = cell.sizes(), cell.module("reference")
+    spec = lambda shape, dtype=jnp.float32: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
+    params = {p: spec(s) for p, s in ref.param_shapes(sizes)[0].items()}
+    ids = spec((sizes["per_rank_batch"], sizes["seq_len"]), jnp.int32)
+    tx = optimizers.make(cell.mix["optimizer"])
+    opt = jax.tree_util.tree_map(lambda a: spec(a.shape, a.dtype),
+                                 jax.eval_shape(tx.init, params))
+    if which == "program":
+        apply_fn = cell.module("program").build(sizes)["apply_fn"]
+        loss_of = lambda p, x, y: apply_fn({"params": seeded.nest(p)}, x, labels=y)
+    else:
+        loss_of = lambda p, x, y: ref.loss_fn(p, {}, x, y, sizes)[0]
+
+    def step(p, o, x, y):
+        loss, g = jax.value_and_grad(loss_of)(p, x, y)
+        updates, o = tx.update(g, o, p)
+        out = (optax.apply_updates(p, updates), o, loss)
+        return out + (g,) if which == "reference" else out  # check.local_step_fn's
+
+    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(params, opt, ids, ids).compile()
+    # beside XLA's own grouped products of the five expert layers
+    assert len(re.findall(r"%attention_global(?:\.\d+)? = ", compiled.as_text())) \
+        == kernel_calls
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes - m.alias_size_in_bytes
+             + m.temp_size_in_bytes)
+    assert 0.25 * 16.909e9 < total <= budget, total
+
+
 def test_grouped_expert_products_compile_to_xlas_kernel_for_v5e(one_chip):
     """`held_topk_experts` at the benchmark's sizes: the three grouped
     products of a pass and their transposes are XLA's own grouped-matmul
