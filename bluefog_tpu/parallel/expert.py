@@ -27,6 +27,7 @@ runs without its exchange, and nothing stands in for the absent chips.
 from __future__ import annotations
 
 import math
+from fractions import Fraction
 from functools import partial
 from typing import Optional, Tuple
 
@@ -218,20 +219,37 @@ def _grouped_matmul_bwd(dtype, res, g):
 _grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
 
 
-# rows of the sorted buffer one pass computes: the grain at which the
-# layer's cost follows the load of the experts held.  Every pass pays about
+# the most rows of the sorted buffer one pass computes.  Every pass pays about
 # 2.5 ms that its rows do not (twelve grouped products at 0.2 ms each before
 # their first tile, three float32 sums of the stacks' gradients): read at
 # 4096 rows a pass, where they were half of a pass (my chip runs, PR 29).
-# 16,384 is a step's tokens in the benchmark's cell: one pass at even
+# 16,384 is a step's tokens in the SmallThinker cells: one pass at even
 # routing (12,288 rows), six if every token picks six experts held here.
 PASS_ROWS = 16384
 
+# what a pass holds over the load the shapes promise (T k H / E rows at even
+# routing), and the grain its row count is rounded up to.  Gather, mask,
+# products and scatter-add cost what the buffer holds, valid rows or not:
+# 0.33-0.6 ms a thousand rows, a layer forward and backward; a second pass
+# costs 1.5 ms with 8 experts held, 4.4 with 16, 6.0 with 32 (the float32
+# sums of the stacks' gradients), as much as 3,000 to 10,000 spare rows (my
+# chip runs, PR 46).  So the buffer follows the load, with room to stay one
+# pass: under seeded weights a layer read -15 to +16 % of the even load over
+# a window, and +39 % in the one layer of Ling in which the router's bias
+# favours the held experts' group (1,425 rows for 1,024: the grain's 2,048
+# hold them).  4/3 is also what keeps the SmallThinker cells' 12,288 rows in
+# their one pass of 16,384.
+HEADROOM = Fraction(4, 3)
+ROW_GRAIN = 1024
 
-def _pass_rows(tokens: int, top_k: int, held: int) -> int:
-    """:data:`PASS_ROWS`, or all the rows there can be if that is fewer."""
+
+def _pass_rows(tokens: int, top_k: int, held: int, num_experts: int) -> int:
+    """The static row count of a pass: the even load ``tokens * top_k * held
+    / num_experts`` times :data:`HEADROOM`, rounded up to :data:`ROW_GRAIN`;
+    never more than :data:`PASS_ROWS`, nor than all the rows there can be."""
     worst = tokens * min(top_k, held)
-    return min(PASS_ROWS, -(-worst // 8) * 8)
+    promised = math.ceil(HEADROOM * tokens * top_k * held / num_experts)
+    return min(PASS_ROWS, -(-promised // ROW_GRAIN) * ROW_GRAIN, -(-worst // 8) * 8)
 
 
 def _passes(ends, rows):
@@ -349,12 +367,14 @@ def held_topk_experts(m, experts, weights, params, held, num_experts: int,
     How: the ``T * k`` assignments are sorted by expert held (those to
     experts held elsewhere last), and the sorted rows that are assigned
     here are computed in passes of ``rows`` rows (default
-    :data:`PASS_ROWS`): gather, three grouped products (``lax.ragged_dot``
-    with the pass's own group sizes; operands in ``m``'s type, float32
-    accumulators), scatter-add by token.  There are as many passes as the
-    load asks for, ``ceil(assigned / rows)``, so the layer's cost follows
-    the load of the experts held, and a pile-up on one expert costs time
-    and never a token."""
+    :func:`_pass_rows`: the load the shapes promise, ``T k H / num_experts``
+    rows at even routing, and a third, up to :data:`PASS_ROWS`): gather,
+    three grouped products (``lax.ragged_dot`` with the pass's own group
+    sizes; operands in ``m``'s type, float32 accumulators), scatter-add by
+    token.  There are as many passes as the load asks for, ``ceil(assigned
+    / rows)``, so the layer's cost follows the load of the experts held:
+    routing that tilts past the buffer costs a second, equally small pass,
+    and a pile-up on one expert costs time and never a token."""
     T, d = m.shape
     k = experts.shape[1]
     held = tuple(int(e) for e in held)
@@ -367,7 +387,7 @@ def held_topk_experts(m, experts, weights, params, held, num_experts: int,
         raise ValueError(
             f"params hold {params['wg'].shape[0]} experts, held names {H}")
     if rows is None:
-        rows = _pass_rows(T, k, H)
+        rows = _pass_rows(T, k, H, total)
     A = T * k
     reg = _telemetry.get_registry()
     if reg.enabled:
@@ -375,6 +395,7 @@ def held_topk_experts(m, experts, weights, params, held, num_experts: int,
         reg.gauge("moe.experts_total").set(total)
         reg.gauge("moe.top_k").set(k)
         reg.gauge("moe.buffer_rows").set(rows)
+        reg.gauge("moe.rows_expected").set(A * H / total)
 
     with jax.named_scope("moe_experts"):
         # global expert id -> its place here, H for "held elsewhere"

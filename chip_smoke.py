@@ -765,7 +765,7 @@ def phase_experts_piled(cfg, seed, clock):
             return dict(vjp(cot)[0], out=y)
         return run(args, x, cot)
 
-    rows = cfg["rows"] or ep._pass_rows(T, k, H)
+    rows = cfg["rows"] or ep._pass_rows(T, k, H, E)
     assigned = int(jnp.sum(ep.route_topk(x, router, k)[0] < H))
     passes = -(-assigned // rows)
     assert passes > 1 and assigned % rows, (

@@ -190,9 +190,69 @@ def test_gauges_say_what_was_built(monkeypatch, tmp_path):
         telemetry.reset()
     assert gauges == {
         "moe.experts_held": 4, "moe.experts_total": 16, "moe.top_k": 3,
-        "moe.buffer_rows": 384,  # 128 tokens x min(3, 4): under a pass
+        "moe.buffer_rows": 384,  # 128 tokens x min(3, 4): all the rows there can be
+        "moe.rows_expected": 96.0,  # 128 x 3 x 4 / 16: what even routing sends here
         "attention.window": 24, "attention.layers_window": 3,
         "attention.layers_global": 1}
-    # the benchmark's layer: passes of 16,384 sorted rows, one at even routing
-    # (12,288 rows expected), six if every token picks six experts held here
-    assert ep._pass_rows(16384, 6, 8) == ep.PASS_ROWS == 16384
+    # the SmallThinker cells' layer: passes of 16,384 sorted rows, one at even
+    # routing (12,288 rows expected), six if every token picks six experts held
+    assert ep._pass_rows(16384, 6, 8, 64) == ep.PASS_ROWS == 16384
+
+
+# a step's tokens, top-k, experts held, experts the router knows; the rows of a pass
+CELLS = {"smallthinker": ((16384, 6, 8, 64), 16384), "ling": ((8192, 8, 8, 512), 2048),
+         "kanana": ((8192, 6, 16, 128), 8192), "laguna": ((8192, 8, 32, 256), 11264)}
+
+
+@pytest.mark.parametrize("name", CELLS)
+def test_a_pass_is_sized_for_the_load_the_shapes_promise(name):
+    """Not for the worst routing: the buffer holds the even load and a third,
+    in whole grains.  SmallThinker's stays the 16,384 it was chosen at; of the
+    three cells that inherited it Ling's and Kanana's get half of it or less
+    and Laguna's, whose even load is half of it, two thirds."""
+    (tokens, k, held, total), rows = CELLS[name]
+    even = tokens * k * held / total
+    assert ep._pass_rows(tokens, k, held, total) == rows <= ep.PASS_ROWS
+    assert ep.HEADROOM * even <= rows < ep.HEADROOM * even + ep.ROW_GRAIN
+    # never more rows than there can be: few tokens, or fewer held than chosen
+    assert ep._pass_rows(96, k, held, total) == 96 * min(k, held)
+    assert ep._pass_rows(tokens, k, 1, total) <= tokens
+
+
+@pytest.mark.parametrize("push,passes", [(1.5, 2), (3.5, 3)])
+def test_routing_that_overfills_the_default_buffer_takes_more_passes(
+        reference, layer, push, passes):
+    """1,024 tokens promise 768 rows on the 8 experts held, so a pass is
+    1,024 rows; a router pushed towards them sends more: two passes, three,
+    the last partly filled, and the plain reference's numbers, output and
+    every gradient, as from one pass of all the rows there can be."""
+    tokens, held = 1024, list(range(8))
+    ks = jax.random.split(jax.random.PRNGKey(11), 2)
+    # a last feature of ones: its row of the router leans on the experts held
+    x = jnp.concatenate([jax.random.normal(ks[0], (tokens, D)),
+                         jnp.ones((tokens, 1))], axis=1)
+    router = jnp.concatenate([layer["router"],
+                              jnp.zeros((1, E)).at[0, :8].set(push)])
+    piled = dict(layer, x=x, m=jax.random.normal(ks[1], (tokens, D)), router=router)
+    rows = ep._pass_rows(tokens, K, len(held), E)
+    assigned = int(jnp.sum(ep.route_topk(x, router, K)[0] < 8))
+    assert rows == 1024 and -(-assigned // rows) == passes and assigned % rows
+
+    def ours(a, rows=None):
+        return share(a, held, rows)
+
+    def plain(a):
+        p = {("l", n): a[n][:8] for n in ("wg", "wu", "wd")}
+        return reference.expert_terms(
+            a["m"], a["x"] @ a["router"], p, "l",
+            {"moe_num_active_primary_experts": K}, False, tuple(held))
+
+    np.testing.assert_allclose(ours(piled), plain(piled), atol=2e-5)
+    got = jax.grad(lambda a: jnp.sum(ours(a) ** 2))(piled)
+    whole = jax.grad(lambda a: jnp.sum(ours(a, tokens * K) ** 2))(piled)
+    want = jax.grad(lambda a: jnp.sum(plain(a) ** 2))(piled)
+    for name in ("router", "m", "wg", "wu", "wd"):
+        scale = float(jnp.max(jnp.abs(want[name])))
+        assert scale > 0
+        np.testing.assert_allclose(got[name], want[name], atol=2e-5 * scale + 1e-7)
+        np.testing.assert_allclose(got[name], whole[name], atol=2e-5 * scale + 1e-7)

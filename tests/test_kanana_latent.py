@@ -500,6 +500,35 @@ def test_the_standing_decoders_lower_to_what_they_lowered_to(name):
     assert hashlib.sha256(text.encode()).hexdigest() == LOWERED[name]
 
 
+def test_smallthinkers_expert_layer_at_its_cells_size_lowers_to_what_it_did():
+    """The rehearsal sizes above are too small to say: there a pass holds all
+    the rows there can be, whatever sizes it.  The layer alone at the
+    SmallThinker cells' 16,384 tokens, 6 of 64, 8 held, hidden 2560, width 768,
+    loss-and-gradient: the text the parent commit (80edb39, before a pass was
+    sized from the load) lowered it to, one pass of 16,384 rows in it."""
+    from bluefog_tpu.parallel import expert as ep
+
+    small = manifest.resolve("smallthinker-21b-a3b-atc-warmup-b2-s8k-1chip").sizes()
+    T, d = small["per_rank_batch"] * small["seq_len"], small["hidden_size"]
+    k, H = small["moe_num_active_primary_experts"], small["moe_num_primary_experts_held"]
+    E, f = small["moe_num_primary_experts"], small["moe_ffn_hidden_size"]
+    assert (T, k, H, E, d, f) == (16384, 6, 8, 64, 2560, 768)
+    S = jax.ShapeDtypeStruct
+    stacks = {"wg": S((H, d, f), jnp.float32), "wu": S((H, d, f), jnp.float32),
+              "wd": S((H, f, d), jnp.float32)}
+
+    def loss(m, weights, stacks, experts):
+        y = ep.held_topk_experts(m, experts, weights, stacks, range(H), E)
+        return jnp.sum(y.astype(jnp.float32))
+
+    text = jax.jit(jax.value_and_grad(loss, (0, 1, 2))).lower(
+        S((T, d), jnp.bfloat16), S((T, k), jnp.float32), stacks,
+        S((T, k), jnp.int32)).as_text()
+    assert "16384x2560" in text
+    assert hashlib.sha256(text.encode()).hexdigest() \
+        == "08fddf530150f5d79740e1e4b19af5b542e1fcfa3be28abf5cf7817801a8b08c"
+
+
 # ---- the configuration, the FLOP count, the manifest, the readers ----------------
 
 

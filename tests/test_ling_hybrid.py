@@ -571,6 +571,35 @@ def test_three_steps_of_adamw_as_the_cells_correct_compares_them(cell, ref):
     assert 0 < np.max(np.abs(moved)) < 1e-6 * np.max(np.abs(params0[bias]))
 
 
+def test_the_routing_tool_counts_the_rows_on_the_experts_held(cell, ref, capsys):
+    """`python -m chipbench.routing_ling`: `chipbench.routing`'s readings for
+    this configuration, whose reference has no `held_rows`; the count is made
+    from the reference's own mixers, norms and `route`, in the three layers of
+    the rehearsal that have experts."""
+    from chipbench import routing_ling
+
+    assert routing_ling.main(["--workload", CELL, "--seeds", "1", "--seconds", "0.5",
+                              "--rehearse"]) == 0
+    row, = [json.loads(l) for l in capsys.readouterr().out.splitlines()
+            if l.startswith("{")]
+    sizes = cell.sizes(rehearse=True)
+    total = sizes["per_rank_batch"] * sizes["seq_len"] * sizes["num_experts_per_tok"]
+    assert row["seed"] == 300 and row["failed"] == 0 and row["steps_in_window"] >= 2
+    assert row["even_rows"] == total * sizes["num_experts_held"] / sizes["num_experts"]
+    for rows in (row["held_rows_first_step"], row["held_rows_last_step"]):
+        # one routing group of four: a layer's count follows how often it stays
+        assert len(rows) == 3 and all(0 < r < total for r in rows)
+    # every expert held: every assignment, in every layer that has experts
+    every = dict(sizes, num_experts_held=sizes["num_experts"])
+    params = seeded.make_weights(ref, every, seed=9)[0]
+    (x, _), = seeded.make_batches(ref, every, 9, ranks=1, pool=1)
+    assert np.asarray(routing_ling.held_rows(ref, params, x[0], every)).tolist() \
+        == [total] * 3
+    assert routing_ling.main(["--workload", CELL, "--seeds", "1"]) == 2
+    captured = capsys.readouterr()
+    assert captured.out == "" and "no TPU" in captured.err
+
+
 # ---- gauges, the configuration, the FLOP count, the readers ------------------
 
 
