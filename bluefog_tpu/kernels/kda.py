@@ -2,8 +2,9 @@
 rule with a decay a channel, in its chunked form, forward and backward.
 
 For every head (keys and queries of ``K`` channels, values of ``V``, a state
-``S`` of ``K x V``) and token ``t``, with a log-decay a channel ``g[t] <= 0``
-and a step ``b[t]`` in (0, 1)::
+``S`` of ``K x V``) and token ``t``, with a log-decay a channel ``g[t] <= 0``,
+a step ``b[t]`` in (0, 1), and ``k[t]`` the head's projected key over its
+length, ``q[t]`` its query over its length and ``sqrt(K)``::
 
     S[t] = (I - b[t] k[t] k[t]^T) Diag(exp g[t]) S[t-1] + b[t] k[t] v[t]^T    S[-1] = 0
     o[t] = S[t]^T q[t]
@@ -31,9 +32,19 @@ read ``q``, ``k``, ``v`` in the type the mixer hands over, ``g`` and ``beta``
 where the model holds them, a ``[chunk, 4 K]`` block of ``[batch, T, H x K]``
 at the step's four heads, and widen it in VMEM; the forward writes
 what the walk takes in the layout it takes it, the backward the five
-gradients in the arguments' layout and types.  Float32 throughout, every
-product at ``Precision.HIGHEST``, the running sum one of them (with a
-triangle of ones).  ``exp(-G)`` is not finite over a chunk (``g >= -5`` a token:
+gradients in the arguments' layout and types.  **The unit vectors are taken
+there** (PR 47): ``q`` and ``k`` come in as projected and convolved, and on
+the widened block, a head's ``K`` channels on the lanes, ``x rsqrt(sum(x x) +
+1e-6)`` is a multiply, a lane reduction, an ``rsqrt`` and a multiply (the
+query's also over ``sqrt(K)``; the constants of chipbench's reference).  The
+unit vectors stay in float32 from the raw block on, and the backward kernel,
+which makes them again with the pairs, hands back the raw blocks' cotangents:
+with ``y = s x / |x|``, ``dx = (s / |x|) (dy - y sum(dy y) / s^2)``, the part
+of ``dy`` across ``x``.  (As an XLA expression in the mixer the same norms
+were three float32 passes over ``[batch, T, H, K]`` a layer, 60 ms of the
+Ling cell's step.)  Float32 throughout, every product at
+``Precision.HIGHEST``, the running sum one of them (with a triangle of
+ones).  ``exp(-G)`` is not finite over a chunk (``g >= -5`` a token:
 e^320 at 64 tokens), so the pairs are formed on sub-blocks of ``SUB`` = 16
 tokens, relative to the running sum at each sub-block's **middle** token
 ``m``: rows and keys of sub-block ``I`` carry ``exp(+-(G - G[m]))``, between
@@ -67,6 +78,11 @@ and 1.75 us a chunk and head, where two heads a step read 1.12 and 1.84 and
 one head 1.64 and 2.51 (my chip runs, PR 44; the XLA expression these replace
 read 1.55 forward, 1.83 recomputed and 4.1 backward in the step).  The
 inverse is half of the forward (0.47 us without it), its merges 0.35 of that.
+With the unit vectors taken inside, a layer's 1,024 grid steps in one call
+read 4.01 us a step forward and 7.72 backward by the host's clock, where the
+kernels before, handed unit vectors, read 4.09 and 7.49 the same way (my chip
+runs, PR 47): the norms are within the forward's noise and 3 % of the
+backward.
 
 What walks the state is the pair of Pallas kernels ``kda_chunk_fwd`` and
 ``kda_chunk_bwd`` (the names the device trace shows), grid ``(batch, heads,
@@ -102,6 +118,7 @@ __all__ = ["kda_chunked", "SUB"]
 
 SUB = 16  # tokens of a sub-block: exp(5 x 16) is finite in float32
 _HEADS_A_STEP = 4  # of the stateless stage's kernels, at most
+_EPS = 1e-6  # beside a head's squared length, as the reference's `_unit`
 _HIGH = lax.Precision.HIGHEST
 
 
@@ -199,6 +216,21 @@ def _head(ref, j, width):
     return ref[0, :, j * width:(j + 1) * width].astype(jnp.float32)
 
 
+def _unit(x, scale=1.0):
+    """``y = scale x / |x|`` down the rows of ``x`` ``[c, K]`` (a head's
+    channels on the lanes: one lane reduction a row) and ``rho = scale /
+    |x|`` ``[c, 1]``.  A row of zeros stays zeros."""
+    rho = scale * lax.rsqrt(jnp.sum(x * x, axis=1, keepdims=True) + _EPS)
+    return x * rho, rho
+
+
+def _unit_pull(dy, y, rho, scale=1.0):
+    """``x``'s cotangent from ``dy``, that of :func:`_unit`'s ``y``: ``dy``
+    less its share along ``y``, over ``x``'s length."""
+    along = jnp.sum(dy * y, axis=1, keepdims=True) * scale ** -2
+    return rho * (dy - y * along)
+
+
 def _step_size(beta_ref, head):
     """``beta`` of head ``head`` down the rows, ``[c, 1]``, from the ``[1, c,
     H]`` block of all the heads'."""
@@ -212,6 +244,7 @@ def _intra_fwd_kernel(h, n, q_ref, k_ref, v_ref, g_ref, beta_ref,
     heads = []
     for j in range(hb):
         q, k, g = (_head(ref, j, kd) for ref in (q_ref, k_ref, g_ref))
+        q, k = _unit(q, kd ** -0.5)[0], _unit(k)[0]
         b = _step_size(beta_ref, h * hb + j)
         made = _pairs(q, k, g)
         decay, last = jnp.exp(made.gsum), made.gsum[-1:]
@@ -234,8 +267,11 @@ def _intra_bwd_kernel(h, n, q_ref, k_ref, v_ref, g_ref, beta_ref,
     r, i = _iota((c, c), 0), _iota((c, c), 1)
     token = _iota((c, kd), 0)
     heads = []
-    for j in range(hb):  # the forward's pairs again
+    norms = []
+    for j in range(hb):  # the forward's unit vectors and pairs again
         q, k, g = (_head(ref, j, kd) for ref in (q_ref, k_ref, g_ref))
+        (q, rq), (k, rk) = _unit(q, kd ** -0.5), _unit(k)
+        norms.append((rq, rk))
         heads.append((q, k, _head(v_ref, j, vd), _step_size(beta_ref, h * hb + j),
                       _pairs(q, k, g)))
     xs = _unit_lower_inverses([b * made.kp for _, _, _, b, made in heads])
@@ -285,8 +321,11 @@ def _intra_bwd_kernel(h, n, q_ref, k_ref, v_ref, g_ref, beta_ref,
             dmid = dmid - jnp.sum(part, axis=0, keepdims=True)
             dgsum = dgsum + jnp.where(token == m * SUB + SUB // 2 - 1, dmid, 0.0)
         dgsum = dgsum + jnp.where(token == c - 1, dlast, 0.0)
-        dq_ref[0, :, j * kd:(j + 1) * kd] = dq.astype(dq_ref.dtype)
-        dk_ref[0, :, j * kd:(j + 1) * kd] = dk.astype(dk_ref.dtype)
+        # of the blocks as they were read, through the unit vectors
+        rq, rk = norms[j]
+        dq_ref[0, :, j * kd:(j + 1) * kd] = _unit_pull(
+            dq, q, rq, kd ** -0.5).astype(dq_ref.dtype)
+        dk_ref[0, :, j * kd:(j + 1) * kd] = _unit_pull(dk, k, rk).astype(dk_ref.dtype)
         dv_ref[0, :, j * vd:(j + 1) * vd] = (b * dvb).astype(dv_ref.dtype)
         dg_ref[0, :, j * kd:(j + 1) * kd] = _sum_back(dgsum).astype(dg_ref.dtype)
         dbeta_ref[0, j, 0] = jnp.sum(jnp.where(r == i, db, 0.0), axis=0, keepdims=True)
@@ -493,10 +532,13 @@ def kda_chunked(q, k, v, g, beta, *, chunk=64, heads_at_once=4, interpret=None):
     """``o[t] = S[t]^T q[t]`` of the recurrence in the module's docstring,
     differentiable in all five arguments.
 
-    ``q``, ``k``: ``[batch, T, H, K]`` as the recurrence takes them (after the
-    norm and the query's scale); ``v``: ``[batch, T, H, V]``; ``g``: ``[batch,
-    T, H, K]``, the log-decay, at most 0 and not under -5.5 a token (what
-    keeps a sub-block's ``exp`` finite); ``beta``: ``[batch, T, H]``.
+    ``q``, ``k``: ``[batch, T, H, K]`` as projected and convolved: the
+    kernels take a head's vector over its length, ``x / sqrt(sum(x x) +
+    1e-6)``, and the query over ``sqrt(K)`` besides, and the recurrence runs on
+    those; the gradients are the raw arrays'.  ``v``: ``[batch, T, H, V]``;
+    ``g``: ``[batch, T, H, K]``, the log-decay, at most 0 and not under -5.5 a
+    token (what keeps a sub-block's ``exp`` finite); ``beta``: ``[batch, T,
+    H]``.
     ``chunk`` is ``SUB`` times a power of two; a ``T`` that it does not
     divide is padded with tokens that leave the state as it is.  The heads are
     walked ``heads_at_once`` at a time (where that divides them), each group
@@ -513,7 +555,7 @@ def kda_chunked(q, k, v, g, beta, *, chunk=64, heads_at_once=4, interpret=None):
     # block is whole 128-lane tiles and the kernels read it where it is ([T,
     # 4, K] is tiled four rows at a time and would be copied a call)
     args = tuple(a.reshape(a.shape[:2] + (-1,)) for a in (q, k, v, g)) + (beta,)
-    if pad:  # k = 0, beta = 0, g = 0: the state passes
+    if pad:  # k = 0 (its unit vector too), beta = 0, g = 0: the state passes
         args = tuple(jnp.pad(a, ((0, 0), (0, pad), (0, 0))) for a in args)
     one = functools.partial(_heads, chunk=chunk, interpret=interpret)
     if heads % heads_at_once or heads == heads_at_once:

@@ -73,7 +73,9 @@ REMAT_KEEPS = ("attn_out", "attn_lse", "mixer_out", "mlp_gate_up")
 # the chunk's stateless stage was an XLA expression; PERF.md section 6, PR 44
 # and PR 43); with its output kept, the recomputed block does not run
 # the group's forward a second time before the group's backward runs it a
-# third.
+# third.  A recomputed delta-rule block makes its projections, convolution,
+# gates and gated norm again; the unit vectors of q and k are the kernels' own
+# (PR 47), so it makes neither them nor their float32 copies.
 DELTA_KEEPS = ("attn_out", "attn_lse", "kda_out")
 
 
@@ -376,8 +378,10 @@ class KDAMixer(nn.Module):
     """Kimi Delta Attention (arXiv:2510.26692 section 3).  ``[q, k, v] = u
     W_qkv`` through a causal depth-wise convolution and SiLU, no bias (the
     kernels of :mod:`bluefog_tpu.kernels.causal_conv` where they take the
-    shapes); a head at a time ``q / |q| / sqrt(K)`` and ``k / |k|``; the
-    log-decay a channel ``lower_bound * sigmoid(exp(A_log[head]) * (u W_f +
+    shapes); a head at a time ``q / |q| / sqrt(K)`` and ``k / |k|``, taken by
+    the delta rule's kernels on the blocks they read (this module hands them q
+    and k as convolved and makes no float32 copy of either); the log-decay a
+    channel ``lower_bound * sigmoid(exp(A_log[head]) * (u W_f +
     dt_bias))``, in ``(lower_bound, 0)``; the step ``sigmoid(u W_b)`` a head;
     the delta rule of :func:`bluefog_tpu.kernels.kda.kda_chunked`; each head's
     output through an RMS norm with one learned weight a channel, times
@@ -425,12 +429,8 @@ class KDAMixer(nn.Module):
                 jnp.exp(a_log)[:, None] * f.reshape(B, T, H, hd))
             beta = jax.nn.sigmoid(dense(H, name="kda_b")(u).astype(jnp.float32))
         with jax.named_scope("kda_chunk"):
-            def unit(x):  # a head's vector over its length, float32
-                x = x.astype(jnp.float32)
-                return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
-
-            o = kda_chunked((unit(q) * hd ** -0.5).astype(self.dtype),
-                            unit(k).astype(self.dtype), v, g, beta, chunk=self.chunk)
+            # q and k as convolved: the stage's kernels take the unit vectors
+            o = kda_chunked(q, k, v, g, beta, chunk=self.chunk)
             o = checkpoint_name(o, "kda_out")
         with jax.named_scope("kda_gate_norm"):
             gate = jax.nn.sigmoid(dense(inner, name="kda_g")(u).astype(jnp.float32))

@@ -1120,15 +1120,19 @@ def phase_conv(cfg, seed, on_tpu, clock):
 
 def phase_kda(cfg, seed, on_tpu, clock):
     """`kda_chunked` (the chunk's stateless stage through `kda_intra_fwd` /
-    `kda_intra_bwd`, 3.75 and 7.0 us a grid step of four heads and a chunk,
-    the walk through `kda_chunk_fwd` / `kda_chunk_bwd`: four Pallas kernels;
-    8.02 ms a forward call and 20.91 with backward, my chip runs, PR 44) at
-    the sizes of a linear layer of the benchmark's
-    `ling-3.0-flash-vl` cell against the recurrence of chipbench's plain
-    reference taken token by token, eight heads at a time: the output and all
-    five gradients in relative L2, and the host clock over ``calls`` calls of
-    the forward alone and of forward and backward.  The decays are drawn over
-    their whole range, a quarter of the channels within 1e-2 of the bound."""
+    `kda_intra_bwd`, which since PR 47 take the unit vectors of q and k
+    themselves, the walk through `kda_chunk_fwd` / `kda_chunk_bwd`: four
+    Pallas kernels; 7.96 ms a forward call and 21.03 with backward, beside
+    8.03 and 20.89 of the parent's kernels handed unit vectors made outside
+    them, my chip runs, PR 47) at the sizes of a linear layer of the
+    benchmark's `ling-3.0-flash-vl` cell, handed q and k raw in bfloat16 as
+    the convolution leaves them, against the recurrence of
+    chipbench's plain reference taken token by token, eight heads at a time,
+    on the reference's own unit vectors of the same q and k in float32: the
+    output and all five gradients in relative L2, and the host clock over
+    ``calls`` calls of the forward alone and of forward and backward.  The
+    decays are drawn over their whole range, a quarter of the channels within
+    1e-2 of the bound."""
     from bluefog_tpu.kernels.kda import kda_chunked
     from chipbench import manifest
 
@@ -1137,10 +1141,7 @@ def phase_kda(cfg, seed, on_tpu, clock):
     t0 = time.perf_counter()
     T, H, K = cfg["seq"], cfg["heads"], cfg["head_dim"]
     keys = jax.random.split(jax.random.PRNGKey(seed), 7)
-    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
-    q = (unit(jax.random.normal(keys[0], (1, T, H, K))) * K ** -0.5).astype(jnp.bfloat16)
-    k = unit(jax.random.normal(keys[1], (1, T, H, K))).astype(jnp.bfloat16)
-    v, go = (jax.random.normal(r, (1, T, H, K), jnp.bfloat16) for r in keys[2:4])
+    q, k, v, go = (jax.random.normal(r, (1, T, H, K), jnp.bfloat16) for r in keys[:4])
     shift = jnp.where(jax.random.uniform(keys[4], (K,)) < 0.25, 9.0, -4.0)
     g = cfg["lower"] * jax.nn.sigmoid(shift + jax.random.normal(keys[5], (1, T, H, K)))
     beta = jax.nn.sigmoid(jax.random.normal(keys[6], (1, T, H)))
@@ -1159,8 +1160,13 @@ def phase_kda(cfg, seed, on_tpu, clock):
         groups = max(1, H // 8)
         split = lambda a: jnp.moveaxis(
             f32(a).reshape((T, groups, H // groups) + a.shape[3:]), 1, 0)
-        one = jax.checkpoint(lambda a: reference.kda_scan(*a))
-        o = jax.lax.map(one, tuple(map(split, (q, k, v, g, beta))))
+
+        def one(a):  # a head's vector over its length, the query over sqrt(K)
+            q, k, *rest = a
+            return reference.kda_scan(reference._unit(q) * K ** -0.5,
+                                      reference._unit(k), *rest)
+
+        o = jax.lax.map(jax.checkpoint(one), tuple(map(split, (q, k, v, g, beta))))
         return jnp.moveaxis(o, 0, 1).reshape(1, T, H, K)
 
     chunked = lambda *a: kda_chunked(*a, chunk=cfg["chunk"], interpret=not on_tpu)
@@ -1171,9 +1177,10 @@ def phase_kda(cfg, seed, on_tpu, clock):
           for kind, fn in (("fwd", jax.jit(chunked)), ("fwd_bwd", both))}
     _emit("kda_vs_recurrence", t0, clock, seq=T, heads=H, head_dim=K,
           chunk=cfg["chunk"], interpret=not on_tpu,
-          compared="o and the five gradients of kda_chunked (bfloat16 q, k, v) "
-                   "against the float32 recurrence taken token by token: relative "
-                   "L2; ms a call, host clock",
+          compared="o and the five gradients of kda_chunked (raw bfloat16 q, k; v) "
+                   "against the float32 recurrence taken token by token on the "
+                   "unit vectors of the same q and k: relative L2; ms a call, host "
+                   "clock",
           rel_l2=rel, ms_per_call=ms, rel_l2_tol=KDA_L2_RTOL)
     for n, gap in rel.items():
         assert gap <= KDA_L2_RTOL, f"{n}: {gap} from the recurrence in relative L2"

@@ -4,7 +4,9 @@ oracle that `tests/test_ling_hybrid.py` holds the stage's two Pallas kernels
 (`kda_intra_fwd`, `kda_intra_bwd`) to.  Float32, every product at
 ``Precision.HIGHEST``, the pairs on sub-blocks of ``SUB`` tokens relative to
 the running sum at each sub-block's middle token, the inverse by substitution
-and the merges ``X - X A_off X``."""
+and the merges ``X - X A_off X``.  Since PR 47 the kernels take a head's unit
+vector themselves: :func:`on_units` puts the same norm, as a plain expression
+that JAX differentiates, in front of this oracle or of the token recurrence."""
 
 import jax.numpy as jnp
 from jax import lax
@@ -17,6 +19,21 @@ _HIGH = lax.Precision.HIGHEST
 def _mm(spec, a, b):
     return jnp.einsum(spec, a, b, precision=_HIGH,
                       preferred_element_type=jnp.float32)
+
+
+def unit(x, scale=1.0):
+    """``scale x / |x|`` over the last axis, as chipbench's reference takes a
+    head's vector over its length (`reference/ling-3.0-flash-vl.py: _unit`)."""
+    return x * lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6) * scale
+
+
+def on_units(fn):
+    """``fn(q, k, ...)`` handed q and k raw, ``[..., K]``: the key over its
+    length and the query over its length and ``sqrt(K)``, in float32, first."""
+    def of_raw(q, k, *rest):
+        q, k = q.astype(jnp.float32), k.astype(jnp.float32)
+        return fn(unit(q, q.shape[-1] ** -0.5), unit(k), *rest)
+    return of_raw
 
 
 def _same_block(c, size):

@@ -473,16 +473,22 @@ def test_a_delta_rule_layer_without_a_head_size_is_refused(cell):
 # The standing decoders' programs at their rehearsal sizes: the StableHLO text
 # of loss-and-gradient, as `jit(...).lower(...).as_text()` of the parent commit
 # (f1fb8b5, PR 44) gave it.  A hash, because the texts are 1.2 to 2.6 MB; a PR
-# that means to change what one of them lowers to replaces its line.
+# that means to change what one of them lowers to replaces its line.  PR 47
+# replaced Ling's (771adfbc...: its mixer hands q and k raw to the delta rule's
+# kernels, which take the unit vectors) and added this file's own cell at the
+# hash of PR 47's parent (8f82114): it builds Ling's decoder class with no
+# delta-rule layer, and a change to that layer must not reach it.
 LOWERED = {
     "ling-3.0-flash-vl-atc-warmup-b1-s8k-1chip":
-        "771adfbc49e71a4632e6acc615f8b30e7b3f178a98307d0204df0105b70e41e7",
+        "e5569a6361513621afed6f11fd8916e241870bfaec61d7b3ca90e16299e1729b",
     "granite-4.0-h-micro-atc-warmup-b1-s8k-1chip":
         "709927d17eea333679c74e2f51cdb16b1f040eb879cfac4ef45e269dd07ad59b",
     "laguna-xs.2-atc-warmup-b1-s8k-1chip":
         "dd5029b60057b96eace882c332b03b25479409159ae6eed99674f13af36d6c62",
     "smallthinker-21b-a3b-atc-warmup-b2-s8k-1chip":
         "1b418ca17393ef5375d3c733fd0da3f148a16a668f4084bcad4bb73e2134e8eb",
+    "kanana-2-30b-a3b-atc-warmup-b1-s8k-1chip":
+        "0d0396b84222beec9e78bea16e21404b82fbbeb2d69d1cae796b35741ee3873e",
 }
 
 

@@ -62,14 +62,13 @@ def ref(cell):
 
 
 def _delta_inputs(seed, t, gate, heads=4, k=16, batch=2):
-    """q and k as the mixer hands them (unit length, the query over sqrt K),
-    the log-decay in (-5, 0): `bound` within 1e-2 of -5 on every channel and
-    token, `none` within 1e-2 of 0, `spread` over the whole range."""
+    """q and k as the mixer hands them (raw: normal draws, lengths about
+    sqrt K; the kernels take the unit vectors and the recurrence's side takes
+    them through `kda_oracle.on_units`), the log-decay in (-5, 0): `bound`
+    within 1e-2 of -5 on every channel and token, `none` within 1e-2 of 0,
+    `spread` over the whole range."""
     r = jax.random.split(jax.random.PRNGKey(seed), 6)
-    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
-    q = unit(jax.random.normal(r[0], (batch, t, heads, k))) * k ** -0.5
-    kk = unit(jax.random.normal(r[1], (batch, t, heads, k)))
-    v = jax.random.normal(r[2], (batch, t, heads, k))
+    q, kk, v = (jax.random.normal(key, (batch, t, heads, k)) for key in r[:3])
     shift, spread = {"bound": (9.0, 0.5), "none": (-9.0, 0.5), "spread": (0.0, 2.0)}[gate]
     g = -5.0 * jax.nn.sigmoid(shift + spread * jax.random.normal(r[3], (batch, t, heads, k)))
     beta = jax.nn.sigmoid(jax.random.normal(r[4], (batch, t, heads)))
@@ -103,7 +102,7 @@ def test_the_chunked_delta_rule_is_the_token_recurrence(ref, t, chunk, gate, at_
     args, weight = _delta_inputs(t, t, gate)
     got = _values_and_grads(
         lambda *a: kda_chunked(*a, chunk=chunk, heads_at_once=at_once), args, weight)
-    want = _values_and_grads(jax.vmap(ref.kda_scan), args, weight)
+    want = _values_and_grads(kda_oracle.on_units(jax.vmap(ref.kda_scan)), args, weight)
     if gate != "spread":
         assert float(jnp.max(args[3])) < -4.99 or float(jnp.min(args[3])) > -0.01
     for name, a, b in zip(NAMES, got, want):
@@ -126,9 +125,62 @@ def test_the_delta_rule_in_bfloat16_stays_within_its_smoke_tolerance(ref):
     low = lambda a: a.astype(jnp.bfloat16)
     got = kda_chunked(low(q), low(k), low(v), g, beta, chunk=64)
     assert got.dtype == jnp.bfloat16
-    want = jax.vmap(ref.kda_scan)(q, k, v, g, beta)
+    want = kda_oracle.on_units(jax.vmap(ref.kda_scan))(q, k, v, g, beta)
     assert float(jnp.max(jnp.abs(got.astype(jnp.float32) - want))) < 2e-2 * float(
         jnp.max(jnp.abs(want)))
+
+
+def _across(d, x):
+    """`sum(d * x)` a head and token over the largest `|d| |x|`: 0 where `d`
+    lies across `x`."""
+    return float(jnp.max(jnp.abs(jnp.sum(d * x, axis=-1)))) / float(
+        jnp.max(jnp.linalg.norm(d, axis=-1) * jnp.linalg.norm(x, axis=-1)))
+
+
+def test_a_keys_length_changes_nothing_and_its_gradient_lies_across_it():
+    """The kernels take a head's vector over its length: seven times the key
+    (the second sequence of the batch) is the key, and the norm's adjoint
+    projects the unit vector's cotangent onto what lies across the raw one
+    (the query's too)."""
+    args, weight = _delta_inputs(7, 32, "spread", heads=2, batch=1)
+    twice = tuple(jnp.concatenate([a, a]) for a in args)
+    q, k = twice[0], twice[1].at[1].multiply(7.0)
+    got = _values_and_grads(lambda *a: kda_chunked(*a, chunk=16), (q, k) + twice[2:],
+                            jnp.concatenate([weight, weight]))
+    for name, a in zip(NAMES, got):
+        one, seven = (a[0], a[1] * 7.0) if name == "dk" else a
+        assert float(jnp.max(jnp.abs(seven - one))) <= 2e-6 * float(
+            jnp.max(jnp.abs(one))), name
+    assert _across(got[1], q) <= 1e-6 and _across(got[2], k) <= 1e-6
+
+
+def test_a_head_of_zero_keys_gives_finite_values_and_the_recurrences_gradients(ref):
+    """`rsqrt(0 + 1e-6)` is 1e3 and `0 x 1e3` is 0: the head writes nothing,
+    reads nothing back, and its key's gradient is 1e3 times the unit
+    vector's cotangent, as JAX's own of the expression."""
+    (q, k, *rest), weight = _delta_inputs(6, 32, "spread", heads=2, batch=1)
+    args = (q, k.at[:, :, 1].set(0.0)) + tuple(rest)
+    got = _values_and_grads(lambda *a: kda_chunked(*a, chunk=16), args, weight)
+    want = _values_and_grads(kda_oracle.on_units(jax.vmap(ref.kda_scan)), args, weight)
+    assert float(jnp.max(jnp.abs(got[0][:, :, 1]))) == 0.0
+    for name, a, b in zip(NAMES, got, want):
+        assert bool(jnp.all(jnp.isfinite(a))), name
+        assert float(jnp.max(jnp.abs(a - b))) <= 2e-5 * float(jnp.max(jnp.abs(b))), name
+
+
+def test_the_padding_is_tokens_that_follow_and_are_not_looked_at():
+    """24 tokens in chunks of 16: the raw keys are padded with zeros, whose
+    unit vector is zero, so the 8 tokens of padding write nothing, and no
+    gradient of the 24 differs from what it is when 8 real tokens follow that
+    the loss does not look at."""
+    args, weight = _delta_inputs(8, 32, "spread", heads=2, batch=1)
+    fn = lambda *a: kda_chunked(*a, chunk=16)
+    short = _values_and_grads(fn, tuple(a[:, :24] for a in args), weight[:, :24])
+    whole = _values_and_grads(fn, args, weight.at[:, 24:].set(0.0))
+    for name, a, b in zip(NAMES, short, whole):
+        assert a.shape[1] == 24 and bool(jnp.all(jnp.isfinite(a))), name
+        assert float(jnp.max(jnp.abs(a - b[:, :24]))) <= 2e-6 * float(
+            jnp.max(jnp.abs(b))), name
 
 
 @pytest.mark.parametrize("chunk", [24, 48, 8])
@@ -158,8 +210,8 @@ def _stage_case(chunk, gate, on_grid=True):
     flat = lambda a: a.reshape(a.shape[:2] + (-1,))  # the heads side by side
     got, pull = jax.vjp(lambda q, k, v, g, beta: kda._intra(
         flat(q), flat(k), flat(v), flat(g), beta, chunk, True), *args)
-    want, pull_oracle = jax.vjp(lambda *a: kda_oracle.intra(
-        *(kda_oracle.by_chunk(x, chunk) for x in a), jnp.float32), *args)
+    want, pull_oracle = jax.vjp(kda_oracle.on_units(lambda *a: kda_oracle.intra(
+        *(kda_oracle.by_chunk(x, chunk) for x in a), jnp.float32)), *args)
     cotangents = tuple(jax.random.normal(jax.random.PRNGKey(n), w.shape)
                        for n, w in enumerate(want))
     return got, want, pull(cotangents), pull_oracle(cotangents)
@@ -297,6 +349,41 @@ def test_the_delta_mixer_is_the_references(cell, ref, tokens, channels, kernels)
     module = hybrid.KDAMixer(heads, 16, 4, 32, sizes["kda_lower_bound"],
                              sizes["rms_norm_eps"], jnp.float32)
     _against_reference(module, ref.kda_mixer, mixer, u, weight, sizes)
+
+
+def _primitives_under(jaxpr, scope, inside=False):
+    """The primitives of `jaxpr` traced under the named scope `scope`, nested
+    jaxprs walked, a Pallas kernel's body left out."""
+    found = []
+    for eqn in jaxpr.eqns:
+        here = inside or scope in str(eqn.source_info.name_stack).split("/")
+        if eqn.primitive.name == "pallas_call":
+            continue
+        nested = [getattr(v, "jaxpr", v) for value in eqn.params.values()
+                  for v in (value if isinstance(value, (tuple, list)) else (value,))
+                  if hasattr(getattr(v, "jaxpr", v), "eqns")]
+        if not nested and here:
+            found.append(eqn.primitive.name)
+        for inner in nested:
+            found.extend(_primitives_under(inner, scope, here))
+    return found
+
+
+def test_the_mixer_takes_no_norm_of_q_or_k_outside_the_kernels():
+    """The witness that the kernels took the unit vectors: in the mixer's
+    forward and backward pass, under `kda_chunk` and outside the Pallas calls,
+    nothing is summed over a head's channels, rooted or multiplied: reshapes,
+    transposes, slices and casts of what the kernels are handed and hand
+    back.  Under `kda_gate_norm` the same walk finds the gated norm's sum."""
+    module = hybrid.KDAMixer(8, 16, 4, 32, -5.0, 1e-6, jnp.bfloat16)
+    u = jnp.ones((1, 64, 32), jnp.bfloat16)
+    params = jax.eval_shape(module.init, jax.random.PRNGKey(0), u)
+    traced = jax.make_jaxpr(jax.grad(lambda p, x: jnp.sum(
+        module.apply(p, x).astype(jnp.float32)), (0, 1)))(params, u)
+    under = set(_primitives_under(traced.jaxpr, "kda_chunk"))
+    assert under and not under & {"reduce_sum", "rsqrt", "mul", "div", "integer_pow",
+                                  "sqrt", "dot_general"}, sorted(under)
+    assert "reduce_sum" in _primitives_under(traced.jaxpr, "kda_gate_norm")
 
 
 def dict_cell(cell, **changed):

@@ -284,11 +284,17 @@ def test_recomputed_granite_blocks_keep_what_their_policy_names_for_v5e(
 # once.  The budgets are the temporaries' bytes read at PR 44 (504,187,904 and
 # 1,275,390,976; 1,041,389,568 and 3,572,845,056 at PR 43, with the stateless
 # stage an XLA expression) plus 5 %: what is left is what the stage's kernels
-# hand the walk's and take back from them, six arrays a head each way.
-@pytest.mark.parametrize("at_once,temp_budget", [
-    pytest.param(4, 529_400_000, id="B1-T8192-H32-K128-four-heads-a-group"),
-    pytest.param(32, 1_339_200_000, id="B1-T8192-H32-K128-the-layer-at-once")])
-def test_delta_rule_kernels_compile_for_v5e(one_chip, at_once, temp_budget):
+# hand the walk's and take back from them, six arrays a head each way.  They
+# stand since PR 47 put the unit vectors of q and k into the stage's kernels.
+# The group's case carries a budget for those two kernels alone, a group's call
+# of each: the bytes of generated code read at PR 47 (1,355,776; 1,326,592
+# before the norms went in) plus 5 %, since what is traced, compiled and
+# loaded is paid in every run's set-up (PR 32).
+@pytest.mark.parametrize("at_once,temp_budget,stage_code_budget", [
+    pytest.param(4, 529_400_000, 1_423_500, id="B1-T8192-H32-K128-four-heads-a-group"),
+    pytest.param(32, 1_339_200_000, None, id="B1-T8192-H32-K128-the-layer-at-once")])
+def test_delta_rule_kernels_compile_for_v5e(one_chip, at_once, temp_budget,
+                                            stage_code_budget):
     """The chunked delta rule, forward and backward: the state carried
     transposed in VMEM scratch, a [1, 128] decay broadcast down its rows,
     transposed products on bfloat16 operands, which interpret mode cannot
@@ -296,7 +302,7 @@ def test_delta_rule_kernels_compile_for_v5e(one_chip, at_once, temp_budget):
     float32 products at `Precision.HIGHEST`, plain and transposed, 16-lane
     slices and concatenations of the triangle's diagonal blocks, a block of
     four lanes of `beta`, rolls down the sublanes."""
-    from bluefog_tpu.kernels.kda import kda_chunked
+    from bluefog_tpu.kernels import kda
 
     T, H, K = 8192, 32, 128
     spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -304,8 +310,8 @@ def test_delta_rule_kernels_compile_for_v5e(one_chip, at_once, temp_budget):
         spec((1, T, H, K), jnp.float32), spec((1, T, H), jnp.float32))
 
     def loss(*a):
-        return jnp.sum(kda_chunked(*a, chunk=64, heads_at_once=at_once,
-                                   interpret=False).astype(jnp.float32))
+        return jnp.sum(kda.kda_chunked(*a, chunk=64, heads_at_once=at_once,
+                                       interpret=False).astype(jnp.float32))
 
     compiled = jax.jit(jax.grad(loss, argnums=tuple(range(5)))).lower(*args).compile()
     text = compiled.as_text()
@@ -314,6 +320,19 @@ def test_delta_rule_kernels_compile_for_v5e(one_chip, at_once, temp_budget):
     assert [tuple(o.shape) for o in compiled.out_info] == [a.shape for a in args]
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp <= temp_budget, temp
+    if stage_code_budget is not None:
+        group = (spec((1, T, at_once * K), jnp.bfloat16),) * 3 + (
+            spec((1, T, at_once * K), jnp.float32), spec((1, T, at_once), jnp.float32))
+
+        def through_the_stage(*a):  # the outputs too: their gradient alone needs no forward
+            made = kda._intra(*a, 64, False)
+            return sum(jnp.sum(o.astype(jnp.float32)) for o in made), made
+
+        stage = jax.jit(jax.grad(through_the_stage, argnums=tuple(range(5)),
+                                 has_aux=True)).lower(*group).compile()
+        assert stage.as_text().count("tpu_custom_call") == 2
+        code = stage.memory_analysis().generated_code_size_in_bytes
+        assert code <= stage_code_budget, code
 
 
 def test_flash_kernels_take_a_wider_query_key_head_for_v5e(one_chip):

@@ -407,7 +407,13 @@ def test_final_quality_parity_head_to_head():
         xb = jnp.broadcast_to(train[None], (K,) + train.shape)
         for _ in range(CALLS):
             params, bs, state, loss, _ = step_fn(params, bs, state, xb, xb)
-        mean_p = jax.tree_util.tree_map(lambda a: a.mean(0), params)
+        # one program for the means, and done before the evaluation is taken
+        # operation by operation: a mean a leaf, each an all-reduce over the
+        # eight devices dispatched while the last is in flight, is what the
+        # CPU's rendezvous gave up on after 40 s under a loaded machine
+        # ("only 7 of them arrived"), and the worker went with it
+        mean_p = jax.block_until_ready(jax.jit(lambda p: jax.tree_util.tree_map(
+            lambda a: a.mean(0), p))(params))
         el = float(model.apply({"params": mean_p}, eval_ids,
                                labels=eval_ids))
         spread = max(float(np.asarray(l).std(axis=0).max())
