@@ -8,7 +8,11 @@
 #   make changed FILES="a.py b.py"
 #                        run only the rule families gating the listed files
 #                        (see conformance.FAMILY_MAP) — the pre-commit gate
-#   make test            tier-1 pytest (not slow)
+#   make test            tier-1 pytest (not slow) as the driver runs it: xdist, six
+#                        workers, --dist load, the junit file /tmp/_t1.xml
+#                        (/root/TESTS_LAST_RUN.json: commands[0], cut at 1470 s)
+#   make test-slow       the tests marked slow (-m slow); run it in a PR that
+#                        adds a configuration or edits a reference
 #   make distrib         distribution-plane gate: the distrib rule family
 #                        (pinned tree campaigns + kill/delta models) plus the
 #                        loopback fan-out bench arm (benchmarks/serving.py)
@@ -23,10 +27,11 @@
 
 PY      ?= python
 ENV     := JAX_PLATFORMS=cpu
-PYTEST  := $(ENV) $(PY) -m pytest tests/ -q -m 'not slow' \
-           --continue-on-collection-errors -p no:cacheprovider
+PYTEST  := $(ENV) $(PY) -m pytest tests/ -q \
+           --continue-on-collection-errors -p no:cacheprovider -p xdist -n 6 \
+           --dist load -p no:randomly
 
-.PHONY: verify analyze selftest changed test distrib loadgen monitor
+.PHONY: verify analyze selftest changed test test-slow distrib loadgen monitor
 
 verify: selftest analyze test
 
@@ -41,7 +46,10 @@ changed:
 	$(ENV) $(PY) -m bluefog_tpu.analysis --changed-only $(FILES) --no-hlo
 
 test:
-	$(PYTEST)
+	$(PYTEST) -m 'not slow' --junitxml=/tmp/_t1.xml
+
+test-slow:
+	$(PYTEST) -m slow
 
 distrib:
 	$(ENV) $(PY) -m bluefog_tpu.analysis --family distrib

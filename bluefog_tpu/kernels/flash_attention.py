@@ -91,7 +91,6 @@ from __future__ import annotations
 
 import functools
 import math
-import os
 from typing import Callable, NamedTuple, Optional, Tuple
 
 import jax
@@ -120,65 +119,21 @@ _LANES = 128
 # - 1024^2 blocks (the default since): the conclusion flipped; a 1024-row
 #   scalar tile amortizes the narrow DMA that the 512-row tile could not,
 #   and 16x fewer scalar bytes win.  8 ships.
-_SCALAR_LANES = int(os.environ.get("BLUEFOG_FLASH_SCALAR_LANES", "8"))
-_ALIGNED_ENABLED = os.environ.get("BLUEFOG_FLASH_ALIGNED", "1") != "0"
-# Experiment knob (MEASURED NULL, default off): run the kernels' softmax
-# recurrences in base-2 (exp2/log2) with scale*log2(e) folded into the q
-# operand — the FA2 CUDA trick.  The (o, lse) contract stays natural-log
-# (lse converted at kernel finish), so ring merges and the XLA paths are
-# unaffected.  Numerics: the folded multiplier is never a power of two, so
-# q rounds once in its storage dtype (<= 2^-9 relative on bf16 scores;
-# exact-ish on f32/CPU); all CPU-interpret numerics tests pass either way.
-# On against off: not measured by any cell (a 2026-07 A/B, its record
-# deleted, read within noise to negative: Mosaic's natural exp evidently
-# already lowers to the cheap path).
-_EXP2_ENABLED = os.environ.get("BLUEFOG_FLASH_EXP2", "0") != "0"
-# Experiment knob: backward-only block override ("BQxBK", e.g. "512x1024").
-# The bwd kernels carry more live VMEM tiles than the forward (p, dp, ds
-# alongside q/k/v/do and the packed scalars), so their best block shape
-# need not match the forward's; this decouples them for A/B sweeps
-# without touching the API.  Empty = backward inherits the forward blocks.
-_BWD_BLOCKS = None
-if os.environ.get("BLUEFOG_FLASH_BWD_BLOCKS"):
-    try:
-        _BWD_BLOCKS = tuple(
-            int(x) for x in os.environ["BLUEFOG_FLASH_BWD_BLOCKS"].split("x"))
-    except ValueError:
-        _BWD_BLOCKS = ()  # non-numeric parts get the same diagnostic
-    if len(_BWD_BLOCKS) != 2:
-        raise ValueError(
-            "BLUEFOG_FLASH_BWD_BLOCKS must be 'BQxBK' (e.g. '512x1024'), "
-            f"got {os.environ['BLUEFOG_FLASH_BWD_BLOCKS']!r}")
-_LOG2E = math.log2(math.e)
-_LN2 = math.log(2.0)
+_SCALAR_LANES = 8
 _MAX_UNROLL = 64  # triangular fast paths unroll at most this many k blocks
 _SUB_EDGE = 512  # see _sub_edge
-
-
-def _kexp(x):
-    """exp in the kernel's score space (base-2 when _EXP2_ENABLED)."""
-    return jnp.exp2(x) if _EXP2_ENABLED else jnp.exp(x)
 
 
 def _score_operand(q, dtype, scale):
     """The q matmul operand with the softmax scale folded where possible.
 
-    Returns ``(q_operand, scale_scores)``: under exp2 mode scale*log2(e)
-    always folds into q (one D-wide pass; rounds q once in its storage
-    dtype); otherwise an exact power-of-two scale folds losslessly; any
-    other scale stays on the f32 scores (``scale_scores=True``) —
-    shared by the forward and both backward kernels."""
-    if _EXP2_ENABLED:
-        return q * jnp.asarray(scale * _LOG2E, dtype), False
+    Returns ``(q_operand, scale_scores)``: an exact power-of-two scale
+    folds losslessly; any other scale stays on the f32 scores
+    (``scale_scores=True``) — shared by the forward and both backward
+    kernels."""
     if _scale_folds_exactly(scale):
         return q * jnp.asarray(scale, dtype), False
     return q, True
-
-
-def _lse_in_score_space(lse):
-    """Natural-log lse converted to the kernel's score space (base-2
-    under exp2 mode) for the backward recompute ``p = exp(s - lse)``."""
-    return lse * _LOG2E if _EXP2_ENABLED else lse
 
 
 def _use_triangular(causal, tri_delta, tq, tk, num_k):
@@ -514,7 +469,7 @@ def _fwd_kernel(qs_ref, ks_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         k = k_ref[0]  # [block_k, D]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # [block_q, block_k] fp32 (base-2 space under _EXP2_ENABLED)
+        )  # [block_q, block_k] fp32
         if scale_scores:
             s = s * scale
         sentinel_rows = False
@@ -541,8 +496,8 @@ def _fwd_kernel(qs_ref, ks_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         m_prev = m_ref[:, :1]  # [block_q, 1] (replicated columns)
         m_cur = jnp.max(s, axis=-1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
-        alpha = _kexp(m_prev - m_new)  # [block_q, 1]
-        p = _kexp(s - m_new)  # [block_q, block_k]
+        alpha = jnp.exp(m_prev - m_new)  # [block_q, 1]
+        p = jnp.exp(s - m_new)  # [block_q, block_k]
         if sentinel_rows:
             # fully-masked rows have m_new == sentinel and would otherwise
             # contribute exp(0) == 1 per entry
@@ -574,12 +529,8 @@ def _fwd_kernel(qs_ref, ks_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
     def _finish():
         l = l_ref[:, :1]
         o_ref[0] = (acc[...] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
-        # lse contract is natural-log regardless of the kernel's score
-        # space: base-2 m converts via ln(2)
-        m_fin = m_ref[:, :_SCALAR_LANES]
-        if _EXP2_ENABLED:
-            m_fin = m_fin * _LN2
-        lse = m_fin + jnp.log(jnp.maximum(l_ref[:, :_SCALAR_LANES], 1e-30))
+        lse = m_ref[:, :_SCALAR_LANES] + jnp.log(
+            jnp.maximum(l_ref[:, :_SCALAR_LANES], 1e-30))
         lse_ref[0] = lse.astype(jnp.float32)
 
 
@@ -592,7 +543,7 @@ def _aligned_or_none(tri_delta, causal, tq, tk, block_q, block_k):
     triangle); at delta >= 2 the last key of tile iq-1 would be a future
     position for the first row of q block iq.  Larger static deltas fall
     back to the general masked path."""
-    if (_ALIGNED_ENABLED and causal and tri_delta is not None
+    if (causal and tri_delta is not None
             and tri_delta <= 1 and tq == tk and block_q == block_k):
         return tri_delta
     return None
@@ -816,7 +767,7 @@ def _bwd_dkv_kernel(qs_ref, ks_ref, q_ref, g_ref, aux_ref,
         g = g_ref[0, rows]  # [bq, D]
         k = k_ref[0, cols]  # [bk, D]
         v = v_ref[0, cols]  # [bk, D]
-        lse = _lse_in_score_space(aux_ref[0, rows][:, :1])  # [bq, 1]
+        lse = aux_ref[0, rows][:, :1]  # [bq, 1]
         corr = aux_ref[0, rows][:, half:half + 1]
         qk, scale_scores = _score_operand(q, q_ref.dtype, scale)
         s = jax.lax.dot_general(
@@ -839,11 +790,11 @@ def _bwd_dkv_kernel(qs_ref, ks_ref, q_ref, g_ref, aux_ref,
             else:
                 s = _aligned_mask(s, bq, bk, aligned_delta)
             # masked entries (and whole sentinel-lse rows) exp to exactly 0
-            p = _kexp(jnp.where(s > _MASK_THRESH, s - lse, _NEG_INF))
+            p = jnp.exp(jnp.where(s > _MASK_THRESH, s - lse, _NEG_INF))
         else:
             # interior tile: nothing is masked and (aligned path) no
             # sentinel-lse row can appear here — plain recompute
-            p = _kexp(s - lse)
+            p = jnp.exp(s - lse)
         dv_acc[cols] += jax.lax.dot_general(
             p.astype(g.dtype), g, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -906,7 +857,7 @@ def _bwd_dq_kernel(qs_ref, ks_ref, q_ref, g_ref, aux_ref,
         g = g_ref[0, rows]
         k = k_ref[0, cols]
         v = v_ref[0, cols]
-        lse = _lse_in_score_space(aux_ref[0, rows][:, :1])
+        lse = aux_ref[0, rows][:, :1]
         corr = aux_ref[0, rows][:, half:half + 1]
         qk, scale_scores = _score_operand(q, q_ref.dtype, scale)
         s = jax.lax.dot_general(
@@ -928,9 +879,9 @@ def _bwd_dq_kernel(qs_ref, ks_ref, q_ref, g_ref, aux_ref,
                 s = jnp.where(_visible(qpos, kpos, window), s, _NEG_INF)
             else:
                 s = _aligned_mask(s, bq, bk, aligned_delta)
-            p = _kexp(jnp.where(s > _MASK_THRESH, s - lse, _NEG_INF))
+            p = jnp.exp(jnp.where(s > _MASK_THRESH, s - lse, _NEG_INF))
         else:
-            p = _kexp(s - lse)
+            p = jnp.exp(s - lse)
         dp = jax.lax.dot_general(
             g, v, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -1237,12 +1188,10 @@ def _flash_core_bwd(scale, causal, block_q, block_k, interpret, tri_delta,
         delta = jnp.sum(o.astype(jnp.float32) * g.astype(jnp.float32),
                         axis=-1)  # [BH, Tq]
         corr = g_lse.astype(jnp.float32) - delta
-        bwd_bq, bwd_bk = (_BWD_BLOCKS if _BWD_BLOCKS is not None
-                          else (block_q, block_k))
         dq, dk, dv = _flash_bwd_pallas(
             q, k, v, lse, corr,
             q_start.astype(jnp.int32), k_start.astype(jnp.int32), g,
-            scale=scale, causal=causal, block_q=bwd_bq, block_k=bwd_bk,
+            scale=scale, causal=causal, block_q=block_q, block_k=block_k,
             interpret=interpret, tri_delta=tri_delta, window=window,
         )
     return dq, dk, dv, jnp.zeros_like(q_start), jnp.zeros_like(k_start)

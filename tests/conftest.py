@@ -78,7 +78,6 @@ def pytest_unconfigure(config):
 # `--dist load` hands the tests out in that order, so one of these started in
 # the run's last minutes is what every other worker then waits for.
 LONGEST = (
-    "test_tpu_compile.py::test_the_latent_attention_decoders_step_fits_a_v5e[reference",
     "test_striped_ring.py::test_striped_ring_gradients",
     "test_tpu_compile.py::test_the_latent_attention_decoders_step_fits_a_v5e[program",
     "test_training.py::test_final_quality_parity_head_to_head",

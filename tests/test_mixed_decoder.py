@@ -1,15 +1,11 @@
-"""The mixed-attention expert decoder (`models.transformer.MixedAttentionMoELM`)
-against the configuration's plain reference at a small size, the pieces it
-shares with the models that were there (`_rotary`'s base, `_DecoderBlock`'s
-head size), the configuration file against its published source, and the
-lowered text of what was there before: `switch_moe`, `LlamaLM`, `BertEncoder`
-and `window=None` attention lower to the text the parent's tree gave."""
+"""`smallthinker-21b-a3b`'s own (`models.transformer.MixedAttentionMoELM`): loss
+and gradients against the plain reference under windows smaller than, equal to
+and larger than the sequence; `_rotary`'s base and `_DecoderBlock`'s head size;
+and what was there before lowering to the parent's text (`switch_moe`,
+`LlamaLM`, `BertEncoder`, `window=None` attention).  The cases it shares with
+the other decoder configurations are in `tests/test_decoder_cells.py`."""
 
 import hashlib
-import json
-import os
-import sys
-from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -19,29 +15,14 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from bluefog_tpu.kernels.flash_attention import flash_attention
 from bluefog_tpu.models import transformer as tr
-from bluefog_tpu.models.transformer import BertEncoder, LlamaLM, MixedAttentionMoELM
+from bluefog_tpu.models.transformer import BertEncoder, LlamaLM
 from bluefog_tpu.parallel import expert as ep
-from bluefog_tpu.training import make_lm_loss_fns
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, REPO)
-
-from chipbench import manifest, seeded  # noqa: E402
-
-CELL = "smallthinker-21b-a3b-atc-warmup-b2-s8k-1chip"
+from decoder_cells import SMALLTHINKER, model_matches, reference_case, widened
 
 
 @pytest.fixture(scope="module")
 def cell():
-    return manifest.resolve(CELL)
-
-
-def _float32_program(cell, sizes):
-    """The program's model at `sizes`, computing in float32 so that the
-    comparison with the float32 reference is of the mathematics."""
-    program = cell.module("program").build(sizes)
-    model = program["model"].clone(dtype=jnp.float32)
-    return make_lm_loss_fns(model)[0]
+    return SMALLTHINKER.cell
 
 
 @pytest.mark.parametrize("seq_len,window", [(64, 24), (32, 32), (32, 80)])
@@ -50,27 +31,10 @@ def test_loss_and_gradients_match_the_plain_reference(cell, seq_len, window):
     global layer, the rotary layers, 4 query heads on 2 key-value heads of
     16 with hidden 64, the router ahead of the attention, the held experts,
     the chunked loss over the slice."""
-    sizes = dict(cell.sizes(rehearse=True), seq_len=seq_len,
-                 sliding_window_size=window)
-    ref = cell.module("reference")
-    apply_fn = _float32_program(cell, sizes)
-    params, _ = seeded.make_weights(ref, sizes, seed=11)
-    # std 0.02 at hidden 64 leaves the experts' terms at 1e-4 of the stream:
-    # widen them so that a wrong expert shows in the loss
-    params = {p: a * (12.0 if p[-1] in ("wg", "wu", "wd", "router") else 1.0)
-              for p, a in params.items()}
-    (x, y), = seeded.make_batches(ref, sizes, 11, ranks=1, pool=1)
-    x, y = x[0], y[0]
-    lp, gp = jax.jit(jax.value_and_grad(
-        lambda p: apply_fn({"params": seeded.nest(p)}, x, labels=y)))(params)
-    (lr, _), gr = jax.jit(jax.value_and_grad(
-        lambda p: ref.loss_fn(p, {}, x, y, sizes), has_aux=True))(params)
-    assert abs(float(lp) - float(lr)) < 1e-5
-    assert set(gp) == set(gr) == set(ref.param_shapes(sizes)[0])
-    for path in gr:
-        a, b = np.asarray(gp[path], np.float64), np.asarray(gr[path], np.float64)
-        assert np.linalg.norm(b) > 0, path
-        assert np.linalg.norm(a - b) / np.linalg.norm(b) < 2e-3, path
+    case = reference_case(cell, dict(cell.sizes(rehearse=True), seq_len=seq_len,
+                                     sliding_window_size=window), widened)
+    assert all(np.linalg.norm(g) > 0 for g in case[-1].values())
+    model_matches(cell, case, 2e-3)
 
 
 def test_the_layers_differ_by_kind_and_the_head_size_is_its_own(cell):
@@ -84,7 +48,6 @@ def test_the_layers_differ_by_kind_and_the_head_size_is_its_own(cell):
     assert model.rope_base == 1_500_000 and model.top_k == 6
     assert program["loss_fn"]("the loss", None) == "the loss"
     shapes, _ = cell.module("reference").param_shapes(sizes)
-    assert sum(int(np.prod(s)) for s in shapes.values()) == 370_547_200
     assert shapes[("layer_0", "q", "kernel")] == (2560, 28, 128)
     assert shapes[("layer_3", "wd")] == (8, 768, 2560)
 
@@ -109,63 +72,6 @@ def test_decoder_block_takes_an_explicit_head_size():
     assert shapes["DenseGeneral_0"]["kernel"] == (32, 4, 24)
     assert shapes["Dense_0"]["kernel"] == (96, 32)
     assert block.apply(v, x, jnp.arange(8)).shape == x.shape
-
-
-# ---- the configuration file against its source ---------------------------
-
-PUBLISHED = {  # config.json of the source, as the guide's catalog copies it
-    "head_dim": 128, "hidden_size": 2560, "max_position_embeddings": 16384,
-    "moe_ffn_hidden_size": 768, "moe_num_active_primary_experts": 6,
-    "moe_num_primary_experts": 64, "num_attention_heads": 28,
-    "num_hidden_layers": 52, "num_key_value_heads": 4, "rms_norm_eps": 1e-06,
-    "rope_theta": 1500000, "sliding_window_size": 4096, "vocab_size": 151936,
-}
-
-
-def test_no_width_differs_from_the_source_and_the_cut_is_stated(cell):
-    cfg = cell.config
-    cut = {"num_hidden_layers": 4, "vocab_size": 18992}
-    for key, value in PUBLISHED.items():
-        assert cfg[key] == cut.get(key, value), key
-        if key in cfg["sizes"]:
-            assert cfg["sizes"][key] == cfg[key], key  # one number, stated twice
-    assert cfg["reduced"] == ["num_hidden_layers", "moe_num_primary_experts_held",
-                              "vocab_size"]
-    assert cfg["sizes"]["moe_num_primary_experts_held"] == 8 \
-        == cfg["moe_num_primary_experts_held"]
-    assert cfg["published"]["num_hidden_layers"] == 52
-    assert cfg["published"]["moe_num_primary_experts"] == 64
-    assert cfg["published"]["vocab_size"] == 151936 == 8 * cfg["vocab_size"]
-    assert cfg["sliding_window_layout"] == cfg["rope_layout"] == [0, 1, 1, 1] * 13
-    assert cfg["sizes"]["sliding_window_layout"] == cfg["sliding_window_layout"]
-    assert cfg["moe_primary_router_apply_softmax"] and cfg["norm_topk_prob"]
-    assert cfg["tie_word_embeddings"] is False
-    assert "eight" in cfg["deployment"] and "one period" in cfg["deployment"]
-    mix = cell.mix
-    assert mix["sizes"] == {"per_rank_batch": 2, "seq_len": 8192}
-    assert cfg["optimizer"] == {
-        "name": "adamw", "learning_rate": 3e-4, "weight_decay": 0.1}
-    assert mix["optimizer"] == dict(cfg["optimizer"], warmup_steps=2000)
-    entry = next(c for c in manifest.load_manifest()["configs"]
-                 if c["name"] == cell.config_name)
-    assert entry["source"] == cfg["source"] and entry["source"].endswith("config.json")
-
-
-def test_flops_count_the_visible_pairs_the_held_experts_and_the_slice(cell):
-    flops, sizes = cell.module("flops"), cell.sizes()
-    assert flops.visible_pairs(8192) == 33_558_528
-    assert flops.visible_pairs(8192, 4096) == 25_167_872
-    assert flops.visible_pairs(8192, 9000) == flops.visible_pairs(8192)
-    per_token = 2 * flops.forward_macs(sizes) / sizes["seq_len"]
-    assert per_token == pytest.approx(492.57e6, rel=1e-4)
-    head = 2 * 2560 * 18992
-    assert head == pytest.approx(97.2e6, rel=1e-3)
-    assert flops.train_flops_per_sample(sizes) * 2 == pytest.approx(24.21e12, rel=1e-3)
-    # a kernel call: the dK/dV kernel does twice the forward's products
-    f, fb = flops.kernel_call(sizes, "fwd", 4096)
-    d, db = flops.kernel_call(sizes, "dkv", 4096)
-    assert d == 2 * f and f == 2 * 2 * 128 * 25_167_872 * 56
-    assert 1.0e9 < fb < db < 1.6e9
 
 
 # ---- what was there lowers to what it lowered to -------------------------
