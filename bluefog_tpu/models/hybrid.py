@@ -8,8 +8,11 @@ arXiv:2510.26692), whose attention layer is DeepSeek-V2's latent attention
 is, after a leading dense layer, DeepSeek-V3's expert layer; with latent
 attention in every layer, no gate on its heads and the rotary over interleaved
 pairs, the same class is a DeepSeek-V3-style decoder (kakaocorp's kanana-2).
-Both are stacks of :class:`_HybridBlock`, which takes its mixer and its
-feed-forward part as it is handed them.
+:class:`ShortConvMoELM`: LiquidAI's LFM2 expert decoder (`model_type`
+lfm2_moe), whose mixer is a gated short convolution (:class:`ShortConvMixer`)
+in three layers of four and grouped-query attention with a norm a head on q
+and k in the fourth.  All are stacks of :class:`_HybridBlock`, which takes its
+mixer and its feed-forward part as it is handed them.
 
 Of :class:`HybridMambaLM`:
 
@@ -50,7 +53,8 @@ from bluefog_tpu.models.transformer import (
 )
 
 __all__ = ["DeltaLatentMoELM", "HybridMambaLM", "KDAMixer", "LatentAttentionMixer",
-           "Mamba2Mixer", "causal_conv", "conv_silu"]
+           "Mamba2Mixer", "ShortConvMixer", "ShortConvMoELM", "causal_conv", "conv_silu",
+           "gated_short_conv"]
 
 # What the backward pass of a recomputed block is handed beside the block's
 # input, each a `checkpoint_name` where the value is made: the flash forward's
@@ -182,11 +186,15 @@ class Mamba2Mixer(nn.Module):
 
 
 class _AttentionMixer(nn.Module):
-    """Grouped-query causal attention over the whole sequence, no position
-    signal; the kernels read the shared key-value heads in place.  They scale
-    scores by ``1 / sqrt(head_dim)``, so ``q`` carries the rest of ``scale``
-    (exact in bfloat16 where that is a power of two, as Granite's 1/64 on
-    heads of 64)."""
+    """Grouped-query causal attention over the whole sequence; the kernels
+    read the shared key-value heads in place.  They scale scores by ``1 /
+    sqrt(head_dim)``, so ``q`` carries the rest of ``scale`` (exact in
+    bfloat16 where that is a power of two, as Granite's 1/64 on heads of 64).
+    As Granite calls it, **no position signal** and no norm.  With
+    ``qk_norm_eps`` every head of ``q`` and of ``k`` goes through an RMS norm
+    over its channels first, one learned scale a channel that the heads share
+    (``q_norm``, ``k_norm``; float32); with ``rotary`` both are then turned,
+    half-split (LFM2's attention layer has both)."""
 
     num_heads: int
     num_kv_heads: int
@@ -194,14 +202,27 @@ class _AttentionMixer(nn.Module):
     scale: float
     dtype: Any
     attention_fn: Callable  # (q, k, v) -> out, causal
+    rotary: Optional[Rotary] = None
+    qk_norm_eps: Optional[float] = None
 
     @nn.compact
     def __call__(self, u):
         B, T, d = u.shape
         H, kvh, hd = self.num_heads, self.num_kv_heads, self.head_dim
         dense = partial(nn.DenseGeneral, use_bias=False, dtype=self.dtype)
-        q = dense((H, hd), name="q")(u) * (self.scale * math.sqrt(hd))
+        rest = self.scale * math.sqrt(hd)  # of the scale, beside the kernels' own
+        q = dense((H, hd), name="q")(u)
+        if self.qk_norm_eps is None:
+            q = q * rest
         k, v = dense((kvh, hd), name="k")(u), dense((kvh, hd), name="v")(u)
+        if self.qk_norm_eps is not None:  # a norm would take a scale put on before it
+            with jax.named_scope("attention_qk_norm"):
+                norm = partial(RMSNorm, dtype=self.dtype, eps=self.qk_norm_eps)
+                q, k = norm(name="q_norm")(q) * rest, norm(name="k_norm")(k)
+        if self.rotary is not None:
+            positions = jnp.arange(T)
+            q = _rotary(q, positions, rotary=self.rotary)
+            k = _rotary(k, positions, rotary=self.rotary)
         with jax.named_scope("attention_global"):
             att = self.attention_fn(q, k, v)
         return dense(d, name="o")(att.reshape(B, T, H * hd))
@@ -362,6 +383,60 @@ class HybridMambaLM(nn.Module):
         untied = lambda: _HeadKernel(self.vocab_size, name="head")(self.hidden_size)
         return _head(h, embed, None if self.tie_embeddings else untied, labels,
                      self.head_chunks)
+
+
+def gated_short_conv(bcx, kernel):
+    """``C * causal_conv(B * x, kernel, 0)`` with ``[B, C, x] = bcx`` in thirds
+    of its channels: LFM2's gated short convolution.  Both gates and the taps'
+    sum in float32, one rounding to ``bcx``'s type.  The definition, and the
+    path of the shapes that :mod:`bluefog_tpu.kernels.causal_conv` does not
+    tile."""
+    d = kernel.shape[1]
+    gate_b, gate_c, x = (bcx[..., i * d:(i + 1) * d].astype(jnp.float32)
+                         for i in range(3))
+    return (gate_c * causal_conv(gate_b * x, kernel, 0.0)).astype(bcx.dtype)
+
+
+def short_conv_kernels_take(tokens, channels, width):
+    """Whether a short-convolution layer goes through the kernels of
+    :mod:`bluefog_tpu.kernels.causal_conv` or through
+    :func:`gated_short_conv`: by the shapes alone, as
+    :func:`conv_kernels_take`."""
+    from bluefog_tpu.kernels.causal_conv import tiles
+
+    return tiles(tokens, channels, width)
+
+
+class ShortConvMixer(nn.Module):
+    """LFM2's gated short convolution: ``[B, C, x] = u W_in`` (three chunks of
+    the hidden size, in that order); ``z = B * x``; a causal depth-wise
+    convolution of ``conv_width`` taps over ``z``, no bias, no activation; ``y
+    = (C * conv) W_out``.  No state, no heads, no position signal: the taps
+    see ``conv_width - 1`` tokens back.  Where the kernels take the shapes,
+    one kernel each way reads the three chunks where ``W_in`` left them
+    (:func:`bluefog_tpu.kernels.causal_conv.short_conv`); the gates and the
+    taps' sum in float32, the products in ``dtype``."""
+
+    conv_width: int = 3
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, u):
+        from bluefog_tpu.kernels.causal_conv import short_conv
+
+        d = u.shape[-1]
+        init = nn.initializers.normal(0.02)
+        dense = partial(nn.Dense, use_bias=False, dtype=self.dtype, kernel_init=init)
+        with jax.named_scope("short_conv_in_proj"):
+            bcx = dense(3 * d, name="in_proj")(u)
+        with jax.named_scope("short_conv_gate"):
+            taps = self.param("conv_kernel", init, (self.conv_width, d), jnp.float32)
+            if short_conv_kernels_take(u.shape[1], d, self.conv_width):
+                y = short_conv(bcx, taps)
+            else:
+                y = gated_short_conv(bcx, taps)
+        with jax.named_scope("short_conv_out_proj"):
+            return dense(d, name="out_proj")(y)
 
 
 def kda_conv_kernels_take(tokens, inner, width):
@@ -618,3 +693,121 @@ class DeltaLatentMoELM(nn.Module):
         return _head(h, embed,
                      lambda: _HeadKernel(self.vocab_size, name="head")(self.hidden_size),
                      labels, self.head_chunks)
+
+
+class ShortConvMoELM(nn.Module):
+    """LiquidAI's LFM2 expert decoder: ``layer_kinds`` names each layer's
+    mixer, ``"conv"`` (:class:`ShortConvMixer`) or ``"attention"``
+    (:class:`_AttentionMixer` with a norm a head on q and k and a half-split
+    rotary over the whole head at ``rope_theta``, scores over
+    ``sqrt(head_dim)``); ``layer_dense`` says where the feed-forward part is
+    the dense gated MLP of ``dff`` and where this share of the expert layer:
+    sigmoid scores over ``num_experts``, the choice on the score plus a bias
+    that takes no gradient, weights the chosen scores over their sum plus
+    ``route_eps``, times ``routed_scale``, the ``experts_held`` computed
+    dropless, **no shared expert** unless ``shared_dff`` says one.  Pre-norm
+    residual blocks, the head tied to the embedding, every block recomputed in
+    the backward pass but for :data:`REMAT_KEEPS`.  With ``labels`` the
+    chunked next-token loss.
+
+    A class of its own beside :class:`HybridMambaLM` and
+    :class:`DeltaLatentMoELM`: the first has no feed-forward kind a layer and
+    requires the scan's sizes, the second requires latent attention's and
+    unties its head; this one shares their block, their feed-forward parts,
+    their head and Granite's attention mixer, and adds the layer table."""
+
+    vocab_size: int
+    hidden_size: int
+    layer_kinds: Tuple[str, ...]
+    layer_dense: Tuple[bool, ...]
+    dff: int
+    num_heads: int
+    num_kv_heads: int
+    head_dim: int
+    rope_theta: float
+    num_experts: int
+    top_k: int
+    experts_held: Tuple[int, ...]
+    expert_dff: int
+    routed_scale: float = 1.0
+    route_eps: float = 1e-6
+    shared_dff: int = 0
+    conv_width: int = 3
+    eps: float = 1e-5
+    tie_embeddings: bool = True
+    remat: bool = True
+    head_chunks: int = 1
+    dtype: Any = jnp.bfloat16
+    attention_fn: Optional[Callable] = None  # None: the flash kernels
+
+    @nn.compact
+    def __call__(self, input_ids, labels=None):
+        from bluefog_tpu.kernels.flash_attention import flash_attention
+        from bluefog_tpu.telemetry import registry as _telemetry
+
+        kinds, is_dense = tuple(self.layer_kinds), tuple(self.layer_dense)
+        if set(kinds) - {"conv", "attention"} or len(is_dense) != len(kinds):
+            raise ValueError(f"layer kinds {sorted(set(kinds))}: 'conv' or 'attention', "
+                             f"and {len(is_dense)} feed-forward kinds for {len(kinds)}")
+        if self.num_heads % self.num_kv_heads:
+            raise ValueError(f"num_heads {self.num_heads} not divisible by "
+                             f"num_kv_heads {self.num_kv_heads}")
+        n_conv, n_att = kinds.count("conv"), kinds.count("attention")
+        keeps = REMAT_KEEPS if self.remat else ()
+        reg = _telemetry.get_registry()
+        if reg.enabled:
+            tokens, width = input_ids.size, jnp.dtype(self.dtype).itemsize
+            kept = {
+                "attn_out": n_att * tokens * self.num_heads * self.head_dim * width,
+                "attn_lse": n_att * tokens * self.num_heads * 4,
+                "mixer_out": len(kinds) * tokens * self.hidden_size * width,
+                "mlp_gate_up": sum(is_dense) * tokens * 2 * self.dff * width,
+            }
+            for name, value in (
+                    ("mixer.layers_short_conv", n_conv),
+                    ("mixer.layers_attention", n_att),
+                    ("short_conv.taps", self.conv_width),
+                    ("short_conv.kernel_layers", n_conv * short_conv_kernels_take(
+                        input_ids.shape[1], self.hidden_size, self.conv_width)),
+                    ("attention.qk_norm", 1),
+                    ("attention.layers_global", n_att),
+                    ("attention.heads_global", self.num_heads),
+                    ("attention.kv_heads", self.num_kv_heads),
+                    ("attention.scale", self.head_dim ** -0.5),
+                    ("moe.score", 1),  # 1: sigmoid scores (0: a softmax's)
+                    ("moe.groups", 1), ("moe.groups_kept", 1),
+                    ("moe.shared_width", self.shared_dff),
+                    ("moe.routed_scale", self.routed_scale),
+                    ("moe.dense_layers", sum(is_dense)),
+                    ("lm.tied_head", int(self.tie_embeddings)),
+                    ("lm.remat_blocks", len(kinds) if self.remat else 0),
+                    ("lm.remat_kept_names", len(keeps)),
+                    ("lm.remat_kept_mb", sum(kept[k] for k in keeps) / 1e6)):
+                reg.gauge(name).set(value)
+        mixers = {
+            "conv": partial(ShortConvMixer, self.conv_width, self.dtype, name="mixer"),
+            "attention": partial(
+                _AttentionMixer, self.num_heads, self.num_kv_heads, self.head_dim,
+                self.head_dim ** -0.5, self.dtype,
+                self.attention_fn or partial(flash_attention, causal=True),
+                rotary_frequencies(self.head_dim, self.rope_theta), self.eps,
+                name="mixer"),
+        }
+        ffns = {
+            True: dense_ffn(self.dff, self.dtype),
+            False: expert_ffn(
+                self.num_experts, self.top_k, tuple(self.experts_held), self.expert_dff,
+                self.shared_dff, self.routed_scale, self.dtype, score="sigmoid",
+                bias=True, eps=self.route_eps),
+        }
+        embed = nn.Embed(self.vocab_size, self.hidden_size, dtype=self.dtype,
+                         embedding_init=nn.initializers.normal(0.02), name="embed")
+        h = jnp.take(embed.embedding, input_ids, axis=0).astype(self.dtype)
+        block_cls = _remat(keeps) if self.remat else _HybridBlock
+        for i, kind in enumerate(kinds):
+            h = block_cls(mixers[kind], ffns[is_dense[i]], 1.0, self.eps, self.dtype,
+                          name=f"layer_{i}")(h)
+        h = RMSNorm(dtype=jnp.float32, eps=self.eps, name="final_norm")(h)
+        untied = lambda: _HeadKernel(self.vocab_size, name="head")(self.hidden_size)
+        return _head(h, embed, None if self.tie_embeddings else untied, labels,
+                     self.head_chunks)

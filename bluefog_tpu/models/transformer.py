@@ -727,7 +727,9 @@ def expert_feed_forward(block, m, num_experts, top_k, experts_held, expert_dff,
     """This share's part of a top-k expert layer beside a shared expert that
     every share computes, on the normed stream ``m [B, T, d]``; the leaves
     (``router``, the held experts' ``wg`` / ``wu`` / ``wd`` stacks, ``shared``)
-    are ``block``'s, the module whose ``__call__`` this runs in.  ``routing``:
+    are ``block``'s, the module whose ``__call__`` this runs in.  With
+    ``shared_dff`` 0 the model has no shared expert: no ``shared`` leaf, no
+    ``moe_shared`` scope.  ``routing``:
     :func:`bluefog_tpu.parallel.expert.route_topk`'s keywords; with ``bias``
     true the choice's bias is the leaf ``router_bias``."""
     from bluefog_tpu.parallel.expert import held_topk_experts, route_topk
@@ -748,6 +750,8 @@ def expert_feed_forward(block, m, num_experts, top_k, experts_held, expert_dff,
     }
     y = held_topk_experts(rows, experts, weights, stacks, experts_held,
                           num_experts, activation=jax.nn.silu)
+    if not shared_dff:
+        return y.reshape(B, T, d)
     with jax.named_scope("moe_shared"):
         return y.reshape(B, T, d) + _GatedMLP(shared_dff, dtype, name="shared")(m)
 

@@ -137,7 +137,8 @@ def switch_moe(
 
 
 def route_topk(x, router, top_k: int, scale: float = 1.0, *, score: str = "softmax",
-               bias=None, groups: int = 1, groups_kept: Optional[int] = None):
+               bias=None, groups: int = 1, groups_kept: Optional[int] = None,
+               eps: float = 0.0):
     """Top-k routing over all the experts the router knows.
 
     ``x [T, d]`` is what the router reads; ``router [d, E]``.  Logits, top-k
@@ -154,12 +155,14 @@ def route_topk(x, router, top_k: int, scale: float = 1.0, *, score: str = "softm
     equal groups, a group scores the sum of its two largest ``s + bias``, the
     ``groups_kept`` best groups stay and the ``top_k`` largest ``s + bias``
     among their experts are chosen; the weights are the chosen ``s`` **without
-    the bias** over their sum, times ``scale``."""
+    the bias** over their sum, times ``scale``.  ``eps`` is added to that sum
+    where a model's denominator has one (LFM2's ``1e-6``); at 0 the sum stands
+    bare."""
     with jax.named_scope("moe_route"):
         logits = jnp.einsum("td,de->te", x.astype(jnp.float32),
                             router.astype(jnp.float32),
                             precision=lax.Precision.HIGHEST)
-        if score == "softmax" and bias is None and groups == 1:
+        if score == "softmax" and bias is None and groups == 1 and not eps:
             top, experts = lax.top_k(logits, top_k)
             weights = jax.nn.softmax(top, axis=-1)
             return experts, weights if scale == 1.0 else weights * scale
@@ -179,7 +182,8 @@ def route_topk(x, router, top_k: int, scale: float = 1.0, *, score: str = "softm
             choice = jnp.where(stays[:, :, None], by_group, -jnp.inf).reshape(t, e)
         experts = lax.top_k(choice, top_k)[1]
         chosen = jnp.take_along_axis(s, experts, axis=-1)
-        return experts, chosen * (scale / jnp.sum(chosen, axis=-1, keepdims=True))
+        total = jnp.sum(chosen, axis=-1, keepdims=True)
+        return experts, chosen * (scale / (total + eps if eps else total))
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(3,))

@@ -24,8 +24,9 @@ phase that fails raises: the traceback goes to stderr, the last line says
                                      chunked scan's kernels vs the recurrence
                                      taken token by token and the flash
                                      kernels at heads of 64 vs dense attention,
-                                     the causal convolution's kernels vs the
-                                     reference's expression in float32, a
+                                     the causal convolution's kernels and the
+                                     gated short convolution's vs the
+                                     references' expressions in float32, a
                                      latent-attention mixer with no gate and
                                      interleaved rotary pairs vs its dense
                                      float32 oracle
@@ -105,6 +106,9 @@ FULL = dict(
     # a state-space layer's convolution of that cell: the 8,512-wide product
     # of its input projection, 4,096 channels of x and 128 each of B and C
     conv=dict(seq=8192, inner=4096, states=128, heads=64, width=4, calls=20),
+    # a conv layer's gated short convolution of the `lfm2-24b-a2b` cell: [B, C, x]
+    # the 6,144 channels of the input projection's product
+    short_conv=dict(seq=8192, channels=2048, width=3, calls=20),
     # one linear layer's delta rule and one latent-attention layer's kernel
     # call of the `ling-3.0-flash-vl` cell: 1 x 8192 x 32 heads of 128, chunks
     # of 64; 128 + 64 rotary query-key channels beside values of 128
@@ -130,6 +134,7 @@ TINY = dict(
     ssd=dict(seq=64, heads=4, head_dim=16, state=32, chunks=(8, 16), calls=2,
              att_heads=4, att_kv_heads=2, att_head_dim=16),
     conv=dict(seq=64, inner=128, states=64, heads=8, width=4, calls=2),
+    short_conv=dict(seq=64, channels=128, width=3, calls=2),
     kda=dict(seq=128, heads=4, head_dim=16, chunk=32, lower=-5.0, calls=2),
     mla=dict(seq=128, heads=4, nope=16, rope=8, v_dim=16, calls=2),
     mla_mixer=dict(seq=128, hidden=64, heads=4, rank=32, nope=16, rope=8, v_dim=16,
@@ -206,7 +211,7 @@ KDA_L2_RTOL = 2e-2
 PHASES = ("ops_windows", "resnet_atc", "resnet_allreduce", "contraction",
           "buckets_vs_per_leaf", "decoder", "experts_piled", "kda_vs_recurrence",
           "mla_two_head_sizes", "mla_mixer_no_gate", "shared_heads", "subtiles", "ssd",
-          "conv")
+          "conv", "short_conv")
 
 
 class _CompileClock:
@@ -1118,6 +1123,61 @@ def phase_conv(cfg, seed, on_tpu, clock):
 # ---------------------------------------------------------------------------
 
 
+def phase_short_conv(cfg, seed, on_tpu, clock):
+    """`kernels.causal_conv.short_conv` (LFM2's gated short convolution, `C *
+    conv(B * x)`, through the `short_conv_fwd` / `short_conv_bwd` kernels) at
+    the sizes of a conv layer of the benchmark's `lfm2-24b-a2b` cell, on the
+    input projection's whole `[B, C, x]` product, against the plain
+    reference's three shifted multiply-adds in float32: the output and the two
+    gradients in relative L2, and the host clock over ``calls`` calls of the
+    forward alone and of forward and backward, for the kernels and for
+    `hybrid.gated_short_conv`'s expression."""
+    from bluefog_tpu.kernels.causal_conv import short_conv
+    from bluefog_tpu.models import hybrid
+    from chipbench import manifest
+
+    reference = manifest.load_module(os.path.join(
+        manifest.REPO, "chipbench", "reference", "lfm2-24b-a2b.py"))
+    t0 = time.perf_counter()
+    T, d, W = cfg["seq"], cfg["channels"], cfg["width"]
+    keys = jax.random.split(jax.random.PRNGKey(seed), 3)
+    bcx = jax.random.normal(keys[0], (1, T, 3 * d), jnp.bfloat16)
+    g = jax.random.normal(keys[1], (1, T, d), jnp.bfloat16).astype(jnp.float32)
+    taps = 0.5 * jax.random.normal(keys[2], (W, d))
+    args = (bcx, taps)
+    assert hybrid.short_conv_kernels_take(T, d, W)
+
+    def in_float32(bcx, taps):
+        gate_b, gate_c, x = (bcx[0, :, i * d:(i + 1) * d].astype(jnp.float32)
+                             for i in range(3))
+        return (gate_c * reference.short_conv(gate_b * x, taps))[None]
+
+    paths = {"kernels": short_conv, "expression": hybrid.gated_short_conv}
+    loss = lambda fn: lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * g)
+
+    def values(fn):
+        y, grads = jax.jit(lambda *a: (fn(*a), jax.grad(loss(fn), (0, 1))(*a)))(*args)
+        return dict(zip(("y", "dbcx", "dkernel"), (y,) + grads))
+
+    want = values(in_float32)
+    rel, ms = {}, {}
+    for name, fn in paths.items():
+        rel[name] = _rel_l2(values(fn), want)
+        ms[name] = {kind: _ms_a_call(jax.jit(timed), args, cfg["calls"]) for kind, timed in (
+            ("fwd", fn), ("fwd_bwd", jax.grad(loss(fn), (0, 1))))}
+    _emit("short_conv_vs_expression", t0, clock, seq=T, channels=d, of=3 * d, width=W,
+          interpret=not on_tpu,
+          compared="C * conv(B * x) and its gradients in [B, C, x] and the taps "
+                   "(bfloat16 in and out) against the reference's shifted "
+                   "multiply-adds in float32: relative L2; ms a call, host clock; the "
+                   "kernels, hybrid.gated_short_conv's expression",
+          rel_l2=rel, ms_per_call=ms, rel_l2_tol=CONV_L2_RTOL)
+    for name, gaps in rel.items():
+        for n, gap in gaps.items():
+            assert gap <= CONV_L2_RTOL, (
+                f"{name} {n}: {gap} from the float32 expression in relative L2")
+
+
 def phase_kda(cfg, seed, on_tpu, clock):
     """`kda_chunked` (the chunk's stateless stage through `kda_intra_fwd` /
     `kda_intra_bwd`, which since PR 47 take the unit vectors of q and k
@@ -1371,6 +1431,8 @@ def run(args, device):
             phase_ssd(sizes["ssd"], args.seed, on_tpu, clock)
         if want("conv"):
             phase_conv(sizes["conv"], args.seed, on_tpu, clock)
+        if want("short_conv"):
+            phase_short_conv(sizes["short_conv"], args.seed, on_tpu, clock)
     bf.shutdown()
 
 

@@ -946,7 +946,144 @@ KANANA = Decoder(
     cli_seed=2**31 + 5,
 )
 
-TABLE = (SMALLTHINKER, LAGUNA, GRANITE, LING, KANANA)
+# ---- lfm2-24b-a2b ------------------------------------------------------------------------------
+
+
+def _lfm2_gauges(tokens, kernel_layers):
+    """Published layers 1-3 at hidden 128: a conv layer with the dense MLP, an
+    attention layer and a conv layer with experts.  128 channels are a whole
+    lane block; 12 tokens for 32 are no whole 8-row tiles."""
+    return dict(sizes={}, fields={}, tokens=tokens, wanted={
+        "mixer.layers_short_conv": 2, "mixer.layers_attention": 1, "short_conv.taps": 3,
+        "short_conv.kernel_layers": kernel_layers, "attention.qk_norm": 1,
+        "attention.layers_global": 1, "attention.heads_global": 4,
+        "attention.kv_heads": 2, "attention.scale": 32 ** -0.5, "moe.score": 1,
+        "moe.groups": 1, "moe.groups_kept": 1, "moe.shared_width": 0,
+        "moe.routed_scale": 1, "moe.dense_layers": 1, "moe.experts_held": 4,
+        "moe.experts_total": 16, "moe.top_k": 4, "lm.tied_head": 1,
+        "lm.remat_blocks": 3, "lm.remat_kept_names": 4,
+        # bfloat16 of the tokens: the attention layer's [4, T, 32] and float32
+        # [4, T], three layers' [T, 128], the dense layer's [T, 192]
+        "lm.remat_kept_mb": tokens * (256 + 16 + 768 + 384) / 1e6})
+
+
+def _lfm2_flops(flops, sizes):
+    d, s = 2048, 8192
+    pairs = s * (s + 1) // 2
+    conv = s * (d * 6144 + d * d + 5 * d)           # in, out, three taps and two gates
+    attention = s * (2 * d * 2048 + 2 * d * 512) + pairs * 32 * 2 * 64
+    experts = s * (d * 64 + 4 * 8 / 64 * 3 * d * 1536)
+    want = s * d * 8192 + 6 * conv + 2 * attention + s * 3 * d * 11776 + 7 * experts
+    yield "the layers held", flops.layers(sizes), (6, 2, 1, 7)
+    yield "forward", flops.forward_macs(sizes), pytest.approx(want, rel=1e-12)
+    yield "a step", flops.train_flops_per_sample(sizes), pytest.approx(6 * want, rel=1e-12)
+    yield "a forward pass, about", 2 * want, pytest.approx(4.56e12, rel=2e-3)
+    # the issue's split of a forward pass, in TFLOP
+    yield "six short-conv mixers", 2 * 6 * conv, pytest.approx(1.65e12, rel=2e-3)
+    yield "two attention layers", 2 * 2 * attention, pytest.approx(0.89e12, rel=5e-3)
+    yield "the mixers' share", 0.54 < (6 * conv + 2 * attention) / want < 0.58, True
+    # a kernel call: B, C, x in and y out forward; dy beside them in, three out backward
+    yield "the forward kernel", flops.kernel_call(sizes, "fwd"), \
+        (s * d * (2 + 6), s * d * 2 * 4)
+    yield "the forward's bytes", flops.kernel_call(sizes, "fwd")[1], 134_217_728
+    yield "the backward kernel", flops.kernel_call(sizes, "bwd"), \
+        (s * d * (2 + 6 + 6 + 3 + 6), s * d * 2 * 7)
+    for kernel in ("fwd", "bwd"):  # bound by the bytes, as counted
+        work, nbytes = flops.kernel_call(sizes, kernel)
+        yield f"{kernel}: the bytes lead", nbytes / 819e9 > 10 * work / 197e12, True
+
+
+def _lfm2_readers(cell):
+    flops, sizes = cell.module("flops"), cell.sizes()
+    op = lambda name, path: types.SimpleNamespace(
+        name=name, path=path, within=None, recomputed=False)
+    ops = {"%short_conv_fwd.3 = bf16[1,8192,2048]{2,1,0:T(8,128)(2,1)} custom-call(": 0.40,
+           "%short_conv_fwd.4": 0.44, "%short_conv_bwd.1 = (bf16[1,8192,6144]{2,1,0": 0.90,
+           "%attention_global.2": 7.0, "%fusion.9": 100.0, "%short_conv_fwd_other": 50.0,
+           "%fusion.1": 2.0, "%fusion.2": 3.0, "%fusion.3": 5.0}
+    root = "jit(local_step)/forward_backward/layer_2/mixer/"
+    record = [(op("short_conv_fwd.3", root + "short_conv_gate/pallas_call"), "x", 0.40),
+              (op("short_conv_fwd.4", "jit(local_step)/rematted_computation/layer_2/mixer/"
+                  "short_conv_gate/pallas_call"), "x", 0.44),
+              (op("short_conv_bwd.1", root + "short_conv_gate/pallas_call"), "x", 0.90),
+              (op("fusion.1", root + "short_conv_in_proj/in_proj/dot_general"), "x", 2.0),
+              (op("fusion.2", root + "short_conv_out_proj/out_proj/dot_general"), "x", 3.0),
+              (op("fusion.3", root + "attention_qk_norm/q_norm/mul"), "x", 5.0),
+              (op("fusion.9", root + "o/dot_general"), "attention_proj", 100.0)]
+    memo = lambda ops_: {step_scopes.MEMO: {
+        "ops": ops_, "groups": {}, "recomputed": 0.0, "found": 0.0}}
+    run = _traced(ops, flops, sizes, **memo(record))
+    yield "short_conv_kernels_ms_per_step", run, pytest.approx(1.74)
+    yield "short_conv_mixer_ms_per_step", run, pytest.approx(0.40 + 0.44 + 0.90 + 2.0 + 3.0)
+    yield "attention_global_ms_per_step", run, 7.0
+    for kernel, ms, calls in (("fwd", 0.84, 2), ("bwd", 0.90, 1)):
+        work, nbytes = flops.kernel_call(sizes, kernel)
+        ideal = calls * max(work / 197e12, nbytes / 819e9)
+        yield f"short_conv_{kernel}_roofline", run, pytest.approx(100 * ideal / (ms / 1e3))
+    yield "short_conv_fwd_roofline", run, pytest.approx(39.0, abs=0.1)  # 2 x 0.164 of 0.84 ms
+    # a program without such kernels or without a record of its step (the
+    # parent's), a run without a trace, a rehearsal, a run of another cell
+    bare = _traced({"%fusion": 1.0}, flops, sizes, **memo([]))
+    yield from _silent(("short_conv_fwd_roofline", "short_conv_bwd_roofline"), bare,
+                       dict(run, peaks=None), dict(run, flops_per_sample=1.0))
+    yield from _silent(("short_conv_kernels_ms_per_step", "short_conv_mixer_ms_per_step"),
+                       bare)
+
+
+LFM2 = Decoder(
+    cell_name="lfm2-24b-a2b-atc-warmup-b1-s8k-1chip",
+    catalog="LFM2-24B-A2B",
+    cut={"num_hidden_layers": 8, "num_experts": 8, "vocab_size": 8192},
+    reduced=["num_hidden_layers", "num_experts", "vocab_size"],
+    cut_also=("parameters",),
+    published_stated={"num_hidden_layers": 40, "num_experts": 64, "vocab_size": 65536},
+    sizes_say={"num_experts": 64, "num_experts_held": 8,
+               "published_layer_index": [1, 2, 3, 4, 5, 6, 7, 8]},
+    marks={"deployment": ("one chip of 8", "stage of five"),
+           "expert_load": ("an eighth", "4,096"),
+           "assumed": ("tied to the embedding", "2048 / 32 = 64", "half-split pairs",
+                       "[B, C, x]", "their sum + 1e-6", "balancing update",
+                       "`num_dense_layers` 2", "embedding_norm", "sqrt(2 x 40)",
+                       "uniform in [-0.05, 0.05]", "AdamW 3e-4", "recomputed",
+                       "segment_ids")},
+    mix_as=("laguna-xs.2-atc-warmup-b1-s8k-1chip", ()),
+    per_layer={"train_step_host_ms_per_step", "attention_ms_per_step",
+               "attention_global_ms_per_step", "attention_proj_ms_per_step",
+               "expert_ms_per_step", "expert_dispatch_ms_per_step", "mlp_ms_per_step",
+               "head_loss_ms_per_step", "optimizer_ms_per_step", "recompute_ms_per_step",
+               # this configuration's own
+               "short_conv_mixer_ms_per_step", "short_conv_kernels_ms_per_step",
+               "short_conv_fwd_roofline", "short_conv_bwd_roofline"},
+    parameters=(("a short-convolution mixer", _mixer(0), 16_783_360),
+                ("an attention mixer", _mixer(1), 10_485_888),
+                ("the dense layer", _layer(0), 89_139_200),
+                ("an expert layer's feed-forward", _layer(1, but=_FFN), 75_628_608),
+                ("a conv layer with experts", _layer(2), 92_416_064),
+                ("an attention layer with experts", _layer(1), 86_118_592),
+                ("the embedding, which is the head, and the last norm", _ENDS, 16_779_264),
+                ("the cut", _ALL, 740_235_968)),
+    flops=_lfm2_flops,
+    readers={"short-conv": _lfm2_readers},
+    router_biases=2,
+    no_leaf_named=("shared", "head", "conv_bias"),
+    rules={"rope_theta": dict(rope_theta=1e4), "routed_scale": dict(routed_scale=2.0),
+           "top_k": dict(top_k=2), "a_shared_expert": dict(shared_dff=32),
+           "an_untied_head": dict(tie_embeddings=False),
+           "a_second_dense_layer": dict(layer_dense=(True, True, False)),
+           "no_attention_layer": dict(layer_kinds=("conv", "conv", "conv"))},
+    remat_off=dict(remat=False),
+    adamw_seed=2**31 + 7,
+    decayed_only=(("layer_1", "router_bias"),),
+    gauges={"as-rehearsed": _lfm2_gauges(32, 2),
+            "tokens-that-do-not-tile": _lfm2_gauges(12, 0)},
+    gauges_absent=("ssm.", "kda.", "mla."),
+    foreign_kinds=(("conv", "mamba", "attention"), "mamba"),
+    control_seed=2**31 + 35,
+    unchanged_seed=2**31 + 99,
+    cli_seed=2**31 + 5,
+)
+
+TABLE = (SMALLTHINKER, LAGUNA, GRANITE, LING, KANANA, LFM2)
 
 
 def each(field=None):

@@ -240,5 +240,5 @@ def test_the_cell_rehearses_through_the_command_line(entry):
     assert line["correct"] is True and line["failed"] == 0
     assert line["device"]["platform"] == "cpu"
     assert set(line["metrics"]) == {"train_samples_s_chip", "step_ms_p95", "setup_s"}
-    assert set(line["checks"]) >= {"loss_gap", "grad_norm_gap", "delta_norm_gap",
-                                   "change1_rel_l2", "assoc_p_gap"}
+    assert set(line["checks"]) >= set(entry.reference.LIMITS) >= {
+        "grad_norm_gap", "delta_norm_gap", "change1_rel_l2", "assoc_p_gap"}

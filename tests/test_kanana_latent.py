@@ -274,21 +274,26 @@ def test_smallthinkers_expert_layer_at_its_cells_size_lowers_to_what_it_did():
 def test_the_cell_and_its_three_metrics_are_the_manifests(cell):
     assert cell.mix_name == "atc-warmup-b1-s8k-1chip" and cell.chips == 1
     bench = manifest.load_manifest()
-    entry = next(c for c in bench["configs"] if c["name"] == cell.config_name)
-    assert entry == bench["configs"][-1] and len(bench["configs"]) == 7
-    assert bench["workloads"][-1]["name"] == CELL and len(bench["workloads"]) == 10
+    # the seventh configuration and the tenth cell; later ones stand after them
+    assert [c["name"] for c in bench["configs"]].index(cell.config_name) == 6
+    assert [w["name"] for w in bench["workloads"]].index(CELL) == 9
     unscoped = next(p for p in bench["per_layer"] if p["name"] == "unscoped_ms_per_step")
     assert CELL not in unscoped["workloads"]  # the mla_* ops would be counted twice
-    own = bench["per_layer"][-3:]
+    names = [p["name"] for p in bench["per_layer"]]
+    first = names.index(dc.KANANA_ROOFLINES[0])
+    own = bench["per_layer"][first:first + 3]
     assert [p["name"] for p in own] == list(dc.KANANA_ROOFLINES)
     for p in own:
         assert p == {"name": p["name"], "unit": "%", "better": "higher",
                      "source": "device_trace", "layer": "kernels",
                      "moves": "train_samples_s_chip", "workloads": [CELL]}
-    # appended, and the standing cells' lists otherwise as they were
-    for p in bench["per_layer"][:-3]:
+    # appended, and the standing cells' lists otherwise as they were: only
+    # cells the manifest lists after this one stand after it
+    order = [w["name"] for w in bench["workloads"]]
+    for p in bench["per_layer"][:first]:
         if CELL in p.get("workloads", []):
-            assert p["workloads"][-1] == CELL
+            assert all(order.index(w) > 9 for w in
+                       p["workloads"][p["workloads"].index(CELL) + 1:])
 
 
 def test_the_rotarys_width_goes_by_another_name_than_the_sources(cell):

@@ -232,6 +232,39 @@ def test_causal_conv_kernels_compile_for_v5e(one_chip):
     assert compiled.memory_analysis().generated_code_size_in_bytes <= 508_500
 
 
+# The lfm2-24b-a2b cell's gated short convolution at its size: 8,192 tokens,
+# [B, C, x] the 6,144 channels of the input projection's product.  The budget:
+# the bytes of generated code read at the first compile (320,512, PR 49) plus 10 %.
+def test_short_conv_kernels_compile_for_v5e(one_chip):
+    """The gated short convolution's two kernels: the three chunks read at
+    their offsets of the one product, the backward pass's fourth grid axis
+    whose output block walks the cotangent's three chunks while the inputs'
+    blocks stand, two whole blocks kept in VMEM between its steps.  One
+    [T, 6144] cotangent comes out, no float32 [T, C] and no slice is made."""
+    from bluefog_tpu.kernels import causal_conv
+    from bluefog_tpu.models import hybrid
+
+    T, d = 8192, 2048
+    spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    args = (spec((1, T, 3 * d), jnp.bfloat16), spec((3, d), jnp.float32))
+    assert hybrid.short_conv_kernels_take(T, d, 3)
+
+    def loss(bcx, taps):  # the output too: its gradient alone needs no forward
+        y = causal_conv.short_conv(bcx, taps, interpret=False)
+        return jnp.sum(y.astype(jnp.float32)), y
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1), has_aux=True)).lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2
+    for name in ("short_conv_fwd", "short_conv_bwd"):
+        assert name in text  # the names the device trace shows
+    assert "f32[1,8192,2048]" not in text and "f32[1,8194,2048]" not in text
+    assert "bf16[1,8192,2048]{2,1,0:T(8,128)(2,1)} slice" not in text
+    assert [tuple(o.shape) for o in jax.tree_util.tree_leaves(compiled.out_info)] == [
+        a.shape for a in args] + [(1, T, d)]
+    assert compiled.memory_analysis().generated_code_size_in_bytes <= 352_600
+
+
 # blocks of the granite-4.0-h-micro cell (B1 S8192, hidden 2048, MLP 8192; 64
 # scan heads of 64 with a state of 128; 32 query heads on 8 of 64) recomputed
 # under the model's own policy: one of each kind, and two state-space blocks,
@@ -368,22 +401,44 @@ def test_flash_kernels_take_a_wider_query_key_head_for_v5e(one_chip):
 # PR but a `benchmark` one may edit, and the chip runs that very step after
 # every window of the cell.  The program's case holds the bytes of the code
 # that `perf_opt` PRs edit, and stays.
-@pytest.mark.parametrize("which,kernel_calls,budget", [
-    pytest.param("program", 18, 13_259_000_000, id="program-B1-T8192-six-MLA-layers"),
-    pytest.param("reference", 0, 13_041_000_000, id="reference-float32-in-pieces",
+#
+# The lfm2-24b-a2b cell's step (B1 S8192, published layers 1-8: six gated short
+# convolutions, two attention layers of 32 heads on 8 of 64, 740.2 M parameters)
+# and its reference's, the same way.  Bytes read at PR 49: 14,215,708,672 (8.88
+# GB of weights and moments standing, the gradient's 2.96 among the
+# temporaries) and 13,287,420,416 (11.84 GB of results), plus 3 %.
+_KANANA, _LFM2 = ("kanana-2-30b-a3b-atc-warmup-b1-s8k-1chip",
+                  "lfm2-24b-a2b-atc-warmup-b1-s8k-1chip")
+
+
+@pytest.mark.parametrize("cell_name,which,kernel_calls,budget", [
+    pytest.param(_KANANA, "program", {"attention_global": 18}, 13_259_000_000,
+                 id="program-B1-T8192-six-MLA-layers"),
+    pytest.param(_KANANA, "reference", {"attention_global": 0}, 13_041_000_000,
+                 id="reference-float32-in-pieces", marks=pytest.mark.slow),
+    pytest.param(_LFM2, "program",
+                 {"attention_global": 6, "short_conv_fwd": 12, "short_conv_bwd": 6},
+                 14_642_000_000, id="lfm2-program-B1-T8192-six-conv-two-attention"),
+    pytest.param(_LFM2, "reference",
+                 {"attention_global": 0, "short_conv_fwd": 0, "short_conv_bwd": 0},
+                 13_686_000_000, id="lfm2-reference-float32-in-pieces",
                  marks=pytest.mark.slow)])
-def test_the_latent_attention_decoders_step_fits_a_v5e(one_chip, monkeypatch, which,
-                                                       kernel_calls, budget):
-    """Eighteen flash kernel calls in the program's step: forward, dK/dV and
-    dQ of six layers at a query-key head of 192 beside a value head of 128,
-    the forward not run again because its output and logsumexp are kept."""
+def test_a_decoder_cells_step_fits_a_v5e(one_chip, monkeypatch, cell_name, which,
+                                         kernel_calls, budget):
+    """Kanana: eighteen flash kernel calls in the program's step: forward,
+    dK/dV and dQ of six layers at a query-key head of 192 beside a value head
+    of 128, the forward not run again because its output and logsumexp are
+    kept.  LFM2: the same three of two attention layers at heads of 64, and of
+    six short-convolution layers the forward kernel twice (the recomputed
+    block runs it again) and the backward kernel once."""
     import sys
     sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     from chipbench import manifest, optimizers, seeded
 
-    monkeypatch.setattr(importlib.import_module("bluefog_tpu.kernels.flash_attention"),
-                        "_default_interpret", lambda: False)
-    cell = manifest.resolve("kanana-2-30b-a3b-atc-warmup-b1-s8k-1chip")
+    for module in ("flash_attention", "causal_conv"):
+        monkeypatch.setattr(importlib.import_module(f"bluefog_tpu.kernels.{module}"),
+                            "_default_interpret", lambda: False)
+    cell = manifest.resolve(cell_name)
     sizes, ref = cell.sizes(), cell.module("reference")
     spec = lambda shape, dtype=jnp.float32: jax.ShapeDtypeStruct(
         shape, dtype, sharding=one_chip)
@@ -405,9 +460,10 @@ def test_the_latent_attention_decoders_step_fits_a_v5e(one_chip, monkeypatch, wh
         return out + (g,) if which == "reference" else out  # check.local_step_fn's
 
     compiled = jax.jit(step, donate_argnums=(0, 1)).lower(params, opt, ids, ids).compile()
-    # beside XLA's own grouped products of the five expert layers
-    assert len(re.findall(r"%attention_global(?:\.\d+)? = ", compiled.as_text())) \
-        == kernel_calls
+    # beside XLA's own grouped products of the expert layers
+    text = compiled.as_text()
+    for kernel, calls in kernel_calls.items():
+        assert len(re.findall(rf"%{kernel}(?:\.\d+)? = ", text)) == calls, kernel
     m = compiled.memory_analysis()
     total = (m.argument_size_in_bytes + m.output_size_in_bytes - m.alias_size_in_bytes
              + m.temp_size_in_bytes)
