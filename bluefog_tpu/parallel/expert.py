@@ -33,7 +33,6 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax import lax
 
 from bluefog_tpu.parallel._util import resolve_axis_size, vma_full
@@ -242,7 +241,10 @@ PASS_ROWS = 16384
 # a window, and +39 % in the one layer of Ling in which the router's bias
 # favours the held experts' group (1,425 rows for 1,024: the grain's 2,048
 # hold them).  4/3 is also what keeps the SmallThinker cells' 12,288 rows in
-# their one pass of 16,384.
+# their one pass of 16,384.  What is gathered or scattered is a pass's rows
+# and never all T k assignments: a scalar looked up or added costs the chip
+# 5-9 ns, 0.33-0.57 ms an op over Ling's 65,536 for 1,024 held, forward, again
+# under a block's remat and transposed (my chip runs, PR 46; gone since PR 50).
 HEADROOM = Fraction(4, 3)
 ROW_GRAIN = 1024
 
@@ -263,14 +265,15 @@ def _passes(ends, rows):
 
 def _pass(i, rows, k, order, starts, ends):
     """Pass ``i`` covers the sorted rows ``[i * rows, (i + 1) * rows)``:
-    their first row, their tokens, which of them are assigned to an expert
-    held here, and how many rows of each expert the pass holds."""
+    their assignments (indices into the flat ``[T * k]``), their tokens,
+    which of them are assigned to an expert held here, and how many rows of
+    each expert the pass holds."""
     first = i * rows
-    tok = lax.dynamic_slice_in_dim(order, first, rows) // k
+    idx = lax.dynamic_slice_in_dim(order, first, rows)
     valid = (first + jnp.arange(rows)) < ends[-1]
     sizes = (jnp.clip(ends, first, first + rows)
              - jnp.clip(starts, first, first + rows)).astype(jnp.int32)
-    return first, tok, valid, sizes
+    return idx, idx // k, valid, sizes
 
 
 def _pass_rows_out(xs, w_rows, wg, wu, wd, sizes, valid, dtype, activation):
@@ -300,52 +303,54 @@ def _add_rows(acc, tok, rows):
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10))
-def _held_passes(m, w_sorted, wg, wu, wd, order, starts, ends, k, rows, activation):
+def _held_passes(m, w, wg, wu, wd, order, starts, ends, k, rows, activation):
     """``out[t] = sum over t's assignments a to experts held of w[a]
     E(m[t])``, float32, computed in as many passes of ``rows`` sorted rows as
     there are assignments (a loop whose length is the load's, which reverse
     mode cannot differentiate: hence the rule below, which walks the same
-    passes and lets ``jax.vjp`` differentiate each).  ``w_sorted`` are the
-    weights in sorted order.  Its residuals are its inputs: every pass is
-    recomputed in the backward pass."""
-    return _held_passes_fwd(m, w_sorted, wg, wu, wd, order, starts, ends, k, rows,
+    passes and lets ``jax.vjp`` differentiate each).  ``w [T * k]`` are the
+    weights as the router gave them, one an assignment, unsorted: a pass
+    gathers the ``rows`` it computes by its slice of ``order``, and in the
+    backward pass adds their cotangents back at the same places (every
+    assignment stands once in ``order``; the rows past the last one name
+    assignment 0 and add zeros).  Its residuals are still its inputs: every
+    pass is recomputed in the backward pass, and a block recomputed under
+    remat gathers nothing for it."""
+    return _held_passes_fwd(m, w, wg, wu, wd, order, starts, ends, k, rows,
                             activation)[0]
 
 
-def _held_passes_fwd(m, w_sorted, wg, wu, wd, order, starts, ends, k, rows,
-                     activation):
+def _held_passes_fwd(m, w, wg, wu, wd, order, starts, ends, k, rows, activation):
     def body(i, out):
-        first, tok, valid, sizes = _pass(i, rows, k, order, starts, ends)
-        w_rows = lax.dynamic_slice_in_dim(w_sorted, first, rows)
-        y = _pass_rows_out(m[tok], w_rows, wg, wu, wd, sizes, valid, m.dtype,
+        idx, tok, valid, sizes = _pass(i, rows, k, order, starts, ends)
+        y = _pass_rows_out(m[tok], w[idx], wg, wu, wd, sizes, valid, m.dtype,
                            activation)
         return _add_rows(out, tok, y)
 
     out = lax.fori_loop(0, _passes(ends, rows), body,
                         vma_full(m, _by_lanes(m.shape), jnp.float32))
-    return out.reshape(m.shape), (m, w_sorted, wg, wu, wd, order, starts, ends)
+    return out.reshape(m.shape), (m, w, wg, wu, wd, order, starts, ends)
 
 
 def _held_passes_bwd(k, rows, activation, res, g):
-    m, w_sorted, wg, wu, wd, order, starts, ends = res
+    m, w, wg, wu, wd, order, starts, ends = res
 
     def body(i, acc):
-        dm, dw_sorted, dwg, dwu, dwd = acc
-        first, tok, valid, sizes = _pass(i, rows, k, order, starts, ends)
+        dm, dw, dwg, dwu, dwd = acc
+        idx, tok, valid, sizes = _pass(i, rows, k, order, starts, ends)
         _, vjp = jax.vjp(
             lambda xs, w_rows, wg, wu, wd: _pass_rows_out(
                 xs, w_rows, wg, wu, wd, sizes, valid, m.dtype, activation),
-            m[tok], lax.dynamic_slice_in_dim(w_sorted, first, rows), wg, wu, wd)
+            m[tok], w[idx], wg, wu, wd)
         dxs, dw_rows, g1, g2, g3 = vjp(g[tok])
-        return (_add_rows(dm, tok, dxs),
-                lax.dynamic_update_slice_in_dim(dw_sorted, dw_rows, first, 0),
+        return (_add_rows(dm, tok, dxs), dw.at[idx].add(dw_rows),
                 dwg + g1, dwu + g2, dwd + g3)
 
     zeros = lambda shape: vma_full(g, shape, jnp.float32)  # typed as under shard_map
-    dm, dw_sorted, dwg, dwu, dwd = lax.fori_loop(0, _passes(ends, rows), body, (
-        zeros(_by_lanes(m.shape)), zeros(w_sorted.shape), zeros(wg.shape),
+    dm, dw, dwg, dwu, dwd = lax.fori_loop(0, _passes(ends, rows), body, (
+        zeros(_by_lanes(m.shape)), zeros(w.shape), zeros(wg.shape),
         zeros(wu.shape), zeros(wd.shape)))
-    return (dm.reshape(m.shape).astype(m.dtype), dw_sorted.astype(w_sorted.dtype),
+    return (dm.reshape(m.shape).astype(m.dtype), dw.astype(w.dtype),
             dwg.astype(wg.dtype), dwu.astype(wu.dtype), dwd.astype(wd.dtype),
             None, None, None)
 
@@ -378,7 +383,17 @@ def held_topk_experts(m, experts, weights, params, held, num_experts: int,
     token.  There are as many passes as the load asks for, ``ceil(assigned
     / rows)``, so the layer's cost follows the load of the experts held:
     routing that tilts past the buffer costs a second, equally small pass,
-    and a pile-up on one expert costs time and never a token."""
+    and a pile-up on one expert costs time and never a token.
+
+    Before the passes nothing is looked up or scattered over the ``T * k``
+    assignments, of which a share holds 2-12 %: ``held`` is static, so an
+    assignment's place among the experts held and the count of each
+    expert's rows are comparisons against ``held`` and sums (one fused
+    pass over ``[T * k, H]``; written for ``H`` well under ``num_experts``,
+    and still right with every expert held); the one op over all the
+    assignments is the stable sort.  The weights stay as the router gave
+    them: a pass gathers the ``rows`` it computes, and the backward pass
+    adds their cotangents back a pass at a time (:func:`_held_passes`)."""
     T, d = m.shape
     k = experts.shape[1]
     held = tuple(int(e) for e in held)
@@ -400,20 +415,21 @@ def held_topk_experts(m, experts, weights, params, held, num_experts: int,
         reg.gauge("moe.top_k").set(k)
         reg.gauge("moe.buffer_rows").set(rows)
         reg.gauge("moe.rows_expected").set(A * H / total)
+        reg.gauge("moe.assignments").set(A)
 
     with jax.named_scope("moe_experts"):
-        # global expert id -> its place here, H for "held elsewhere"
-        place = np.full((total,), H, np.int32)
-        place[list(held)] = np.arange(H)
-        group = jnp.asarray(place)[experts].reshape(A)        # [A] in 0..H
+        # an assignment's place among the experts held, H for "held elsewhere":
+        # `held` is static, so both are comparisons and sums, nothing looked up
+        hit = experts.reshape(A, 1) == jnp.asarray(held, experts.dtype)  # [A, H]
+        group = jnp.min(jnp.where(hit, jnp.arange(H, dtype=jnp.int32), H), axis=-1)
         order = jnp.argsort(group, stable=True)               # held ones first
-        sizes = jnp.bincount(group, length=H + 1)[:H].astype(jnp.int32)
+        sizes = jnp.sum(hit, axis=0, dtype=jnp.int32)
         ends = jnp.cumsum(sizes)
         # the last pass may reach past the A assignments: never past this
         most = -(-T * min(k, H) // rows) * rows
         if most > A:  # the padding names assignment 0; its rows are not valid
             order = jnp.concatenate([order, jnp.zeros((most - A,), order.dtype)])
-        out = _held_passes(m, weights.reshape(A)[order], params["wg"],
-                           params["wu"], params["wd"], order, ends - sizes, ends,
-                           k, rows, activation)
+        out = _held_passes(m, weights.reshape(A), params["wg"], params["wu"],
+                           params["wd"], order, ends - sizes, ends, k, rows,
+                           activation)
         return out.astype(m.dtype)

@@ -2,10 +2,13 @@
 (`parallel.expert.route_topk`, `held_topk_experts`): the shares add up to the
 uncut layer of the configuration's plain reference, no assignment is lost
 when one expert gets every token, gradients reach the router and the experts,
-and the trace-time gauges say what was built."""
+the layer is the parent's prologue to the bit with nothing looked up or
+scattered over all the assignments, and the trace-time gauges say what was
+built."""
 
 import os
 import sys
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -192,6 +195,7 @@ def test_gauges_say_what_was_built(monkeypatch, tmp_path):
         "moe.experts_held": 4, "moe.experts_total": 16, "moe.top_k": 3,
         "moe.buffer_rows": 384,  # 128 tokens x min(3, 4): all the rows there can be
         "moe.rows_expected": 96.0,  # 128 x 3 x 4 / 16: what even routing sends here
+        "moe.assignments": 384,  # 128 x 3: what the sort and the comparisons run over
         "attention.window": 24, "attention.layers_window": 3,
         "attention.layers_global": 1}
     # the SmallThinker cells' layer: passes of 16,384 sorted rows, one at even
@@ -219,6 +223,18 @@ def test_a_pass_is_sized_for_the_load_the_shapes_promise(name):
     assert ep._pass_rows(tokens, k, 1, total) <= tokens
 
 
+def _pushed(layer, tokens, held, push):
+    """`tokens` tokens that differ, a last feature of ones whose row of the
+    router leans by `push` on the experts `held`, and their stacks."""
+    ks = jax.random.split(jax.random.PRNGKey(11), 2)
+    x = jnp.concatenate([jax.random.normal(ks[0], (tokens, D)),
+                         jnp.ones((tokens, 1))], axis=1)
+    lean = jnp.zeros((1, E)).at[0, jnp.asarray(held)].set(push)
+    stacks = {n: layer[n][jnp.asarray(held)] for n in ("wg", "wu", "wd")}
+    return dict(stacks, x=x, m=jax.random.normal(ks[1], (tokens, D)),
+                router=jnp.concatenate([layer["router"], lean]))
+
+
 @pytest.mark.parametrize("push,passes", [(1.5, 2), (3.5, 3)])
 def test_routing_that_overfills_the_default_buffer_takes_more_passes(
         reference, layer, push, passes):
@@ -227,15 +243,9 @@ def test_routing_that_overfills_the_default_buffer_takes_more_passes(
     the last partly filled, and the plain reference's numbers, output and
     every gradient, as from one pass of all the rows there can be."""
     tokens, held = 1024, list(range(8))
-    ks = jax.random.split(jax.random.PRNGKey(11), 2)
-    # a last feature of ones: its row of the router leans on the experts held
-    x = jnp.concatenate([jax.random.normal(ks[0], (tokens, D)),
-                         jnp.ones((tokens, 1))], axis=1)
-    router = jnp.concatenate([layer["router"],
-                              jnp.zeros((1, E)).at[0, :8].set(push)])
-    piled = dict(layer, x=x, m=jax.random.normal(ks[1], (tokens, D)), router=router)
+    piled = _pushed(layer, tokens, held, push)
     rows = ep._pass_rows(tokens, K, len(held), E)
-    assigned = int(jnp.sum(ep.route_topk(x, router, K)[0] < 8))
+    assigned = int(jnp.sum(ep.route_topk(piled["x"], piled["router"], K)[0] < 8))
     assert rows == 1024 and -(-assigned // rows) == passes and assigned % rows
 
     def ours(a, rows=None):
@@ -256,3 +266,157 @@ def test_routing_that_overfills_the_default_buffer_takes_more_passes(
         assert scale > 0
         np.testing.assert_allclose(got[name], want[name], atol=2e-5 * scale + 1e-7)
         np.testing.assert_allclose(got[name], whole[name], atol=2e-5 * scale + 1e-7)
+
+
+# ---- the prologue: the parent's to the bit, and nothing looked up over T k -------
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(8, 9))
+def _parents_passes(m, w_sorted, wg, wu, wd, order, starts, ends, k, rows):
+    """`_held_passes` as the parent commit (1cede2d) had it: handed all `T k`
+    weights in sorted order, a pass slices its rows of them, and the backward
+    pass writes their cotangents back slice by slice."""
+    return _parents_passes_fwd(m, w_sorted, wg, wu, wd, order, starts, ends, k, rows)[0]
+
+
+def _parents_passes_fwd(m, w_sorted, wg, wu, wd, order, starts, ends, k, rows):
+    def body(i, out):
+        _, tok, valid, sizes = ep._pass(i, rows, k, order, starts, ends)
+        w_rows = jax.lax.dynamic_slice_in_dim(w_sorted, i * rows, rows)
+        y = ep._pass_rows_out(m[tok], w_rows, wg, wu, wd, sizes, valid, m.dtype,
+                              jax.nn.relu)
+        return ep._add_rows(out, tok, y)
+
+    out = jax.lax.fori_loop(0, ep._passes(ends, rows), body,
+                            jnp.zeros(ep._by_lanes(m.shape), jnp.float32))
+    return out.reshape(m.shape), (m, w_sorted, wg, wu, wd, order, starts, ends)
+
+
+def _parents_passes_bwd(k, rows, res, g):
+    m, w_sorted, wg, wu, wd, order, starts, ends = res
+
+    def body(i, acc):
+        dm, dw_sorted, dwg, dwu, dwd = acc
+        _, tok, valid, sizes = ep._pass(i, rows, k, order, starts, ends)
+        _, vjp = jax.vjp(
+            lambda xs, w_rows, wg, wu, wd: ep._pass_rows_out(
+                xs, w_rows, wg, wu, wd, sizes, valid, m.dtype, jax.nn.relu),
+            m[tok], jax.lax.dynamic_slice_in_dim(w_sorted, i * rows, rows), wg, wu, wd)
+        dxs, dw_rows, g1, g2, g3 = vjp(g[tok])
+        return (ep._add_rows(dm, tok, dxs),
+                jax.lax.dynamic_update_slice_in_dim(dw_sorted, dw_rows, i * rows, 0),
+                dwg + g1, dwu + g2, dwd + g3)
+
+    zeros = lambda like: jnp.zeros(like.shape, jnp.float32)
+    dm, dw_sorted, dwg, dwu, dwd = jax.lax.fori_loop(0, ep._passes(ends, rows), body, (
+        jnp.zeros(ep._by_lanes(m.shape), jnp.float32), zeros(w_sorted), zeros(wg),
+        zeros(wu), zeros(wd)))
+    return (dm.reshape(m.shape).astype(m.dtype), dw_sorted, dwg.astype(wg.dtype),
+            dwu.astype(wu.dtype), dwd.astype(wd.dtype), None, None, None)
+
+
+_parents_passes.defvjp(_parents_passes_fwd, _parents_passes_bwd)
+
+
+def parents_layer(m, experts, weights, params, held, num_experts, rows=None):
+    """The oracle: `held_topk_experts` with the parent commit's prologue: a
+    table gather names the experts held, `bincount` counts them, and every
+    one of the `T k` weights is gathered into sorted order before the passes."""
+    tokens, k, held = m.shape[0], experts.shape[1], tuple(held)
+    H, A = len(held), tokens * k
+    rows = rows or ep._pass_rows(tokens, k, H, num_experts)
+    place = np.full((num_experts,), H, np.int32)
+    place[list(held)] = np.arange(H)
+    group = jnp.asarray(place)[experts].reshape(A)
+    order = jnp.argsort(group, stable=True)
+    sizes = jnp.bincount(group, length=H + 1)[:H].astype(jnp.int32)
+    ends = jnp.cumsum(sizes)
+    most = -(-tokens * min(k, H) // rows) * rows
+    if most > A:
+        order = jnp.concatenate([order, jnp.zeros((most - A,), order.dtype)])
+    out = _parents_passes(m, weights.reshape(A)[order], params["wg"], params["wu"],
+                          params["wd"], order, ends - sizes, ends, k, rows)
+    return out.astype(m.dtype)
+
+
+@pytest.mark.parametrize("held,push,rows,passes", [
+    (tuple(range(8)), 0.0, None, 1), (tuple(range(8)), 1.5, None, 2),
+    (tuple(range(8)), 3.5, 256, 10), ((5, 1, 9), 0.0, None, 1), ((5, 1, 9), 2.0, 64, 11),
+    (tuple(range(E)), 0.0, 1000, 7)],
+    ids=["one-pass", "two-passes", "ten-passes", "held-5-1-9", "held-5-1-9-pushed",
+         "every-expert-held"])
+def test_the_layer_is_the_parents_prologue_to_the_bit(layer, held, push, rows, passes):
+    """The weights gathered a pass and the held named by comparison change no
+    bit: the loss, the output and the gradients to `m`, to the router (through
+    `weights`) and to the three stacks equal those of the parent's prologue,
+    at one pass, at several, with held experts that are no leading range and
+    with every expert held."""
+    a = _pushed(layer, 1024, held, push)
+    assigned = int(jnp.isin(ep.route_topk(a["x"], a["router"], K)[0],
+                            jnp.asarray(held)).sum())
+    assert -(-assigned // (rows or ep._pass_rows(1024, K, len(held), E))) == passes
+
+    def run(fn):
+        def loss(a):
+            experts, weights = ep.route_topk(a["x"], a["router"], K)
+            y = fn(a["m"], experts, weights, a, held, E, rows=rows)
+            return jnp.sum(y ** 2), y
+        return jax.jit(jax.value_and_grad(loss, has_aux=True))(a)
+
+    (got_loss, got_out), got = run(ep.held_topk_experts)
+    (want_loss, want_out), want = run(parents_layer)
+    assert float(jnp.max(jnp.abs(want["router"]))) > 0
+    np.testing.assert_array_equal(got_loss, want_loss)
+    np.testing.assert_array_equal(got_out, want_out)
+    for name in ("m", "router", "wg", "wu", "wd"):
+        np.testing.assert_array_equal(got[name], want[name], err_msg=name)
+
+
+def _outside_the_loops(text):
+    """The lines of a lowered module that stand in no `stablehlo.while`, and
+    how many `while`s there are."""
+    kept, depth, inside, loops = [], 0, None, 0
+    for line in text.splitlines():
+        if inside is None and "stablehlo.while" in line:
+            inside, loops = depth, loops + 1
+        if inside is None:
+            kept.append(line)
+        depth += line.count("{") - line.count("}")
+        if inside is not None and depth <= inside and "}" in line:
+            inside = None
+    return kept, loops
+
+
+def _count(lines, op):
+    return sum(line.count(f'"stablehlo.{op}"(') for line in lines)
+
+
+@pytest.mark.parametrize("name", ["ling", "laguna"])
+def test_nothing_is_gathered_or_scattered_over_all_the_assignments(name):
+    """Loss-and-gradient of the layer at a cell's shapes (Ling's 65,536
+    assignments for 2,048 rows a pass; Laguna's, whose last pass reaches past
+    them): every gather and scatter stands inside one of the two loops over the
+    passes, on a pass's rows; over all `T k` there is the one sort.  The
+    parent's prologue has two gathers and two scatters before its loops."""
+    (tokens, k, H, total), rows = CELLS[name]
+    d, f, S = 2560, 768, jax.ShapeDtypeStruct
+    stacks = {"wg": S((H, d, f), jnp.float32), "wu": S((H, d, f), jnp.float32),
+              "wd": S((H, f, d), jnp.float32)}
+
+    def lowered(fn):
+        def loss(m, weights, stacks, experts):
+            return jnp.sum(fn(m, experts, weights, stacks, range(H), total)
+                           .astype(jnp.float32))
+        return jax.jit(jax.value_and_grad(loss, (0, 1, 2))).lower(
+            S((tokens, d), jnp.bfloat16), S((tokens, k), jnp.float32), stacks,
+            S((tokens, k), jnp.int32)).as_text()
+
+    text = lowered(ep.held_topk_experts)
+    ours, loops = _outside_the_loops(text)
+    assert loops == 2 and f"{rows}x{d}" in text
+    assert [_count(ours, op) for op in ("gather", "scatter", "sort")] == [0, 0, 1]
+    # in the loops: m[tok] and w[idx] forward, with g[tok] backward; out; dm and dw
+    assert [_count(text.splitlines(), op) for op in ("gather", "scatter")] == [5, 3]
+    theirs, loops = _outside_the_loops(lowered(parents_layer))
+    assert loops == 2
+    assert [_count(theirs, op) for op in ("gather", "scatter", "sort")] == [2, 2, 1]

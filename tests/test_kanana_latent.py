@@ -210,18 +210,21 @@ def test_a_delta_rule_layer_without_a_head_size_is_refused(cell):
 # replaced Ling's (771adfbc...: its mixer hands q and k raw to the delta rule's
 # kernels, which take the unit vectors) and added this file's own cell at the
 # hash of PR 47's parent (8f82114): it builds Ling's decoder class with no
-# delta-rule layer, and a change to that layer must not reach it.
+# delta-rule layer, and a change to that layer must not reach it.  PR 50
+# replaced the four that run `held_topk_experts` (Ling, Laguna, SmallThinker,
+# Kanana: a pass gathers its own weights and the held experts are named by
+# comparison); Granite's, which has no expert layer, is PR 44's still.
 LOWERED = {
     "ling-3.0-flash-vl-atc-warmup-b1-s8k-1chip":
-        "e5569a6361513621afed6f11fd8916e241870bfaec61d7b3ca90e16299e1729b",
+        "511b054b4a2aeb437e17bf45a171bac5e181c03354548982dcd72aae6fe4e650",
     "granite-4.0-h-micro-atc-warmup-b1-s8k-1chip":
         "709927d17eea333679c74e2f51cdb16b1f040eb879cfac4ef45e269dd07ad59b",
     "laguna-xs.2-atc-warmup-b1-s8k-1chip":
-        "dd5029b60057b96eace882c332b03b25479409159ae6eed99674f13af36d6c62",
+        "68773a73f8cdcec8914611d7d2f7a95ff7f33426a002fe57a8818975c7e8eda1",
     "smallthinker-21b-a3b-atc-warmup-b2-s8k-1chip":
-        "1b418ca17393ef5375d3c733fd0da3f148a16a668f4084bcad4bb73e2134e8eb",
+        "5beb16b5a2c572dec19230225c81aba9fef0fa1b382054b210558752b88515cb",
     "kanana-2-30b-a3b-atc-warmup-b1-s8k-1chip":
-        "0d0396b84222beec9e78bea16e21404b82fbbeb2d69d1cae796b35741ee3873e",
+        "45bac18613ca4377e63b01bfd40b39a66949e88f56fa0fa088d0d7c038cb407e",
 }
 
 
@@ -243,8 +246,9 @@ def test_smallthinkers_expert_layer_at_its_cells_size_lowers_to_what_it_did():
     """The rehearsal sizes above are too small to say: there a pass holds all
     the rows there can be, whatever sizes it.  The layer alone at the
     SmallThinker cells' 16,384 tokens, 6 of 64, 8 held, hidden 2560, width 768,
-    loss-and-gradient: the text the parent commit (80edb39, before a pass was
-    sized from the load) lowered it to, one pass of 16,384 rows in it."""
+    loss-and-gradient: one pass of 16,384 rows in it, as at 80edb39, before a
+    pass was sized from the load; the text is what PR 50's tree lowers it to
+    (its parent, 1cede2d, gathered every weight before the passes: 08fddf53...)."""
     from bluefog_tpu.parallel import expert as ep
 
     small = manifest.resolve("smallthinker-21b-a3b-atc-warmup-b2-s8k-1chip").sizes()
@@ -265,7 +269,7 @@ def test_smallthinkers_expert_layer_at_its_cells_size_lowers_to_what_it_did():
         S((T, k), jnp.int32)).as_text()
     assert "16384x2560" in text
     assert hashlib.sha256(text.encode()).hexdigest() \
-        == "08fddf530150f5d79740e1e4b19af5b542e1fcfa3be28abf5cf7817801a8b08c"
+        == "9c7c576fcb635e0f7f99bf034c11c04bdb424161dd1b6f70d78b1abcd1d6cb98"
 
 
 # ---- the manifest, the readers --------------------------------------------------
