@@ -25,7 +25,8 @@ signal**, its scores scaled by ``attention_multiplier`` in place of
 input is ``embedding_multiplier`` times the embedding, the logits are the
 final norm's output times the **same** tensor over ``logits_scaling``; with
 ``labels`` the model returns the chunked next-token loss
-(:func:`bluefog_tpu.models.transformer.chunked_softmax_cross_entropy`), so
+(:func:`bluefog_tpu.models.transformer.chunked_softmax_cross_entropy`: one
+loop over the chunks that takes the head's gradient with the loss), so
 :func:`bluefog_tpu.training.make_lm_loss_fns`' identity loss serves it.
 """
 

@@ -337,22 +337,26 @@ def _bf16_matmul_f32_acc_fwd(x, kernel):
     return y, (xb, kb)
 
 
-def _bf16_matmul_f32_acc_bwd(res, g):
-    xb, kb = res
-    gb = g.astype(jnp.bfloat16)
-    nbatch = gb.ndim - 1
+def _matmul_f32_acc_bwd(x, kernel, g):
+    """``(dx, dW)`` of ``x @ kernel`` under the cotangent ``g``, the three in
+    one dtype, both products accumulated and returned in f32."""
+    nbatch = g.ndim - 1
     # dx[..., d] = g[..., v] @ kernel[d, v]^T
     dx = jax.lax.dot_general(
-        gb, kb, (((nbatch,), (1,)), ((), ())),
+        g, kernel, (((nbatch,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
     # dW[d, v] = sum over batch dims of x[..., d] * g[..., v]
     batch_axes = tuple(range(nbatch))
     dw = jax.lax.dot_general(
-        xb, gb, ((batch_axes, batch_axes), ((), ())),
+        x, g, ((batch_axes, batch_axes), ((), ())),
         preferred_element_type=jnp.float32,
     )
     return dx, dw
+
+
+def _bf16_matmul_f32_acc_bwd(res, g):
+    return _matmul_f32_acc_bwd(*res, g.astype(jnp.bfloat16))
 
 
 _bf16_matmul_f32_acc.defvjp(_bf16_matmul_f32_acc_fwd, _bf16_matmul_f32_acc_bwd)
@@ -378,7 +382,6 @@ def _head_matmul(x, kernel, dtype):
     )
 
 
-@jax.named_scope("lm_head_loss")
 def chunked_softmax_cross_entropy(hidden, kernel, labels, num_chunks,
                                   dtype=jnp.float32, onehot_targets=False,
                                   kernel_constraint=None):
@@ -389,14 +392,22 @@ def chunked_softmax_cross_entropy(hidden, kernel, labels, num_chunks,
     its backward cotangent doubles that) and are flatly infeasible at
     Llama-3-8B's 128k vocab.  This computes the shifted-LM loss
     ``mean(CE(logits[:, :-1], labels[:, 1:]))`` as a ``lax.scan`` over
-    ``num_chunks`` sequence chunks with a ``jax.checkpoint`` body: the
-    forward keeps only the running (sum, count) scalars, and the backward
-    recomputes each chunk's ``[B, T/num_chunks, vocab]`` logits on the
-    fly — peak logits memory drops by ``num_chunks``× at the cost of one
-    extra head matmul (2·B·T·d·V flops, ~2% of a 1B model's 6N step).
+    ``num_chunks`` sequence chunks that holds one chunk's
+    ``[B, T/num_chunks, vocab]`` logits at a time: peak logits memory drops
+    by ``num_chunks``×.
+
+    The loss is a mean of independent terms, so a chunk's gradient is known
+    the moment its logits are: ``(softmax - onehot) * w / count``.  Under
+    differentiation (a ``jax.custom_vjp``, reverse mode only) that same loop
+    takes ``dx = dlogits W^T`` and ``dW += x^T dlogits`` on the logits while
+    they are there, and the backward pass only scales the two kept arrays
+    (``dx`` in ``hidden``'s dtype, ``dW`` in f32) by the loss's cotangent:
+    three head matmuls a step and no loop backward.  Called without a
+    gradient it is the forward loop alone, one matmul.
 
     Equivalent to the full-logits loss to f32 roundoff
-    (`tests/test_training.py::test_llama_head_chunks_matches_full`).
+    (`tests/test_training.py::test_llama_head_chunks_matches_full`,
+    `::test_chunked_loss_takes_its_gradient_in_the_forward_loop`).
 
     Args:
       hidden: ``[B, T, d]`` final hidden states (any float dtype; logits
@@ -412,33 +423,43 @@ def chunked_softmax_cross_entropy(hidden, kernel, labels, num_chunks,
         compile measured full-batch f32 activation gathers from exactly
         this; see ``LlamaLM.spmd_vocab``).
       kernel_constraint: applied to ``kernel`` INSIDE the scan body, once
-        per chunk.  Under FSDP this must be the SHARDING-ONLY per-read
-        marker (``fsdp_param_io_constraint(...).sharding_only`` — no
-        grad-dtype cast, or every chunk cotangent would round and the
-        scan transpose would sum in bf16) and must sit inside the body:
-        with the marker only outside, the transpose's accumulator is laid
-        out replicated — measured as the largest single temps item of the
-        8B compile (f32[4096,128k] ≈ 2.1 GB per buffer).
+        per chunk, and to the ``dW`` accumulator the loop carries.  Under
+        FSDP this must be the SHARDING-ONLY per-read marker
+        (``fsdp_param_io_constraint(...).sharding_only`` — no grad-dtype
+        cast: the accumulator sums in f32 and is rounded once, outside)
+        and must sit inside the body: with the marker only outside, the
+        accumulator is laid out replicated — measured as the largest single
+        temps item of the 8B compile (f32[4096,128k] ≈ 2.1 GB per buffer).
     """
     B, T, _ = hidden.shape
     if T % num_chunks:
         raise ValueError(f"num_chunks {num_chunks} must divide T {T}")
-    # shift the targets left so every chunk scores positions uniformly;
-    # the pad at T-1 carries weight 0 (the last token predicts nothing)
-    y = jnp.concatenate([labels[:, 1:], labels[:, :1]], axis=1)
-    w = jnp.concatenate(
-        [jnp.ones((B, T - 1), jnp.float32), jnp.zeros((B, 1), jnp.float32)],
-        axis=1,
-    )
     tc = T // num_chunks
-    xs = hidden.reshape(B, num_chunks, tc, hidden.shape[-1]).transpose(1, 0, 2, 3)
-    ys = y.reshape(B, num_chunks, tc).transpose(1, 0, 2)
-    ws = w.reshape(B, num_chunks, tc).transpose(1, 0, 2)
+    # the last token predicts nothing.  The loss itself divides by the weights'
+    # sum, the same number: XLA makes a division by a literal a product, and
+    # the value without a gradient is the checkpointed loop's to the bit
+    count = B * (T - 1)
+    pin = kernel_constraint or (lambda a: a)
+    # under shard_map the kept dx and dW vary over the axes of both operands:
+    # widen each to the other's out here, where autodiff sums back over them
+    vma = jax.typeof(hidden).vma | jax.typeof(kernel).vma
+    hidden, kernel = (
+        jax.lax.pcast(a, tuple(sorted(vma - jax.typeof(a).vma)), to="varying")
+        for a in (hidden, kernel))
 
-    @jax.checkpoint
-    def body(carry, xyw):
-        xc, yc, wc = xyw
-        k = kernel if kernel_constraint is None else kernel_constraint(kernel)
+    def split(hidden, labels):
+        # shift the targets left so every chunk scores positions uniformly;
+        # the pad at T-1 carries weight 0
+        y = jnp.concatenate([labels[:, 1:], labels[:, :1]], axis=1)
+        w = jnp.concatenate(
+            [jnp.ones((B, T - 1), jnp.float32), jnp.zeros((B, 1), jnp.float32)],
+            axis=1,
+        )
+        return tuple(
+            jnp.moveaxis(a.reshape(B, num_chunks, tc, *a.shape[2:]), 1, 0)
+            for a in (hidden, y, w))
+
+    def chunk_loss(k, xc, yc, wc):
         logits = _head_matmul(xc, k, dtype)  # [B, tc, V] — the peak
         lse = jax.nn.logsumexp(logits, axis=-1)
         if onehot_targets:
@@ -447,13 +468,53 @@ def chunked_softmax_cross_entropy(hidden, kernel, labels, num_chunks,
                                         dtype=logits.dtype), axis=-1)
         else:
             tgt = jnp.take_along_axis(logits, yc[..., None], axis=-1)[..., 0]
-        # per-chunk outputs instead of a scalar carry: under shard_map a
-        # plain-zeros carry init would mismatch the body's varying-axes
-        # type (jax vma rules); stacked outputs inherit it automatically
-        return carry, (((lse - tgt) * wc).sum(), wc.sum())
+        return logits, lse, (((lse - tgt) * wc).sum(), wc.sum())
 
-    _, (tots, cnts) = jax.lax.scan(body, (), (xs, ys, ws))
-    return tots.sum() / cnts.sum()
+    @jax.custom_vjp
+    def loss(hidden, kernel, labels):
+        def body(carry, xyw):
+            return carry, chunk_loss(pin(kernel), *xyw)[-1]
+
+        with jax.named_scope("lm_head_loss"):
+            # per-chunk outputs instead of a scalar carry: under shard_map a
+            # plain-zeros carry init would mismatch the body's varying-axes
+            # type (jax vma rules); stacked outputs inherit it automatically
+            _, (tots, cnts) = jax.lax.scan(body, (), split(hidden, labels))
+            return tots.sum() / cnts.sum()
+
+    def loss_fwd(hidden, kernel, labels):
+        def body(dw, xyw):
+            xc, yc, wc = xyw
+            # the chunk sliced on its own before the products read it: as a
+            # dynamic slice of the stack fused into the dW product, that product
+            # read 12.6 ms a step for the 8.6 of the same product behind a plain
+            # chunk (v5e, SmallThinker's shapes, PERF.md section 6, PR 51)
+            xc = jax.lax.optimization_barrier(xc)
+            k = pin(kernel)
+            logits, lse, sums = chunk_loss(k, xc, yc, wc)
+            onehot = jax.nn.one_hot(yc, logits.shape[-1], dtype=logits.dtype)
+            dlogits = ((jnp.exp(logits - lse[..., None]) - onehot)
+                       * (wc / count)[..., None])
+            # the operand types of _head_matmul's own VJP
+            dxc, dwc = _matmul_f32_acc_bwd(
+                xc.astype(dtype), k.astype(dtype), dlogits.astype(dtype))
+            return pin(dw + dwc), (dxc.astype(xc.dtype), sums)
+
+        with jax.named_scope("lm_head_loss"):
+            # zeros of the kernel's own type: its varying axes under shard_map
+            dw, (dxs, (tots, cnts)) = jax.lax.scan(
+                body, pin(jnp.zeros_like(kernel, jnp.float32)),
+                split(hidden, labels))
+            dx = jnp.moveaxis(dxs, 0, 1).reshape(hidden.shape)
+            return tots.sum() / cnts.sum(), (dx, dw.astype(kernel.dtype))
+
+    def loss_bwd(kept, g):
+        dx, dw = kept
+        with jax.named_scope("lm_head_loss"):
+            return (g * dx).astype(dx.dtype), (g * dw).astype(dw.dtype), None
+
+    loss.defvjp(loss_fwd, loss_bwd)
+    return loss(hidden, kernel, labels)
 
 
 class _HeadKernel(nn.Module):
@@ -491,7 +552,9 @@ class LlamaLM(nn.Module):
     remat_policy: Optional[str] = None  # _remat_block: None|"dots"|"dots_no_batch"|"attn"
     scan_layers: bool = False  # lax.scan over stacked layers: O(1)-size HLO
     num_kv_heads: Optional[int] = None  # GQA: kv heads < query heads
-    head_chunks: int = 0  # >1: chunked LM loss, never materializes full logits
+    # >1: chunked LM loss (one loop that takes the head's gradient with the
+    # loss), never materializes full logits
+    head_chunks: int = 0
     head_dtype: Any = jnp.float32  # bf16: 1-pass MXU head, f32 accumulation
     # vocab-dim-sharded deployment mode (FSDP/ZeRO with the embedding and
     # head kernels sharded over their vocab axis): route every vocab-indexed
@@ -609,8 +672,8 @@ class LlamaLM(nn.Module):
         if labels is None:
             return _head_matmul(x, kernel, self.head_dtype)  # f32 logits
         if self.head_chunks > 1:
-            # sharding-only pin per chunk (keeps the scan-transpose
-            # accumulator sharded); the cast already happened above
+            # sharding-only pin per chunk and on the loop's dW accumulator
+            # (keeps it sharded); the cast already happened above
             wc = self.weight_constraint
             if wc is not None and not hasattr(wc, "sharding_only"):
                 raise ValueError(

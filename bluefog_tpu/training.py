@@ -72,9 +72,10 @@ def make_lm_loss_fns(model):
 
     With ``head_chunks > 1`` the model computes the chunked scalar loss
     itself (``apply(variables, ids, labels=ids)`` — the full
-    ``[B, T, vocab]`` logits never materialize) and ``loss_fn`` is the
-    identity; otherwise the model returns logits and ``loss_fn`` is the
-    standard shifted cross-entropy.  One definition shared by
+    ``[B, T, vocab]`` logits never materialize, and the head's gradient is
+    taken in that one loop, a ``jax.custom_vjp``: reverse mode only) and
+    ``loss_fn`` is the identity; otherwise the model returns logits and
+    ``loss_fn`` is the standard shifted cross-entropy.  One definition shared by
     ``chip_smoke.py`` and ``examples/jax_llama_pretrain.py`` so the
     chunked-loss contract cannot drift between them.
     """

@@ -158,12 +158,14 @@ def test_one_rank_step_takes_the_old_path(one, monkeypatch):
 # in interpret mode; with `@name_<n>` written `@name_N` the text (1,430,350
 # characters) is 6f0fa38's.  PR 50 changed the decoder's again: its expert
 # layer gathers a pass's weights inside the pass and names the experts held by
-# comparison (d96fcf3a... before); ResNet-50's and BERT's are 60fb42e's still.
+# comparison (d96fcf3a... before), and PR 51 once more: the chunked head and
+# loss takes its gradient in its forward loop (8908aae9... before); ResNet-50's
+# and BERT's are 60fb42e's still.
 PARENT = {
     "resnet50": "256a819480d47ec6178251a6c84ba21859746b606ce444f1c967201f2400471a",
     "bert-base": "d576b07eb83dc4deeb004ee577bd1aaa7df21de5ff06f0ba2ee104953b6367b0",
     "smallthinker-21b-a3b":
-        "8908aae913cc85539046d45be8f13ffe7a899374cb3f6f35cd537259bbb5cd17",
+        "137d99f56e3d28aeeecd76dfc146a4774b95e6971099bc8bdad7b6e13271ffbc",
 }
 
 

@@ -213,18 +213,21 @@ def test_a_delta_rule_layer_without_a_head_size_is_refused(cell):
 # delta-rule layer, and a change to that layer must not reach it.  PR 50
 # replaced the four that run `held_topk_experts` (Ling, Laguna, SmallThinker,
 # Kanana: a pass gathers its own weights and the held experts are named by
-# comparison); Granite's, which has no expert layer, is PR 44's still.
+# comparison); Granite's, which has no expert layer, was PR 44's until PR 51
+# replaced all five: every decoder ends in `chunked_softmax_cross_entropy`,
+# whose gradient is now taken in its forward loop (a `custom_vjp`, no
+# `checkpoint`; Granite's was 709927d1..., this file's cell's 45bac186...).
 LOWERED = {
     "ling-3.0-flash-vl-atc-warmup-b1-s8k-1chip":
-        "511b054b4a2aeb437e17bf45a171bac5e181c03354548982dcd72aae6fe4e650",
+        "f8d62086177dd395bcf60d19d2617d8dc763e0edf1664c441b1cba38d492ae33",
     "granite-4.0-h-micro-atc-warmup-b1-s8k-1chip":
-        "709927d17eea333679c74e2f51cdb16b1f040eb879cfac4ef45e269dd07ad59b",
+        "83b707c64d21c73664894b8bd56ff6fd9c4914c3493b29e2c0ad091125f6e5e8",
     "laguna-xs.2-atc-warmup-b1-s8k-1chip":
-        "68773a73f8cdcec8914611d7d2f7a95ff7f33426a002fe57a8818975c7e8eda1",
+        "dd842a6c622a527f5434db51401d9e1857e02d1aae4119bc04446a1f95d1f907",
     "smallthinker-21b-a3b-atc-warmup-b2-s8k-1chip":
-        "5beb16b5a2c572dec19230225c81aba9fef0fa1b382054b210558752b88515cb",
+        "3ebb25b9ee424522c76b3b73201abae353d56de657ec2aa97aeb1062301212bb",
     "kanana-2-30b-a3b-atc-warmup-b1-s8k-1chip":
-        "45bac18613ca4377e63b01bfd40b39a66949e88f56fa0fa088d0d7c038cb407e",
+        "4133bcd4b79c76b20887251512a4e72be61118307fb0ddd9ce1570f34f37f993",
 }
 
 

@@ -178,8 +178,19 @@ def test_backward_and_recomputed_are_read_off_the_path(stepped):
         assert any("/checkpoint/rematted_computation/layer_0/" in op.path for op in again)
     elif kind == "resnet":
         assert not again
-    else:  # only the chunked loss's body is under jax.checkpoint
-        assert again and all("lm_head_loss" in op.path for op in again)
+    else:  # no block is rematerialised, and the chunked loss runs no loop backward
+        assert not again
+
+
+def test_the_head_and_loss_is_three_products_in_the_forward_loop(stepped):
+    """A decoder's step: the chunk's logits, `dx` and `dW` while the logits
+    are there, and no product of the head's scope traced backward or made
+    again."""
+    kind, record, text = stepped
+    products = re.findall(r' dot\(.*op_name="([^"]*lm_head_loss[^"]*)"', text)
+    assert len(products) == (0 if kind == "resnet" else 3), products
+    assert not any(tl.BACKWARD_MARK in p or tl.RECOMPUTED_MARK in p for p in products)
+    assert not any(op.recomputed for op in record.ops if "lm_head_loss" in op.path)
 
 
 def test_the_record_is_made_when_first_read_and_once(monkeypatch):

@@ -538,3 +538,143 @@ def test_llama_head_bf16_close_to_f32():
             np.testing.assert_allclose(
                 np.asarray(a), np.asarray(b), atol=2e-2, rtol=2e-2
             )
+
+
+# ---- the chunked head and loss: its gradient taken in the forward loop ---------
+
+
+def _parents_chunked_loss(hidden, kernel, labels, num_chunks,
+                          dtype=jnp.float32, onehot_targets=False,
+                          kernel_constraint=None):
+    """`chunked_softmax_cross_entropy` as the parent commit (0044f21) had it:
+    a `jax.checkpoint` body, so that the backward loop makes each chunk's
+    logits again and autodiff takes the gradient.  The oracle."""
+    from bluefog_tpu.models.transformer import _head_matmul
+
+    B, T, _ = hidden.shape
+    if T % num_chunks:
+        raise ValueError(f"num_chunks {num_chunks} must divide T {T}")
+    # shift the targets left so every chunk scores positions uniformly;
+    # the pad at T-1 carries weight 0 (the last token predicts nothing)
+    y = jnp.concatenate([labels[:, 1:], labels[:, :1]], axis=1)
+    w = jnp.concatenate(
+        [jnp.ones((B, T - 1), jnp.float32), jnp.zeros((B, 1), jnp.float32)],
+        axis=1,
+    )
+    tc = T // num_chunks
+    xs = hidden.reshape(B, num_chunks, tc, hidden.shape[-1]).transpose(1, 0, 2, 3)
+    ys = y.reshape(B, num_chunks, tc).transpose(1, 0, 2)
+    ws = w.reshape(B, num_chunks, tc).transpose(1, 0, 2)
+
+    @jax.checkpoint
+    def body(carry, xyw):
+        xc, yc, wc = xyw
+        k = kernel if kernel_constraint is None else kernel_constraint(kernel)
+        logits = _head_matmul(xc, k, dtype)  # [B, tc, V] — the peak
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        if onehot_targets:
+            tgt = jnp.sum(
+                logits * jax.nn.one_hot(yc, logits.shape[-1],
+                                        dtype=logits.dtype), axis=-1)
+        else:
+            tgt = jnp.take_along_axis(logits, yc[..., None], axis=-1)[..., 0]
+        # per-chunk outputs instead of a scalar carry: under shard_map a
+        # plain-zeros carry init would mismatch the body's varying-axes
+        # type (jax vma rules); stacked outputs inherit it automatically
+        return carry, (((lse - tgt) * wc).sum(), wc.sum())
+
+    _, (tots, cnts) = jax.lax.scan(body, (), (xs, ys, ws))
+    return tots.sum() / cnts.sum()
+
+
+def _whole_logits_loss(hidden, kernel, labels):
+    logits = hidden @ kernel
+    return optax.softmax_cross_entropy_with_integer_labels(
+        logits[:, :-1], labels[:, 1:]).mean()
+
+
+@pytest.mark.parametrize("tied", [False, True], ids=["untied", "tied"])
+@pytest.mark.parametrize("B", [1, 2])
+@pytest.mark.parametrize("onehot", [False, True], ids=["gather", "onehot"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_chunked_loss_takes_its_gradient_in_the_forward_loop(dtype, onehot, B, tied):
+    """Loss and gradients of the custom rule against the parent's checkpointed
+    loop and against the float32 loss over whole logits, under a cotangent of 3 with
+    `hidden` used a second time; the head's tensor its own or the transpose
+    of the embedding the lookup reads.  Without a gradient the value is the
+    parent's to the bit."""
+    from bluefog_tpu.models.transformer import chunked_softmax_cross_entropy
+
+    T, d, V, n = 64, 32, 101, 4
+    rng = np.random.default_rng(B + 2 * onehot)
+    ids = jnp.asarray(rng.integers(0, V, size=(B, T)), jnp.int32)
+    labels = jnp.asarray(rng.integers(0, V, size=(B, T)), jnp.int32)
+    params = {"embed": jnp.asarray(rng.normal(size=(V, d)) * 0.3, jnp.float32),
+              "mix": jnp.asarray(rng.normal(size=(d, d)) * 0.3, jnp.float32)}
+    if not tied:
+        params["head"] = jnp.asarray(rng.normal(size=(d, V)) * 0.3, jnp.float32)
+
+    def objective(head_loss):
+        def f(p):
+            hidden = jnp.tanh(p["embed"][ids] @ p["mix"])
+            kernel = p["embed"].T if tied else p["head"]
+            return 3.0 * head_loss(hidden, kernel) + 0.01 * (hidden ** 2).sum()
+        return jax.jit(jax.value_and_grad(f))(params)
+
+    kw = dict(dtype=dtype, onehot_targets=onehot)
+    got_loss, got = objective(
+        lambda h, k: chunked_softmax_cross_entropy(h, k, labels, n, **kw))
+    # bf16 operands: a rounded cotangent; against float32 logits, rounded ones
+    tol, whole_tol = (1e-5, 1e-5) if dtype == jnp.float32 else (2e-2, 5e-3)
+    for reference, loss_tol in (
+            (lambda h, k: _parents_chunked_loss(h, k, labels, n, **kw), 1e-5),
+            (lambda h, k: _whole_logits_loss(h, k, labels), whole_tol)):
+        want_loss, want = objective(reference)
+        np.testing.assert_allclose(got_loss, want_loss, rtol=loss_tol)
+        assert set(got) == set(want)
+        for name in want:
+            assert got[name].dtype == want[name].dtype
+            np.testing.assert_allclose(
+                got[name], want[name], rtol=0,
+                atol=tol * float(jnp.abs(want[name]).max()), err_msg=name)
+
+    hidden = jnp.tanh(params["embed"][ids] @ params["mix"])
+    kernel = params["embed"].T if tied else params["head"]
+    plain = [jax.jit(lambda h, k, f=f: f(h, k, labels, n, **kw))(hidden, kernel)
+             for f in (chunked_softmax_cross_entropy, _parents_chunked_loss)]
+    assert plain[0] == plain[1]
+
+
+@pytest.mark.parametrize("shared", ["kernel", "hidden"])
+def test_chunked_loss_under_shard_map_with_one_operand_shared(devices, shared):
+    """Data-parallel `shard_map`: the head's tensor the same on every rank
+    and the batch not (or the other way round).  The kept `dx` and `dW` vary
+    over the rank axis, and the shared operand's gradient comes back summed
+    over it, as the parent's loop gave it."""
+    from jax.sharding import Mesh, PartitionSpec as P
+    from bluefog_tpu.models.transformer import chunked_softmax_cross_entropy
+
+    R, T, d, V, n = 4, 16, 8, 11, 2
+    rng = np.random.default_rng(5)
+    mesh = Mesh(np.array(devices[:R]), ("r",))
+    per_rank = lambda *shape: jnp.asarray(rng.normal(size=(R,) + shape), jnp.float32)
+    one = lambda *shape: jnp.asarray(rng.normal(size=(1,) + shape), jnp.float32)
+    hidden = one(1, T, d) if shared == "hidden" else per_rank(1, T, d)
+    kernel = one(d, V) if shared == "kernel" else per_rank(d, V)
+    labels = jnp.asarray(rng.integers(0, V, size=(R, 1, T)), jnp.int32)
+    specs = tuple(P() if a.shape[0] == 1 else P("r") for a in (hidden, kernel))
+
+    def ranks(head_loss):
+        def local(h, k, y):
+            loss, grads = jax.value_and_grad(
+                lambda h, k: head_loss(h[0], k[0], y[0], n), (0, 1))(h, k)
+            return loss[None], grads
+        return jax.jit(jax.shard_map(
+            local, mesh=mesh, in_specs=specs + (P("r"),),
+            out_specs=(P("r"), specs)))(hidden, kernel, labels)
+
+    got, want = ranks(chunked_softmax_cross_entropy), ranks(_parents_chunked_loss)
+    for a, b in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
