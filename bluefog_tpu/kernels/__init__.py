@@ -10,6 +10,9 @@ them.
 shared key-value heads read in place and a value head of another size than the
 query-key head's.  ``kda``: the chunked delta rule with a decay a channel of a
 Kimi Delta Attention layer (:func:`bluefog_tpu.kernels.kda.kda_chunked`).
+``gdn``: the gated delta rule with one decay a head of any size and value
+heads on fewer key heads (:func:`bluefog_tpu.kernels.gdn.gdn_chunked`: a
+stateless stage of its own over ``kda``'s walk).
 ``ssd``: the chunked state-space scan
 of a Mamba-2 layer (:func:`bluefog_tpu.kernels.ssd.ssd_scan`).
 ``causal_conv``: that layer's depth-wise causal convolution with its bias and

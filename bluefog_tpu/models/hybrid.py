@@ -11,8 +11,12 @@ pairs, the same class is a DeepSeek-V3-style decoder (kakaocorp's kanana-2).
 :class:`ShortConvMoELM`: LiquidAI's LFM2 expert decoder (`model_type`
 lfm2_moe), whose mixer is a gated short convolution (:class:`ShortConvMixer`)
 in three layers of four and grouped-query attention with a norm a head on q
-and k in the fourth.  All are stacks of :class:`_HybridBlock`, which takes its
-mixer and its feed-forward part as it is handed them.
+and k in the fourth.  :class:`GatedDeltaMoELM`: Qwen3-Next's (`model_type`
+qwen3_next), whose mixer is the gated delta rule with one decay a head
+(:class:`GatedDeltaNetMixer`) in three layers of four and attention with an
+output gate a channel in the fourth, every norm zero-centred.  All are stacks
+of :class:`_HybridBlock`, which takes its mixer and its feed-forward part as
+it is handed them.
 
 Of :class:`HybridMambaLM`:
 
@@ -53,9 +57,9 @@ from bluefog_tpu.models.transformer import (
     rotary_frequencies,
 )
 
-__all__ = ["DeltaLatentMoELM", "HybridMambaLM", "KDAMixer", "LatentAttentionMixer",
-           "Mamba2Mixer", "ShortConvMixer", "ShortConvMoELM", "causal_conv", "conv_silu",
-           "gated_short_conv"]
+__all__ = ["DeltaLatentMoELM", "GatedDeltaMoELM", "GatedDeltaNetMixer", "HybridMambaLM",
+           "KDAMixer", "LatentAttentionMixer", "Mamba2Mixer", "ShortConvMixer",
+           "ShortConvMoELM", "causal_conv", "conv_silu", "gated_short_conv"]
 
 # What the backward pass of a recomputed block is handed beside the block's
 # input, each a `checkpoint_name` where the value is made: the flash forward's
@@ -82,6 +86,11 @@ REMAT_KEEPS = ("attn_out", "attn_lse", "mixer_out", "mlp_gate_up")
 # gates and gated norm again; the unit vectors of q and k are the kernels' own
 # (PR 47), so it makes neither them nor their float32 copies.
 DELTA_KEEPS = ("attn_out", "attn_lse", "kda_out")
+# What :class:`GatedDeltaMoELM` keeps: the attention layer's flash residuals,
+# the gated delta rule's output (its walk under `gdn_chunked`'s checkpoint a
+# group of heads does not run a second time before its backward runs it a
+# third) and every mixer's output, 34 MB a layer at 8,192 tokens x 2,048.
+GATED_DELTA_KEEPS = ("attn_out", "attn_lse", "gdn_out", "mixer_out")
 
 
 def causal_conv(x, kernel, bias):
@@ -194,8 +203,13 @@ class _AttentionMixer(nn.Module):
     As Granite calls it, **no position signal** and no norm.  With
     ``qk_norm_eps`` every head of ``q`` and of ``k`` goes through an RMS norm
     over its channels first, one learned scale a channel that the heads share
-    (``q_norm``, ``k_norm``; float32); with ``rotary`` both are then turned,
-    half-split (LFM2's attention layer has both)."""
+    (``q_norm``, ``k_norm``; float32; ``zero_centered``: ``1 + w``, ``w`` from
+    zeros); with ``rotary`` both are then turned, half-split (LFM2's attention
+    layer has both), over the ``2 len(rotary.inv_freq)`` first channels of a
+    head (Qwen3-Next's quarter).  With ``gate`` (Qwen3-Next's) ``q``'s product
+    is twice as wide, ``[q, gate]`` a head, and the kernels' output is times
+    ``sigmoid(gate)`` a channel before ``o``; without it no wider leaf and no
+    ``attention_gate`` scope."""
 
     num_heads: int
     num_kv_heads: int
@@ -205,6 +219,8 @@ class _AttentionMixer(nn.Module):
     attention_fn: Callable  # (q, k, v) -> out, causal
     rotary: Optional[Rotary] = None
     qk_norm_eps: Optional[float] = None
+    gate: bool = False
+    zero_centered: bool = False
 
     @nn.compact
     def __call__(self, u):
@@ -212,13 +228,18 @@ class _AttentionMixer(nn.Module):
         H, kvh, hd = self.num_heads, self.num_kv_heads, self.head_dim
         dense = partial(nn.DenseGeneral, use_bias=False, dtype=self.dtype)
         rest = self.scale * math.sqrt(hd)  # of the scale, beside the kernels' own
-        q = dense((H, hd), name="q")(u)
+        if self.gate:
+            q = dense((H, 2 * hd), name="q")(u)
+            q, gate = q[..., :hd], q[..., hd:]
+        else:
+            q = dense((H, hd), name="q")(u)
         if self.qk_norm_eps is None:
             q = q * rest
         k, v = dense((kvh, hd), name="k")(u), dense((kvh, hd), name="v")(u)
         if self.qk_norm_eps is not None:  # a norm would take a scale put on before it
             with jax.named_scope("attention_qk_norm"):
-                norm = partial(RMSNorm, dtype=self.dtype, eps=self.qk_norm_eps)
+                norm = partial(RMSNorm, dtype=self.dtype, eps=self.qk_norm_eps,
+                               zero_centered=self.zero_centered)
                 q, k = norm(name="q_norm")(q) * rest, norm(name="k_norm")(k)
         if self.rotary is not None:
             positions = jnp.arange(T)
@@ -226,6 +247,9 @@ class _AttentionMixer(nn.Module):
             k = _rotary(k, positions, rotary=self.rotary)
         with jax.named_scope("attention_global"):
             att = self.attention_fn(q, k, v)
+        if self.gate:
+            with jax.named_scope("attention_gate"):
+                att = (att * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(self.dtype)
         return dense(d, name="o")(att.reshape(B, T, H * hd))
 
 
@@ -256,10 +280,12 @@ class _HybridBlock(nn.Module):
     residual_multiplier: float
     eps: float
     dtype: Any
+    zero_centered: bool = False  # the two norms' scales (:class:`RMSNorm`)
 
     @nn.compact
     def __call__(self, h):
-        norm = partial(RMSNorm, dtype=self.dtype, eps=self.eps)
+        norm = partial(RMSNorm, dtype=self.dtype, eps=self.eps,
+                       zero_centered=self.zero_centered)
         r = self.residual_multiplier
         mixed = checkpoint_name(self.mixer()(norm(name="mixer_norm")(h)), "mixer_out")
         h = h + (r * mixed).astype(h.dtype)
@@ -281,6 +307,25 @@ def _head(h, embed, head_of, labels, head_chunks):
     if labels is None:
         return _head_matmul(h, kernel, jnp.float32)
     return chunked_softmax_cross_entropy(h, kernel, labels, max(head_chunks, 1))
+
+
+def _decoder(model, input_ids, labels, blocks, keeps, zero_centered=False):
+    """What :class:`ShortConvMoELM` and :class:`GatedDeltaMoELM` share, in
+    ``model``'s ``__call__``: the embedding's rows, ``blocks`` (``(mixer,
+    ffn)`` a layer) as ``layer_<i>`` under ``keeps``, the final norm and the
+    head, tied or its own (``model.tie_embeddings``)."""
+    embed = nn.Embed(model.vocab_size, model.hidden_size, dtype=model.dtype,
+                     embedding_init=nn.initializers.normal(0.02), name="embed")
+    h = jnp.take(embed.embedding, input_ids, axis=0).astype(model.dtype)
+    block_cls = _remat(keeps) if model.remat else _HybridBlock
+    for i, (mixer, ffn) in enumerate(blocks):
+        h = block_cls(mixer, ffn, 1.0, model.eps, model.dtype, zero_centered,
+                      name=f"layer_{i}")(h)
+    h = RMSNorm(dtype=jnp.float32, eps=model.eps, zero_centered=zero_centered,
+                name="final_norm")(h)
+    untied = lambda: _HeadKernel(model.vocab_size, name="head")(model.hidden_size)
+    return _head(h, embed, None if model.tie_embeddings else untied, labels,
+                 model.head_chunks)
 
 
 class HybridMambaLM(nn.Module):
@@ -514,6 +559,77 @@ class KDAMixer(nn.Module):
             o = (o.reshape(B, T, inner) * gate).astype(self.dtype)
         with jax.named_scope("kda_out_proj"):
             return dense(d, name="kda_o")(o)
+
+
+class GatedDeltaNetMixer(nn.Module):
+    """Qwen3-Next's linear layer, the gated delta rule (Gated DeltaNet,
+    arXiv:2412.06464).  ``[q, k, v, z] = u W_qkvz``, ``key_heads`` heads of
+    ``key_dim`` for q and for k, ``num_heads`` of ``value_dim`` for v and for
+    z, in that order on the lanes (the checkpoint groups the columns by key
+    head: a permutation of them and of the convolution's taps); ``[b, a] = u
+    W_ba``, a number a value head each; ``[q, k, v]`` through a causal
+    depth-wise convolution and SiLU, no bias, read where the product left them
+    (z is not convolved; the kernels of :mod:`bluefog_tpu.kernels.causal_conv`
+    where their ``tiles`` takes the shapes, :func:`causal_conv` elsewhere); a head at a time ``q / |q| / sqrt(K)`` and ``k / |k|``,
+    taken by the delta rule's kernels on the blocks they read; the step
+    ``sigmoid(b)`` and the log-decay ``-exp(A_log) softplus(a + dt_bias)``, **one
+    number a value head and token, of any size**, in float32; the recurrence of
+    :func:`bluefog_tpu.kernels.gdn.gdn_chunked`, key head ``j`` serving value
+    heads ``share j ..``; each head's output through an RMS norm with one
+    learned weight a channel that the heads share (a plain scale from ones),
+    times ``silu(z)``; ``W_o``.  No position signal: the decay carries it."""
+
+    num_heads: int      # value heads
+    key_heads: int
+    key_dim: int
+    value_dim: int
+    conv_width: int = 4
+    chunk: int = 64
+    eps: float = 1e-6
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, u):
+        from bluefog_tpu.kernels.causal_conv import causal_conv_silu, tiles
+        from bluefog_tpu.kernels.gdn import gdn_chunked
+        from bluefog_tpu.parallel._util import vma_full
+
+        B, T, d = u.shape
+        H, hk, kd, vd = self.num_heads, self.key_heads, self.key_dim, self.value_dim
+        keys, values = hk * kd, H * vd
+        conv = 2 * keys + values
+        init = nn.initializers.normal(0.02)
+        dense = partial(nn.Dense, use_bias=False, dtype=self.dtype, kernel_init=init)
+        with jax.named_scope("gdn_in_proj"):
+            qkvz = dense(conv + values, name="gdn_qkvz")(u)
+            ba = dense(2 * H, name="gdn_ba")(u)
+        with jax.named_scope("gdn_conv"):
+            taps = self.param("conv_kernel", init, (self.conv_width, conv), jnp.float32)
+            if tiles(T, conv, self.conv_width):
+                # no bias: zeros that vary over the mesh as the taps do
+                qkv = causal_conv_silu(qkvz, taps, vma_full(taps, (conv,), jnp.float32))
+            else:
+                qkv = jax.nn.silu(causal_conv(qkvz[..., :conv], taps, 0.0)).astype(self.dtype)
+            q = qkv[..., :keys].reshape(B, T, hk, kd)
+            k = qkv[..., keys:2 * keys].reshape(B, T, hk, kd)
+            v = qkv[..., 2 * keys:].reshape(B, T, H, vd)
+        with jax.named_scope("gdn_gates"):
+            # the checkpoint's rule: A uniform in (0, 16), dt_bias ones
+            a_log = self.param(
+                "A_log", lambda key, shape: jnp.log(jax.random.uniform(
+                    key, shape, jnp.float32, 1e-3, 16.0)), (H,))
+            dt_bias = self.param("dt_bias", nn.initializers.ones_init(), (H,), jnp.float32)
+            beta = jax.nn.sigmoid(ba[..., :H].astype(jnp.float32))
+            g = -jnp.exp(a_log) * jax.nn.softplus(ba[..., H:].astype(jnp.float32) + dt_bias)
+        with jax.named_scope("gdn_chunk"):
+            # q and k as convolved: the stage's kernels take the unit vectors
+            o = checkpoint_name(gdn_chunked(q, k, v, g, beta, chunk=self.chunk), "gdn_out")
+        with jax.named_scope("gdn_gate_norm"):
+            z = qkvz[..., conv:].astype(jnp.float32)
+            o = RMSNorm(dtype=jnp.float32, eps=self.eps, name="gdn_norm")(o)
+            o = (o.reshape(B, T, values) * jax.nn.silu(z)).astype(self.dtype)
+        with jax.named_scope("gdn_out_proj"):
+            return dense(d, name="gdn_o")(o)
 
 
 class LatentAttentionMixer(nn.Module):
@@ -801,14 +917,116 @@ class ShortConvMoELM(nn.Module):
                 self.shared_dff, self.routed_scale, self.dtype, score="sigmoid",
                 bias=True, eps=self.route_eps),
         }
-        embed = nn.Embed(self.vocab_size, self.hidden_size, dtype=self.dtype,
-                         embedding_init=nn.initializers.normal(0.02), name="embed")
-        h = jnp.take(embed.embedding, input_ids, axis=0).astype(self.dtype)
-        block_cls = _remat(keeps) if self.remat else _HybridBlock
-        for i, kind in enumerate(kinds):
-            h = block_cls(mixers[kind], ffns[is_dense[i]], 1.0, self.eps, self.dtype,
-                          name=f"layer_{i}")(h)
-        h = RMSNorm(dtype=jnp.float32, eps=self.eps, name="final_norm")(h)
-        untied = lambda: _HeadKernel(self.vocab_size, name="head")(self.hidden_size)
-        return _head(h, embed, None if self.tie_embeddings else untied, labels,
-                     self.head_chunks)
+        return _decoder(self, input_ids, labels,
+                        [(mixers[kind], ffns[dense]) for kind, dense in zip(kinds, is_dense)],
+                        keeps)
+
+
+class GatedDeltaMoELM(nn.Module):
+    """Qwen3-Next's decoder: ``layer_kinds`` names each layer's mixer,
+    ``"gdn"`` (:class:`GatedDeltaNetMixer`: ``gdn_heads`` value heads of
+    ``gdn_value_dim`` on ``gdn_key_heads`` key heads of ``gdn_key_dim``) or
+    ``"attention"`` (:class:`_AttentionMixer` with an output gate a channel, a
+    zero-centred norm a head on q and k and a half-split rotary over the first
+    ``rotary_dims`` channels of a head at ``rope_theta``, scores over
+    ``sqrt(head_dim)``); every layer's feed-forward part is this share of the
+    expert layer: a softmax over ``num_experts``, the ``top_k`` largest, their
+    weights over their sum, the ``experts_held`` computed dropless beside a
+    shared expert of ``shared_dff`` times its own sigmoid gate a token.  Every
+    block norm and the final norm are zero-centred (``1 + w``).  Pre-norm
+    residual blocks, embedding and head untied, every block recomputed in the
+    backward pass but for :data:`GATED_DELTA_KEEPS`.  With ``labels`` the
+    chunked next-token loss."""
+
+    vocab_size: int
+    hidden_size: int
+    layer_kinds: Tuple[str, ...]
+    num_heads: int
+    num_kv_heads: int
+    head_dim: int
+    rotary_dims: int
+    rope_theta: float
+    gdn_heads: int
+    gdn_key_heads: int
+    gdn_key_dim: int
+    gdn_value_dim: int
+    num_experts: int
+    top_k: int
+    experts_held: Tuple[int, ...]
+    expert_dff: int
+    shared_dff: int
+    conv_width: int = 4
+    chunk: int = 64
+    eps: float = 1e-6
+    tie_embeddings: bool = False
+    remat: bool = True
+    head_chunks: int = 1
+    dtype: Any = jnp.bfloat16
+    attention_fn: Optional[Callable] = None  # None: the flash kernels
+
+    @nn.compact
+    def __call__(self, input_ids, labels=None):
+        from bluefog_tpu.kernels.causal_conv import tiles
+        from bluefog_tpu.kernels.flash_attention import flash_attention
+        from bluefog_tpu.kernels.gdn import kernels_take
+        from bluefog_tpu.telemetry import registry as _telemetry
+
+        kinds = tuple(self.layer_kinds)
+        if set(kinds) - {"gdn", "attention"}:
+            raise ValueError(f"layer kinds {sorted(set(kinds))}: 'gdn' or 'attention'")
+        if self.num_heads % self.num_kv_heads or self.gdn_heads % self.gdn_key_heads:
+            raise ValueError(
+                f"heads {self.num_heads} on {self.num_kv_heads} key-value heads, "
+                f"{self.gdn_heads} value heads on {self.gdn_key_heads} key heads")
+        n_gdn, n_att = kinds.count("gdn"), kinds.count("attention")
+        keeps = GATED_DELTA_KEEPS if self.remat else ()
+        reg = _telemetry.get_registry()
+        if reg.enabled:
+            tokens, width = input_ids.size, jnp.dtype(self.dtype).itemsize
+            kept = {
+                "attn_out": n_att * tokens * self.num_heads * self.head_dim * width,
+                "attn_lse": n_att * tokens * self.num_heads * 4,
+                "gdn_out": n_gdn * tokens * self.gdn_heads * self.gdn_value_dim * width,
+                "mixer_out": len(kinds) * tokens * self.hidden_size * width,
+            }
+            for name, value in (
+                    ("gdn.layers", n_gdn), ("gdn.heads", self.gdn_heads),
+                    ("gdn.key_heads", self.gdn_key_heads), ("gdn.chunk", self.chunk),
+                    # whose chunks' stateless stage the kernels take
+                    ("gdn.kernel_layers", n_gdn * kernels_take(
+                        self.gdn_key_dim, self.gdn_value_dim, self.gdn_heads,
+                        self.gdn_heads // self.gdn_key_heads)),
+                    ("gdn.conv_kernel_layers", n_gdn * tiles(
+                        input_ids.shape[1], 2 * self.gdn_key_heads * self.gdn_key_dim
+                        + self.gdn_heads * self.gdn_value_dim, self.conv_width)),
+                    ("attention.gate", 1), ("attention.qk_norm", 1),
+                    ("attention.rotary_dims", self.rotary_dims),
+                    ("attention.layers_global", n_att),
+                    ("attention.heads_global", self.num_heads),
+                    ("attention.kv_heads", self.num_kv_heads),
+                    ("attention.scale", self.head_dim ** -0.5),
+                    ("moe.score", 0),  # 0: a softmax's scores (1: sigmoid)
+                    ("moe.groups", 1), ("moe.groups_kept", 1),
+                    ("moe.shared_width", self.shared_dff), ("moe.shared_gate", 1),
+                    ("moe.routed_scale", 1.0), ("moe.dense_layers", 0),
+                    ("lm.tied_head", int(self.tie_embeddings)),
+                    ("lm.remat_blocks", len(kinds) if self.remat else 0),
+                    ("lm.remat_kept_names", len(keeps)),
+                    ("lm.remat_kept_mb", sum(kept[k] for k in keeps) / 1e6)):
+                reg.gauge(name).set(value)
+        mixers = {
+            "gdn": partial(GatedDeltaNetMixer, self.gdn_heads, self.gdn_key_heads,
+                           self.gdn_key_dim, self.gdn_value_dim, self.conv_width,
+                           self.chunk, self.eps, self.dtype, name="mixer"),
+            "attention": partial(
+                _AttentionMixer, self.num_heads, self.num_kv_heads, self.head_dim,
+                self.head_dim ** -0.5, self.dtype,
+                self.attention_fn or partial(flash_attention, causal=True),
+                rotary_frequencies(self.rotary_dims, self.rope_theta), self.eps,
+                gate=True, zero_centered=True, name="mixer"),
+        }
+        ffn = expert_ffn(self.num_experts, self.top_k, tuple(self.experts_held),
+                         self.expert_dff, self.shared_dff, 1.0, self.dtype,
+                         shared_gate=True)
+        return _decoder(self, input_ids, labels, [(mixers[kind], ffn) for kind in kinds],
+                        keeps, zero_centered=True)

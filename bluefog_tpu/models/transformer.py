@@ -187,12 +187,20 @@ def _rotary(x, positions, base=10000.0, rotary: Optional[Rotary] = None,
 
 
 class RMSNorm(nn.Module):
+    """``x rsqrt(mean(x^2) + eps) scale``; with ``zero_centered`` the leaf is
+    the scale's distance from 1, ``x rsqrt(..) (1 + scale)`` from zeros
+    (Qwen3-Next's norms: weight decay then pulls the scale to 1, not to 0)."""
+
     dtype: Any = jnp.float32
     eps: float = 1e-6
+    zero_centered: bool = False
 
     @nn.compact
     def __call__(self, x):
-        scale = self.param("scale", nn.initializers.ones_init(), (x.shape[-1],))
+        if self.zero_centered:
+            scale = 1.0 + self.param("scale", nn.initializers.zeros_init(), (x.shape[-1],))
+        else:
+            scale = self.param("scale", nn.initializers.ones_init(), (x.shape[-1],))
         var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
         return (x.astype(jnp.float32) * jax.lax.rsqrt(var + self.eps) * scale).astype(
             self.dtype
@@ -794,11 +802,14 @@ def expert_feed_forward(block, m, num_experts, top_k, experts_held, expert_dff,
     ``shared_dff`` 0 the model has no shared expert: no ``shared`` leaf, no
     ``moe_shared`` scope.  ``routing``:
     :func:`bluefog_tpu.parallel.expert.route_topk`'s keywords; with ``bias``
-    true the choice's bias is the leaf ``router_bias``."""
+    true the choice's bias is the leaf ``router_bias``; with ``shared_gate``
+    true the shared expert's output is times ``sigmoid(m W_sg)`` a token, the
+    leaf ``shared_gate`` ``[d, 1]`` (Qwen3-Next's; without it no such leaf)."""
     from bluefog_tpu.parallel.expert import held_topk_experts, route_topk
 
     B, T, d = m.shape
     init = nn.initializers.normal(0.02)
+    shared_gate = routing.pop("shared_gate", False)
     router = block.param("router", init, (d, num_experts), jnp.float32)
     if routing.pop("bias", False):
         routing["bias"] = block.param("router_bias", nn.initializers.zeros_init(),
@@ -816,7 +827,13 @@ def expert_feed_forward(block, m, num_experts, top_k, experts_held, expert_dff,
     if not shared_dff:
         return y.reshape(B, T, d)
     with jax.named_scope("moe_shared"):
-        return y.reshape(B, T, d) + _GatedMLP(shared_dff, dtype, name="shared")(m)
+        routed = y.reshape(B, T, d)
+        shared = _GatedMLP(shared_dff, dtype, name="shared")(m)
+        if shared_gate:
+            w_sg = block.param("shared_gate", init, (d, 1), jnp.float32)
+            gate = jnp.dot(m, w_sg.astype(dtype), preferred_element_type=jnp.float32)
+            shared = (shared * jax.nn.sigmoid(gate)).astype(shared.dtype)
+        return routed + shared
 
 
 class _GatedBlock(nn.Module):

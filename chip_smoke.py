@@ -24,6 +24,10 @@ phase that fails raises: the traceback goes to stderr, the last line says
                                      chunked scan's kernels vs the recurrence
                                      taken token by token and the flash
                                      kernels at heads of 64 vs dense attention,
+                                     the gated delta rule's kernels vs the
+                                     recurrence at a decay near 0 and at -30 a
+                                     token and the flash kernels at heads of
+                                     256 vs dense attention,
                                      the causal convolution's kernels and the
                                      gated short convolution's vs the
                                      references' expressions in float32, a
@@ -113,6 +117,13 @@ FULL = dict(
     # call of the `ling-3.0-flash-vl` cell: 1 x 8192 x 32 heads of 128, chunks
     # of 64; 128 + 64 rotary query-key channels beside values of 128
     kda=dict(seq=8192, heads=32, head_dim=128, chunk=64, lower=-5.0, calls=10),
+    # one linear layer's gated delta rule of the `qwen3-next-80b-a3b` cell: 1 x
+    # 8192, 32 value heads on 16 key heads of 128, chunks of 64, the log-decay
+    # near 0 and down to -30 a token; and its attention layer's kernel call: 16
+    # query heads on 2 key-value heads of 256
+    gdn=dict(seq=8192, heads=32, key_heads=16, head_dim=128, chunk=64,
+             decays=(-0.1, -30.0), calls=10, att_heads=16, att_kv_heads=2,
+             att_head_dim=256, block=None),
     mla=dict(seq=8192, heads=32, nope=128, rope=64, v_dim=128, calls=10),
     # a layer's whole mixer of the `kanana-2-30b-a3b` cell: hidden 2048, a
     # latent of 512, no head gate, the rotary over interleaved pairs
@@ -136,6 +147,9 @@ TINY = dict(
     conv=dict(seq=64, inner=128, states=64, heads=8, width=4, calls=2),
     short_conv=dict(seq=64, channels=128, width=3, calls=2),
     kda=dict(seq=128, heads=4, head_dim=16, chunk=32, lower=-5.0, calls=2),
+    gdn=dict(seq=64, heads=2, key_heads=1, head_dim=128, chunk=32,
+             decays=(-0.1, -30.0), calls=2, att_heads=4, att_kv_heads=2,
+             att_head_dim=32, block=16),
     mla=dict(seq=128, heads=4, nope=16, rope=8, v_dim=16, calls=2),
     mla_mixer=dict(seq=128, hidden=64, heads=4, rank=32, nope=16, rope=8, v_dim=16,
                    theta=1e6, calls=2),
@@ -208,9 +222,16 @@ CONV_L2_RTOL = 2e-2
 # applied twice or a sub-block's reference misplaced moves them by 0.1 or more.
 KDA_L2_RTOL = 2e-2
 
+# the gated delta rule (`kernels/gdn.py`: the same operands and the same
+# roundings as the delta rule above, one decay a head) against the recurrence
+# the same way, with one difference in what is compared: at -30 a token the
+# gradient of `g` is 1e-4 of the other gradients (what a token before decays to
+# is e^-30 of it) and is held against the largest of them, not against itself.
+GDN_L2_RTOL = 2e-2
+
 PHASES = ("ops_windows", "resnet_atc", "resnet_allreduce", "contraction",
           "buckets_vs_per_leaf", "decoder", "experts_piled", "kda_vs_recurrence",
-          "mla_two_head_sizes", "mla_mixer_no_gate", "shared_heads", "subtiles", "ssd",
+          "gdn_vs_recurrence", "mla_two_head_sizes", "mla_mixer_no_gate", "shared_heads", "subtiles", "ssd",
           "conv", "short_conv")
 
 
@@ -1246,6 +1267,105 @@ def phase_kda(cfg, seed, on_tpu, clock):
         assert gap <= KDA_L2_RTOL, f"{n}: {gap} from the recurrence in relative L2"
 
 
+def phase_gdn(cfg, seed, on_tpu, clock):
+    """`gdn_chunked` (the chunk's stateless stage through `gdn_intra_fwd` /
+    `gdn_intra_bwd`, one decay a head and the shared key heads read in place,
+    the walk through Ling's `kda_chunk_fwd` / `kda_chunk_bwd`) at the sizes of a
+    linear layer of the benchmark's `qwen3-next-80b-a3b` cell, handed q and k
+    raw in bfloat16 as the convolution leaves them, against the recurrence of
+    chipbench's plain reference taken token by token, eight value heads at a
+    time, on the reference's own unit vectors of the same q and k repeated to
+    the value heads: the output and all five gradients in relative L2 at each of
+    ``decays`` (the log-decay drawn between a fifth of it and it: near 0, and
+    down to -30 a token, which the channel-decay kernels cannot take), and the
+    host clock over ``calls`` calls.  Then the whole-sequence flash kernels at
+    the cell's attention layer (heads of ``att_head_dim``, ``att_heads`` on
+    ``att_kv_heads``) against dense softmax in float32, forward and backward."""
+    from bluefog_tpu.kernels.flash_attention import flash_attention
+    from bluefog_tpu.kernels.gdn import gdn_chunked
+    from chipbench import manifest
+
+    reference = manifest.load_module(os.path.join(
+        manifest.REPO, "chipbench", "reference", "qwen3-next-80b-a3b.py"))
+    t0 = time.perf_counter()
+    T, H, Hk, K = cfg["seq"], cfg["heads"], cfg["key_heads"], cfg["head_dim"]
+    keys = jax.random.split(jax.random.PRNGKey(seed), 7)
+    q, k = (jax.random.normal(r, (1, T, Hk, K), jnp.bfloat16) for r in keys[:2])
+    v, go = (jax.random.normal(r, (1, T, H, K), jnp.bfloat16) for r in keys[2:4])
+    spread = jax.random.uniform(keys[4], (1, T, H), minval=0.2, maxval=1.0)
+    beta = jax.nn.sigmoid(jax.random.normal(keys[5], (1, T, H)))
+
+    def values(fn, args, weight, names=("q", "k", "v", "g", "beta")):
+        def loss(*a):
+            o = fn(*a)
+            return jnp.sum(o.astype(jnp.float32) * weight.astype(jnp.float32)), o
+        (_, o), grads = jax.jit(jax.value_and_grad(
+            loss, tuple(range(len(args))), has_aux=True))(*args)
+        return dict(zip(("o",) + tuple("d" + n for n in names), (o,) + grads))
+
+    def recurrence(q, k, v, g, beta):
+        f32 = lambda a: a[0].astype(jnp.float32)
+        unit = lambda a, scale: jnp.repeat(reference.unit(f32(a)) * scale, H // Hk, axis=1)
+        groups = max(1, H // 8)
+        split = lambda a: jnp.moveaxis(
+            a.reshape((T, groups, H // groups) + a.shape[2:]), 1, 0)
+        o = jax.lax.map(jax.checkpoint(lambda a: reference.gdn_scan(*a)), tuple(map(
+            split, (unit(q, K ** -0.5), unit(k, 1.0), f32(v), f32(g), f32(beta)))))
+        return jnp.moveaxis(o, 0, 1).reshape(1, T, H, K)
+
+    chunked = lambda *a: gdn_chunked(*a, chunk=cfg["chunk"], interpret=not on_tpu)
+    rel, ms = {}, {}
+    for decay in cfg["decays"]:
+        args = (q, k, v, decay * spread, beta)
+        got, want = values(chunked, args, go), values(recurrence, args, go)
+        floor = max(float(jnp.linalg.norm(want[n].astype(jnp.float32)))
+                    for n in want if n != "o")
+        rel[str(decay)] = {
+            n: float(jnp.linalg.norm((got[n] - want[n]).astype(jnp.float32))
+                     / max(float(jnp.linalg.norm(want[n].astype(jnp.float32))),
+                           1e-2 * floor)) for n in got}
+        both = jax.jit(jax.grad(lambda *a: jnp.sum(
+            chunked(*a).astype(jnp.float32) * go.astype(jnp.float32)), tuple(range(5))))
+        ms[str(decay)] = {kind: _ms_a_call(fn, args, cfg["calls"])
+                          for kind, fn in (("fwd", jax.jit(chunked)), ("fwd_bwd", both))}
+    # the attention layer's kernels at its head size
+    Ha, Hkv, D, block = (cfg["att_heads"], cfg["att_kv_heads"], cfg["att_head_dim"],
+                         cfg["block"])
+    aq, ago = (jax.random.normal(r, (1, T, Ha, D), jnp.bfloat16) for r in keys[5:7])
+    ak, av = (jax.random.normal(r, (1, T, Hkv, D), jnp.bfloat16) for r in keys[:2])
+
+    def dense(q, k, v):  # a query head at a time against its group's key-value head
+        heads_first = lambda a: jnp.moveaxis(a[0].astype(jnp.float32), 1, 0)
+        group = jnp.arange(Ha) // (Ha // Hkv)
+        one = jax.checkpoint(lambda a: reference.causal_softmax_head(*a, False))
+        o = jax.lax.map(one, (heads_first(q), heads_first(k)[group], heads_first(v)[group]))
+        return jnp.moveaxis(o, 0, 1)[None]
+
+    fast = lambda *a: flash_attention(*a, causal=True, block_q=block, block_k=block,
+                                      interpret=not on_tpu)
+    att_rel = _rel_l2(values(fast, (aq, ak, av), ago), values(dense, (aq, ak, av), ago))
+    att_both = jax.jit(jax.grad(lambda *a: jnp.sum(
+        fast(*a).astype(jnp.float32) * ago.astype(jnp.float32)), (0, 1, 2)))
+    att_ms = {kind: _ms_a_call(fn, (aq, ak, av), cfg["calls"])
+              for kind, fn in (("fwd", jax.jit(fast)), ("fwd_bwd", att_both))}
+    _emit("gdn_vs_recurrence", t0, clock, seq=T, heads=H, key_heads=Hk, head_dim=K,
+          chunk=cfg["chunk"], interpret=not on_tpu,
+          compared="o and the five gradients of gdn_chunked (raw bfloat16 q, k on "
+                   "shared key heads; v) against the float32 recurrence taken token by "
+                   "token, at each log-decay: relative L2 (a gradient under a hundredth "
+                   "of the largest against that); then o, dq, dk, dv of the flash "
+                   "kernels at the attention layer's head size against dense float32 "
+                   "softmax; ms a call, host clock",
+          rel_l2=rel, ms_per_call=ms, rel_l2_tol=GDN_L2_RTOL,
+          attention=dict(heads=Ha, kv_heads=Hkv, head_dim=D, rel_l2=att_rel,
+                         ms_per_call=att_ms, rel_l2_tol=LOGITS_L2_RTOL))
+    for decay, gaps in rel.items():
+        for n, gap in gaps.items():
+            assert gap <= GDN_L2_RTOL, f"g to {decay}, {n}: {gap} from the recurrence"
+    for n, gap in att_rel.items():
+        assert gap <= LOGITS_L2_RTOL, f"{n}: {gap} from dense softmax in relative L2"
+
+
 def phase_mla(cfg, seed, on_tpu, clock):
     """The whole-sequence flash kernels handed a query-key head of ``nope +
     rope`` beside a value head of ``v_dim`` (a latent-attention layer of the
@@ -1419,6 +1539,8 @@ def run(args, device):
             phase_experts_piled(sizes["experts"], args.seed, clock)
         if want("kda_vs_recurrence"):
             phase_kda(sizes["kda"], args.seed, on_tpu, clock)
+        if want("gdn_vs_recurrence"):
+            phase_gdn(sizes["gdn"], args.seed, on_tpu, clock)
         if want("mla_two_head_sizes"):
             phase_mla(sizes["mla"], args.seed, on_tpu, clock)
         if want("mla_mixer_no_gate"):

@@ -81,6 +81,7 @@ LONGEST = (
     "test_striped_ring.py::test_striped_ring_gradients",
     "test_tpu_compile.py::test_a_decoder_cells_step_fits_a_v5e[program",
     "test_tpu_compile.py::test_a_decoder_cells_step_fits_a_v5e[lfm2-program",
+    "test_tpu_compile.py::test_a_decoder_cells_step_fits_a_v5e[qwen3-next-program",
     "test_training.py::test_final_quality_parity_head_to_head",
     "test_tpu_compile.py::test_recomputed_granite_blocks_keep_what_their_policy_names",
     "test_training.py::test_train_step_with_batch_stats_resnet",

@@ -1083,7 +1083,162 @@ LFM2 = Decoder(
     cli_seed=2**31 + 5,
 )
 
-TABLE = (SMALLTHINKER, LAGUNA, GRANITE, LING, KANANA, LFM2)
+# ---- qwen3-next-80b-a3b ------------------------------------------------------------------------
+
+
+def _qwen_gauges(tokens, conv_kernel_layers):
+    """Published layers 2 and 3 at hidden 128: a gated-delta-rule layer of 2
+    value heads on 1 key head of 128 (a whole lane block a head: the stage's
+    kernels) and a gated attention layer.  12 tokens for 32 are no whole 8-row
+    tiles for the convolution's kernels."""
+    return dict(sizes={}, fields={}, tokens=tokens, wanted={
+        "gdn.layers": 1, "gdn.heads": 2, "gdn.key_heads": 1, "gdn.chunk": 32,
+        "gdn.kernel_layers": 1, "gdn.conv_kernel_layers": conv_kernel_layers,
+        "attention.gate": 1, "attention.qk_norm": 1, "attention.rotary_dims": 8,
+        "attention.layers_global": 1, "attention.heads_global": 4,
+        "attention.kv_heads": 2, "attention.scale": 32 ** -0.5, "moe.score": 0,
+        "moe.groups": 1, "moe.groups_kept": 1, "moe.shared_width": 32,
+        "moe.shared_gate": 1, "moe.routed_scale": 1, "moe.dense_layers": 0,
+        "moe.experts_held": 4, "moe.experts_total": 16, "moe.top_k": 4,
+        "lm.tied_head": 0, "lm.remat_blocks": 2, "lm.remat_kept_names": 4,
+        # bfloat16 of the tokens: the attention layer's [4, T, 32] and float32
+        # [4, T], the delta rule's [T, 2 x 128], two layers' [T, 128]
+        "lm.remat_kept_mb": tokens * (256 + 16 + 512 + 512) / 1e6})
+
+
+def _qwen_flops(flops, sizes):
+    d, s = 2048, 8192
+    vis, pairs = 64 * 65 // 2, s * (s + 1) // 2
+    intra_fwd = 2 * (2 * vis - 64) * 128 + 4 * vis * 256      # two key heads, four value heads
+    chunk_fwd = 4 * (3 * 64 * 128 * 128 + vis * 128)
+    delta = 128 * 8 * (intra_fwd + chunk_fwd)
+    gdn = s * (d * 12288 + d * 64 + 4096 * d) + delta
+    attention = s * (3 * d * 4096 + 2 * d * 512) + pairs * 16 * 512
+    experts = s * (d * 512 + 10 * 32 / 512 * 3 * d * 512 + 3 * d * 512 + d)
+    want = s * d * 18992 + 3 * gdn + attention + 4 * experts
+    yield "the layers held", flops.kinds(sizes), ["gdn", "gdn", "gdn", "attention"]
+    yield "forward", flops.forward_macs(sizes), pytest.approx(want, rel=1e-12)
+    yield "a step", flops.train_flops_per_sample(sizes), pytest.approx(6 * want, rel=1e-12)
+    yield "a step, about", 6 * want, pytest.approx(11.39e12, rel=2e-3)
+    # the issue's split of a token's forward pass, in M multiply-accumulates (its
+    # delta rule counted the chunk's whole square: 2.9 where the visible pairs are 2.1)
+    yield "a linear mixer", gdn / s, pytest.approx(35.8e6, rel=5e-3)
+    yield "the attention mixer", attention / s, pytest.approx(60.8e6, rel=5e-3)
+    yield "a feed-forward part", experts / s, pytest.approx(6.2e6, rel=1e-2)
+    yield "the mixers' share", 0.71 < (3 * gdn + attention) / want < 0.74, True
+    yield "the stage's forward kernel", flops.kernel_macs(sizes, "intra_fwd"), intra_fwd
+    yield "the walk's forward kernel", flops.kernel_macs(sizes, "chunk_fwd"), chunk_fwd
+    yield "the walk's backward kernel", flops.kernel_macs(sizes, "chunk_bwd"), \
+        4 * (7 * 64 * 128 * 128 + 2 * vis * 128)
+    ling = manifest.resolve(LING.cell_name)     # the walk's kernels are Ling's, to the byte
+    for kernel in ("fwd", "bwd"):
+        yield f"the walk's {kernel} call is Ling's", flops.kernel_call(sizes, "chunk_" + kernel), \
+            ling.module("flops").kernel_call(ling.sizes(), kernel)
+    # a call of the stage forward: q, k of two key heads, v, g, beta in; the six out
+    read = 2 * 2 * 64 * 128 * 2 + 4 * 64 * 128 * 2 + 2 * 4 * 64 * 4
+    walked = 4 * (3 * 64 * 128 * 2 + 64 * 128 * 4 + 64 * 64 * 4 + 128 * 4)
+    yield "the stage's forward call", flops.kernel_call(sizes, "intra_fwd"), \
+        (2 * intra_fwd * 128, 128 * (read + walked))
+    yield "the stage's backward bytes", flops.kernel_call(sizes, "intra_bwd")[1], \
+        128 * (2 * read + walked)
+    for kernel in ("intra_fwd", "intra_bwd", "chunk_fwd", "chunk_bwd"):
+        work, nbytes = flops.kernel_call(sizes, kernel)
+        yield f"{kernel}: the bytes lead", nbytes / 819e9 > 4 * work / 197e12, True
+
+
+def _qwen_readers(cell):
+    flops, sizes = cell.module("flops"), cell.sizes()
+    op = lambda name, path, within=None: types.SimpleNamespace(
+        name=name, path=path, within=within, recomputed=False)
+    ops = {"%gdn_intra_fwd.3 = (bf16[...]": 4.0, "%gdn_intra_fwd.4": 6.0,
+           "%gdn_intra_bwd.1": 9.0, "%kda_chunk_fwd.7": 3.0, "%kda_chunk_bwd.2": 8.0,
+           "%attention_global.2": 7.0, "%fusion.9": 100.0, "%gdn_intra_fwd_other": 50.0,
+           "%fusion.1": 2.0, "%fusion.2": 3.0, "%fusion.3": 5.0}
+    root = "jit(local_step)/forward_backward/layer_2/mixer/"
+    loop = root + "gdn_chunk/while/body"
+    record = [(op("gdn_intra_fwd.3", loop + "/gdn_intra", "while.1"), "x", 4.0),
+              (op("gdn_intra_fwd.4", loop + "/gdn_intra", "while.2"), "x", 6.0),
+              (op("gdn_intra_bwd.1", loop + "/gdn_intra", "while.3"), "x", 9.0),
+              (op("kda_chunk_fwd.7", loop, "while.1"), "x", 3.0),
+              (op("kda_chunk_bwd.2", loop, "while.3"), "x", 8.0),
+              (op("fusion.1", loop + "/gdn_intra/transpose", "while.3"), "x", 2.0),
+              (op("fusion.2", root + "gdn_in_proj/gdn_qkvz/dot_general"), "x", 3.0),
+              (op("fusion.3", root + "attention_gate/mul"), "attention_proj", 5.0),
+              (op("fusion.9", root + "o/dot_general"), "attention_proj", 100.0)]
+    memo = lambda ops_: {step_scopes.MEMO: {
+        "ops": ops_, "groups": {}, "recomputed": 0.0, "found": 0.0}}
+    run = _traced(ops, flops, sizes, **memo(record))
+    yield "gdn_kernels_ms_per_step", run, 30.0
+    yield "gdn_mixer_ms_per_step", run, 5.0
+    yield "attention_global_ms_per_step", run, 7.0
+    trips = 32 // flops.HEADS_A_CALL
+    for kernel, ms, sites in (("intra_fwd", 10.0, 2), ("intra_bwd", 9.0, 1),
+                              ("chunk_fwd", 3.0, 1), ("chunk_bwd", 8.0, 1)):
+        work, nbytes = flops.kernel_call(sizes, kernel)
+        ideal = sites * trips * max(work / 197e12, nbytes / 819e9)
+        yield f"gdn_{kernel}_roofline", run, pytest.approx(100 * ideal / (ms / 1e3))
+    # eight trips of 0.0826 ms, twice, in 10 ms
+    yield "gdn_intra_fwd_roofline", run, pytest.approx(13.2, abs=0.1)
+    # a program without such kernels or without a record of its step (the
+    # parent's), a run without a trace, a rehearsal, a run of another cell
+    bare = _traced({"%fusion": 1.0}, flops, sizes, **memo([]))
+    yield from _silent(tuple(f"gdn_{k}_roofline" for k in (
+        "intra_fwd", "intra_bwd", "chunk_fwd", "chunk_bwd")), bare,
+        dict(run, peaks=None), dict(run, flops_per_sample=1.0))
+    yield from _silent(("gdn_kernels_ms_per_step", "gdn_mixer_ms_per_step"), bare)
+
+
+QWEN3_NEXT = Decoder(
+    cell_name="qwen3-next-80b-a3b-atc-warmup-b1-s8k-1chip",
+    catalog="Qwen3-Next-80B-A3B-Instruct",
+    cut={"num_hidden_layers": 4, "num_experts": 32, "vocab_size": 18992},
+    reduced=["num_hidden_layers", "num_experts", "vocab_size"],
+    cut_also=("parameters",),
+    published_stated={"num_hidden_layers": 48, "num_experts": 512, "vocab_size": 151936},
+    sizes_say={"num_experts": 512, "num_experts_held": 32,
+               "published_layer_index": [0, 1, 2, 3], "gdn_chunk_size": 64},
+    marks={"deployment": ("one chip of 16", "one of 8", "stage of twelve"),
+           "expert_load": ("a sixteenth", "5,120"),
+           "assumed": ("(i + 1) % `full_attention_interval`", "`attention_bias`",
+                       "multi-token prediction", "auxiliary", "groups the columns by key head",
+                       "the norm before the gate", "uniform in (0, 16)", "`dt_bias` ones",
+                       "(1 + w)", "channel i with channel i + 32", "sqrt(2 x 48)",
+                       "AdamW 3e-4", "recomputed", "segment_ids")},
+    mix_as=("laguna-xs.2-atc-warmup-b1-s8k-1chip", ()),
+    per_layer={"train_step_host_ms_per_step", "attention_ms_per_step",
+               "attention_global_ms_per_step", "attention_proj_ms_per_step",
+               "expert_ms_per_step", "expert_dispatch_ms_per_step", "mlp_ms_per_step",
+               "head_loss_ms_per_step", "optimizer_ms_per_step", "recompute_ms_per_step",
+               # this configuration's own
+               "gdn_mixer_ms_per_step", "gdn_kernels_ms_per_step",
+               "gdn_intra_fwd_roofline", "gdn_intra_bwd_roofline",
+               "gdn_chunk_fwd_roofline", "gdn_chunk_bwd_roofline"},
+    parameters=(("a gated-delta-rule mixer", _mixer(0), 33_718_464),
+                ("the gated attention mixer", _mixer(3), 27_263_488),
+                ("a layer's feed-forward part", _layer(0, but=_FFN), 104_859_648),
+                ("a linear layer", _layer(1), 138_582_208),
+                ("the attention layer", _layer(3), 132_127_232),
+                ("the embedding, the head, the last norm", _ENDS, 77_793_280),
+                ("the cut", _ALL, 625_667_136)),
+    flops=_qwen_flops,
+    readers={"gated-delta-rule": _qwen_readers},
+    no_leaf_named=("router_bias", "conv_bias", "layer_0/mlp/"),
+    rules={"rope_theta": dict(rope_theta=1e4), "the_whole_head_turned": dict(rotary_dims=32),
+           "top_k": dict(top_k=2), "a_wider_shared_expert": dict(shared_dff=16),
+           "a_tied_head": dict(tie_embeddings=True),
+           "no_attention_layer": dict(layer_kinds=("gdn", "gdn"))},
+    remat_off=dict(remat=False),
+    adamw_seed=2**31 + 7,
+    gauges={"as-rehearsed": _qwen_gauges(32, 1),
+            "tokens-that-do-not-tile": _qwen_gauges(12, 0)},
+    gauges_absent=("ssm.", "kda.", "mla.", "short_conv."),
+    foreign_kinds=(("gdn", "kda"), "kda"),
+    control_seed=2**31 + 35,
+    unchanged_seed=2**31 + 99,
+    cli_seed=2**31 + 5,
+)
+
+TABLE = (SMALLTHINKER, LAGUNA, GRANITE, LING, KANANA, LFM2, QWEN3_NEXT)
 
 
 def each(field=None):

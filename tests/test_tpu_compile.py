@@ -368,6 +368,54 @@ def test_delta_rule_kernels_compile_for_v5e(one_chip, at_once, temp_budget,
         assert code <= stage_code_budget, code
 
 
+# The gated delta rule of the qwen3-next-80b-a3b cell: 32 value heads on 16 key
+# heads of 128, 8,192 tokens, four value heads a group.  Temporaries read at PR
+# 52: 260,917,760 (a group's six arrays and their cotangents, the states a
+# chunk, and the eight groups' stacked inputs and gradients) plus 5 %.
+def test_gated_delta_rule_kernels_compile_for_v5e(one_chip):
+    """The stage's two kernels beside Ling's walk: a `[chunk, chunk]` decay
+    matrix from masked sums down the sublanes, a block of two key heads read at
+    the index of four value heads, `[1, chunk]` rows of dg and dbeta, one
+    product each for the pairs at `Precision.HIGHEST`: what interpret mode
+    cannot refuse and Mosaic can."""
+    from bluefog_tpu.kernels import gdn
+
+    T, Hk, H, K = 8192, 16, 32, 128
+    spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    args = (spec((1, T, Hk, K), jnp.bfloat16),) * 2 + (
+        spec((1, T, H, K), jnp.bfloat16), spec((1, T, H), jnp.float32),
+        spec((1, T, H), jnp.float32))
+
+    def loss(*a):
+        return jnp.sum(gdn.gdn_chunked(*a, chunk=64, interpret=False).astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=tuple(range(5)))).lower(*args).compile()
+    text = compiled.as_text()
+    for name in ("gdn_intra_fwd", "gdn_intra_bwd", "kda_chunk_fwd", "kda_chunk_bwd"):
+        assert name in text  # the names the benchmark's readers and the trace look up
+    assert "kda_intra" not in text
+    assert [tuple(o.shape) for o in compiled.out_info] == [a.shape for a in args]
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= 274_000_000, temp
+
+
+def test_flash_kernels_take_heads_of_256_for_v5e(one_chip):
+    """The qwen3-next-80b-a3b cell's attention layer: 16 query heads on 2
+    key-value heads of 256 at 8,192 tokens, the default 1024 x 1024 tiles: a
+    256-deep q, k, v and a float32 accumulator of [1024, 256] in VMEM."""
+    T = 8192
+    spec = lambda h: jax.ShapeDtypeStruct((1, T, h, 256), jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=True, interpret=False)
+                       .astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        spec(16), spec(2), spec(2)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 3
+    assert [tuple(o.shape)[-2:] for o in compiled.out_info] == [(16, 256), (2, 256), (2, 256)]
+
+
 def test_flash_kernels_take_a_wider_query_key_head_for_v5e(one_chip):
     """The latent-attention layer's call of the ling-3.0-flash-vl cell: 32
     heads, queries and keys of 128 + 64 rotary, values of 128, 8,192 tokens.
@@ -407,8 +455,19 @@ def test_flash_kernels_take_a_wider_query_key_head_for_v5e(one_chip):
 # and its reference's, the same way.  Bytes read at PR 49: 14,215,708,672 (8.88
 # GB of weights and moments standing, the gradient's 2.96 among the
 # temporaries) and 13,287,420,416 (11.84 GB of results), plus 3 %.
-_KANANA, _LFM2 = ("kanana-2-30b-a3b-atc-warmup-b1-s8k-1chip",
-                  "lfm2-24b-a2b-atc-warmup-b1-s8k-1chip")
+#
+# The qwen3-next-80b-a3b cell's step (B1 S8192, published layers 0-3: three
+# gated-delta-rule layers, one gated attention layer of 16 heads on 2 of 256,
+# 625.7 M parameters) and its reference's, the same way.  Bytes read at PR 52:
+# 11,621,391,872 (7.51 GB of weights and moments standing, 4.11 GB of
+# temporaries, the gradient's 2.50 among them) and 15,307,039,232 (10.01
+# GB of results), plus 3 %.  `gdn_intra_bwd` is counted twice a layer: the call
+# and the fusion the compiler wraps it in to write dq into the groups' stack.
+_KANANA, _LFM2, _QWEN = ("kanana-2-30b-a3b-atc-warmup-b1-s8k-1chip",
+                         "lfm2-24b-a2b-atc-warmup-b1-s8k-1chip",
+                         "qwen3-next-80b-a3b-atc-warmup-b1-s8k-1chip")
+_QWEN_KERNELS = ("attention_global", "gdn_intra_fwd", "gdn_intra_bwd", "kda_chunk_fwd",
+                 "kda_chunk_bwd")
 
 
 @pytest.mark.parametrize("cell_name,which,kernel_calls,budget", [
@@ -422,6 +481,11 @@ _KANANA, _LFM2 = ("kanana-2-30b-a3b-atc-warmup-b1-s8k-1chip",
     pytest.param(_LFM2, "reference",
                  {"attention_global": 0, "short_conv_fwd": 0, "short_conv_bwd": 0},
                  13_686_000_000, id="lfm2-reference-float32-in-pieces",
+                 marks=pytest.mark.slow),
+    pytest.param(_QWEN, "program", dict(zip(_QWEN_KERNELS, (3, 6, 6, 6, 3))),
+                 11_970_000_000, id="qwen3-next-program-B1-T8192-three-gdn-one-attention"),
+    pytest.param(_QWEN, "reference", dict.fromkeys(_QWEN_KERNELS, 0),
+                 15_766_000_000, id="qwen3-next-reference-float32-in-pieces",
                  marks=pytest.mark.slow)])
 def test_a_decoder_cells_step_fits_a_v5e(one_chip, monkeypatch, cell_name, which,
                                          kernel_calls, budget):
@@ -430,12 +494,16 @@ def test_a_decoder_cells_step_fits_a_v5e(one_chip, monkeypatch, cell_name, which
     of 128, the forward not run again because its output and logsumexp are
     kept.  LFM2: the same three of two attention layers at heads of 64, and of
     six short-convolution layers the forward kernel twice (the recomputed
-    block runs it again) and the backward kernel once."""
+    block runs it again) and the backward kernel once.  Qwen3-Next: the three
+    flash kernels of one attention layer at heads of 256, and of three
+    gated-delta-rule layers the stage's and the walk's forward kernels twice
+    (the delta rule's output is kept; a group of heads under its own checkpoint
+    runs them again before its backward) and their backward kernels once."""
     import sys
     sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     from chipbench import manifest, optimizers, seeded
 
-    for module in ("flash_attention", "causal_conv"):
+    for module in ("flash_attention", "causal_conv", "gdn"):
         monkeypatch.setattr(importlib.import_module(f"bluefog_tpu.kernels.{module}"),
                             "_default_interpret", lambda: False)
     cell = manifest.resolve(cell_name)
